@@ -19,8 +19,6 @@ def _profile_model(which: str, trace_dir: str):
     import jax
     import numpy as np
 
-    from bench import bench_loop, gpt2_loop  # reuse exact bench setup
-
     import jax.numpy as jnp
     from ray_tpu.parallel.mesh import MeshSpec
     from ray_tpu.train.spmd import (make_causal_lm_trainer,
@@ -59,18 +57,18 @@ def _profile_model(which: str, trace_dir: str):
     step = trainer.step.lower(state, resident).compile()
     for _ in range(3):
         state, metrics = step(state, resident)
-    float(jax.device_get(metrics["loss"]))
+    jax.block_until_ready(metrics["loss"])
 
     run_dir = os.path.join(trace_dir, which)
     with jax.profiler.trace(run_dir):
         for _ in range(5):
             state, metrics = step(state, resident)
-        float(jax.device_get(metrics["loss"]))
+        jax.block_until_ready(metrics["loss"])
 
     t0 = time.perf_counter()
     for _ in range(10):
         state, metrics = step(state, resident)
-    float(jax.device_get(metrics["loss"]))
+    jax.block_until_ready(metrics["loss"])
     dt = (time.perf_counter() - t0) / 10
     return run_dir, dt
 

@@ -5,10 +5,7 @@ import logging
 import os
 
 from ray_tpu._private.gcs import GcsServer
-from ray_tpu._private.node import restore_tpu_plugin_env
 from ray_tpu.common.config import SystemConfig
-
-restore_tpu_plugin_env()
 
 
 async def main():

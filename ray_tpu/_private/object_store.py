@@ -28,14 +28,8 @@ _LIB = None
 def _lib() -> ctypes.CDLL:
     global _LIB
     if _LIB is None:
-        path = os.path.join(os.path.dirname(__file__), "..", "core", "libplasmax.so")
-        path = os.path.abspath(path)
-        src = os.path.abspath(os.path.join(
-            os.path.dirname(path), "..", "..", "src", "plasmax", "store.cc"))
-        if not os.path.exists(path) or (
-                os.path.exists(src)
-                and os.path.getmtime(src) > os.path.getmtime(path)):
-            _build_lib(path)
+        from ray_tpu._private.native_build import ensure_built
+        path = ensure_built("libplasmax.so", "plasmax/store.cc", "-lpthread")
         lib = ctypes.CDLL(path)
         lib.px_segment_size.restype = ctypes.c_uint64
         lib.px_segment_size.argtypes = [ctypes.c_uint64, ctypes.c_uint32]
@@ -69,17 +63,6 @@ def _lib() -> ctypes.CDLL:
         lib.px_stats.argtypes = [ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64)]
         _LIB = lib
     return _LIB
-
-
-def _build_lib(out_path: str):
-    """Build libplasmax.so from source on first use (source ships in src/)."""
-    import subprocess
-    src = os.path.join(os.path.dirname(out_path), "..", "..", "src", "plasmax",
-                       "store.cc")
-    src = os.path.abspath(src)
-    os.makedirs(os.path.dirname(out_path), exist_ok=True)
-    subprocess.check_call(
-        ["g++", "-O2", "-fPIC", "-shared", "-o", out_path, src, "-lpthread"])
 
 
 DEFAULT_NSLOTS = 1 << 16

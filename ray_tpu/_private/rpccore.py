@@ -15,7 +15,6 @@ from __future__ import annotations
 import ctypes
 import logging
 import os
-import tempfile
 import threading
 from typing import List, Optional, Tuple
 
@@ -55,25 +54,13 @@ def _lib():
         if _LIB is not None or _LIB_FAILED:
             return _LIB
         try:
-            path = os.path.abspath(os.path.join(
-                os.path.dirname(__file__), "..", "core", "librpcx.so"))
-            src = os.path.abspath(os.path.join(
-                os.path.dirname(path), "..", "..", "src", "rpccore",
-                "rpcx.cc"))
-            if not os.path.exists(path) or (
-                    os.path.exists(src)
-                    and os.path.getmtime(src) > os.path.getmtime(path)):
-                _build(src, path)
-            lib = ctypes.CDLL(path)
+            from ray_tpu._private.native_build import ensure_built
+            lib = ctypes.CDLL(
+                ensure_built("librpcx.so", "rpccore/rpcx.cc", "-lpthread"))
             lib.rpcx_abi_version.restype = ctypes.c_int
             if lib.rpcx_abi_version() != _ABI:
-                # stale binary from an older source tree (mtime can lie
-                # across checkouts): rebuild once, then give up
-                _build(src, path)
-                lib = ctypes.CDLL(path)
-                if lib.rpcx_abi_version() != _ABI:
-                    raise RuntimeError(
-                        f"librpcx ABI {lib.rpcx_abi_version()} != {_ABI}")
+                raise RuntimeError(
+                    f"librpcx ABI {lib.rpcx_abi_version()} != {_ABI}")
             lib.rpcx_create.restype = ctypes.c_void_p
             lib.rpcx_listen.restype = ctypes.c_int
             lib.rpcx_listen.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
@@ -110,20 +97,6 @@ def _lib():
             _LIB_FAILED = True
             _LIB = None
     return _LIB
-
-
-def _build(src: str, out_path: str):
-    import subprocess
-    os.makedirs(os.path.dirname(out_path), exist_ok=True)
-    fd, tmp = tempfile.mkstemp(suffix=".so", dir=os.path.dirname(out_path))
-    os.close(fd)
-    try:
-        subprocess.check_call(
-            ["g++", "-O2", "-fPIC", "-shared", "-o", tmp, src, "-lpthread"])
-        os.replace(tmp, out_path)
-    finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
 
 
 def is_tcp_address(address: str) -> bool:

@@ -29,7 +29,6 @@ from __future__ import annotations
 import ctypes
 import logging
 import os
-import tempfile
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
@@ -57,16 +56,9 @@ def _lib():
     if _LIB is not None or _LIB_FAILED:
         return _LIB
     try:
-        path = os.path.abspath(os.path.join(
-            os.path.dirname(__file__), "..", "core", "libschedcore.so"))
-        src = os.path.abspath(os.path.join(
-            os.path.dirname(path), "..", "..", "src", "schedcore",
-            "schedcore.cc"))
-        if not os.path.exists(path) or (
-                os.path.exists(src)
-                and os.path.getmtime(src) > os.path.getmtime(path)):
-            _build(src, path)
-        lib = ctypes.CDLL(path)
+        from ray_tpu._private.native_build import ensure_built
+        lib = ctypes.CDLL(
+            ensure_built("libschedcore.so", "schedcore/schedcore.cc"))
         lib.scx_create.restype = ctypes.c_void_p
         lib.scx_destroy.argtypes = [ctypes.c_void_p]
         lib.scx_set_tpu_res.argtypes = [ctypes.c_void_p, ctypes.c_int]
@@ -125,23 +117,6 @@ def _lib():
         _LIB_FAILED = True
         _LIB = None
     return _LIB
-
-
-def _build(src: str, out_path: str):
-    import subprocess
-    os.makedirs(os.path.dirname(out_path), exist_ok=True)
-    # build to a temp path + atomic rename: many raylet processes may
-    # race to build on a fresh checkout
-    fd, tmp = tempfile.mkstemp(suffix=".so",
-                               dir=os.path.dirname(out_path))
-    os.close(fd)
-    try:
-        subprocess.check_call(
-            ["g++", "-O2", "-fPIC", "-shared", "-o", tmp, src])
-        os.replace(tmp, out_path)
-    finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
 
 
 class PendingTask:

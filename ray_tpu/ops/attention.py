@@ -17,7 +17,9 @@ Shapes: [batch, heads, seq, head_dim]; block sizes default 128 (MXU tile).
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 from typing import Optional
 
 import jax
@@ -622,6 +624,23 @@ def _flash_bwd_rule(sm_scale, causal, block_q, block_k, interpret, exact,
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+_TRACE_MESH = threading.local()
+
+
+@contextlib.contextmanager
+def attention_mesh(mesh):
+    """Name the mesh a step is being traced for. ``flash_attention``
+    has no other way to learn it (tracers under jit carry no sharding),
+    and needs it to wrap its Pallas calls in ``shard_map``; the SPMD
+    trainers (train/spmd.py) enter this around the model's trace."""
+    prev = getattr(_TRACE_MESH, "mesh", None)
+    _TRACE_MESH.mesh = mesh
+    try:
+        yield
+    finally:
+        _TRACE_MESH.mesh = prev
+
+
 def flash_attention(q, k, v, *, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
@@ -634,6 +653,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
     On TPU runs the Pallas kernel; elsewhere falls back to the XLA reference
     (still fused reasonably by XLA on CPU for tests).
+
+    Traced under ``attention_mesh(mesh)`` for a mesh of more than one
+    device, the kernel runs inside ``shard_map`` on each device's batch
+    and head shard (GSPMD cannot partition a Mosaic kernel).
 
     ``exact`` picks the softmax numerics explicitly: ``True`` forces
     the streaming flash kernels (exact running-max softmax — use for
@@ -673,15 +696,32 @@ def flash_attention(q, k, v, *, causal: bool = False,
             block_q, block_k = bq, bk
     if not use and not interpret:
         return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-    # Fold the softmax scale into q OUTSIDE the kernel (one [b,h,s,d]
-    # multiply, and autodiff routes the matching dq scale through it) so
-    # the kernels skip a full [bq, block_k] multiply per kv block.
-    q = (q * sm_scale).astype(q.dtype)
-    if (debug if debug is not None else _attn_debug()) and \
-            _use_whole_kv(sq, sk, q.shape[3], exact):
-        _debug_check_logits(q, k)
-    return _flash_attention(q, k, v, 1.0, causal, block_q, block_k,
-                            interpret, exact)
+
+    def local(q, k, v):
+        # Fold the softmax scale into q OUTSIDE the kernel (one [b,h,s,d]
+        # multiply, and autodiff routes the matching dq scale through it)
+        # so the kernels skip a full [bq, block_k] multiply per kv block.
+        q = (q * sm_scale).astype(q.dtype)
+        if (debug if debug is not None else _attn_debug()) and \
+                _use_whole_kv(sq, sk, q.shape[3], exact):
+            _debug_check_logits(q, k)
+        return _flash_attention(q, k, v, 1.0, causal, block_q, block_k,
+                                interpret, exact)
+
+    mesh = getattr(_TRACE_MESH, "mesh", None)
+    if mesh is not None and mesh.size > 1:
+        # Mosaic kernels cannot be partitioned by GSPMD: on a multi-
+        # device mesh each device runs the kernel on its own batch and
+        # head shard (attention mixes neither), the sequence whole.
+        from jax.sharding import PartitionSpec as P
+
+        from ray_tpu.parallel.jax_compat import shard_map
+        batch = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names)
+        head = "tp" if "tp" in mesh.axis_names else None
+        spec = P(batch or None, head, None, None)
+        local = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                          out_specs=spec, check_vma=False)
+    return local(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -802,19 +842,23 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables,
 
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                          m_ref, l_ref, acc_ref, *, block_size,
-                         num_blocks):
-    """One (sequence, kv-head, page) grid step of paged flash decode.
+                         num_blocks, head_dim):
+    """One (sequence, page) grid step of paged flash decode, all kv
+    heads of the page at once.
 
     The page refs were DMA'd by the scalar-prefetched index map (the
-    block table picks the physical page per grid step), so the body is
-    plain flash: one [G, bs] dot, online softmax, [G, D] accumulate.
-    Fully-masked pages (past the sequence length) contribute zero
-    because masked logits are a large-but-finite negative, never -inf.
+    block table picks the physical page per grid step) as one dense
+    [bs, Hkv*D] tile; each kv head is a static lane slice of it, and
+    its body is plain flash: one [G, bs] dot, online softmax, [G, D]
+    accumulate. Fully-masked pages (past the sequence length)
+    contribute zero because masked logits are a large-but-finite
+    negative, never -inf.
     """
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
+    D = head_dim
 
     @pl.when(j == 0)
     def _():
@@ -822,23 +866,24 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[:]                                  # [G, D]
-    k = k_ref[:]                                  # [bs, D]
-    v = v_ref[:]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    pos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
-    s = jnp.where(pos < len_ref[b], s, _NEG_INF)
-    m_prev, l_prev = m_ref[:], l_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[:], l_ref[:] = m_new, l_new
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    for h in range(q_ref.shape[0]):               # static: Hkv heads
+        q = q_ref[h]                              # [G, D]
+        k = k_ref[:, h * D:(h + 1) * D]           # [bs, D]
+        v = v_ref[:, h * D:(h + 1) * D]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        pos = j * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        s = jnp.where(pos < len_ref[b], s, _NEG_INF)
+        m_prev, l_prev = m_ref[h], l_ref[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[h] = m_new
+        l_ref[h] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(j == num_blocks - 1)
     def _():
@@ -852,11 +897,15 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
     """Pallas paged-attention decode: q [B, H, D] against block-table-
     addressed pages, without gathering the cache into contiguous HBM.
 
-    Grid (B, Hkv, NB); the block table + lengths ride scalar prefetch
-    so each grid step's BlockSpec index map DMAs exactly the page it
-    needs (pallas_guide: PrefetchScalarGridSpec). Off-TPU (and not
-    ``interpret``) this falls back to the gather reference — numerics
-    are identical (gated in tests), so callers never branch.
+    Grid (B, NB); the block table + lengths ride scalar prefetch so
+    each grid step's BlockSpec index map DMAs exactly the page it
+    needs (pallas_guide: PrefetchScalarGridSpec). A page is viewed as
+    [bs, Hkv*D] (a free reshape of the pool), so the k/v block equals
+    the trailing array dims — the TPU lowering refuses a block that
+    squeezes the second-to-last dim, which a per-head page block would.
+    Off-TPU (and not ``interpret``) this falls back to the gather
+    reference — numerics are identical (gated in tests), so callers
+    never branch.
 
     GQA note: the G = H // Hkv query heads of one kv head form the
     kernel's [G, D] q block; small G under-fills TPU sublanes — pad
@@ -879,24 +928,24 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
         sm_scale = D ** -0.5
     qf = (q * sm_scale).astype(q.dtype).reshape(B, Hkv, G, D)
     kernel = functools.partial(_paged_decode_kernel, block_size=bs,
-                               num_blocks=NB)
+                               num_blocks=NB, head_dim=D)
+    page_spec = pl.BlockSpec((None, bs, Hkv * D),
+                             lambda b, j, bt, ln: (bt[b, j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, NB),
+        grid=(B, NB),
         in_specs=[
-            pl.BlockSpec((None, None, G, D),
-                         lambda b, h, j, bt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((None, bs, None, D),
-                         lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0)),
-            pl.BlockSpec((None, bs, None, D),
-                         lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0)),
+            pl.BlockSpec((None, Hkv, G, D),
+                         lambda b, j, bt, ln: (b, 0, 0, 0)),
+            page_spec,
+            page_spec,
         ],
-        out_specs=pl.BlockSpec((None, None, G, D),
-                               lambda b, h, j, bt, ln: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((None, Hkv, G, D),
+                               lambda b, j, bt, ln: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -905,7 +954,8 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      qf, k_pages, v_pages)
+      qf, k_pages.reshape(P, bs, Hkv * D),
+      v_pages.reshape(P, bs, Hkv * D))
     return out.reshape(B, H, D)
 
 

@@ -1,30 +1,14 @@
-"""Version compat for jax APIs that moved between releases.
-
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the
-top-level ``jax`` namespace, renaming ``check_rep`` -> ``check_vma`` and
-replacing the ``auto`` (complement) axis set with an explicit
-``axis_names`` (manual) set. Callers here use the new-style spelling;
-this wrapper translates for older jax.
+"""One spelling of ``jax.shard_map`` for the package: keyword-only, with
+``axis_names`` (the manual axes) and ``check_vma`` passed only when set.
 """
 
 from __future__ import annotations
 
+from jax import shard_map as _sm
+
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma=None):
-    try:
-        from jax import shard_map as _sm  # jax >= 0.6
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        kw = {}
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            if auto:
-                kw["auto"] = auto
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   **kw)
     kw = {}
     if check_vma is not None:
         kw["check_vma"] = check_vma

@@ -93,18 +93,13 @@ class MeshSpec:
 def _topology_aware_reshape(devices: List, shape: Tuple[int, ...]) -> np.ndarray:
     """Order devices so innermost mesh axes are ICI-adjacent.
 
-    On TPU, jax device ids are assigned so that consecutive ids are
-    physically adjacent within a tray; jax.experimental.mesh_utils does the
-    full topology-aware assignment for pod slices — use it when available and
-    fall back to id-order otherwise (CPU meshes in tests don't care).
+    On TPU jax.experimental.mesh_utils does the topology-aware assignment
+    (and raises if it cannot: a mesh in id order on a real slice would be
+    silently slow); elsewhere id order (CPU meshes in tests don't care).
     """
-    try:
+    if getattr(devices[0], "platform", "") == "tpu" and len(devices) > 1:
         from jax.experimental import mesh_utils
-        plat = getattr(devices[0], "platform", "")
-        if plat == "tpu" and len(devices) > 1:
-            return mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        pass
+        return mesh_utils.create_device_mesh(shape, devices=devices)
     ordered = sorted(devices, key=lambda d: (getattr(d, "process_index", 0),
                                              d.id))
     return np.array(ordered).reshape(shape)

@@ -267,6 +267,7 @@ class LLMServer:
     def __llm_metrics__(self):
         m = self.engine.metrics()
         m["token_ledger"] = self.engine.token_ledger()
+        m["device"] = self.adapter.device_info()
         return m
 
     # ------------------------------------------------- serve integration

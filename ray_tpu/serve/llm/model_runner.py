@@ -33,9 +33,10 @@ Two implementations:
 * ``FlaxModelAdapter`` — wraps ``models/gpt2.py`` / ``models/llama.py``
   incremental-decode paths: bucketed (batch, length) jit shapes, paged
   caches threaded through ``ops.attention.cached_attention``, padding
-  rows parked on the null page. On TPU the single-token decode rides
-  the ``paged_attention_decode`` Pallas kernel via the shared cached
-  paths; on CPU the gather reference keeps numerics identical.
+  rows parked on the null page. Prefill and decode alike go
+  ``paged_gather`` + ``decode_attention`` (XLA) on every platform; the
+  ``paged_attention_decode`` Pallas kernel compiles for the chip and is
+  tested against that reference, but is not on this path (ROADMAP S2).
   ``decode_window`` reuses the same paged path — the multi-token
   incremental step is causal at the right offsets by construction
   (``q_positions = seq_lengths[:, None] + arange(S)``), so batched
@@ -89,6 +90,9 @@ class ToyAdapter:
             (cache.num_blocks, cache.block_size, self.dim), np.float32)
         # seq id -> {"table": np.ndarray pages, "len": cached tokens}
         self._state: Dict[str, Dict[str, Any]] = {}
+
+    def device_info(self) -> Dict[str, Any]:
+        return {"platform": "host", "device_kind": "numpy"}
 
     def copy_page(self, src: int, dst: int):
         self.pages[dst] = self.pages[src]
@@ -250,6 +254,16 @@ class FlaxModelAdapter:
     def n_layers(self) -> int:
         return getattr(self.cfg, "n_layer",
                        getattr(self.cfg, "n_layers", 0))
+
+    def device_info(self) -> Dict[str, Any]:
+        """The device that holds ``params`` (what ``__llm_metrics__``
+        reports: a replica that was granted no chip serves from the CPU
+        and must say so)."""
+        import jax
+        dev = next(iter(jax.tree_util.tree_leaves(self.params)[0].devices()))
+        stats = dev.memory_stats() or {}
+        return {"platform": dev.platform, "device_kind": dev.device_kind,
+                "peak_bytes_in_use": stats.get("peak_bytes_in_use")}
 
     def bind_cache(self, cache):
         jnp = self._jnp

@@ -31,7 +31,8 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ray_tpu.parallel.mesh import (MeshSpec, param_sharding)
+from ray_tpu.ops.attention import attention_mesh
+from ray_tpu.parallel.mesh import MeshSpec, param_sharding
 
 
 def state_shardings(abstract_state, mesh, spec: MeshSpec, override=None):
@@ -145,7 +146,8 @@ def make_causal_lm_trainer(
                                      deterministic=True)
             return causal_lm_loss(logits, batch["labels"])
 
-        loss, grads = jax.value_and_grad(loss_fn)(state["params"])
+        with attention_mesh(mesh):
+            loss, grads = jax.value_and_grad(loss_fn)(state["params"])
         updates, opt = tx.update(grads, state["opt"], state["params"])
         params = optax.apply_updates(state["params"], updates)
         gnorm = optax.global_norm(grads)

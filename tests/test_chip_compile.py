@@ -1,0 +1,126 @@
+"""The main path's kernels compile for the chip, at real widths.
+
+The TPU's compiler is installed and compiles for a v5e that is described,
+not attached (on-chip-measurement guide, section 2). Interpret mode never
+sees what it refuses: tiling, VMEM, Mosaic's partitioning rule. Nothing
+runs here, so these tests say nothing about results or times.
+
+This is the only file that describes the topology, and it does so inside
+a module-scoped fixture: only one process may load libtpu, so the call
+must not happen at import, in a ``skipif``, in ``parametrize`` arguments
+or in ``conftest.py``, and no test here may compile in a child process.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from ray_tpu.ops import attention as A
+from ray_tpu.parallel.mesh import MeshSpec
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without one: keep it off for this module
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args):
+    # conftest.py pins f32-exact matmuls for the CPU numerics tests; the
+    # chip path compiles at the default precision, as production does
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+def _qkv(shape, sharding):
+    return (jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding),) * 3
+
+
+def _flash(exact, grad):
+    def fwd(q, k, v):
+        return A.flash_attention(q, k, v, causal=True, force_pallas=True,
+                                 exact=exact)
+    if not grad:
+        return fwd
+    return jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("exact", [False, True],
+                         ids=["whole-kv", "streaming"])
+def test_flash_attention_gpt2_small_shape(one_chip, exact, grad):
+    """[16, 12, 1024, 64] bf16 causal: the GPT-2 small train step's
+    attention, on both sides of ``_use_whole_kv``."""
+    assert A._use_whole_kv(1024, 1024, 64, exact) is (not exact)
+    _compile(_flash(exact, grad), *_qkv((16, 12, 1024, 64), one_chip))
+
+
+def test_flash_attention_streaming_long_wide(one_chip):
+    """[1, 32, 4096, 128]: past the whole-kv limit, head dim 128."""
+    assert not A._use_whole_kv(4096, 4096, 128, None)
+    _compile(_flash(None, False), *_qkv((1, 32, 4096, 128), one_chip))
+
+
+def _mesh4(topo):
+    spec = MeshSpec(dp=2, tp=2)
+    mesh = spec.build(list(topo.devices))
+    return mesh, NamedSharding(mesh, P(("dp", "fsdp"), "tp", None, None))
+
+
+def test_flash_attention_shard_mapped_on_dp2_tp2(topo):
+    """Under ``attention_mesh`` the kernel runs per (batch, head) shard
+    inside ``shard_map``: forward and backward compile for four chips."""
+    mesh, sharding = _mesh4(topo)
+
+    def loss(q, k, v):
+        with A.attention_mesh(mesh):
+            return A.flash_attention(q, k, v, causal=True,
+                                     force_pallas=True).astype(
+                                         jnp.float32).sum()
+    _compile(jax.grad(loss, argnums=(0, 1, 2)),
+             *_qkv((32, 12, 1024, 64), sharding))
+
+
+def test_bare_pallas_call_is_refused_on_a_mesh(topo):
+    """The rule the wrap exists for: GSPMD cannot partition a Mosaic
+    kernel, so without the mesh context the same program is refused."""
+    _, sharding = _mesh4(topo)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _compile(_flash(None, False), *_qkv((32, 12, 1024, 64), sharding))
+
+
+@pytest.mark.parametrize("H,Hkv,D", [(12, 12, 64), (32, 8, 128)],
+                         ids=["gpt2-small", "llama-gqa"])
+def test_paged_attention_decode(one_chip, H, Hkv, D):
+    """B=8 sequences against 16-token pages, 64 pages each."""
+    B, bs, NB = 8, 16, 64
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    pages = sds((1 + B * NB, bs, Hkv, D), jnp.bfloat16)
+    _compile(lambda *a: A.paged_attention_decode(*a, interpret=False),
+             sds((B, H, D), jnp.bfloat16), pages, pages,
+             sds((B, NB), jnp.int32), sds((B,), jnp.int32))

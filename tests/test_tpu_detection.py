@@ -7,6 +7,8 @@ autodetection tests.
 import os
 from unittest import mock
 
+import pytest
+
 from ray_tpu._private.raylet import (_chips_from_accel_type,
                                      detect_tpu_chips)
 from ray_tpu.common.config import SystemConfig
@@ -68,3 +70,43 @@ def test_accel_type_env_fallback():
             os.environ.pop(k, None)
         with mock.patch("os.path.isdir", return_value=False):
             assert detect_tpu_chips(_cfg()) == 4
+
+
+def _listing(files):
+    def listdir(path):
+        if path in files:
+            return files[path]
+        raise FileNotFoundError(path)
+    return mock.patch("os.listdir", side_effect=listdir)
+
+
+@pytest.mark.parametrize("dev,want", [
+    # a v5e host: one numbered file per chip beside the control node; the
+    # /dev/vfio directory itself is not a chip
+    ({"/dev": ["null", "vfio"], "/dev/vfio": ["3", "vfio"]}, 1),
+    ({"/dev": ["null", "vfio"],
+      "/dev/vfio": ["0", "1", "2", "3", "vfio"]}, 4),
+    ({"/dev": ["accel0", "accel1", "accelerometer", "vfio"],
+      "/dev/vfio": ["vfio"]}, 2),
+    ({"/dev": ["null"]}, 0),
+], ids=["vfio-1", "vfio-4", "accel-2", "none"])
+def test_device_files_count_chips(dev, want):
+    env = {"TPU_SKIP_MDS_QUERY": "1"}
+    with mock.patch.dict(os.environ, env), _listing(dev):
+        for k in ("RTPU_NUM_TPUS", "TPU_VISIBLE_CHIPS",
+                  "TPU_VISIBLE_DEVICES", "TPU_ACCELERATOR_TYPE",
+                  "JAX_PLATFORMS"):
+            os.environ.pop(k, None)
+        assert detect_tpu_chips(_cfg()) == want
+
+
+def test_declared_topology_caps_device_files():
+    env = {"TPU_ACCELERATOR_TYPE": "v5litepod-4",
+           "TPU_SKIP_MDS_QUERY": "1"}
+    dev = {"/dev": ["vfio"],
+           "/dev/vfio": [str(i) for i in range(8)] + ["vfio"]}
+    with mock.patch.dict(os.environ, env), _listing(dev):
+        for k in ("RTPU_NUM_TPUS", "TPU_VISIBLE_CHIPS",
+                  "TPU_VISIBLE_DEVICES"):
+            os.environ.pop(k, None)
+        assert detect_tpu_chips(_cfg()) == 4
