@@ -45,15 +45,25 @@ overlapping parent/child spans never double-count and uncovered gaps
 fall to the nearest enclosing span's phase. ``aggregate_critical_path``
 sums the same attribution across a cohort (e.g. a game day's p99
 requests).
+
+Step spans (``step_span``) are the other half: work that belongs to an
+engine step, a train step or a feed, not to one request. They are not
+sampled and not shipped anywhere: each is a
+``jax.profiler.TraceAnnotation`` (so it lies on the device trace's clock
+whenever a profiler session is open) and a host-clock record hung under
+the span open on the same thread; finished root spans land in a bounded
+ring their owner reads (docs/TRACING.md, "Step spans").
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 import zlib
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from collections import deque
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 PHASES = ("queue", "schedule", "dispatch", "transfer", "execute",
           "deserialize", "submit", "other")
@@ -345,6 +355,81 @@ def stop_flusher() -> None:
         _flusher_stop.set()
     _flusher_stop = None
     _flusher_started = False
+
+
+# -------------------------------------------------------- step spans
+
+STEP_RING = 4096
+
+_step_tls = threading.local()
+_step_roots: Deque[Dict[str, Any]] = deque(maxlen=STEP_RING)
+
+
+class step_span:
+    """A span of step-level work: ``with step_span("llm.step", i=3):``.
+
+    (a) Enters ``jax.profiler.TraceAnnotation(name, **attrs)`` when jax
+    is already imported in this process (it is never imported from here:
+    a driver stays off the backend), so the span lies on the device
+    trace's clock while a profiler session is open and costs next to
+    nothing when none is. (b) Reads the host clock twice and hangs the
+    finished span ``{name, t0, t1, attrs, children}`` under the span open
+    on the same thread. (c) A finished root span goes to ``ring`` (the
+    owner's bounded deque; the module's own when none is given, read
+    with ``step_roots``). ``RTPU_TRACING=0`` turns (b) and (c) off.
+
+    ``set(**attrs)`` adds what is known only when the work is done (how
+    many were admitted, how many bytes came back); the profiler's
+    annotation carries the attributes given at entry. Never keep one open
+    across a generator's ``yield``: the stack of open spans is the
+    thread's."""
+
+    __slots__ = ("name", "attrs", "ring", "rec", "_ann")
+
+    def __init__(self, name: str, ring: Optional[Deque] = None, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.ring = ring
+        self.rec: Optional[Dict[str, Any]] = None
+        self._ann = None
+
+    def set(self, **attrs) -> None:
+        if self.rec is not None:
+            self.rec["attrs"].update(attrs)
+
+    def __enter__(self) -> "step_span":
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._ann = jax.profiler.TraceAnnotation(self.name, **self.attrs)
+            self._ann.__enter__()
+        if enabled():
+            stack = getattr(_step_tls, "stack", None)
+            if stack is None:
+                stack = _step_tls.stack = []
+            self.rec = {"name": self.name, "t0": time.time(), "t1": None,
+                        "attrs": self.attrs, "children": []}
+            stack.append(self.rec)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        rec = self.rec
+        if rec is not None:
+            rec["t1"] = time.time()
+            stack = _step_tls.stack
+            stack.pop()
+            if stack:
+                stack[-1]["children"].append(rec)
+            else:
+                (_step_roots if self.ring is None else self.ring).append(rec)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+
+
+def step_roots(name: Optional[str] = None) -> List[Dict[str, Any]]:
+    """Finished root step spans of this process that were given no ring
+    of their own (the feed's), oldest first."""
+    return [r for r in list(_step_roots)
+            if name is None or r["name"] == name]
 
 
 # ------------------------------------------------- task-span synthesis

@@ -150,8 +150,13 @@ def compile_cache_env(env: Dict[str, str]) -> None:
     ops, and by default those hold the Python call stack of whoever
     traced it: the same step compiled from two call sites then has two
     keys and the cache never hits (measured on the v5e: 40 s again in a
-    second process). Locations keep the innermost frame only."""
+    second process). Locations keep the innermost frame only: by the
+    limit on their depth, not by
+    ``JAX_INCLUDE_FULL_TRACEBACKS_IN_LOCATIONS=false``, which also cuts
+    every operation's ``op_name`` down to its primitive and so takes the
+    ``jax.named_scope`` paths out of the compiled program and of every
+    device trace (docs/TRACING.md, "Names on the device trace")."""
     env.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(
         os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__)))), ".cache", "jax"))
-    env.setdefault("JAX_INCLUDE_FULL_TRACEBACKS_IN_LOCATIONS", "false")
+    env.setdefault("JAX_TRACEBACK_IN_LOCATIONS_LIMIT", "1")

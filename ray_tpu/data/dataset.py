@@ -19,6 +19,7 @@ from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
 
 import numpy as np
 
+from ray_tpu._private import tracing
 from ray_tpu.data.block import Block, BlockAccessor, BlockMetadata, VALUE_COL
 from ray_tpu.data._internal.plan import (AllToAllStage, ExecutionPlan,
                                          OneToOneStage, get_metadata)
@@ -510,12 +511,27 @@ class Dataset:
             return (jax.device_put(batch, sharding) if sharding is not None
                     else jax.device_put(batch))
 
-        it = self.iter_batches(batch_size=batch_size, batch_format="numpy",
-                               drop_last=drop_last,
-                               pad_to_batch=pad_to_batch, **kw)
+        def _size(batch):
+            cols = list(batch.values()) if isinstance(batch, dict) else [batch]
+            return len(cols[0]), sum(np.asarray(c).nbytes for c in cols)
+
+        it = iter(self.iter_batches(batch_size=batch_size,
+                                    batch_format="numpy",
+                                    drop_last=drop_last,
+                                    pad_to_batch=pad_to_batch, **kw))
         prev = None
-        for batch in it:
-            cur = _put(batch)
+        while True:
+            # step spans (docs/TRACING.md) are closed before each yield:
+            # the stack of open spans belongs to the consumer's thread
+            with tracing.step_span("data.feed.host_batch") as span:
+                batch = next(it, None)
+                if batch is not None:
+                    rows, nbytes = _size(batch)
+                    span.set(rows=rows, bytes=nbytes)
+            if batch is None:
+                break
+            with tracing.step_span("data.feed.device_put", bytes=nbytes):
+                cur = _put(batch)
             if prev is not None:
                 yield prev
             prev = cur

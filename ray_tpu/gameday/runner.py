@@ -571,7 +571,8 @@ def run_scenario(scenario: Scenario, *, scale: float = 1.0,
                 if st:
                     llm_metrics[hex_id] = {
                         k: v for k, v in st.items()
-                        if k != "token_ledger"}
+                        if k not in ("token_ledger", "step_log",
+                                     "request_log")}
                     llm_ledgers.append(
                         {"replica": hex_id,
                          "records": st.get("token_ledger") or []})

@@ -175,7 +175,8 @@ class GPT2(nn.Module):
                 x = Block(cfg, name=f"h_{i}")(x, deterministic)
         x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
         # weight-tied LM head
-        logits = wte.attend(x.astype(jnp.float32))
+        with jax.named_scope("lm_head"):
+            logits = wte.attend(x.astype(jnp.float32))
         return (logits, new_caches) if incremental else logits
 
 
@@ -190,6 +191,7 @@ def init_kv_cache(cfg: GPT2Config, batch_size: int, max_len: int):
             for _ in range(cfg.n_layer)]
 
 
+@jax.named_scope("loss")
 def causal_lm_loss(logits, labels, ignore_index: int = -100):
     """Next-token cross entropy; labels == input_ids shifted by the caller
     or equal to input_ids (then shifting happens here).

@@ -233,7 +233,7 @@ def _whole_forward(q, k, v, causal, interpret=False):
     vf = v.reshape(b * h, sk, d)
     kernel = functools.partial(_whole_fwd_kernel, causal=causal,
                                block_q=bq, head_dim=d)
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(b * h, sq // bq),
         in_specs=[
@@ -250,7 +250,10 @@ def _whole_forward(q, k, v, causal, interpret=False):
             jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(qf, kf, vf)
+        name="flash_fwd",
+    )
+    with jax.named_scope("flash_fwd"):
+        out, lse = call(qf, kf, vf)
     return out.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 
 
@@ -271,7 +274,7 @@ def _whole_backward(res, g, *, causal, interpret=False):
     deltaf = delta.reshape(b * h, sq, 1)
     kernel = functools.partial(_whole_bwd_kernel, causal=causal,
                                block_q=bq)
-    dq, dk, dv = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(b * h, sq // bq),
         in_specs=[
@@ -293,7 +296,10 @@ def _whole_backward(res, g, *, causal, interpret=False):
             jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
         ],
         interpret=interpret,
-    )(qf, kf, vf, dof, lsef, deltaf)
+        name="flash_bwd",
+    )
+    with jax.named_scope("flash_bwd"):
+        dq, dk, dv = call(qf, kf, vf, dof, lsef, deltaf)
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
 
@@ -384,7 +390,7 @@ def _flash_forward(q, k, v, sm_scale, causal, block_q, block_k,
     vf = v.reshape(b * h, sk, d)
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                                block_k=bk, seq_k=sk)
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(b * h, sq // bq),
         in_specs=[
@@ -401,7 +407,10 @@ def _flash_forward(q, k, v, sm_scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(qf, kf, vf)
+        name="flash_fwd_blocked",
+    )
+    with jax.named_scope("flash_fwd_blocked"):
+        out, lse = call(qf, kf, vf)
     return out.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 
 
@@ -538,7 +547,7 @@ def _flash_backward(res, g, *, sm_scale, causal, block_q, block_k,
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
                                    causal=causal, block_q=bq, seq_q=sq)
-    dk, dv = pl.pallas_call(
+    call = pl.pallas_call(
         dkv_kernel,
         grid=(b * h, sk // bk),
         in_specs=[
@@ -558,11 +567,14 @@ def _flash_backward(res, g, *, sm_scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
         ],
         interpret=interpret,
-    )(qf, kf, vf, dof, lsef, deltaf)
+        name="flash_bwd_dkv",
+    )
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = call(qf, kf, vf, dof, lsef, deltaf)
 
     dq_kernel = functools.partial(_bwd_dq_kernel, sm_scale=sm_scale,
                                   causal=causal, block_k=bk, seq_k=sk)
-    dq = pl.pallas_call(
+    call = pl.pallas_call(
         dq_kernel,
         grid=(b * h, sq // bq),
         in_specs=[
@@ -576,7 +588,10 @@ def _flash_backward(res, g, *, sm_scale, causal, block_q, block_k,
         out_specs=pl.BlockSpec((None, bq, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         interpret=interpret,
-    )(qf, kf, vf, dof, lsef, deltaf)
+        name="flash_bwd_dq",
+    )
+    with jax.named_scope("flash_bwd_dq"):
+        dq = call(qf, kf, vf, dof, lsef, deltaf)
 
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
@@ -948,14 +963,17 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
             pltpu.VMEM((Hkv, G, D), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      qf, k_pages.reshape(P, bs, Hkv * D),
-      v_pages.reshape(P, bs, Hkv * D))
+        name="paged_attention_decode",
+    )
+    with jax.named_scope("paged_attention_decode"):
+        out = call(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+                   qf, k_pages.reshape(P, bs, Hkv * D),
+                   v_pages.reshape(P, bs, Hkv * D))
     return out.reshape(B, H, D)
 
 

@@ -9,7 +9,9 @@ shedding, ledgers, tracing, HA and drain all apply unchanged:
   ``__llm_open__(payload)``        start a stream -> {"stream_id"}
   ``__llm_next__(sid, cursor, w)`` cursor poll -> token delta
   ``__llm_cancel__(sid)``          abandon a stream
-  ``__llm_metrics__()``            engine metrics + token ledger
+  ``__llm_metrics__()``            engine metrics + token ledger +
+                                   step_log + request_log
+  ``__llm_profile__(dir, s)``      jax.profiler capture of a live replica
   ``__llm_prefill__(payload)``     disagg hop 1: prompt + first token,
                                    returns a KV handoff descriptor
   ``__llm_adopt__(handoff)``       disagg hop 2: rebind the shipped KV
@@ -267,8 +269,21 @@ class LLMServer:
     def __llm_metrics__(self):
         m = self.engine.metrics()
         m["token_ledger"] = self.engine.token_ledger()
+        m["step_log"] = self.engine.step_log()
+        m["request_log"] = self.engine.request_log()
         m["device"] = self.adapter.device_info()
         return m
+
+    def __llm_profile__(self, log_dir: str, seconds: float = 4.0):
+        """Profile ``seconds`` of whatever this replica is doing into
+        ``log_dir`` (a ``jax.profiler`` capture: the device's operations
+        with the engine's step spans on the same clock; docs/TRACING.md
+        says how to open it). Blocks for the duration."""
+        from ray_tpu.util import tpu_profiler
+        t0 = time.time()
+        with tpu_profiler.trace("llm", log_dir=log_dir) as d:
+            time.sleep(float(seconds))
+        return {"log_dir": d, "t0": t0, "t1": time.time()}
 
     # ------------------------------------------------- serve integration
 

@@ -56,6 +56,14 @@ call; on finish the engine records ``llm.queue`` / ``llm.kv_alloc`` /
 ``llm.kv_ship`` / ``llm.draft`` / ``llm.verify`` phase spans, so
 ``ray-tpu trace critical-path`` attributes time-to-first-token vs
 inter-token latency per request.
+
+Step spans (``tracing.step_span``, docs/TRACING.md): every engine step
+is an ``llm.step`` tree (``llm.step.decode`` / ``.admit`` / ``.prefill``
+/ ``.commit``, the adapter's ``runner.*`` spans below them) kept in a
+ring of 4096 (``step_log``), and every finished request leaves one
+record in ``request_log``. Neither is sampled or shipped; both are empty
+under ``RTPU_TRACING=0``. The cumulative ``*_total`` step counters in
+``metrics()`` are counted whatever the switch says.
 """
 
 from __future__ import annotations
@@ -69,6 +77,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+from ray_tpu._private import tracing
 from ray_tpu.serve.exceptions import ReplicaOverloadedError
 from ray_tpu.serve.llm.kv_cache import OutOfKVBlocksError, PagedKVCache
 
@@ -205,6 +214,17 @@ class LLMEngine:
         # (rid, n_tokens, finish_reason, n_prompt, n_cached) — the
         # server half of the game-day per-token reconciliation
         self._token_ledger = deque(maxlen=65536)
+        # step-level telemetry: finished llm.step trees, one record per
+        # finished request, and counters the engine thread alone writes
+        self._step_log: deque = deque(maxlen=tracing.STEP_RING)
+        self._request_log: deque = deque(maxlen=tracing.STEP_RING)
+        self._steps_total = 0
+        self._prefill_steps_total = 0
+        self._decode_rows_total = 0
+        self._prefill_seqs_total = 0
+        self._prefill_tokens_total = 0
+        self._step_seconds_total = 0.0
+        self._runner_seconds_total = 0.0
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="rtpu-llm-engine")
         self._thread.start()
@@ -516,6 +536,16 @@ class LLMEngine:
                 "ttft_p99_s": round(q(ttft, 0.99), 6),
                 "itl_p50_s": round(q(itl, 0.50), 6),
                 "itl_p99_s": round(q(itl, 0.99), 6),
+                "steps_total": self._steps_total,
+                "prefill_steps_total": self._prefill_steps_total,
+                "decode_rows_total": self._decode_rows_total,
+                "prefill_seqs_total": self._prefill_seqs_total,
+                "prefill_tokens_total": self._prefill_tokens_total,
+                "step_seconds_total": round(self._step_seconds_total, 6),
+                "runner_seconds_total": round(
+                    self._runner_seconds_total, 6),
+                "bucket_first_calls_total": int(getattr(
+                    self.adapter, "bucket_first_calls", 0)),
             }
         out.update(self.cache.stats())
         if self.prefix_cache is not None:
@@ -528,6 +558,18 @@ class LLMEngine:
         counts and prompt lengths by the game-day reconciler."""
         with self._lock:
             return [list(r) for r in self._token_ledger]
+
+    def step_log(self) -> List[Dict[str, Any]]:
+        """The last 4096 finished ``llm.step`` trees, oldest first: each
+        ``{name, t0, t1, attrs, children}`` on the host clock
+        (docs/TRACING.md)."""
+        return list(self._step_log)
+
+    def request_log(self) -> List[Dict[str, Any]]:
+        """One record per finished request (the last 4096): arrival,
+        admission, prefill start, first token and finish on the host
+        clock, and the token counts of the ledger."""
+        return list(self._request_log)
 
     # ------------------------------------------------------------ engine
 
@@ -619,22 +661,41 @@ class LLMEngine:
         """One engine step: decode every RUNNING sequence, then prefill
         this step's admissions (decode first — admission cost must
         never delay in-flight tokens)."""
-        with self._lock:
-            decode_seqs = [self._seqs[sid] for sid in self._running
-                           if sid in self._seqs]
-        if decode_seqs:
-            if self._draft is not None:
-                self._decode_spec(decode_seqs)
-            else:
-                self._decode(decode_seqs)
-        with self._lock:
-            admitted = self._admit_locked()
-        if admitted:
-            self._prefill(admitted)
+        t0 = time.time()
+        self._steps_total += 1
+        with tracing.step_span("llm.step", self._step_log,
+                               i=self._steps_total,
+                               running=len(self._running),
+                               waiting=len(self._waiting)):
+            with self._lock:
+                decode_seqs = [self._seqs[sid] for sid in self._running
+                               if sid in self._seqs]
+            if decode_seqs:
+                if self._draft is not None:
+                    self._decode_spec(decode_seqs)
+                else:
+                    self._decode(decode_seqs)
+            with tracing.step_span("llm.step.admit") as span:
+                with self._lock:
+                    admitted = self._admit_locked()
+                    waiting_left = len(self._waiting)
+                tokens = sum(len(s.prompt) - s.cached_tokens
+                             for s in admitted)
+                span.set(admitted=len(admitted), prefill_tokens=tokens,
+                         waiting_left=waiting_left)
+            if admitted:
+                self._prefill_steps_total += 1
+                self._prefill_seqs_total += len(admitted)
+                self._prefill_tokens_total += tokens
+                self._prefill(admitted, tokens)
+        self._step_seconds_total += time.time() - t0
 
     def _decode(self, seqs: List[Sequence]):
         t0 = time.time()
-        logits = self.adapter.decode(seqs)      # [B, V] np.ndarray
+        with tracing.step_span("llm.step.decode", n=len(seqs)):
+            logits = self.adapter.decode(seqs)      # [B, V] np.ndarray
+        self._decode_rows_total += len(seqs)
+        self._runner_seconds_total += time.time() - t0
         self._commit(seqs, logits, step_t0=t0)
 
     def _decode_spec(self, seqs: List[Sequence]):
@@ -644,6 +705,17 @@ class LLMEngine:
         ride the same step with single-token windows — composable with
         everything else."""
         t0 = time.time()
+        with tracing.step_span("llm.step.decode", n=len(seqs)):
+            windows = self._draft_windows(seqs)
+            tv = time.time()
+            rows = self.adapter.decode_window(seqs, windows)
+            verify_dt = time.time() - tv
+        self._decode_rows_total += len(seqs)
+        self._runner_seconds_total += verify_dt
+        self._commit_window(seqs, windows, rows, step_t0=t0,
+                            verify_dt=verify_dt)
+
+    def _draft_windows(self, seqs: List[Sequence]) -> List[List[int]]:
         k = self.config.spec_k
         vocab = getattr(self.adapter, "vocab_size", None)
         windows: List[List[int]] = []
@@ -665,30 +737,29 @@ class LLMEngine:
             s.draft_proposed += len(win) - 1
             self._total_draft += len(win) - 1
             windows.append(win)
-        tv = time.time()
-        rows = self.adapter.decode_window(seqs, windows)
-        verify_dt = time.time() - tv
-        self._commit_window(seqs, windows, rows, step_t0=t0,
-                            verify_dt=verify_dt)
+        return windows
 
-    def _prefill(self, seqs: List[Sequence]):
+    def _prefill(self, seqs: List[Sequence], tokens: int):
         t0 = time.time()
-        for s in seqs:
-            s.t_prefill_start = t0
-        logits = self.adapter.prefill(seqs)     # [B, V]
-        t1 = time.time()
-        if self.prefix_cache is not None:
-            # publish the finished prompts' full pages to the radix
-            # tree (before _commit can free a finished seq's pages)
+        with tracing.step_span("llm.step.prefill", n=len(seqs),
+                               tokens=tokens):
             for s in seqs:
-                table = self.cache.block_table(s.seq_id)
-                if table:
-                    self.prefix_cache.insert(s.prompt, table)
-        with self._lock:
-            for s in seqs:
-                s.t_prefill_end = t1
-                s.status = RUNNING
-                self._running.append(s.seq_id)
+                s.t_prefill_start = t0
+            logits = self.adapter.prefill(seqs)     # [B, V]
+            t1 = time.time()
+            self._runner_seconds_total += t1 - t0
+            if self.prefix_cache is not None:
+                # publish the finished prompts' full pages to the radix
+                # tree (before _commit can free a finished seq's pages)
+                for s in seqs:
+                    table = self.cache.block_table(s.seq_id)
+                    if table:
+                        self.prefix_cache.insert(s.prompt, table)
+            with self._lock:
+                for s in seqs:
+                    s.t_prefill_end = t1
+                    s.status = RUNNING
+                    self._running.append(s.seq_id)
         self._commit(seqs, logits, step_t0=t0)
 
     def _sample(self, seq: Sequence, row) -> int:
@@ -718,33 +789,35 @@ class LLMEngine:
         """Sample one token per sequence and publish: streaming
         cursors advance, finished sequences free their pages and their
         batch slot immediately (the admission the NEXT step sees)."""
-        now = time.time()
-        finished: List[Sequence] = []
-        with self._lock:
-            for i, seq in enumerate(seqs):
-                sid = seq.seq_id
-                if sid not in self._seqs or seq.status not in (RUNNING,
-                                                               WAITING):
-                    continue
-                tok = self._sample(seq, logits[i])
-                if seq.t_first_token is None:
-                    seq.t_first_token = now
-                    self._ttft.append(now - seq.t_arrival)
-                else:
-                    self._itl.append(now - step_t0)
-                seq.tokens.append(tok)
-                self._total_generated += 1
-                if self._finish_checks_locked(seq, tok):
-                    seq.status = FINISHED
-                    seq.t_finish = now
-                    try:
-                        self._running.remove(sid)
-                    except ValueError:
-                        pass
-                    finished.append(seq)
-            self._rate_win.append((now, len(seqs)))
-            self._out_cv.notify_all()
-        self._retire(finished)
+        with tracing.step_span("llm.step.commit", n=len(seqs)) as span:
+            now = time.time()
+            finished: List[Sequence] = []
+            with self._lock:
+                for i, seq in enumerate(seqs):
+                    sid = seq.seq_id
+                    if sid not in self._seqs or seq.status not in (RUNNING,
+                                                                   WAITING):
+                        continue
+                    tok = self._sample(seq, logits[i])
+                    if seq.t_first_token is None:
+                        seq.t_first_token = now
+                        self._ttft.append(now - seq.t_arrival)
+                    else:
+                        self._itl.append(now - step_t0)
+                    seq.tokens.append(tok)
+                    self._total_generated += 1
+                    if self._finish_checks_locked(seq, tok):
+                        seq.status = FINISHED
+                        seq.t_finish = now
+                        try:
+                            self._running.remove(sid)
+                        except ValueError:
+                            pass
+                        finished.append(seq)
+                self._rate_win.append((now, len(seqs)))
+                self._out_cv.notify_all()
+            self._retire(finished)
+            span.set(finished=len(finished))
 
     def _commit_window(self, seqs: List[Sequence],
                        windows: List[List[int]], rows,
@@ -754,56 +827,58 @@ class LLMEngine:
         correction/bonus, and roll the KV cache back over rejected
         window positions."""
         from ray_tpu.serve.llm.spec_decode import greedy_verify
-        now = time.time()
-        finished: List[Sequence] = []
-        rollbacks: List[tuple] = []
-        total_committed = 0
-        with self._lock:
-            for seq, win, row in zip(seqs, windows, rows):
-                sid = seq.seq_id
-                if sid not in self._seqs or seq.status != RUNNING:
-                    # cancelled mid-step: its state is already released
-                    continue
-                if len(win) == 1:
-                    committed = [self._sample(seq, row[0])]
-                else:
-                    seq.verify_s += verify_dt / max(1, len(seqs))
-                    argmaxes = [int(r.argmax()) for r in row]
-                    committed = greedy_verify(win, argmaxes)
-                    acc = max(0, len(committed) - 1)
-                    seq.draft_accepted += acc
-                    self._total_accepted += acc
-                applied = 0
-                dt_tok = (now - step_t0) / max(1, len(committed))
-                for tok in committed:
-                    if seq.t_first_token is None:
-                        seq.t_first_token = now
-                        self._ttft.append(now - seq.t_arrival)
+        with tracing.step_span("llm.step.commit", n=len(seqs)) as span:
+            now = time.time()
+            finished: List[Sequence] = []
+            rollbacks: List[tuple] = []
+            total_committed = 0
+            with self._lock:
+                for seq, win, row in zip(seqs, windows, rows):
+                    sid = seq.seq_id
+                    if sid not in self._seqs or seq.status != RUNNING:
+                        # cancelled mid-step: its state is already released
+                        continue
+                    if len(win) == 1:
+                        committed = [self._sample(seq, row[0])]
                     else:
-                        self._itl.append(dt_tok)
-                    seq.tokens.append(int(tok))
-                    applied += 1
-                    self._total_generated += 1
-                    if self._finish_checks_locked(seq, int(tok)):
-                        break
-                total_committed += applied
-                # cache holds len(win) new positions; keep exactly the
-                # ones a sequential decode would have written
-                if applied < len(win):
-                    rollbacks.append((sid, len(win) - applied))
-                if seq.finish_reason:
-                    seq.status = FINISHED
-                    seq.t_finish = now
-                    try:
-                        self._running.remove(sid)
-                    except ValueError:
-                        pass
-                    finished.append(seq)
-            self._rate_win.append((now, total_committed))
-            self._out_cv.notify_all()
-        for sid, n in rollbacks:
-            self.adapter.rollback(sid, n)
-        self._retire(finished)
+                        seq.verify_s += verify_dt / max(1, len(seqs))
+                        argmaxes = [int(r.argmax()) for r in row]
+                        committed = greedy_verify(win, argmaxes)
+                        acc = max(0, len(committed) - 1)
+                        seq.draft_accepted += acc
+                        self._total_accepted += acc
+                    applied = 0
+                    dt_tok = (now - step_t0) / max(1, len(committed))
+                    for tok in committed:
+                        if seq.t_first_token is None:
+                            seq.t_first_token = now
+                            self._ttft.append(now - seq.t_arrival)
+                        else:
+                            self._itl.append(dt_tok)
+                        seq.tokens.append(int(tok))
+                        applied += 1
+                        self._total_generated += 1
+                        if self._finish_checks_locked(seq, int(tok)):
+                            break
+                    total_committed += applied
+                    # cache holds len(win) new positions; keep exactly the
+                    # ones a sequential decode would have written
+                    if applied < len(win):
+                        rollbacks.append((sid, len(win) - applied))
+                    if seq.finish_reason:
+                        seq.status = FINISHED
+                        seq.t_finish = now
+                        try:
+                            self._running.remove(sid)
+                        except ValueError:
+                            pass
+                        finished.append(seq)
+                self._rate_win.append((now, total_committed))
+                self._out_cv.notify_all()
+            for sid, n in rollbacks:
+                self.adapter.rollback(sid, n)
+            self._retire(finished)
+            span.set(finished=len(finished))
 
     def _retire(self, finished: List[Sequence]):
         for seq in finished:
@@ -843,7 +918,16 @@ class LLMEngine:
             self._token_ledger.append(
                 (seq.request_id, len(seq.tokens), reason,
                  len(seq.prompt), seq.cached_tokens))
-        self._record_spans(seq)
+        rec = {"request_id": seq.request_id, "t_arrival": seq.t_arrival,
+               "t_admit": seq.t_alloc,
+               "t_prefill_start": seq.t_prefill_start,
+               "t_first_token": seq.t_first_token,
+               "t_finish": seq.t_finish, "n_prompt": len(seq.prompt),
+               "n_cached": seq.cached_tokens, "n_tokens": len(seq.tokens),
+               "finish_reason": reason}
+        if tracing.enabled():
+            self._request_log.append(rec)
+        self._record_spans(seq, rec)
 
     def _fail_all(self, err: Exception):
         """A model-step failure fails the sequences it was computing —
@@ -867,17 +951,16 @@ class LLMEngine:
 
     # ------------------------------------------------------------ tracing
 
-    def _record_spans(self, seq: Sequence):
-        """Phase spans for the PR 9 trace plane: queue / kv-alloc /
-        prefix-lookup / prefill / decode (+ kv_ship for adopted
-        sequences, draft/verify aggregates for speculative ones),
-        parented under the ``__llm_open__`` call's replica execute
-        span — TTFT = queue + kv_alloc + prefill, inter-token latency
-        = decode / n_tokens."""
+    def _record_spans(self, seq: Sequence, rec: Dict[str, Any]):
+        """Phase spans for the PR 9 trace plane, from the request's
+        ``request_log`` record: queue / kv-alloc / prefix-lookup /
+        prefill / decode (+ kv_ship for adopted sequences, draft/verify
+        aggregates for speculative ones), parented under the
+        ``__llm_open__`` call's replica execute span — TTFT = queue +
+        kv_alloc + prefill, inter-token latency = decode / n_tokens."""
         ctx = seq.trace_ctx
         if not ctx or not ctx.get("trace_id"):
             return
-        from ray_tpu._private import tracing
         tid, parent = ctx["trace_id"], ctx.get("span_id")
 
         def span(name, phase, t0, t1, attrs=None, min_width=None):
@@ -893,35 +976,34 @@ class LLMEngine:
                 start_ts=t0, end_ts=t1, attrs=attrs)
 
         alloc_start = getattr(seq, "_t_alloc_start", None)
-        span("llm.queue", "queue", seq.t_arrival,
-             alloc_start or seq.t_prefill_start)
-        span("llm.kv_alloc", "schedule", alloc_start, seq.t_alloc)
-        if seq.cached_tokens and not seq.adopted:
+        span("llm.queue", "queue", rec["t_arrival"],
+             alloc_start or rec["t_prefill_start"])
+        span("llm.kv_alloc", "schedule", alloc_start, rec["t_admit"])
+        if rec["n_cached"] and not seq.adopted:
             # sub-µs radix walk: clamp so the span survives recording
             span("llm.prefix_lookup", "schedule", alloc_start,
-                 seq.t_alloc, attrs={"cached_tokens": seq.cached_tokens},
+                 rec["t_admit"], attrs={"cached_tokens": rec["n_cached"]},
                  min_width=2e-5)
-        span("llm.prefill", "execute", seq.t_prefill_start,
+        span("llm.prefill", "execute", rec["t_prefill_start"],
              seq.t_prefill_end,
-             attrs={"prompt_tokens": len(seq.prompt),
-                    "cached_tokens": seq.cached_tokens})
+             attrs={"prompt_tokens": rec["n_prompt"],
+                    "cached_tokens": rec["n_cached"]})
         if seq.adopted:
             span("llm.kv_ship", "transfer", seq.t_import_start,
                  seq.t_import_end,
-                 attrs={"prompt_tokens": len(seq.prompt),
+                 attrs={"prompt_tokens": rec["n_prompt"],
                         "lane": seq.import_lane or "inline"},
                  min_width=2e-5)
-        span("llm.decode", "execute", seq.t_first_token, seq.t_finish,
-             attrs={"tokens": len(seq.tokens),
+        first = rec["t_first_token"]
+        span("llm.decode", "execute", first, rec["t_finish"],
+             attrs={"tokens": rec["n_tokens"],
                     "finish_reason": seq.finish_reason})
-        if seq.draft_proposed and seq.t_first_token is not None:
-            span("llm.draft", "execute", seq.t_first_token,
-                 seq.t_first_token + seq.draft_s,
+        if seq.draft_proposed and first is not None:
+            span("llm.draft", "execute", first, first + seq.draft_s,
                  attrs={"proposed": seq.draft_proposed,
                         "accepted": seq.draft_accepted},
                  min_width=2e-5)
-            span("llm.verify", "execute", seq.t_first_token,
-                 seq.t_first_token + seq.verify_s,
+            span("llm.verify", "execute", first, first + seq.verify_s,
                  attrs={"proposed": seq.draft_proposed,
                         "accepted": seq.draft_accepted},
                  min_width=2e-5)

@@ -51,6 +51,12 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ray_tpu._private import tracing
+
+# the shortest token-axis bucket: a decode step (one new token a row) and
+# any prefill of at most this many tokens run the same program
+_MIN_S = 8
+
 
 def _pad_pow2(n: int, lo: int = 1) -> int:
     p = lo
@@ -210,6 +216,13 @@ class ToyAdapter:
             self._state.pop(seq_id, None)
 
 
+def bucket_name(B: int, S: int, full: bool = False) -> str:
+    """The jitted step's name for one (batch, length) bucket."""
+    if full:
+        return f"llm_verify_b{B}_s{S}"
+    return f"llm_decode_b{B}" if S == _MIN_S else f"llm_prefill_b{B}_s{S}"
+
+
 class FlaxModelAdapter:
     """GPT-2 / Llama incremental decode over the paged pool.
 
@@ -248,6 +261,7 @@ class FlaxModelAdapter:
             params = self.model.init(jax.random.PRNGKey(seed), dummy)
         self.params = params
         self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
+        self.bucket_first_calls = 0        # _fns misses: steps that compiled
         self._lock = threading.Lock()
 
     @property
@@ -316,39 +330,50 @@ class FlaxModelAdapter:
                 logits, idx[:, None, None], axis=1)[:, 0]
             return last, k_new, v_new
 
+        # one name per bucket, so a device trace's XLA Modules line says
+        # which program ran (jit names the module after the function)
+        step.__name__ = step.__qualname__ = bucket_name(B, S, full)
         # donate the pools on TPU (in-place page update, zero copy);
         # CPU ignores donation and would warn on every compile
         donate = (2, 3) if jax.devices()[0].platform == "tpu" else ()
         fn = jax.jit(step, donate_argnums=donate)
         self._fns[key] = fn
+        self.bucket_first_calls += 1
         return fn
 
-    def _run(self, rows: List[Dict[str, Any]],
-             full: bool = False) -> np.ndarray:
+    def _run(self, rows: List[Dict[str, Any]], op: str) -> np.ndarray:
         """rows: [{tokens: [ints], len: cache length, table: [pages]}]
-        -> last-token logits [B, V] (or full [B, S, V] when ``full``)
-        for the real rows."""
+        -> last-token logits [B, V] (or full [B, S, V] when ``op`` is
+        ``verify``) for the real rows. ``op`` (prefill | decode |
+        verify) labels the step spans."""
         jnp = self._jnp
+        full = op == "verify"
         B = _pad_pow2(len(rows))
-        S = _pad_pow2(max(len(r["tokens"]) for r in rows), 8)
-        tokens = np.zeros((B, S), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        valid = np.zeros((B, S), bool)
-        tables = np.zeros((B, self.nb_max), np.int32)
-        for i, r in enumerate(rows):
-            n = len(r["tokens"])
-            tokens[i, :n] = r["tokens"]
-            lengths[i] = r["len"]
-            valid[i, :n] = True
-            t = r["table"][:self.nb_max]
-            tables[i, :len(t)] = t
-        fn = self._step_fn(B, S, full)
-        with self._lock:
-            logits, self.k_pages, self.v_pages = fn(
-                self.params, jnp.asarray(tokens), self.k_pages,
-                self.v_pages, jnp.asarray(tables),
-                jnp.asarray(lengths), jnp.asarray(valid))
-        return np.asarray(logits[:len(rows)], np.float32)
+        S = _pad_pow2(max(len(r["tokens"]) for r in rows), _MIN_S)
+        with tracing.step_span("runner.build_inputs", op=op, B=B, S=S):
+            tokens = np.zeros((B, S), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            valid = np.zeros((B, S), bool)
+            tables = np.zeros((B, self.nb_max), np.int32)
+            for i, r in enumerate(rows):
+                n = len(r["tokens"])
+                tokens[i, :n] = r["tokens"]
+                lengths[i] = r["len"]
+                valid[i, :n] = True
+                t = r["table"][:self.nb_max]
+                tables[i, :len(t)] = t
+        with tracing.step_span("runner.dispatch", B=B, S=S,
+                               first_call=(B, S, full) not in self._fns):
+            fn = self._step_fn(B, S, full)
+            with self._lock:
+                logits, self.k_pages, self.v_pages = fn(
+                    self.params, jnp.asarray(tokens), self.k_pages,
+                    self.v_pages, jnp.asarray(tables),
+                    jnp.asarray(lengths), jnp.asarray(valid))
+        with tracing.step_span("runner.fetch") as span:
+            out = np.asarray(logits[:len(rows)], np.float32)
+            span.set(bytes=out.nbytes)
+        return out
 
     def prefill(self, seqs) -> np.ndarray:
         rows = []
@@ -366,7 +391,7 @@ class FlaxModelAdapter:
                                      "len": len(s.prompt)}
             rows.append({"tokens": s.prompt[cached:], "len": cached,
                          "table": table})
-        return self._run(rows)
+        return self._run(rows, "prefill")
 
     def decode(self, seqs) -> np.ndarray:
         rows = []
@@ -375,7 +400,7 @@ class FlaxModelAdapter:
             rows.append({"tokens": [s.tokens[-1]], "len": st["len"],
                          "table": st["table"]})
             st["len"] += 1
-        return self._run(rows)
+        return self._run(rows, "decode")
 
     def decode_window(self, seqs, windows) -> List[np.ndarray]:
         """One batched multi-token incremental step; causal masking at
@@ -388,7 +413,7 @@ class FlaxModelAdapter:
             rows.append({"tokens": list(win), "len": st["len"],
                          "table": st["table"]})
             st["len"] += len(win)
-        full = self._run(rows, full=True)      # [B, S, V]
+        full = self._run(rows, "verify")       # [B, S, V]
         return [full[i, :len(win)] for i, win in enumerate(windows)]
 
     def rollback(self, seq_id: str, n: int):

@@ -148,9 +148,10 @@ def make_causal_lm_trainer(
 
         with attention_mesh(mesh):
             loss, grads = jax.value_and_grad(loss_fn)(state["params"])
-        updates, opt = tx.update(grads, state["opt"], state["params"])
-        params = optax.apply_updates(state["params"], updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt = tx.update(grads, state["opt"], state["params"])
+            params = optax.apply_updates(state["params"], updates)
+            gnorm = optax.global_norm(grads)
         new_state = {"params": params, "opt": opt,
                      "step": state["step"] + 1}
         return new_state, {"loss": loss, "grad_norm": gnorm}
@@ -228,8 +229,9 @@ def make_image_classifier_trainer(
 
         (loss, (logits, new_bs)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state["params"])
-        updates, opt = tx.update(grads, state["opt"], state["params"])
-        params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, opt = tx.update(grads, state["opt"], state["params"])
+            params = optax.apply_updates(state["params"], updates)
         acc = jnp.mean(
             (jnp.argmax(logits, -1) == batch["label"]).astype(jnp.float32))
         new_state = {"params": params, "batch_stats": new_bs,
@@ -356,12 +358,13 @@ def make_pipelined_lm_trainer(
                 batch["labels"].reshape(-1, batch["labels"].shape[-1]))
 
         loss, grads = jax.value_and_grad(loss_fn)(state["params"])
-        updates, opt = tx.update(grads, state["opt"], state["params"])
-        params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, opt = tx.update(grads, state["opt"], state["params"])
+            params = optax.apply_updates(state["params"], updates)
+            gnorm = optax.global_norm(grads)
         new_state = {"params": params, "opt": opt,
                      "step": state["step"] + 1}
-        return new_state, {"loss": loss,
-                           "grad_norm": optax.global_norm(grads)}
+        return new_state, {"loss": loss, "grad_norm": gnorm}
 
     step = jax.jit(
         train_step,
