@@ -59,7 +59,7 @@ class CausalSelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True, kv_cache=None,
-                 seq_lengths=None, valid=None):
+                 seq_lengths=None, valid=None, layer=None):
         cfg = self.config
         B, S, E = x.shape
         head_dim = cfg.n_embd // cfg.n_head
@@ -77,7 +77,7 @@ class CausalSelfAttention(nn.Module):
             tok = lambda t: t.reshape(B, S, cfg.n_head, head_dim)  # noqa: E731
             y, new_cache = cached_attention(
                 tok(q), tok(k), tok(v), kv_cache, seq_lengths,
-                valid=valid)
+                valid=valid, layer=layer)
             y = y.reshape(B, S, E)
             y = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="c_proj")(y)
             return (nn.Dropout(cfg.dropout)(y, deterministic),
@@ -115,13 +115,13 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True, kv_cache=None,
-                 seq_lengths=None, valid=None):
+                 seq_lengths=None, valid=None, layer=None):
         cfg = self.config
         if kv_cache is not None:
             y, new_cache = CausalSelfAttention(cfg, name="attn")(
                 nn.LayerNorm(dtype=jnp.float32, name="ln_1")(x),
                 deterministic, kv_cache=kv_cache,
-                seq_lengths=seq_lengths, valid=valid)
+                seq_lengths=seq_lengths, valid=valid, layer=layer)
             x = x + y
             x = x + MLP(cfg, name="mlp")(
                 nn.LayerNorm(dtype=jnp.float32, name="ln_2")(x),
@@ -136,6 +136,10 @@ class Block(nn.Module):
 
 class GPT2(nn.Module):
     config: GPT2Config
+    # serving: the blocks' parameters stacked on a leading layer axis
+    # under "h" (not "h_0" .. "h_{L-1}") and ONE block's program looped
+    # over it, so compile time and program size do not grow with depth
+    stacked: bool = False
 
     @nn.compact
     def __call__(self, input_ids, deterministic: bool = True,
@@ -143,9 +147,11 @@ class GPT2(nn.Module):
                  kv_cache=None, seq_lengths=None, valid=None):
         """Full forward (logits) — or, with ``kv_cache``, one
         incremental step: the S tokens of ``input_ids`` are appended to
-        per-layer caches (``init_kv_cache`` / the serve LLM engine's
-        paged pool) holding ``seq_lengths`` prior tokens, and the
-        return value is ``(logits, new_kv_cache)``. Prefill is the
+        the caches (``init_kv_cache``'s list of per-layer caches, or
+        the serve LLM engine's paged pool, one dict for all layers:
+        ``ops.attention.cached_attention`` takes either with the layer's
+        index) holding ``seq_lengths`` prior tokens, and the return
+        value is ``(logits, new_kv_cache)``. Prefill is the
         ``seq_lengths == 0`` case; decode passes one token at a time.
         ``valid`` marks real tokens when S is padded to a bucket."""
         cfg = self.config
@@ -164,20 +170,29 @@ class GPT2(nn.Module):
                        dtype=cfg.dtype, name="wpe")
         x = wte(input_ids) + wpe(positions)
         x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
-        new_caches = []
-        for i in range(cfg.n_layer):
+
+        def block(h, carry, i):
+            x, cache = carry
             if incremental:
-                x, c = Block(cfg, name=f"h_{i}")(
-                    x, deterministic, kv_cache=kv_cache[i],
-                    seq_lengths=seq_lengths, valid=valid)
-                new_caches.append(c)
-            else:
-                x = Block(cfg, name=f"h_{i}")(x, deterministic)
+                return h(x, deterministic, kv_cache=cache,
+                         seq_lengths=seq_lengths, valid=valid, layer=i), None
+            return (h(x, deterministic), None), None
+
+        if self.stacked:
+            (x, kv_cache), _ = nn.scan(
+                block, variable_axes={"params": 0},
+                split_rngs={"params": True})(
+                    Block(cfg, name="h"), (x, kv_cache),
+                    jnp.arange(cfg.n_layer))
+        else:
+            for i in range(cfg.n_layer):
+                (x, kv_cache), _ = block(
+                    Block(cfg, name=f"h_{i}"), (x, kv_cache), i)
         x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
         # weight-tied LM head
         with jax.named_scope("lm_head"):
             logits = wte.attend(x.astype(jnp.float32))
-        return (logits, new_caches) if incremental else logits
+        return (logits, kv_cache) if incremental else logits
 
 
 def init_kv_cache(cfg: GPT2Config, batch_size: int, max_len: int):

@@ -121,7 +121,7 @@ class LlamaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, freqs, kv_cache=None, seq_lengths=None,
-                 valid=None, positions=None):
+                 valid=None, positions=None, layer=None):
         cfg = self.config
         B, S, E = x.shape
         hd = cfg.head_dim
@@ -143,7 +143,7 @@ class LlamaAttention(nn.Module):
             y, new_cache = cached_attention(
                 q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                 v.transpose(0, 2, 1, 3), kv_cache, seq_lengths,
-                valid=valid)
+                valid=valid, layer=layer)
             y = y.reshape(B, S, cfg.n_heads * hd)
             return (nn.Dense(cfg.dim, use_bias=False, dtype=cfg.dtype,
                              name="wo")(y), new_cache)
@@ -186,13 +186,13 @@ class LlamaBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, freqs, kv_cache=None, seq_lengths=None,
-                 valid=None, positions=None):
+                 valid=None, positions=None, layer=None):
         cfg = self.config
         if kv_cache is not None:
             y, new_cache = LlamaAttention(cfg, name="attention")(
                 RMSNorm(cfg.norm_eps, name="attention_norm")(x), freqs,
                 kv_cache=kv_cache, seq_lengths=seq_lengths,
-                valid=valid, positions=positions)
+                valid=valid, positions=positions, layer=layer)
             x = x + y
             x = x + LlamaMLP(cfg, name="feed_forward")(
                 RMSNorm(cfg.norm_eps, name="ffn_norm")(x))
@@ -207,13 +207,17 @@ class LlamaBlock(nn.Module):
 class LlamaModel(nn.Module):
     """Decoder LM: tokens -> logits (f32)."""
     config: LlamaConfig
+    # serving: the blocks' parameters stacked on a leading layer axis
+    # under "layers" and ONE block's program looped over it (see GPT2)
+    stacked: bool = False
 
     @nn.compact
     def __call__(self, input_ids, kv_cache=None, seq_lengths=None,
                  valid=None):
         """Full forward — or, with ``kv_cache``, one incremental step
         (prefill at ``seq_lengths == 0``, then single-token decodes):
-        tokens are appended to the per-layer caches and rotated by
+        tokens are appended to the caches (a list of per-layer caches,
+        or the serving pool: one dict for all layers) and rotated by
         their TRUE absolute positions; returns ``(logits, new_cache)``.
         ``valid`` marks real tokens when S is padded to a bucket."""
         cfg = self.config
@@ -228,20 +232,28 @@ class LlamaModel(nn.Module):
             positions = seq_lengths[:, None] + jnp.arange(S)[None, :]
             if valid is not None:
                 positions = jnp.where(valid, positions, 0)
-        new_caches = []
-        for i in range(cfg.n_layers):
+
+        def block(h, carry, i):
+            x, cache = carry
             if incremental:
-                x, c = LlamaBlock(cfg, name=f"layers_{i}")(
-                    x, freqs, kv_cache=kv_cache[i],
-                    seq_lengths=seq_lengths, valid=valid,
-                    positions=positions)
-                new_caches.append(c)
-            else:
-                x = LlamaBlock(cfg, name=f"layers_{i}")(x, freqs)
+                return h(x, freqs, kv_cache=cache, seq_lengths=seq_lengths,
+                         valid=valid, positions=positions, layer=i), None
+            return (h(x, freqs), None), None
+
+        if self.stacked:
+            (x, kv_cache), _ = nn.scan(
+                block, variable_axes={"params": 0},
+                split_rngs={"params": True})(
+                    LlamaBlock(cfg, name="layers"), (x, kv_cache),
+                    jnp.arange(cfg.n_layers))
+        else:
+            for i in range(cfg.n_layers):
+                (x, kv_cache), _ = block(
+                    LlamaBlock(cfg, name=f"layers_{i}"), (x, kv_cache), i)
         x = RMSNorm(cfg.norm_eps, name="norm")(x)
         logits = nn.Dense(cfg.vocab_size, use_bias=False,
                           dtype=jnp.float32, name="output")(x)
-        return (logits, new_caches) if incremental else logits
+        return (logits, kv_cache) if incremental else logits
 
 
 def init_kv_cache(cfg: LlamaConfig, batch_size: int, max_len: int):

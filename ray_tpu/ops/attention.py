@@ -751,6 +751,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
 # Layouts (chosen so a scatter/gather is one advanced-index op):
 #   contiguous cache   k/v: [B, S_max, Hkv, D]
 #   paged cache        k/v pages: [P, bs, Hkv, D]; block_tables [B, NB]
+#   serving pool       all layers' pages, lane-dense: [L, P, bs, Hkv*D]
 #   lengths            [B] int32 — valid cache entries per sequence
 #
 # Three compute paths, all numerically equivalent (tier-1 gated in
@@ -808,36 +809,41 @@ def decode_attention(q, k_cache, v_cache, lengths, *,
 
 
 def append_kv_pages(k_new, v_new, k_pages, v_pages, block_tables,
-                    lengths, valid=None):
+                    lengths, valid=None, layer=None):
     """Scatter new keys/values into their pages.
 
     k_new/v_new: [B, S, Hkv, D] written at logical positions
     ``lengths .. lengths + S - 1`` of each sequence; ``valid`` ([B, S]
     bool, optional) routes padding tokens to the reserved null page 0
-    instead (batch/length bucketing for jit). Returns updated
-    (k_pages, v_pages). Distinct sequences own distinct pages, so the
-    scatter indices never collide except in the null page (scratch).
+    instead (batch/length bucketing for jit). The pages are one layer's
+    [P, bs, Hkv, D] or, with ``layer``, that layer of the serving pool
+    [L, P, bs, Hkv*D]: B*S rows of it are written, the rest is not
+    touched. Returns updated (k_pages, v_pages). Distinct sequences own
+    distinct pages, so the scatter indices never collide except in the
+    null page (scratch).
     """
     B, S = k_new.shape[:2]
-    bs = k_pages.shape[1]
+    lead = () if layer is None else (layer,)
+    bs, row = k_pages.shape[len(lead) + 1], k_pages.shape[len(lead) + 2:]
     pos = lengths[:, None] + jnp.arange(S)[None, :]        # [B, S]
     page = jnp.take_along_axis(block_tables, pos // bs, axis=1)
     slot = pos % bs
     if valid is not None:
         page = jnp.where(valid, page, 0)
         slot = jnp.where(valid, slot, 0)
-    k_pages = k_pages.at[page, slot].set(k_new)
-    v_pages = v_pages.at[page, slot].set(v_new)
-    return k_pages, v_pages
+    at = (*lead, page, slot)
+    return (k_pages.at[at].set(k_new.reshape(B, S, *row)),
+            v_pages.at[at].set(v_new.reshape(B, S, *row)))
 
 
-def paged_gather(pages, block_tables):
+def paged_gather(pages, block_tables, layer=None):
     """Pages -> per-sequence (padded) contiguous cache:
-    [P, bs, Hkv, D] + [B, NB] -> [B, NB*bs, Hkv, D]."""
+    [P, bs, Hkv, D] + [B, NB] -> [B, NB*bs, Hkv, D]; with ``layer``,
+    [L, P, bs, Hkv*D] -> that layer's [B, NB*bs, Hkv*D]."""
     B, NB = block_tables.shape
-    bs = pages.shape[1]
-    out = pages[block_tables]                              # [B,NB,bs,Hkv,D]
-    return out.reshape(B, NB * bs, *pages.shape[2:])
+    out = pages[block_tables] if layer is None \
+        else pages[layer, block_tables]                    # [B,NB,bs,...]
+    return out.reshape(B, NB * out.shape[2], *out.shape[3:])
 
 
 def paged_attention_reference(q, k_pages, v_pages, block_tables,
@@ -978,26 +984,38 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
 
 
 def cached_attention(q, k_new, v_new, cache, seq_lengths, *,
-                     sm_scale: Optional[float] = None, valid=None):
+                     sm_scale: Optional[float] = None, valid=None,
+                     layer=None):
     """Shared incremental-attention step for the model decode paths
     (models/gpt2.py, models/llama.py).
 
     q/k_new/v_new: [B, S, H|Hkv, D] projections of the S newest tokens
     (q head-major is the CALLER's concern — here everything is token-
-    major, matching the cache layouts). ``cache`` is one layer's cache:
+    major, matching the cache layouts). ``cache`` holds every layer's
+    cache, of which this call reads and writes layer ``layer``: a list
+    of contiguous caches, one a layer (``init_kv_cache``), or the
+    serving pool, one dict for all layers:
 
-      {"k": [B,S_max,Hkv,D], "v": ...}                    contiguous
-      {"k_pages": [P,bs,Hkv,D], "v_pages": ...,
+      [{"k": [B,S_max,Hkv,D], "v": ...}, ...]             contiguous
+      {"k_pages": [L,P,bs,Hkv*D], "v_pages": ...,
        "block_tables": [B,NB]}                            paged
 
+    The pool's minor axis is heads x head dimension, whole lanes, and
+    each block hands the updated cache to the next: in a jit that
+    donates the pools a step scatters B*S rows a layer into them and
+    copies nothing pool-sized (tests/test_chip_compile.py holds it to
+    that). A single contiguous dict, with no ``layer``, is taken too.
     ``seq_lengths`` [B] counts valid cache entries BEFORE this call
     (i.e. the prefix length); ``valid`` ([B, S] bool, optional) marks
     real tokens when the caller padded S to a bucket — padding kv is
     routed to the paged cache's null page and masked out of attention
     by the lengths. Appends the new kv, attends causally, and returns
-    (out [B, S, H, D], updated cache dict).
+    (out [B, S, H, D], updated cache in the form given).
     """
     B, S = q.shape[:2]
+    layers = cache if isinstance(cache, list) else None
+    if layers is not None:
+        cache = layers[layer]
     q_positions = None
     if valid is not None:
         new_len = seq_lengths + jnp.sum(valid.astype(jnp.int32), axis=1)
@@ -1008,13 +1026,16 @@ def cached_attention(q, k_new, v_new, cache, seq_lengths, *,
     else:
         new_len = seq_lengths + S
     if "k_pages" in cache:
+        tables = cache["block_tables"]
         k_pages, v_pages = append_kv_pages(
-            k_new, v_new, cache["k_pages"], cache["v_pages"],
-            cache["block_tables"], seq_lengths, valid=valid)
+            k_new, v_new, cache["k_pages"], cache["v_pages"], tables,
+            seq_lengths, valid=valid, layer=layer)
+
+        def gathered(pages):                               # [B,NB*bs,Hkv,D]
+            return paged_gather(pages, tables, layer).reshape(
+                B, -1, *k_new.shape[2:])
         out = decode_attention(
-            q.transpose(0, 2, 1, 3),
-            paged_gather(k_pages, cache["block_tables"]),
-            paged_gather(v_pages, cache["block_tables"]),
+            q.transpose(0, 2, 1, 3), gathered(k_pages), gathered(v_pages),
             new_len, sm_scale=sm_scale, q_positions=q_positions)
         new_cache = dict(cache, k_pages=k_pages, v_pages=v_pages)
     else:
@@ -1032,4 +1053,6 @@ def cached_attention(q, k_new, v_new, cache, seq_lengths, *,
                                v_cache, new_len, sm_scale=sm_scale,
                                q_positions=q_positions)
         new_cache = dict(cache, k=k_cache, v=v_cache)
+    if layers is not None:
+        new_cache = layers[:layer] + [new_cache] + layers[layer + 1:]
     return out.transpose(0, 2, 1, 3), new_cache

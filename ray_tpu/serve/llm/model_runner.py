@@ -31,9 +31,11 @@ Two implementations:
   the game day and ``_BENCH_LLM`` without flax in the loop.
 
 * ``FlaxModelAdapter`` — wraps ``models/gpt2.py`` / ``models/llama.py``
-  incremental-decode paths: bucketed (batch, length) jit shapes, paged
-  caches threaded through ``ops.attention.cached_attention``, padding
-  rows parked on the null page. Prefill and decode alike go
+  incremental-decode paths in their ``stacked`` form (the blocks'
+  weights stacked, one block's program looped over them): bucketed
+  (batch, length) jit shapes, one paged pool for all layers carried
+  through ``ops.attention.cached_attention`` from block to block,
+  padding rows parked on the null page. Prefill and decode alike go
   ``paged_gather`` + ``decode_attention`` (XLA) on every platform; the
   ``paged_attention_decode`` Pallas kernel compiles for the chip and is
   tested against that reference, but is not on this path (ROADMAP S2).
@@ -216,6 +218,21 @@ class ToyAdapter:
             self._state.pop(seq_id, None)
 
 
+def _stack_blocks(tree, name: str, n: int):
+    """``{name}_0 .. {name}_{n-1}`` of a flax tree -> one entry ``name``
+    whose leaves carry a leading layer axis (what the models' ``stacked``
+    form takes); a tree without them (stacked already, empty, None)
+    passes through."""
+    import jax
+    import jax.numpy as jnp
+    if not tree or f"{name}_0" not in tree.get("params", ()):
+        return tree
+    rest = dict(tree["params"])
+    blocks = [rest.pop(f"{name}_{i}") for i in range(n)]
+    rest[name] = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *blocks)
+    return {**tree, "params": rest}
+
+
 def bucket_name(B: int, S: int, full: bool = False) -> str:
     """The jitted step's name for one (batch, length) bucket."""
     if full:
@@ -229,9 +246,18 @@ class FlaxModelAdapter:
     jit shapes are bucketed (batch to a power of two, prompt length to
     a power of two >= 8); padding rows carry zero lengths and
     null-page block tables, so they scatter into scratch and attend to
-    nothing. Pages live as stacked per-layer jax arrays
-    ([L, P, bs, Hkv, D]) and are donated through every step — the pool
-    is updated in place, never copied.
+    nothing. Pages live as two jax arrays [L, P, bs, Hkv*D]: heads and
+    head dimension share the minor axis, which then fills whole lanes,
+    so the chip keeps the pool in the order scatter and gather index it.
+    The step donates both and every layer writes its B*S new rows into
+    them, so the compiled program aliases the pools to its outputs and
+    copies nothing of their size: tests/test_chip_compile.py,
+    ``test_served_step_writes_the_pool_in_place``. ``export_kv`` /
+    ``import_kv`` blobs keep heads apart: [L, nb, bs, Hkv, D].
+    ``params`` may come as the models' training form (one entry a
+    block); it is kept stacked (``_stack_blocks``), because the step
+    loops one block's program over the layers: a bucket then compiles
+    in seconds and its program does not grow with depth.
     """
 
     def __init__(self, kind: str = "gpt2", config=None,
@@ -243,14 +269,15 @@ class FlaxModelAdapter:
         if kind == "gpt2":
             from ray_tpu.models import gpt2
             self.cfg = config or gpt2.GPT2Config.tiny()
-            self.model = gpt2.GPT2(self.cfg)
+            self.model, self._blocks = gpt2.GPT2(self.cfg, stacked=True), "h"
             self.n_kv_heads = self.cfg.n_head
             self.head_dim = self.cfg.n_embd // self.cfg.n_head
             self.vocab_size = self.cfg.vocab_size
         elif kind == "llama":
             from ray_tpu.models import llama
             self.cfg = config or llama.LlamaConfig.tiny()
-            self.model = llama.LlamaModel(self.cfg)
+            self.model = llama.LlamaModel(self.cfg, stacked=True)
+            self._blocks = "layers"
             self.n_kv_heads = self.cfg.n_kv_heads
             self.head_dim = self.cfg.head_dim
             self.vocab_size = self.cfg.vocab_size
@@ -263,6 +290,15 @@ class FlaxModelAdapter:
         self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
         self.bucket_first_calls = 0        # _fns misses: steps that compiled
         self._lock = threading.Lock()
+
+    @property
+    def params(self):
+        return self._params
+
+    @params.setter
+    def params(self, tree):
+        # accepted as the models' training form (one entry a block) too
+        self._params = _stack_blocks(tree, self._blocks, self.n_layers)
 
     @property
     def n_layers(self) -> int:
@@ -284,7 +320,7 @@ class FlaxModelAdapter:
         self.cache = cache
         dtype = self.cfg.dtype
         shape = (self.n_layers, cache.num_blocks, cache.block_size,
-                 self.n_kv_heads, self.head_dim)
+                 self.n_kv_heads * self.head_dim)
         self.k_pages = jnp.zeros(shape, dtype)
         self.v_pages = jnp.zeros(shape, dtype)
         # NB: every block table is padded to the worst-case blocks per
@@ -308,27 +344,20 @@ class FlaxModelAdapter:
             return fn
         import jax
         jnp = self._jnp
-        L = self.n_layers
 
         def step(params, tokens, k_pages, v_pages, block_tables,
                  seq_lengths, valid):
-            caches = [{"k_pages": k_pages[l], "v_pages": v_pages[l],
-                       "block_tables": block_tables}
-                      for l in range(L)]
-            logits, new = self.model.apply(
-                params, tokens, kv_cache=caches,
-                seq_lengths=seq_lengths, valid=valid)
-            k_new = jnp.stack([c["k_pages"] for c in new])
-            v_new = jnp.stack([c["v_pages"] for c in new])
-            if full:
-                # speculative verify reads logits at EVERY position
-                return logits, k_new, v_new
-            # last REAL token's logits per row
-            idx = jnp.maximum(
-                jnp.sum(valid.astype(jnp.int32), axis=1) - 1, 0)
-            last = jnp.take_along_axis(
-                logits, idx[:, None, None], axis=1)[:, 0]
-            return last, k_new, v_new
+            logits, pool = self.model.apply(
+                params, tokens, seq_lengths=seq_lengths, valid=valid,
+                kv_cache={"k_pages": k_pages, "v_pages": v_pages,
+                          "block_tables": block_tables})
+            if not full:    # speculative verify reads EVERY position
+                # last REAL token's logits per row
+                idx = jnp.maximum(
+                    jnp.sum(valid.astype(jnp.int32), axis=1) - 1, 0)
+                logits = jnp.take_along_axis(
+                    logits, idx[:, None, None], axis=1)[:, 0]
+            return logits, pool["k_pages"], pool["v_pages"]
 
         # one name per bucket, so a device trace's XLA Modules line says
         # which program ran (jit names the module after the function)
@@ -427,9 +456,10 @@ class FlaxModelAdapter:
         nb = -(-int(n_prompt) // bs)
         st = self._state[seq_id]
         idx = jnp.asarray(np.asarray(st["table"][:nb], np.int32))
+        heads = (self.n_layers, nb, bs, self.n_kv_heads, self.head_dim)
         with self._lock:
-            k = np.asarray(self.k_pages[:, idx])
-            v = np.asarray(self.v_pages[:, idx])
+            k = np.asarray(self.k_pages[:, idx]).reshape(heads)
+            v = np.asarray(self.v_pages[:, idx]).reshape(heads)
         return {"kind": f"flax:{self.kind}", "n": int(n_prompt),
                 "k": k, "v": v}
 
@@ -444,11 +474,12 @@ class FlaxModelAdapter:
         nb = -(-int(n_prompt) // bs)
         table = self.cache.block_table(seq_id)
         idx = jnp.asarray(np.asarray(table[:nb], np.int32))
+        merged = (self.n_layers, nb, bs, -1)
         with self._lock:
-            self.k_pages = self.k_pages.at[:, idx].set(
-                jnp.asarray(blob["k"], self.k_pages.dtype))
-            self.v_pages = self.v_pages.at[:, idx].set(
-                jnp.asarray(blob["v"], self.v_pages.dtype))
+            self.k_pages = self.k_pages.at[:, idx].set(jnp.asarray(
+                blob["k"], self.k_pages.dtype).reshape(merged))
+            self.v_pages = self.v_pages.at[:, idx].set(jnp.asarray(
+                blob["v"], self.v_pages.dtype).reshape(merged))
         self._state[seq_id] = {"table": table, "len": int(n_prompt)}
 
     def release(self, seq_id: str):

@@ -124,3 +124,60 @@ def test_paged_attention_decode(one_chip, H, Hkv, D):
     _compile(lambda *a: A.paged_attention_decode(*a, interpret=False),
              sds((B, H, D), jnp.bfloat16), pages, pages,
              sds((B, NB), jnp.int32), sds((B,), jnp.int32))
+
+
+def _served_step(one_chip, topo, monkeypatch, pages, B, S, full):
+    """``FlaxModelAdapter``'s jitted step at GPT-2 large's width, cut to
+    4 layers, compiled for the chip over a pool of ``pages`` pages."""
+    from ray_tpu.models.gpt2 import GPT2Config
+    from ray_tpu.serve.llm.kv_cache import PagedKVCache
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    adapter = FlaxModelAdapter(
+        "gpt2", GPT2Config(n_embd=1280, n_layer=4, n_head=20), params={})
+    params = jax.tree_util.tree_map(
+        lambda s: sds(s.shape, s.dtype),
+        jax.eval_shape(adapter.model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8), jnp.int32)))
+    # the adapter's own pool shape, its page axis at the size asked for
+    adapter.bind_cache(PagedKVCache(2, 16))
+    layers, _, *page = adapter.k_pages.shape
+    pool = sds((layers, pages, *page), adapter.k_pages.dtype)
+    with monkeypatch.context() as m:
+        # the adapter donates the pools when its first device is a TPU
+        m.setattr(jax, "devices", lambda *a, **k: topo.devices)
+        fn = adapter._step_fn(B, S, full)
+    with jax.default_matmul_precision("default"):
+        return pool, fn.lower(
+            params, sds((B, S), jnp.int32), pool, pool,
+            sds((B, adapter.nb_max), jnp.int32), sds((B,), jnp.int32),
+            sds((B, S), jnp.bool_)).compile()
+
+
+@pytest.mark.parametrize("B,S,full", [
+    (16, 8, False), (1, 256, False), (2, 8, True)],
+    ids=["decode", "prefill", "verify"])
+def test_served_step_writes_the_pool_in_place(one_chip, topo, monkeypatch,
+                                              B, S, full):
+    """The donated pools are the program's outputs, nothing of a pool's
+    size is copied, laid out anew, padded or stacked, and what the
+    program needs beside its arguments does not grow with the pool."""
+    import math
+    import re
+    pool, big = _served_step(one_chip, topo, monkeypatch, 1025, B, S, full)
+    count = math.prod(pool.shape)
+    memory = big.memory_analysis()
+    assert memory.alias_size_in_bytes == 2 * count * pool.dtype.itemsize
+    moved = [
+        line.strip()[:120] for line in big.as_text().splitlines()
+        for m in [re.search(r" = \w+\[([\d,]+)\]\S* (copy|copy-start|pad|"
+                            r"dynamic-update-slice|concatenate)\(", line)]
+        if m and math.prod(map(int, m.group(1).split(","))) >= count]
+    assert not moved, moved
+    # (a pool of some tens of MB the compiler keeps in its fast memory,
+    # which no served pool fits: hence no fewer pages than these)
+    _, twice = _served_step(one_chip, topo, monkeypatch, 2049, B, S, full)
+    assert memory.temp_size_in_bytes \
+        == twice.memory_analysis().temp_size_in_bytes
