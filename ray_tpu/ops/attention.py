@@ -846,6 +846,88 @@ def paged_gather(pages, block_tables, layer=None):
     return out.reshape(B, NB * out.shape[2], *out.shape[3:])
 
 
+def append_latent_pages(rows, pages, block_tables, lengths, valid=None,
+                        layer=None):
+    """``append_kv_pages`` for a model that caches ONE row a token (a
+    latent, with no V pool beside it): rows [B, S, W] go to logical
+    positions ``lengths .. lengths + S - 1`` of pages [P, bs, W] or,
+    with ``layer``, of that layer of [L, P, bs, W]; padding tokens go to
+    the null page."""
+    B, S = rows.shape[:2]
+    lead = () if layer is None else (layer,)
+    bs = pages.shape[len(lead) + 1]
+    pos = lengths[:, None] + jnp.arange(S)[None, :]
+    page = jnp.take_along_axis(block_tables, pos // bs, axis=1)
+    slot = pos % bs
+    if valid is not None:
+        page = jnp.where(valid, page, 0)
+        slot = jnp.where(valid, slot, 0)
+    return pages.at[(*lead, page, slot)].set(rows.astype(pages.dtype))
+
+
+def latent_attention(q_nope, q_rope, latent, w_kvb, q_positions, *,
+                     v_dim: int, absorbed: bool = False,
+                     sm_scale: Optional[float] = None, q_block: int = 512):
+    """Multi-head latent attention (MLA) over cached latents.
+
+    q_nope [B, S, H, dn], q_rope [B, S, H, dr]: the new tokens' queries;
+    latent [B, T, R + dr]: each context token's cached row, the
+    normalised compressed latent ``c`` (R values) and the key part that
+    all heads share; w_kvb [R, H, dn + v_dim]: the up-projection to each
+    head's key and value; q_positions [B, S]: a query's absolute
+    position (-1: padding, attends to nothing real). Key t is visible to
+    a query at position p when t <= p. Returns [B, S, H, v_dim].
+
+    ``absorbed=False`` materialises every head's keys and values from
+    the latents (right when S is large: their cost is shared by all
+    queries). ``absorbed=True`` folds the up-projection into the query
+    and the output instead (``q_nope W_uk`` against ``c``, the
+    probabilities' sum of ``c`` through ``W_uv``), so a decode step
+    reads the context's latents once and builds nothing per head."""
+    B, S, H, dn = q_nope.shape
+    R = w_kvb.shape[0]
+    if sm_scale is None:
+        sm_scale = (dn + q_rope.shape[-1]) ** -0.5
+    c, k_rope = latent[..., :R], latent[..., R:]
+    w_uk, w_uv = w_kvb[..., :dn], w_kvb[..., dn:]
+    k_pos = jnp.arange(latent.shape[1])[None, None, None, :]
+    if absorbed:
+        keys = c
+        values = c
+    else:
+        kv = jnp.einsum("btr,rhd->bthd", c, w_kvb)
+        keys, values = kv[..., :dn], kv[..., dn:]
+
+    def attend(qn, qr, pos):                    # a block of the queries
+        if absorbed:
+            qn = jnp.einsum("bshd,rhd->bshr", qn, w_uk)
+            logits = jnp.einsum("bshr,btr->bhst", qn, keys,
+                                preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.einsum("bshd,bthd->bhst", qn, keys,
+                                preferred_element_type=jnp.float32)
+        logits = (logits + jnp.einsum(
+            "bshd,btd->bhst", qr, k_rope,
+            preferred_element_type=jnp.float32)) * sm_scale
+        mask = k_pos <= pos[:, None, :, None]
+        probs = jax.nn.softmax(jnp.where(mask, logits, _NEG_INF), axis=-1)
+        probs = probs.astype(values.dtype)
+        if absorbed:
+            out = jnp.einsum("bhst,btr->bshr", probs, values)
+            return jnp.einsum("bshr,rhd->bshd", out, w_uv)
+        return jnp.einsum("bhst,bthd->bshd", probs, values)
+
+    if S <= q_block or S % q_block:
+        return attend(q_nope, q_rope, q_positions)
+
+    def blocks(t):                  # [B, S, ...] -> [S/qb, B, qb, ...]
+        return jnp.moveaxis(
+            t.reshape(B, S // q_block, q_block, *t.shape[2:]), 1, 0)
+    out = jax.lax.map(lambda a: attend(*a), (
+        blocks(q_nope), blocks(q_rope), blocks(q_positions)))
+    return jnp.moveaxis(out, 0, 1).reshape(B, S, H, v_dim)
+
+
 def paged_attention_reference(q, k_pages, v_pages, block_tables,
                               lengths, *,
                               sm_scale: Optional[float] = None):

@@ -16,6 +16,11 @@ over ICI when the expert dimension is sharded P("ep", ...).
   y        = einsum('sec,ecd->sd', combine, out)    (all_to_all back)
 
 Top-1 (Switch) routing with the standard load-balance auxiliary loss.
+
+``RoutedExperts`` beside it is the layer the served models use: sigmoid
+scores over all experts, top-k, renormalised, no capacity and no drop,
+SwiGLU experts, computed for the experts one chip holds (docs/
+LLM_SERVING.md, "Routed experts").
 """
 
 from __future__ import annotations
@@ -90,6 +95,174 @@ class MoE(nn.Module):
         out = jnp.einsum("ecf,efd->ecd", h, w2.astype(self.dtype))
         y = jnp.einsum("sec,ecd->sd", combine.astype(self.dtype), out)
         return y.reshape(*lead, d).astype(x.dtype), aux
+
+
+class SwiGLU(nn.Module):
+    """``W_d (SiLU(W_g x) * W_u x)``: a dense feed-forward, and the
+    shared expert of ``RoutedExperts``. Weights are stored in
+    ``dtype``."""
+    d_ff: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1]
+        init = nn.initializers.normal(0.02)
+        gate = self.param("gate", init, (d, self.d_ff), self.dtype)
+        up = self.param("up", init, (d, self.d_ff), self.dtype)
+        down = self.param("down", init, (self.d_ff, d), self.dtype)
+        x = x.astype(self.dtype)
+        return jnp.matmul(nn.silu(x @ gate) * (x @ up), down,
+                          preferred_element_type=jnp.float32)
+
+
+# RoutedExperts: the most tokens the dense product takes, and the rows of
+# a grouped product's block. What is served lies far to either side (a
+# decode step has at most 64 tokens, a prefill at least 512), so the two
+# were set by that and not by a sweep: nothing between was measured.
+DENSE_BELOW = 256
+BLOCK_ROWS = 256
+
+
+class RoutedExperts(nn.Module):
+    """A routed-experts layer that drops no token, for the experts held
+    HERE (the share of one chip under expert parallelism).
+
+    The router scores every token against all ``num_experts`` (sigmoid,
+    float32), chooses the ``top_k`` largest of ``score + bias``, and
+    weighs the chosen by ``score / sum(chosen scores)`` (if
+    ``renormalize``) times ``scaling``. Of the chosen, this layer
+    computes those in ``held = (first, count)``: the part of the result
+    that its own SwiGLU experts give, plus ``shared_d_ff`` wide shared
+    expert(s) that every chip computes alike. What the absent experts
+    would add is left out; nothing stands in for them. Returns ``(y,
+    counts)``, counts [count] int32 the real tokens sent to each held
+    expert this call.
+
+    Two products, by the number of tokens (static): up to
+    ``DENSE_BELOW`` tokens every held expert multiplies all of them and
+    the unrouted pairs are weighed zero (a decode step: the experts'
+    weights are read once either way, and that is what the step costs);
+    above it the assignments are sorted by expert into row blocks of
+    ``BLOCK_ROWS`` that each belong to one expert, and a loop multiplies
+    the blocks that hold a token (grouped products; rows for the worst
+    case, every assignment landing here, so the load changes the time
+    and never the result).
+    """
+    num_experts: int
+    d_ff: int
+    top_k: int
+    held: Optional[Tuple[int, int]] = None       # None: all of them
+    scaling: float = 1.0
+    renormalize: bool = True
+    shared_d_ff: int = 0
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, valid=None):
+        *lead, d = x.shape
+        xf = x.reshape(-1, d)
+        T = xf.shape[0]
+        first, count = self.held or (0, self.num_experts)
+        real = jnp.ones((T,), bool) if valid is None else valid.reshape(T)
+
+        with jax.named_scope("moe/router"):
+            w_r = self.param("router", nn.initializers.normal(0.02),
+                             (d, self.num_experts), jnp.float32)
+            bias = self.param("router_bias", nn.initializers.zeros,
+                              (self.num_experts,), jnp.float32)
+            scores = jax.nn.sigmoid(jnp.matmul(
+                xf.astype(jnp.float32), w_r,
+                precision=jax.lax.Precision.HIGHEST))          # [T, E]
+            _, chosen = jax.lax.top_k(scores + bias, self.top_k)
+            w = jnp.take_along_axis(scores, chosen, axis=1)    # [T, k]
+            if self.renormalize:
+                w = w / jnp.sum(w, axis=-1, keepdims=True)
+            w = w * self.scaling
+            here = (chosen >= first) & (chosen < first + count) \
+                & real[:, None]
+            # the held experts are 0..count-1 here; `count` means "not
+            # this chip's"
+            local = jnp.where(here, chosen - first, count)
+            counts = jnp.sum(jax.nn.one_hot(local, count + 1,
+                                            dtype=jnp.int32),
+                             axis=(0, 1))[:count]
+
+        init = nn.initializers.normal(0.02)
+        w_gate = self.param("w_gate", init, (count, d, self.d_ff),
+                            self.dtype)
+        w_up = self.param("w_up", init, (count, d, self.d_ff), self.dtype)
+        w_down = self.param("w_down", init, (count, self.d_ff, d),
+                            self.dtype)
+        xb = xf.astype(self.dtype)
+        with jax.named_scope("moe/experts"):
+            if T <= DENSE_BELOW:
+                y = _experts_dense(xb, local, w, w_gate, w_up, w_down)
+            else:
+                y = _experts_sorted(xb, local, w, w_gate, w_up, w_down)
+        if self.shared_d_ff:
+            with jax.named_scope("moe/shared"):
+                y = y + SwiGLU(self.shared_d_ff, self.dtype,
+                               name="shared")(xb)
+        return y.reshape(*lead, d), counts
+
+
+def _experts_dense(x, local, w, w_gate, w_up, w_down):
+    """Every held expert over every token; a pair the router did not
+    choose is weighed zero before the down-projection, which then sums
+    over experts and width in one product."""
+    E = w_gate.shape[0]
+    combine = jnp.sum(jax.nn.one_hot(local, E, dtype=jnp.float32)
+                      * w[..., None], axis=1)                  # [T, E]
+    h = nn.silu(jnp.einsum("td,edf->etf", x, w_gate)) \
+        * jnp.einsum("td,edf->etf", x, w_up)
+    h = h * combine.T[..., None].astype(h.dtype)
+    return jnp.einsum("etf,efd->td", h, w_down,
+                      preferred_element_type=jnp.float32)
+
+
+def _experts_sorted(x, local, w, w_gate, w_up, w_down):
+    """Grouped products: the assignments sorted by expert, each expert's
+    group padded to whole blocks of ``BLOCK_ROWS`` rows, one SwiGLU a
+    block with that block's expert, blocks without a token skipped."""
+    T, d = x.shape
+    E, k, bm = w_gate.shape[0], local.shape[1], BLOCK_ROWS
+    A = T * k
+    e = local.reshape(A)                                   # E: not here
+    sizes = jnp.sum(jax.nn.one_hot(e, E + 1, dtype=jnp.int32), axis=0)
+    padded = -(-sizes[:E] // bm) * bm
+    ends = jnp.cumsum(padded)                              # [E]
+    order = jnp.argsort(e, stable=True)
+    sorted_e = e[order]
+    rank = jnp.arange(A) - (jnp.cumsum(sizes) - sizes)[sorted_e]
+    rows = -(-A // bm) * bm + E * bm                       # worst case
+    dest = jnp.where(sorted_e < E,
+                     (ends - padded)[jnp.minimum(sorted_e, E - 1)] + rank,
+                     rows)
+    xs = jnp.zeros((rows, d), x.dtype).at[dest].set(
+        x[order // k], mode="drop")
+    n_blocks = rows // bm
+    starts = jnp.arange(n_blocks) * bm
+    block_expert = jnp.minimum(
+        jnp.searchsorted(ends, starts, side="right"), E - 1)
+
+    def block(_, b):
+        def run():
+            xb = jax.lax.dynamic_slice_in_dim(xs, b * bm, bm)
+            i = block_expert[b]
+            return jnp.matmul(nn.silu(xb @ w_gate[i]) * (xb @ w_up[i]),
+                              w_down[i], preferred_element_type=jnp.float32)
+        return None, jax.lax.cond(
+            starts[b] < ends[-1], run,
+            lambda: jnp.zeros((bm, d), jnp.float32))
+
+    _, ys = jax.lax.scan(block, None, jnp.arange(n_blocks))
+    ys = ys.reshape(rows, d)
+    back = jnp.zeros((A,), jnp.int32).at[order].set(
+        jnp.minimum(dest, rows - 1).astype(jnp.int32))
+    weight = jnp.where(e < E, w.reshape(A), 0.0)
+    y = ys[back] * weight[:, None]
+    return jnp.sum(y.reshape(T, k, d), axis=1)
 
 
 def expert_sharding_rule(mesh, path: Tuple[str, ...], shape, spec):

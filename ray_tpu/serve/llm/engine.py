@@ -175,6 +175,18 @@ class LLMEngine:
         self.cache = PagedKVCache(self.config.num_blocks,
                                   self.config.block_size)
         adapter.bind_cache(self.cache)
+        self._stateful = bool(getattr(adapter, "has_state", False))
+        if self._stateful:
+            # a recurrent state cannot be cut back, shared by page or
+            # shipped as pages without a snapshot taken at that token
+            self._refuse_with_state(
+                self.config.enable_prefix_cache, "enable_prefix_cache",
+                "a shared prefix page stands for the tokens before it, "
+                "and the state after those tokens was not kept")
+            self._refuse_with_state(
+                self.config.spec_k > 0, "spec_k (speculative decoding)",
+                "a rejected draft token cannot be taken out of the state")
+            adapter.bind_state(self.config.max_running)
         self.prefix_cache = None
         if self.config.enable_prefix_cache:
             from ray_tpu.serve.llm.prefix_cache import RadixPrefixCache
@@ -228,6 +240,14 @@ class LLMEngine:
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="rtpu-llm-engine")
         self._thread.start()
+
+    def _refuse_with_state(self, asked: bool, what: str, why: str):
+        if asked and self._stateful:
+            from ray_tpu.serve.llm.model_runner import RecurrentStateError
+            raise RecurrentStateError(
+                f"{what}: the model keeps recurrent state per sequence; "
+                f"{why}. Needs snapshots of the state at page boundaries "
+                "(ROADMAP R1).")
 
     # ------------------------------------------------------------ intake
 
@@ -290,6 +310,9 @@ class LLMEngine:
         """Prefill-role entry: run the prompt and exactly ONE decode
         step, snapshotting the prompt's KV pages on finish for
         shipment to a decode replica (``take_export``)."""
+        self._refuse_with_state(
+            True, "prefill_export (export_kv)",
+            "the prompt's pages alone do not carry it to another replica")
         sampling = sampling or SamplingParams()
         one = dataclasses.replace(sampling, max_new_tokens=1)
         return self.add_request(prompt_tokens, one, request_id,
@@ -323,6 +346,9 @@ class LLMEngine:
         batch is full, and whatever ``import_kv`` raises on a blob
         mismatch — the deployment falls back to plain ``add_request``
         (re-prefill) in both cases."""
+        self._refuse_with_state(
+            True, "adopt_request (import_kv)",
+            "a blob of pages alone does not restore a prompt")
         sampling = sampling or SamplingParams()
         n_prompt = len(prompt_tokens)
         if n_prompt == 0:
@@ -548,6 +574,9 @@ class LLMEngine:
                     self.adapter, "bucket_first_calls", 0)),
             }
         out.update(self.cache.stats())
+        counters = getattr(self.adapter, "counters", None)
+        if counters is not None:
+            out.update(counters())
         if self.prefix_cache is not None:
             out.update(self.prefix_cache.stats())
         return out
@@ -693,7 +722,8 @@ class LLMEngine:
     def _decode(self, seqs: List[Sequence]):
         t0 = time.time()
         with tracing.step_span("llm.step.decode", n=len(seqs)):
-            logits = self.adapter.decode(seqs)      # [B, V] np.ndarray
+            logits = self.adapter.decode(
+                seqs, **self._tokens_only(seqs))    # [B, V] np.ndarray
         self._decode_rows_total += len(seqs)
         self._runner_seconds_total += time.time() - t0
         self._commit(seqs, logits, step_t0=t0)
@@ -745,7 +775,8 @@ class LLMEngine:
                                tokens=tokens):
             for s in seqs:
                 s.t_prefill_start = t0
-            logits = self.adapter.prefill(seqs)     # [B, V]
+            logits = self.adapter.prefill(
+                seqs, **self._tokens_only(seqs))    # [B, V]
             t1 = time.time()
             self._runner_seconds_total += t1 - t0
             if self.prefix_cache is not None:
@@ -762,8 +793,23 @@ class LLMEngine:
                     self._running.append(s.seq_id)
         self._commit(seqs, logits, step_t0=t0)
 
+    @staticmethod
+    def _greedy(seq: Sequence) -> bool:
+        return seq.sampling.temperature <= 0 or seq.rng is None
+
+    def _tokens_only(self, seqs: List[Sequence]) -> Dict[str, bool]:
+        """An adapter that finds the greedy token on the device is asked
+        for tokens [B] in place of logits [B, V] when no row of the
+        step samples; any other adapter is called as ever."""
+        if getattr(self.adapter, "greedy_on_device", False) \
+                and all(self._greedy(s) for s in seqs):
+            return {"tokens_only": True}
+        return {}
+
     def _sample(self, seq: Sequence, row) -> int:
-        if seq.sampling.temperature <= 0 or seq.rng is None:
+        if getattr(row, "ndim", 1) == 0:    # the adapter's greedy token
+            return int(row)
+        if self._greedy(seq):
             return int(row.argmax())
         x = [v / seq.sampling.temperature for v in row.tolist()]
         m = max(x)

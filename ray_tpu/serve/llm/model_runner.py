@@ -43,6 +43,26 @@ Two implementations:
   incremental step is causal at the right offsets by construction
   (``q_positions = seq_lengths[:, None] + arange(S)``), so batched
   speculative verification is numerically the plain decode loop.
+
+A model may state what it caches instead (``kind="kimi_linear"``:
+``models.kimi_linear.cache_spec``): pages for some layers only, of
+the row width it names, and per-sequence *state* for others. The
+adapter then owns one pool a named page kind and one array a named state
+kind with a **state slot** per running sequence (slot 0 is the null
+slot, where padding rows read and write): a slot is taken and zeroed
+when a sequence is prefilled (``runner.state.admit``) and freed in
+``release``. Pools and state go through the same ``_run``: bucketed,
+donated, written in place. A decode step of such a model is a program
+of its own with one token a row (``S = 1``: the one-token recurrence),
+not the shortest prefill bucket; in the bucket that is as wide as the
+state has slots, the rows go in slot order (``_by_slot``) and the state
+is updated where it lies. Such an adapter also finds each row's greedy
+token on the device (``greedy_on_device``): asked with
+``tokens_only=True``, ``prefill`` / ``decode`` return tokens [B] and the
+logits are not fetched. What cannot work without snapshots of
+the state raises ``RecurrentStateError``: ``decode_window`` /
+``rollback``, ``export_kv`` / ``import_kv`` (and the engine refuses
+``enable_prefix_cache`` and ``spec_k`` at construction).
 """
 
 from __future__ import annotations
@@ -54,6 +74,14 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from ray_tpu._private import tracing
+
+
+class RecurrentStateError(NotImplementedError):
+    """A feature that drops, shares or ships cached tokens was asked of
+    a model that keeps recurrent state per sequence. Pages can be cut at
+    any token; a state can only be restored from a snapshot taken at
+    that token, and nothing takes such snapshots yet (ROADMAP R1)."""
+
 
 # the shortest token-axis bucket: a decode step (one new token a row) and
 # any prefill of at most this many tokens run the same program
@@ -266,6 +294,9 @@ class FlaxModelAdapter:
         import jax.numpy as jnp
         self._jnp = jnp
         self.kind = kind
+        # what the model says it caches (None: K and V pages, every
+        # layer alike)
+        self._spec: Optional[Dict[str, Any]] = None
         if kind == "gpt2":
             from ray_tpu.models import gpt2
             self.cfg = config or gpt2.GPT2Config.tiny()
@@ -281,12 +312,20 @@ class FlaxModelAdapter:
             self.n_kv_heads = self.cfg.n_kv_heads
             self.head_dim = self.cfg.head_dim
             self.vocab_size = self.cfg.vocab_size
+        elif kind == "kimi_linear":
+            from ray_tpu.models import kimi_linear
+            self.cfg = config or kimi_linear.KimiLinearConfig.tiny()
+            self.model = kimi_linear.KimiLinearModel(self.cfg)
+            self._blocks = None            # three block kinds: unrolled
+            self.vocab_size = self.cfg.vocab_size
+            self._spec = kimi_linear.cache_spec(self.cfg)
         else:
             raise ValueError(f"unknown model kind {kind!r}")
         if params is None:
             dummy = jnp.zeros((1, 8), jnp.int32)
             params = self.model.init(jax.random.PRNGKey(seed), dummy)
         self.params = params
+        self._expert_tokens_total = self._expert_tokens_last = None
         self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
         self.bucket_first_calls = 0        # _fns misses: steps that compiled
         self._lock = threading.Lock()
@@ -299,6 +338,19 @@ class FlaxModelAdapter:
     def params(self, tree):
         # accepted as the models' training form (one entry a block) too
         self._params = _stack_blocks(tree, self._blocks, self.n_layers)
+
+    @property
+    def has_state(self) -> bool:
+        """The model keeps per-sequence recurrent state beside pages."""
+        return bool(self._spec and self._spec.get("state"))
+
+    @property
+    def greedy_on_device(self) -> bool:
+        """``prefill`` / ``decode`` take ``tokens_only=True`` and then
+        return each row's greedy token [B] in place of its logits
+        [B, V] (64 rows of 40,960 logits are 10.5 MB a step to fetch
+        and to search on the host: my chip runs, PR 28)."""
+        return self._spec is not None
 
     @property
     def n_layers(self) -> int:
@@ -319,10 +371,20 @@ class FlaxModelAdapter:
         jnp = self._jnp
         self.cache = cache
         dtype = self.cfg.dtype
-        shape = (self.n_layers, cache.num_blocks, cache.block_size,
-                 self.n_kv_heads * self.head_dim)
-        self.k_pages = jnp.zeros(shape, dtype)
-        self.v_pages = jnp.zeros(shape, dtype)
+        if self._spec is None:
+            shape = (self.n_layers, cache.num_blocks, cache.block_size,
+                     self.n_kv_heads * self.head_dim)
+            self.k_pages = jnp.zeros(shape, dtype)
+            self.v_pages = jnp.zeros(shape, dtype)
+        else:
+            # one pool a page kind the model names; state arrays come
+            # with ``bind_state``
+            self._arrays: Dict[str, Any] = {
+                name: jnp.zeros((p["layers"], cache.num_blocks,
+                                 cache.block_size, p["row"]), p["dtype"])
+                for name, p in self._spec["pages"].items()}
+            self._free_slots: List[int] = []
+            self.state_slots = 0
         # NB: every block table is padded to the worst-case blocks per
         # sequence so decode jits once per batch bucket
         self.nb_max = cache.blocks_for(
@@ -330,7 +392,85 @@ class FlaxModelAdapter:
                     getattr(self.cfg, "max_seq_len", 2048)))
         self._state: Dict[str, Dict[str, Any]] = {}
 
+    def bind_state(self, max_running: int):
+        """One state slot a running sequence (and the null slot 0), for
+        each array the model's ``cache_spec`` names under ``state``:
+        [layers, slots, ...]."""
+        jnp = self._jnp
+        self.state_slots = int(max_running)
+        for name, p in self._spec["state"].items():
+            layers, *rest = p["shape"]
+            self._arrays[name] = jnp.zeros(
+                (layers, self.state_slots + 1, *rest), p["dtype"])
+        self._free_slots = list(range(self.state_slots, 0, -1))
+
+    def counters(self) -> Dict[str, Any]:
+        """What ``engine.metrics()`` adds for a model with state or
+        routed experts (docs/TRACING.md)."""
+        if self._spec is None:
+            return {}
+        out = {"state_slots_total": self.state_slots,
+               "state_slots_in_use": self.state_slots
+               - len(self._free_slots)}
+        if self._expert_tokens_total is not None:
+            out["expert_tokens_total"] = self._expert_tokens_total.tolist()
+            out["expert_tokens_last_step"] = self._expert_tokens_last.tolist()
+        return out
+
+    def _take_slots(self, seq_ids: List[str]) -> List[int]:
+        """A zeroed state slot for each newly prefilled sequence."""
+        jnp = self._jnp
+        if len(self._free_slots) < len(seq_ids):
+            raise RuntimeError(
+                f"{len(seq_ids)} sequences admitted with "
+                f"{len(self._free_slots)} state slots free: the engine's "
+                "max_running exceeds what bind_state was given")
+        slots = [self._free_slots.pop() for _ in seq_ids]
+        with tracing.step_span("runner.state.admit", n=len(slots)):
+            idx = np.zeros((_pad_pow2(len(slots)),), np.int32)
+            idx[:len(slots)] = slots
+            names = list(self._spec["state"])
+            with self._lock:
+                cleared = self._zero_fn()(
+                    jnp.asarray(idx), *(self._arrays[n] for n in names))
+                self._arrays.update(zip(names, cleared))
+        return slots
+
+    def _zero_fn(self):
+        fn = self._fns.get("zero_slots")
+        if fn is None:
+            import jax
+
+            def llm_state_admit(idx, *arrays):
+                return tuple(a.at[:, idx].set(0) for a in arrays)
+            donate = tuple(range(1, 1 + len(self._spec["state"]))) \
+                if jax.devices()[0].platform == "tpu" else ()
+            fn = self._fns["zero_slots"] = jax.jit(
+                llm_state_admit, donate_argnums=donate)
+        return fn
+
+    def state_of(self, seq_id: str) -> Dict[str, Any]:
+        """A copy of the sequence's recurrent state as it lies in its
+        slot: one device array a state kind, [layers, ...] (a check or a
+        debugger reads it; the step never does)."""
+        fn = self._fns.get("read_slot")
+        if fn is None:
+            import jax
+            fn = self._fns["read_slot"] = jax.jit(
+                lambda slot, *arrays: tuple(a[:, slot] for a in arrays))
+        names = list(self._spec["state"])
+        slot = np.int32(self._state[seq_id]["slot"])
+        with self._lock:
+            return dict(zip(names, fn(
+                slot, *(self._arrays[n] for n in names))))
+
     def copy_page(self, src: int, dst: int):
+        if self._spec is not None:
+            with self._lock:
+                for name in self._spec["pages"]:
+                    a = self._arrays[name]
+                    self._arrays[name] = a.at[:, dst].set(a[:, src])
+            return
         with self._lock:
             self.k_pages = self.k_pages.at[:, dst].set(
                 self.k_pages[:, src])
@@ -344,6 +484,10 @@ class FlaxModelAdapter:
             return fn
         import jax
         jnp = self._jnp
+        if self._spec is not None:
+            fn = self._fns[key] = self._spec_step_fn(B, S)
+            self.bucket_first_calls += 1
+            return fn
 
         def step(params, tokens, k_pages, v_pages, block_tables,
                  seq_lengths, valid):
@@ -370,43 +514,146 @@ class FlaxModelAdapter:
         self.bucket_first_calls += 1
         return fn
 
-    def _run(self, rows: List[Dict[str, Any]], op: str) -> np.ndarray:
+    def _by_slot(self, B: int, S: int) -> bool:
+        """A decode bucket as wide as the state has slots runs with its
+        rows in slot order (row r is slot r + 1, free slots are padding
+        rows): the program then updates the state where it lies. (Rows
+        gathered from and scattered back to their slots cost a 64-row
+        step 9 of its 29 ms on the device: my chip run, PR 28.)"""
+        return self.has_state and S == 1 and B == self.state_slots
+
+    def _spec_step_fn(self, B: int, S: int):
+        """The step of a model that states its cache: every pool and
+        state array is an argument, donated, and comes back written in
+        place. The rows' integers come as ONE array (``_pack``): each
+        upload is a place where the engine's thread gives way to the
+        threads that answer the streams. Beside the logits the program
+        returns ``small``: each row's greedy token, then the routed
+        layers' per-expert token counts of the step, so that a step
+        whose rows all sample greedily fetches B + layers x experts
+        integers and leaves the logits on the device."""
+        import jax
+        jnp = self._jnp
+        names = list(self._arrays)
+        by_slot = self._by_slot(B, S)
+
+        def step(params, packed, *arrays):
+            tokens, n_new = packed[:, :S], packed[:, S]
+            seq_lengths, slots = packed[:, S + 1], packed[:, S + 2]
+            cache = dict(zip(names, arrays), block_tables=packed[:, S + 3:])
+            if not by_slot:
+                cache["slots"] = slots
+            valid = jnp.arange(S)[None, :] < n_new[:, None]
+            logits, cache, counts = self.model.apply(
+                params, tokens, cache=cache, seq_lengths=seq_lengths,
+                valid=valid, logits_at=jnp.maximum(n_new - 1, 0))
+            logits = logits[:, 0]
+            small = jnp.concatenate([
+                jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                counts.reshape(-1).astype(jnp.int32)])
+            return (logits, small, *(cache[n] for n in names))
+
+        step.__name__ = step.__qualname__ = (
+            f"llm_decode_b{B}" if S == 1 else f"llm_prefill_b{B}_s{S}")
+        donate = tuple(range(2, 2 + len(names))) \
+            if jax.devices()[0].platform == "tpu" else ()
+        return jax.jit(step, donate_argnums=donate)
+
+    def _run(self, rows: List[Dict[str, Any]], op: str,
+             tokens_only: bool = False) -> np.ndarray:
         """rows: [{tokens: [ints], len: cache length, table: [pages]}]
         -> last-token logits [B, V] (or full [B, S, V] when ``op`` is
-        ``verify``) for the real rows. ``op`` (prefill | decode |
-        verify) labels the step spans."""
+        ``verify``) for the real rows; with ``tokens_only`` (a model
+        that states its cache) each row's greedy token [B] instead.
+        ``op`` (prefill | decode | verify) labels the step spans."""
         jnp = self._jnp
         full = op == "verify"
         B = _pad_pow2(len(rows))
         S = _pad_pow2(max(len(r["tokens"]) for r in rows), _MIN_S)
+        if self.has_state and op == "decode":
+            S = 1           # the one-token recurrence, its own program
+        # where each row goes in the padded batch: in order, or (a full
+        # decode bucket of a model with state) to its slot's place
+        at = [r["slot"] - 1 for r in rows] if self._by_slot(B, S) \
+            else list(range(len(rows)))
         with tracing.step_span("runner.build_inputs", op=op, B=B, S=S):
-            tokens = np.zeros((B, S), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            valid = np.zeros((B, S), bool)
-            tables = np.zeros((B, self.nb_max), np.int32)
-            for i, r in enumerate(rows):
-                n = len(r["tokens"])
-                tokens[i, :n] = r["tokens"]
-                lengths[i] = r["len"]
-                valid[i, :n] = True
-                t = r["table"][:self.nb_max]
-                tables[i, :len(t)] = t
+            if self._spec is not None:
+                packed = self._pack(rows, at, B, S)
+            else:
+                tokens = np.zeros((B, S), np.int32)
+                lengths = np.zeros((B,), np.int32)
+                valid = np.zeros((B, S), bool)
+                tables = np.zeros((B, self.nb_max), np.int32)
+                for i, r in enumerate(rows):
+                    n = len(r["tokens"])
+                    tokens[i, :n] = r["tokens"]
+                    lengths[i] = r["len"]
+                    valid[i, :n] = True
+                    t = r["table"][:self.nb_max]
+                    tables[i, :len(t)] = t
         with tracing.step_span("runner.dispatch", B=B, S=S,
                                first_call=(B, S, full) not in self._fns):
             fn = self._step_fn(B, S, full)
             with self._lock:
-                logits, self.k_pages, self.v_pages = fn(
-                    self.params, jnp.asarray(tokens), self.k_pages,
-                    self.v_pages, jnp.asarray(tables),
-                    jnp.asarray(lengths), jnp.asarray(valid))
+                if self._spec is None:
+                    logits, self.k_pages, self.v_pages = fn(
+                        self.params, jnp.asarray(tokens), self.k_pages,
+                        self.v_pages, jnp.asarray(tables),
+                        jnp.asarray(lengths), jnp.asarray(valid))
+                else:
+                    logits, small, *arrays = fn(
+                        self.params, jnp.asarray(packed),
+                        *self._arrays.values())
+                    self._arrays = dict(zip(self._arrays, arrays))
         with tracing.step_span("runner.fetch") as span:
-            out = np.asarray(logits[:len(rows)], np.float32)
+            if self._spec is None:
+                out = np.asarray(logits[:len(rows)], np.float32)
+            else:
+                # whole arrays, cut on the host: a slice on the device
+                # is a program a row count
+                small = np.asarray(small)
+                out = small[:B][at] if tokens_only \
+                    else np.asarray(logits, np.float32)[at]
+                if small.size > B:
+                    span.set(**self._count_experts(
+                        small[B:].reshape(self._spec["expert_counts"])))
             span.set(bytes=out.nbytes)
         return out
 
-    def prefill(self, seqs) -> np.ndarray:
+    def _pack(self, rows, at, B: int, S: int) -> np.ndarray:
+        """The rows' integers as one int32 array [B, S + 3 + nb_max]: a
+        row's new tokens, how many of them are real, the tokens cached
+        before them, its state slot, its block table. A padding row is
+        zeros: nothing real, the null slot, the null page."""
+        packed = np.zeros((B, S + 3 + self.nb_max), np.int32)
+        for i, r in zip(at, rows):
+            n = len(r["tokens"])
+            packed[i, :n] = r["tokens"]
+            packed[i, S:S + 3] = n, r["len"], r.get("slot", 0)
+            t = r["table"][:self.nb_max]
+            packed[i, S + 3:S + 3 + len(t)] = t
+        return packed
+
+    def _count_experts(self, counts: np.ndarray) -> Dict[str, Any]:
+        """counts [routed layers, experts held]: the step's tokens per
+        expert. Kept cumulatively for ``counters()``; the step span gets
+        how many experts the step touched and how uneven the load was
+        (largest over mean, the median of the layers)."""
+        if self._expert_tokens_total is None:
+            self._expert_tokens_total = np.zeros(counts.shape, np.int64)
+        self._expert_tokens_total += counts
+        self._expert_tokens_last = counts
+        mean = counts.mean(axis=1)
+        return {"experts_touched": int((counts > 0).sum()),
+                "expert_tokens": int(counts.sum()),
+                "moe_max_over_mean": float(np.median(
+                    counts.max(axis=1) / np.maximum(mean, 1e-9)))}
+
+    def prefill(self, seqs, tokens_only: bool = False) -> np.ndarray:
         rows = []
-        for s in seqs:
+        slots = self._take_slots([s.seq_id for s in seqs]) \
+            if self.has_state else [0] * len(seqs)
+        for s, slot in zip(seqs, slots):
             cached = _cached_tokens(s)
             if cached % self.cache.block_size:
                 # copy-on-extend: the suffix write lands in the last
@@ -417,25 +664,27 @@ class FlaxModelAdapter:
                     self.copy_page(old, new)
             table = self.cache.block_table(s.seq_id)
             self._state[s.seq_id] = {"table": table,
-                                     "len": len(s.prompt)}
+                                     "len": len(s.prompt), "slot": slot}
             rows.append({"tokens": s.prompt[cached:], "len": cached,
-                         "table": table})
-        return self._run(rows, "prefill")
+                         "table": table, "slot": slot})
+        return self._run(rows, "prefill", tokens_only)
 
-    def decode(self, seqs) -> np.ndarray:
+    def decode(self, seqs, tokens_only: bool = False) -> np.ndarray:
         rows = []
         for s in seqs:
             st = self._state[s.seq_id]
             rows.append({"tokens": [s.tokens[-1]], "len": st["len"],
-                         "table": st["table"]})
+                         "table": st["table"], "slot": st.get("slot", 0)})
             st["len"] += 1
-        return self._run(rows, "decode")
+        return self._run(rows, "decode", tokens_only)
 
     def decode_window(self, seqs, windows) -> List[np.ndarray]:
         """One batched multi-token incremental step; causal masking at
         the right offsets comes from ``cached_attention``'s
         ``q_positions``, so position j's logits condition on exactly
         window[:j+1] — the speculative verify contract."""
+        self._refuse_with_state("decode_window", "a rejected position "
+                                "cannot be taken out of the state again")
         rows = []
         for s, win in zip(seqs, windows):
             st = self._state[s.seq_id]
@@ -445,12 +694,23 @@ class FlaxModelAdapter:
         full = self._run(rows, "verify")       # [B, S, V]
         return [full[i, :len(win)] for i, win in enumerate(windows)]
 
+    def _refuse_with_state(self, what: str, why: str):
+        if self.has_state:
+            raise RecurrentStateError(
+                f"{what}: model kind {self.kind!r} keeps recurrent state "
+                f"per sequence, and {why}; it needs a snapshot of the "
+                "state at the token in question, which nothing takes yet")
+
     def rollback(self, seq_id: str, n: int):
+        self._refuse_with_state("rollback", "the last n tokens cannot be "
+                                "taken out of the state again")
         st = self._state.get(seq_id)
         if st is not None and n > 0:
             st["len"] = max(0, st["len"] - int(n))
 
     def export_kv(self, seq_id: str, n_prompt: int) -> Dict[str, Any]:
+        self._refuse_with_state("export_kv", "the pages alone do not "
+                                "carry a prompt to another replica")
         jnp = self._jnp
         bs = self.cache.block_size
         nb = -(-int(n_prompt) // bs)
@@ -465,6 +725,8 @@ class FlaxModelAdapter:
 
     def import_kv(self, seq_id: str, n_prompt: int,
                   blob: Dict[str, Any]):
+        self._refuse_with_state("import_kv", "a blob of pages alone does "
+                                "not restore a prompt")
         jnp = self._jnp
         if blob.get("kind") != f"flax:{self.kind}":
             raise ValueError(
@@ -483,17 +745,20 @@ class FlaxModelAdapter:
         self._state[seq_id] = {"table": table, "len": int(n_prompt)}
 
     def release(self, seq_id: str):
-        self._state.pop(seq_id, None)
+        st = self._state.pop(seq_id, None)
+        if st is not None and st.get("slot"):
+            self._free_slots.append(st["slot"])
 
 
 def make_adapter(model: str = "toy",
                  model_config: Optional[Dict[str, Any]] = None):
     """Deployment-facing factory: ``model`` is ``toy`` |
-    ``gpt2`` | ``llama`` (tiny test configs unless ``model_config``
-    overrides)."""
+    ``gpt2`` | ``llama`` | ``kimi_linear`` (tiny test configs unless
+    ``model_config`` overrides)."""
     model_config = dict(model_config or {})
     if model == "toy":
         return ToyAdapter(**model_config)
-    if model in ("gpt2", "llama"):
+    if model in ("gpt2", "llama", "kimi_linear"):
         return FlaxModelAdapter(kind=model, **model_config)
-    raise ValueError(f"unknown model {model!r} (toy | gpt2 | llama)")
+    raise ValueError(
+        f"unknown model {model!r} (toy | gpt2 | llama | kimi_linear)")

@@ -181,3 +181,71 @@ def test_served_step_writes_the_pool_in_place(one_chip, topo, monkeypatch,
     _, twice = _served_step(one_chip, topo, monkeypatch, 2049, B, S, full)
     assert memory.temp_size_in_bytes \
         == twice.memory_analysis().temp_size_in_bytes
+
+
+def _kimi_step(one_chip, topo, monkeypatch, pages, slots, B, S):
+    """``FlaxModelAdapter``'s step for Kimi-Linear at the published
+    widths, cut to one period (K K K M), 8 experts and 2048 rows of the
+    vocabulary, over ``pages`` latent pages and ``slots`` state slots."""
+    from ray_tpu.models.kimi_linear import KimiLinearConfig
+    from ray_tpu.serve.llm.kv_cache import PagedKVCache
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    cfg = KimiLinearConfig(vocab_size=2048, num_hidden_layers=4,
+                           experts_held=(0, 8), max_seq_len=1024)
+    adapter = FlaxModelAdapter("kimi_linear", cfg, params={})
+    params = jax.tree_util.tree_map(
+        lambda s: sds(s.shape, s.dtype),
+        jax.eval_shape(adapter.model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8), jnp.int32)))
+    adapter.bind_cache(PagedKVCache(2, 16))
+    adapter.bind_state(1)
+    adapter.state_slots = slots      # B == slots: rows in slot order
+    arrays = []
+    for name, a in adapter._arrays.items():
+        n = pages if name in adapter._spec["pages"] else slots + 1
+        arrays.append(sds((a.shape[0], n, *a.shape[2:]), a.dtype))
+    with monkeypatch.context() as m:
+        m.setattr(jax, "devices", lambda *a, **k: topo.devices)
+        fn = adapter._step_fn(B, S)
+    with jax.default_matmul_precision("default"):
+        return arrays, fn.lower(
+            params, sds((B, S + 3 + adapter.nb_max), jnp.int32),
+            *arrays).compile()
+
+
+@pytest.mark.parametrize("B,S,slots", [(16, 1, 32), (16, 1, 16),
+                                       (1, 256, 32)],
+                         ids=["decode", "decode_by_slot", "prefill"])
+def test_kimi_step_writes_pages_and_state_in_place(one_chip, topo,
+                                                   monkeypatch, B, S, slots):
+    """The latent pool and both state arrays are donated and come back
+    as the program's outputs; nothing of the pool's or the state's size
+    is copied, laid out anew, padded or stacked; what the program needs
+    beside its arguments grows with neither."""
+    import math
+    import re
+    arrays, big = _kimi_step(one_chip, topo, monkeypatch, 2049, slots, B, S)
+    memory = big.memory_analysis()
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in arrays)
+    # (the slot axis of the small arrays is padded to whole tiles)
+    assert held <= memory.alias_size_in_bytes <= 1.02 * held
+    least = min(math.prod(a.shape) for a in arrays[:2])  # pool, KDA state
+    moved = [
+        line.strip()[:120] for line in big.as_text().splitlines()
+        for m in [re.search(r" = \w+\[([\d,]+)\]\S* (copy|copy-start|pad|"
+                            r"concatenate)\(", line)]
+        if m and math.prod(map(int, m.group(1).split(","))) >= least]
+    assert not moved, moved
+    # (one row's scatter is a dynamic-update-slice that names the whole
+    # array and writes a slot of it in place: a copy of it would show
+    # in the temporaries, which must not grow with pages or slots)
+    more = slots if slots == B else 2 * slots
+    grown, twice = _kimi_step(one_chip, topo, monkeypatch, 4097, more, B, S)
+    growth = sum(math.prod(a.shape) * a.dtype.itemsize for a in grown) - held
+    assert twice.memory_analysis().temp_size_in_bytes \
+        - memory.temp_size_in_bytes < 0.1 * growth
+    if slots == B:      # and no loop over the rows writes the state back
+        assert " while(" not in big.as_text()
