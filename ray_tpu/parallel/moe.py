@@ -20,7 +20,10 @@ Top-1 (Switch) routing with the standard load-balance auxiliary loss.
 ``RoutedExperts`` beside it is the layer the served models use: sigmoid
 scores over all experts, top-k, renormalised, no capacity and no drop,
 SwiGLU experts, computed for the experts one chip holds (docs/
-LLM_SERVING.md, "Routed experts").
+LLM_SERVING.md, "Routed experts"). Its product multiplies the groups
+that hold a token and reads no other expert's weights: whole rows
+through the touched experts for few tokens (``ops/routed_experts.py``),
+sorted row blocks for many.
 """
 
 from __future__ import annotations
@@ -30,6 +33,8 @@ from typing import Any, Callable, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops.routed_experts import touched_experts
 
 
 class MoE(nn.Module):
@@ -116,11 +121,13 @@ class SwiGLU(nn.Module):
                           preferred_element_type=jnp.float32)
 
 
-# RoutedExperts: the most tokens the dense product takes, and the rows of
-# a grouped product's block. What is served lies far to either side (a
-# decode step has at most 64 tokens, a prefill at least 512), so the two
-# were set by that and not by a sweep: nothing between was measured.
-DENSE_BELOW = 256
+# RoutedExperts: the most tokens that go as whole rows through the touched
+# experts, and the rows of a sorted product's block. Up to WHOLE_ROWS_BELOW
+# an expert's weights (read once) outweigh the rows it did not get; above,
+# rows are worth sorting. What is served lies far to either side (a decode
+# step has at most 64 tokens, a prefill at least 512), so the two were set
+# by that and not by a sweep: nothing between was measured.
+WHOLE_ROWS_BELOW = 256
 BLOCK_ROWS = 256
 
 
@@ -139,15 +146,18 @@ class RoutedExperts(nn.Module):
     counts)``, counts [count] int32 the real tokens sent to each held
     expert this call.
 
-    Two products, by the number of tokens (static): up to
-    ``DENSE_BELOW`` tokens every held expert multiplies all of them and
-    the unrouted pairs are weighed zero (a decode step: the experts'
-    weights are read once either way, and that is what the step costs);
-    above it the assignments are sorted by expert into row blocks of
-    ``BLOCK_ROWS`` that each belong to one expert, and a loop multiplies
-    the blocks that hold a token (grouped products; rows for the worst
-    case, every assignment landing here, so the load changes the time
-    and never the result).
+    One algorithm, "multiply the groups that hold a token", in two
+    forms by the number of tokens (static). Up to ``WHOLE_ROWS_BELOW``
+    tokens (a decode step) each expert that got a token multiplies all
+    the rows, the rows that did not choose it weighed zero, and an expert
+    without a token is not read at all: what such a step costs is the
+    weights it reads, so its time follows ``counts > 0`` (the step's
+    ``experts_touched``) and its result does not. Forward only
+    (``ops/routed_experts.py``). Above it the assignments are sorted by
+    expert into row blocks of ``BLOCK_ROWS`` that each belong to one
+    expert, and a loop multiplies the blocks that hold a token (rows for
+    the worst case, every assignment landing here, so the load changes
+    the time and never the result).
     """
     num_experts: int
     d_ff: int
@@ -196,8 +206,12 @@ class RoutedExperts(nn.Module):
                             self.dtype)
         xb = xf.astype(self.dtype)
         with jax.named_scope("moe/experts"):
-            if T <= DENSE_BELOW:
-                y = _experts_dense(xb, local, w, w_gate, w_up, w_down)
+            if T <= WHOLE_ROWS_BELOW:
+                combine = jnp.sum(
+                    jax.nn.one_hot(local, count, dtype=jnp.float32)
+                    * w[..., None], axis=1)                    # [T, count]
+                y = touched_experts(xb, combine, counts, w_gate, w_up,
+                                    w_down)
             else:
                 y = _experts_sorted(xb, local, w, w_gate, w_up, w_down)
         if self.shared_d_ff:
@@ -205,20 +219,6 @@ class RoutedExperts(nn.Module):
                 y = y + SwiGLU(self.shared_d_ff, self.dtype,
                                name="shared")(xb)
         return y.reshape(*lead, d), counts
-
-
-def _experts_dense(x, local, w, w_gate, w_up, w_down):
-    """Every held expert over every token; a pair the router did not
-    choose is weighed zero before the down-projection, which then sums
-    over experts and width in one product."""
-    E = w_gate.shape[0]
-    combine = jnp.sum(jax.nn.one_hot(local, E, dtype=jnp.float32)
-                      * w[..., None], axis=1)                  # [T, E]
-    h = nn.silu(jnp.einsum("td,edf->etf", x, w_gate)) \
-        * jnp.einsum("td,edf->etf", x, w_up)
-    h = h * combine.T[..., None].astype(h.dtype)
-    return jnp.einsum("etf,efd->td", h, w_down,
-                      preferred_element_type=jnp.float32)
 
 
 def _experts_sorted(x, local, w, w_gate, w_up, w_down):

@@ -126,6 +126,44 @@ def test_paged_attention_decode(one_chip, H, Hkv, D):
              sds((B, NB), jnp.int32), sds((B,), jnp.int32))
 
 
+def _routed_experts(monkeypatch, T, sharding, mesh=None):
+    """The few-token product at Kimi-Linear's widths (64 held experts of
+    3 x 2304 x 1024 bfloat16) as the chip runs it: the Mosaic kernel,
+    not its interpretation."""
+    from ray_tpu.ops.routed_experts import touched_experts
+    E, d, d_ff = 64, 2304, 1024
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def product(*args):
+        with A.attention_mesh(mesh):
+            return touched_experts(*args)
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    return _compile(
+        product, sds((T, d), jnp.bfloat16), sds((T, E), jnp.float32),
+        sds((E,), jnp.int32), sds((E, d, d_ff), jnp.bfloat16),
+        sds((E, d, d_ff), jnp.bfloat16), sds((E, d_ff, d), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("T", [1, 64, 256])
+def test_routed_experts_few_tokens(one_chip, monkeypatch, T):
+    """One row (padded to a sublane tile), a full decode batch, and the
+    most rows that go this way; no product over the whole stack beside
+    the kernel."""
+    text = _routed_experts(monkeypatch, T, one_chip)
+    assert " convolution(" not in text and " dot(" not in text
+
+
+def test_routed_experts_shard_mapped_on_dp2_tp2(topo, monkeypatch):
+    """Under ``attention_mesh`` the kernel runs inside ``shard_map`` on
+    every device's own copy; without it the mesh refuses it."""
+    mesh, _ = _mesh4(topo)
+    _routed_experts(monkeypatch, 64, NamedSharding(mesh, P()), mesh)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _routed_experts(monkeypatch, 64, NamedSharding(mesh, P()))
+
+
 def _served_step(one_chip, topo, monkeypatch, pages, B, S, full):
     """``FlaxModelAdapter``'s jitted step at GPT-2 large's width, cut to
     4 layers, compiled for the chip over a pool of ``pages`` pages."""
@@ -210,6 +248,8 @@ def _kimi_step(one_chip, topo, monkeypatch, pages, slots, B, S):
     with monkeypatch.context() as m:
         m.setattr(jax, "devices", lambda *a, **k: topo.devices)
         fn = adapter._step_fn(B, S)
+    # the routed experts' kernel as the chip runs it, not interpreted
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
     with jax.default_matmul_precision("default"):
         return arrays, fn.lower(
             params, sds((B, S + 3 + adapter.nb_max), jnp.int32),
