@@ -428,11 +428,16 @@ class ReconnectingConnection:
             return self._conn
         if self._lock is None:
             self._lock = asyncio.Lock()
+        # the deadline runs from when the caller asked, not from when it
+        # got the lock: callers queued behind a re-dial that failed each
+        # try once more and fail, instead of each waiting out a
+        # reconnect_timeout_s of its own, one after the other (a driver
+        # with ten calls queued on a dead GCS came back after 300 s)
+        deadline = (asyncio.get_running_loop().time()
+                    + self.reconnect_timeout_s)
         async with self._lock:
             if self._conn is not None and not self._conn._closed:
                 return self._conn
-            deadline = (asyncio.get_running_loop().time()
-                        + self.reconnect_timeout_s)
             delay = 0.05
             first = self._conn is None
             while True:

@@ -657,7 +657,21 @@ class Worker:
             except Exception:
                 pass
         if self._server is not None:
-            self._server.close()
+            # on the IO loop, whose object it is: closed from this thread
+            # asyncio's Server raced the loop's own detach of a
+            # connection that was closing (TypeError in Server._wakeup),
+            # and the io loop below was then never stopped
+            server = self._server
+
+            async def _close_server():
+                server.close()
+            try:
+                if self.io.loop.is_running():
+                    self.io.run(_close_server(), timeout=5)
+                else:
+                    server.close()
+            except Exception:
+                pass
         if self.io is not None:
             self.io.stop()
 
@@ -1841,6 +1855,16 @@ class Worker:
         the io loop."""
         task_hex = payload["task_id"]
         state = self.pending_tasks.get(task_hex)
+        if state is not None and payload.get("app_error") \
+                and state.retries_left != 0 \
+                and state.spec.get("retry_exceptions"):
+            # before the returns are stored: a getter looks in the memory
+            # store each time its wait step ends, and would raise the
+            # error of an attempt that is being retried
+            state.retries_left -= 1
+            self._bump_attempt(state)
+            self.io.run_async(self._retry(state))
+            return
         for ret in payload["returns"]:
             oid = ObjectID.from_hex(ret["object_id"])
             if ret.get("inline") is not None:
@@ -1857,12 +1881,6 @@ class Worker:
                 # is on another node
                 self.reference_counter.mark_in_plasma(oid)
         if state is not None:
-            if payload.get("app_error") and state.retries_left != 0 and \
-                    state.spec.get("retry_exceptions"):
-                state.retries_left -= 1
-                self._bump_attempt(state)
-                self.io.run_async(self._retry(state))
-                return
             state.done = True
             state.result_event.set()
             for hex_ref, _ in state.spec.get("arg_refs", []):

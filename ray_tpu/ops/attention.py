@@ -755,7 +755,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
 #   lengths            [B] int32 — valid cache entries per sequence
 #
 # Three compute paths, all numerically equivalent (tier-1 gated in
-# tests/test_llm_serving.py):
+# tests/test_llm_kernels_decode.py):
 #   decode_attention            contiguous masked reference (XLA, CPU ok)
 #   paged_attention_reference   gather pages -> decode_attention
 #   paged_attention_decode      Pallas kernel: scalar-prefetched block

@@ -39,7 +39,84 @@ try:
 except Exception:
     pass
 
+import contextlib  # noqa: E402
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
+import threading  # noqa: E402
+
 import pytest  # noqa: E402
+
+# Every phase of every test (setup, call, teardown; a fixture's teardown
+# runs in the teardown of the last test that used it) may take this long:
+# three times the longest test of a whole run (101 s).  One wait without
+# end then costs one test and names it, not the run (ROADMAP D9).
+TEST_LIMIT_S = 300.0
+
+_real_stderr_fd = None
+
+
+def pytest_configure(config):
+    # capture is suspended while plugins are configured, so fd 2 is still
+    # the process's own stderr (an xdist worker's reaches the terminal):
+    # the backstop below must not write into a capture file that dies
+    # with the process
+    global _real_stderr_fd
+    if _real_stderr_fd is None:
+        _real_stderr_fd = os.dup(2)
+
+
+@contextlib.contextmanager
+def _limited(item, phase):
+    """Fail `phase` of `item` with every thread's stack once it has run
+    for TEST_LIMIT_S.  SIGALRM interrupts the main thread's lock, future
+    and socket waits; for a wait no signal breaks (a C call that holds
+    the GIL, a blocked signal) the process exits at twice the limit with
+    its stacks on stderr, so at worst one xdist worker is lost."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def on_alarm(signum, frame):
+        with tempfile.TemporaryFile() as f:
+            faulthandler.dump_traceback(f, all_threads=True)
+            f.seek(0)
+            stacks = f.read().decode(errors="replace")
+        pytest.fail(
+            f"{item.nodeid}: {phase} still running after {TEST_LIMIT_S:g} s "
+            f"(TEST_LIMIT_S, tests/conftest.py); every thread's stack:\n"
+            f"{stacks}", pytrace=False)
+
+    faulthandler.dump_traceback_later(
+        2 * TEST_LIMIT_S, exit=True,
+        file=_real_stderr_fd if _real_stderr_fd is not None else 2)
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    # fires again every tenth of the limit: a test's own `finally` that
+    # waits on the same dead peer is interrupted too
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S, TEST_LIMIT_S / 10)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+        faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_setup(item):
+    with _limited(item, "setup"):
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    with _limited(item, "call"):
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item):
+    with _limited(item, "teardown"):
+        return (yield)
 
 
 def pytest_sessionstart(session):
@@ -96,6 +173,24 @@ def _no_asyncio_teardown_leaks():
     logging.getLogger("asyncio").removeHandler(trap)
     assert not leaked, (
         f"{len(leaked)} asyncio teardown leak(s); first 5: {leaked[:5]}")
+
+
+@pytest.fixture(scope="session")
+def stop_driver():
+    """How a fixture ends a driver process it started (one that called
+    ``ray_tpu.init()``): SIGINT, so that the driver's exit handler takes
+    its GCS, raylet and workers down with it; killed outright, it left
+    them running after every run."""
+    import subprocess
+
+    def stop(proc, timeout=30):
+        proc.send_signal(signal.SIGINT)
+        try:
+            proc.wait(timeout=timeout)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            proc.wait(timeout=timeout)
+    return stop
 
 
 @pytest.fixture(scope="function")

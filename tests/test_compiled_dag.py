@@ -46,7 +46,7 @@ def _pipeline():
 
 def test_compile_execute_roundtrip_equivalence(dag_cluster):
     dag, _ = _pipeline()
-    dynamic = [ray_tpu.get(dag.execute(i)) for i in range(3)]
+    dynamic = [ray_tpu.get(dag.execute(i), timeout=240) for i in range(3)]
     cdag = dag.compile()
     try:
         assert cdag._compiled and not cdag._fallback_only
@@ -100,7 +100,7 @@ def test_multi_output_node_dynamic_and_compiled(dag_cluster):
             [AddK.bind(10).add.bind(mid), AddK.bind(100).add.bind(mid)])
     refs = dag.execute(5)
     assert isinstance(refs, list) and len(refs) == 2
-    assert ray_tpu.get(refs) == [16, 106]
+    assert ray_tpu.get(refs, timeout=240) == [16, 106]
     cdag = dag.compile()
     try:
         assert cdag._compiled
@@ -129,7 +129,7 @@ def test_class_node_caches_actor_across_executions(dag_cluster):
     with InputNode() as inp:
         dag = ChurnProbe.bind().ping.bind(inp)
     for i in range(3):
-        assert ray_tpu.get(dag.execute(i)) == i
+        assert ray_tpu.get(dag.execute(i), timeout=240) == i
     assert len(alive_probes()) == before + 1
 
 
@@ -320,7 +320,7 @@ def test_chaos_stage_kill_falls_back_exactly_once(tmp_path):
             # the dynamic fallback, not twice. (The sink sees each
             # input shifted by the two upstream stages: i + 11.)
             counts = ray_tpu.get(
-                c._cached_actor.seen_counts.remote())
+                c._cached_actor.seen_counts.remote(), timeout=240)
             assert sorted(counts) == [11 + i for i in range(6)]
             assert all(n == 1 for n in counts.values()), counts
         finally:

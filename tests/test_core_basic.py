@@ -14,9 +14,9 @@ from ray_tpu import exceptions
 
 def test_put_get(ray_start_shared):
     ref = ray_tpu.put(42)
-    assert ray_tpu.get(ref) == 42
+    assert ray_tpu.get(ref, timeout=240) == 42
     ref2 = ray_tpu.put({"a": [1, 2, 3], "b": "x"})
-    assert ray_tpu.get(ref2) == {"a": [1, 2, 3], "b": "x"}
+    assert ray_tpu.get(ref2, timeout=240) == {"a": [1, 2, 3], "b": "x"}
 
 
 def test_put_get_numpy_zero_copy(ray_start_shared):
@@ -75,6 +75,25 @@ def test_task_error_propagates(ray_start_shared):
     assert "boom!" in str(ei.value)
 
 
+def test_get_does_not_see_the_error_of_an_attempt_being_retried(
+        ray_start_shared, tmp_path):
+    """The retry outlasts the getter's 2 s wait step: the getter looks
+    in the memory store again while it runs, and must not find the first
+    attempt's error there."""
+    marker = str(tmp_path / "first_attempt_ran")
+
+    @ray_tpu.remote(max_retries=2, retry_exceptions=True)
+    def flaky(path):
+        import os
+        if not os.path.exists(path):
+            open(path, "w").close()
+            raise ValueError("first attempt fails")
+        time.sleep(2.5)
+        return "ok"
+
+    assert ray_tpu.get(flaky.remote(marker), timeout=60) == "ok"
+
+
 def test_wait(ray_start_shared):
     @ray_tpu.remote
     def fast():
@@ -87,7 +106,7 @@ def test_wait(ray_start_shared):
 
     f, s = fast.remote(), slow.remote()
     ready, pending = ray_tpu.wait([f, s], num_returns=1, timeout=30)
-    assert ready and ray_tpu.get(ready[0]) == "fast"
+    assert ready and ray_tpu.get(ready[0], timeout=240) == "fast"
     assert pending == [s] or not pending
 
 
@@ -175,7 +194,7 @@ def test_nested_tasks(ray_start_shared):
 
     @ray_tpu.remote
     def outer(x):
-        return ray_tpu.get(inner.remote(x)) + 10
+        return ray_tpu.get(inner.remote(x), timeout=240) + 10
 
     assert ray_tpu.get(outer.remote(1), timeout=90) == 12
 

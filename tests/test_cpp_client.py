@@ -53,7 +53,7 @@ def cpp_binary(tmp_path_factory):
 
 
 @pytest.fixture(scope="module")
-def server_port():
+def server_port(stop_driver):
     env = dict(os.environ)
     env.pop("RTPU_ADDRESS", None)
     proc = subprocess.Popen(
@@ -73,8 +73,7 @@ def server_port():
         proc.kill()
         pytest.fail("client server did not start")
     yield port
-    proc.kill()
-    proc.wait(timeout=30)
+    stop_driver(proc)
 
 
 def test_cpp_client_end_to_end(cpp_binary, server_port):

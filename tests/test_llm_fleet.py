@@ -495,7 +495,7 @@ for i in range(6):
                     "identical": got == want})
 
 ctrl = ray_tpu.get_actor("SERVE_CONTROLLER")
-_, table = ray_tpu.get(ctrl.get_route_table.remote())
+_, table = ray_tpu.get(ctrl.get_route_table.remote(), timeout=240)
 roles = table["d"].get("replica_roles") or {}
 per_replica = {}
 for hex_id, role in roles.items():

@@ -173,7 +173,7 @@ def test_node_stats_agent(cluster):
         return bytes(2 * 1024 * 1024)  # forces plasma traffic
 
     refs = [burn.remote(i) for i in range(20)]
-    ray_tpu.get(refs)
+    ray_tpu.get(refs, timeout=240)
     state.node_stats()  # prime the cpu_percent delta sample
     time.sleep(0.5)     # the delta needs ticks between the two reads
     stats = state.node_stats()

@@ -23,7 +23,7 @@ def test_state_api(ray_start_shared):
             return "ok"
 
     m = Marker.options(name="state-marker").remote()
-    ray_tpu.get(m.ping.remote())
+    ray_tpu.get(m.ping.remote(), timeout=240)
     nodes = list_nodes()
     assert len(nodes) >= 1 and nodes[0]["alive"]
     actors = list_actors()
@@ -112,7 +112,7 @@ def test_timeline_records_tasks(ray_start_shared):
     def traced():
         return 1
 
-    ray_tpu.get([traced.remote() for _ in range(3)])
+    ray_tpu.get([traced.remote() for _ in range(3)], timeout=240)
     time.sleep(3.0)  # wait for the workers' background flushers
     events = timeline_dump()
     task_events = [e for e in events

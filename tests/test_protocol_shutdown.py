@@ -232,3 +232,54 @@ def test_dispatch_status_batch_gated_on_peer_minor(io):
         return True
 
     assert io.run(scenario())
+
+
+def test_calls_queued_on_a_dead_peer_share_one_reconnect_deadline(io):
+    """Five calls to a peer that is gone all come back with the error
+    inside one `reconnect_timeout_s` (plus a dial each), not one after
+    the other with a timeout of its own each: a driver whose GCS had
+    died sat 300 s in `serve.shutdown()` behind its own background
+    calls."""
+    import socket
+    import time
+    with socket.socket() as s:      # a port nobody listens on
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    rc = protocol.ReconnectingConnection(f"127.0.0.1:{port}",
+                                         reconnect_timeout_s=1.0)
+
+    async def scenario():
+        return await asyncio.gather(
+            *[rc.call("kv_get", {"key": "k"}) for _ in range(5)],
+            return_exceptions=True)
+
+    t0 = time.monotonic()
+    results = io.run(scenario(), timeout=30)
+    took = time.monotonic() - t0
+    assert all(isinstance(r, ConnectionError) for r in results), results
+    assert took < 3.0, took         # five timeouts in a row would be 5 s
+
+
+def test_disconnect_closes_its_server_on_the_io_loop():
+    """asyncio's Server belongs to its loop: closed from the caller's
+    thread it raced the loop's own detach of a closing connection
+    (TypeError in Server._wakeup in one whole run of seven), and the
+    disconnect then never stopped its io loop."""
+    import threading
+
+    import ray_tpu
+    from ray_tpu._private import worker as worker_mod
+    ray_tpu.init(num_cpus=1, object_store_memory=64 * 1024 * 1024)
+    closed_on = []
+    try:
+        w = worker_mod.global_worker()
+        server, io_thread = w._server, w.io._thread
+        real_close = server.close
+
+        def close():
+            closed_on.append(threading.current_thread())
+            real_close()
+        server.close = close
+    finally:
+        ray_tpu.shutdown()
+    assert closed_on == [io_thread]

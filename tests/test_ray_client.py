@@ -29,7 +29,7 @@ while True:
 
 
 @pytest.fixture(scope="module")
-def client_server():
+def client_server(stop_driver):
     env = dict(os.environ)
     env.pop("RTPU_ADDRESS", None)
     proc = subprocess.Popen(
@@ -50,8 +50,7 @@ def client_server():
         proc.kill()
         pytest.fail("client server did not start")
     yield port
-    proc.kill()
-    proc.wait(timeout=30)
+    stop_driver(proc)
 
 
 @pytest.fixture()
@@ -68,7 +67,7 @@ def test_client_put_get_roundtrip(ray_client):
     arr = np.arange(1000, dtype=np.float32)
     ref = ray_tpu.put(arr)
     assert isinstance(ref, ClientObjectRef)
-    out = ray_tpu.get(ref)
+    out = ray_tpu.get(ref, timeout=240)
     np.testing.assert_array_equal(out, arr)
 
 
@@ -79,16 +78,16 @@ def test_client_remote_task(ray_client):
     def add(a, b):
         return a + b
 
-    assert ray_tpu.get(add.remote(2, 40)) == 42
+    assert ray_tpu.get(add.remote(2, 40), timeout=240) == 42
     # ref args resolve server-side to the real objects
     ref = ray_tpu.put(10)
-    assert ray_tpu.get(add.remote(ref, 5)) == 15
+    assert ray_tpu.get(add.remote(ref, 5), timeout=240) == 15
     # options + multiple returns
     @ray_tpu.remote
     def pair(x):
         return x, x + 1
 
-    r1, r2 = ray_tpu.get(pair.options(num_returns=2).remote(7))
+    r1, r2 = ray_tpu.get(pair.options(num_returns=2).remote(7), timeout=240)
     assert (r1, r2) == (7, 8)
 
 
@@ -123,14 +122,14 @@ def test_client_actor_lifecycle(ray_client):
 
     c = Counter.remote(100)
     assert isinstance(c, ClientActorHandle)
-    assert ray_tpu.get(c.incr.remote()) == 101
-    assert ray_tpu.get(c.incr.remote(9)) == 110
+    assert ray_tpu.get(c.incr.remote(), timeout=240) == 101
+    assert ray_tpu.get(c.incr.remote(9), timeout=240) == 110
     # actor handles pass through task args (rehydrated server-side)
     @ray_tpu.remote
     def poke(counter):
-        return ray_tpu.get(counter.incr.remote(5))
+        return ray_tpu.get(counter.incr.remote(5), timeout=240)
 
-    assert ray_tpu.get(poke.remote(c)) == 115
+    assert ray_tpu.get(poke.remote(c), timeout=240) == 115
     ray_tpu.kill(c)
 
 
@@ -151,8 +150,8 @@ def test_client_named_actor(ray_client):
 
     KV.options(name="kv_client_test").remote()
     h = ray_tpu.get_actor("kv_client_test")
-    assert ray_tpu.get(h.set.remote("a", 1))
-    assert ray_tpu.get(h.get.remote("a")) == 1
+    assert ray_tpu.get(h.set.remote("a", 1), timeout=240)
+    assert ray_tpu.get(h.get.remote("a"), timeout=240) == 1
 
 
 def test_client_cluster_info_and_errors(ray_client):
@@ -166,4 +165,4 @@ def test_client_cluster_info_and_errors(ray_client):
         raise ValueError("kaboom")
 
     with pytest.raises(Exception, match="kaboom"):
-        ray_tpu.get(boom.remote())
+        ray_tpu.get(boom.remote(), timeout=240)

@@ -96,7 +96,7 @@ def test_apex_dqn_cartpole(cluster):
     # per-worker epsilon ladder: first worker explores least
     eps = ray_tpu.get([
         w.apply.remote(lambda w: w.policy.exploration_epsilon)
-        for w in algo.workers.remote_workers])
+        for w in algo.workers.remote_workers], timeout=240)
     assert eps[0] > eps[1] or np.isclose(eps[0], 0.4), eps
     assert algo.workers.local_worker.policy.exploration_epsilon == 0.0
     algo.cleanup()

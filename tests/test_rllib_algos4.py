@@ -107,7 +107,7 @@ def test_ddppo_decentralized_learning(cluster):
     # driver policy got the averaged weights (it never learned itself)
     lw_w = algo.workers.local_worker.policy.get_weights()
     rw_w = ray_tpu.get(
-        algo.workers.remote_workers[0].get_weights.remote())
+        algo.workers.remote_workers[0].get_weights.remote(), timeout=240)
     flat_l = np.concatenate([np.ravel(x) for x in
                              _tree_leaves(lw_w)])
     flat_r = np.concatenate([np.ravel(x) for x in
@@ -136,7 +136,7 @@ def test_apex_ddpg_noise_ladder_and_learning(cluster):
     # (base^1 > base^8 for base < 1)
     noises = ray_tpu.get([
         w.apply.remote(lambda w: w.policy.exploration_noise)
-        for w in algo.workers.remote_workers])
+        for w in algo.workers.remote_workers], timeout=240)
     assert noises[0] > noises[1]
     assert algo.workers.local_worker.policy.exploration_noise == 0.0
     algo.cleanup()

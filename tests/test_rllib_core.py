@@ -91,7 +91,7 @@ def test_learner_group_distributed_stays_synchronized(ray_start_shared):
         # replicas applied identical averaged updates -> identical state
         import ray_tpu
         states = ray_tpu.get([w.get_state.remote()
-                              for w in group._workers])
+                              for w in group._workers], timeout=240)
         import jax
         fa = jax.tree.leaves(states[0])
         fb = jax.tree.leaves(states[1])

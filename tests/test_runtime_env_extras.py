@@ -216,7 +216,7 @@ def test_serve_ingress_http_end_to_end():
 
         serve.run(Calc.bind(), route_prefix="/calc", http_port=8155)
         proxy = ray_tpu.get_actor("SERVE_PROXY")
-        port = ray_tpu.get(proxy.get_port.remote())
+        port = ray_tpu.get(proxy.get_port.remote(), timeout=240)
 
         got = json.loads(urllib.request.urlopen(
             f"http://127.0.0.1:{port}/calc/add/23", timeout=30).read())

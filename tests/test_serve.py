@@ -34,9 +34,9 @@ def test_deploy_and_handle(serve_cluster):
             return x * self.offset
 
     h = serve.run(Adder.bind(10), http_port=None)
-    assert ray_tpu.get(h.remote(5)) == 15
+    assert ray_tpu.get(h.remote(5), timeout=240) == 15
     # method routing
-    assert ray_tpu.get(h.mult.remote(5)) == 50
+    assert ray_tpu.get(h.mult.remote(5), timeout=240) == 50
     st = serve.status()
     assert st["Adder"]["status"] == "HEALTHY"
     assert st["Adder"]["live_replicas"] == 2
@@ -54,11 +54,11 @@ def test_function_deployment_and_composition(serve_cluster):
             self.pre = pre
 
         def __call__(self, x):
-            y = ray_tpu.get(self.pre.remote(x))
+            y = ray_tpu.get(self.pre.remote(x), timeout=240)
             return y + 1
 
     h = serve.run(Ingress.bind(Preprocessor.bind()), http_port=None)
-    assert ray_tpu.get(h.remote(10)) == 21
+    assert ray_tpu.get(h.remote(10), timeout=240) == 21
 
 
 def test_rolling_update_reconfigure(serve_cluster):
@@ -74,16 +74,16 @@ def test_rolling_update_reconfigure(serve_cluster):
             return x * self.factor
 
     h = serve.run(Scaler.bind(), http_port=None)
-    assert ray_tpu.get(h.remote(10)) == 20
+    assert ray_tpu.get(h.remote(10), timeout=240) == 20
     # redeploy with new user_config → new version → rolling replace
     h = serve.run(Scaler.options(user_config={"factor": 5}).bind(),
                   http_port=None)
     deadline = time.time() + 30
     while time.time() < deadline:
-        if ray_tpu.get(h.remote(10)) == 50:
+        if ray_tpu.get(h.remote(10), timeout=240) == 50:
             break
         time.sleep(0.2)
-    assert ray_tpu.get(h.remote(10)) == 50
+    assert ray_tpu.get(h.remote(10), timeout=240) == 50
 
 
 def test_http_proxy(serve_cluster):
@@ -95,7 +95,7 @@ def test_http_proxy(serve_cluster):
     serve.run(Echo.bind(), route_prefix="/echo", http_port=8123)
     # the proxy may have bound a fallback port; ask the proxy actor
     proxy = ray_tpu.get_actor("SERVE_PROXY")
-    port = ray_tpu.get(proxy.get_port.remote())
+    port = ray_tpu.get(proxy.get_port.remote(), timeout=240)
     body = json.dumps({"msg": "hi"}).encode()
     req = urllib.request.Request(
         f"http://127.0.0.1:{port}/echo", data=body,
@@ -224,7 +224,7 @@ def test_function_deployment_and_delete(serve_cluster):
 
     h = serve.run(stateless.options(name="ToDelete").bind(),
                   http_port=None)
-    assert ray_tpu.get(h.remote(1)) == 101
+    assert ray_tpu.get(h.remote(1), timeout=240) == 101
     serve.delete("ToDelete")
     deadline = time.time() + 15
     while time.time() < deadline and "ToDelete" in serve.status():

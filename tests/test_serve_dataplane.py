@@ -328,7 +328,7 @@ def test_saturated_deployment_sheds_503(serve_cluster):
     serve.run(OneSlot.bind(), name="shed", route_prefix="/oneslot",
               http_port=8124)
     proxy = ray_tpu.get_actor("SERVE_PROXY")
-    port = ray_tpu.get(proxy.get_port.remote())
+    port = ray_tpu.get(proxy.get_port.remote(), timeout=240)
     outcomes = []
     lock = threading.Lock()
 
@@ -349,7 +349,7 @@ def test_saturated_deployment_sheds_503(serve_cluster):
         t.start()
         time.sleep(0.05)
     for t in threads:
-        t.join()
+        t.join(timeout=120)
     codes = [c for c, _ in outcomes]
     assert 200 in codes, outcomes  # the admitted request completed
     shed = [(c, b) for c, b in outcomes if c == 503]
@@ -371,7 +371,7 @@ def test_replica_shed_is_retriable_actor_error(serve_cluster):
 
     serve.run(OneSlot2.bind(), name="shed2", http_port=None)
     controller = ray_tpu.get_actor(CONTROLLER_NAME)
-    _, table = ray_tpu.get(controller.get_route_table.remote())
+    _, table = ray_tpu.get(controller.get_route_table.remote(), timeout=240)
     replica = get_actor_by_id(table["ShedDirect"]["replicas"][0])
     # bypass the router's own in-flight cap: hit the replica directly,
     # like a second router that hasn't seen this load yet would

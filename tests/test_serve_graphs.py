@@ -51,7 +51,7 @@ def test_dag_driver_multiplexes_routes(serve_cluster):
                           "/negate": Negator.bind()})
     serve.run(app, http_port=8124)
     proxy = ray_tpu.get_actor("SERVE_PROXY")
-    port = ray_tpu.get(proxy.get_port.remote())
+    port = ray_tpu.get(proxy.get_port.remote(), timeout=240)
     assert _get(port, "/double", 21) == {"doubled": 42}
     assert _get(port, "/negate", 5) == {"negated": -5}
     # unknown sub-route → error surfaced (500 from the driver's KeyError)
@@ -70,7 +70,7 @@ def test_dag_driver_under_non_root_prefix(serve_cluster):
     app = DAGDriver.options(name="ApiDriver").bind({"/up": Upper.bind()})
     serve.run(app, name="api_app", route_prefix="/api", http_port=8124)
     proxy = ray_tpu.get_actor("SERVE_PROXY")
-    port = ray_tpu.get(proxy.get_port.remote())
+    port = ray_tpu.get(proxy.get_port.remote(), timeout=240)
     # the driver sees the path BELOW its route prefix
     assert _get(port, "/api/up", "hi") == {"up": "HI"}
     serve.delete_application("api_app")
@@ -111,7 +111,7 @@ def test_multi_app_coexistence(serve_cluster):
     apps = serve.list_applications()
     assert "app_a" in apps and "app_b" in apps
     proxy = ray_tpu.get_actor("SERVE_PROXY")
-    port = ray_tpu.get(proxy.get_port.remote())
+    port = ray_tpu.get(proxy.get_port.remote(), timeout=240)
     # deploying app_b must NOT have torn down app_a
     assert _get(port, "/a") == {"app": "a"}
     assert _get(port, "/b") == {"app": "b"}
@@ -147,7 +147,7 @@ def test_deploy_config_end_to_end(serve_cluster):
     })
     assert names == ["cfg_app"]
     proxy = ray_tpu.get_actor("SERVE_PROXY")
-    port = ray_tpu.get(proxy.get_port.remote())
+    port = ray_tpu.get(proxy.get_port.remote(), timeout=240)
     assert _get(port, "/cfg", {"k": 1}) == {"cfg_echo": {"k": 1}}
     st = serve.status()
     assert st["ConfigEcho"]["app"] == "cfg_app"
@@ -190,5 +190,5 @@ def test_dag_driver_with_http_adapter(serve_cluster):
         {"/sum": SumModel.bind()}, http_adapter=json_to_ndarray)
     serve.run(app, http_port=8127)
     proxy = ray_tpu.get_actor("SERVE_PROXY")
-    port = ray_tpu.get(proxy.get_port.remote())
+    port = ray_tpu.get(proxy.get_port.remote(), timeout=240)
     assert _get(port, "/sum", {"array": [1, 2, 3.5]}) == {"sum": 6.5}

@@ -119,12 +119,15 @@ def test_merge_rebases_to_wall_clock_and_filters(tmp_path):
         {"name": "meta", "ph": "M", "ts": 0.0, "pid": 7},  # not 'X'
     ]
     wall = 1_700_000_000 * 1e6
-    before = len(timeline.collect())
+    # the merged events are found by their label, not by their position
+    # after what was there before: the timeline is a ring of 10,000
+    # events, and in a process whose earlier tests filled it (under
+    # `--dist loadfile`, whichever file this worker ran before) its
+    # length no longer grows
     n = tpu_profiler.merge_into_timeline(
         events, wall_start_us=wall, label="unit-xla", min_dur_us=5.0)
     assert n == 2
-    merged = [e for e in timeline.collect()[before:]
-              if e.get("cat") == "unit-xla"]
+    merged = [e for e in timeline.collect() if e.get("cat") == "unit-xla"]
     by_name = {e["name"]: e for e in merged}
     assert set(by_name) == {"big", "later"}
     assert by_name["big"]["ts"] == wall          # min ts -> wall start
@@ -133,13 +136,11 @@ def test_merge_rebases_to_wall_clock_and_filters(tmp_path):
     many = [{"name": f"s{i}", "ph": "X", "ts": float(i),
              "dur": float(i + 1), "pid": 1, "tid": 0}
             for i in range(50)]
-    before = len(timeline.collect())
     n = tpu_profiler.merge_into_timeline(
         many, wall_start_us=wall, label="unit-cap", max_events=10,
         min_dur_us=0.0)
     assert n == 10
-    kept = [e for e in timeline.collect()[before:]
-            if e.get("cat") == "unit-cap"]
+    kept = [e for e in timeline.collect() if e.get("cat") == "unit-cap"]
     assert {e["name"] for e in kept} == {f"s{i}" for i in range(40, 50)}
 
 
@@ -152,26 +153,28 @@ def test_merge_xla_pid_rows_are_stable_and_separated():
                "pid": 11, "tid": 0},
               {"name": "y", "ph": "X", "ts": 2.0, "dur": 10.0,
                "pid": 22, "tid": 0}]
-    before = len(timeline.collect())
+    def spans():        # by label: the ring may be full (see above)
+        return [e for e in timeline.collect()
+                if e.get("cat") == "unit-rows" and e.get("ph") == "X"]
+
     tpu_profiler.merge_into_timeline(
         events, wall_start_us=0.0, label="unit-rows", min_dur_us=0.0)
-    first = [e for e in timeline.collect()[before:]
-             if e.get("cat") == "unit-rows"]
+    first = spans()
+    assert len(first) == 2
     pids1 = {e["name"]: e["pid"] for e in first}
     assert pids1["x"] != pids1["y"]
     assert all(p >= tpu_profiler._XLA_PID_BASE for p in pids1.values())
     # process_name metadata labels each synthetic row
-    metas = [e for e in timeline.collect()[before:]
+    metas = [e for e in timeline.collect()
              if e.get("name") == "process_name"
              and "unit-rows" in str(e.get("args"))]
     assert len(metas) == 2
     # stability: a second merge (fresh seen_pids map) lands on the
     # same rows — crc32 digest, not Python's randomized hash()
-    before = len(timeline.collect())
     tpu_profiler.merge_into_timeline(
         events, wall_start_us=0.0, label="unit-rows", min_dur_us=0.0)
-    second = [e for e in timeline.collect()[before:]
-              if e.get("cat") == "unit-rows" and e.get("ph") == "X"]
+    second = spans()[2:]
+    assert len(second) == 2
     pids2 = {e["name"]: e["pid"] for e in second}
     assert pids2 == pids1
     timeline.stop_flusher()

@@ -30,9 +30,9 @@ def test_trace_tree_renders_in_timeline(cluster):
 
     @ray_tpu.remote
     def tr_parent(x):
-        return ray_tpu.get(tr_child.remote(x)) + 10
+        return ray_tpu.get(tr_child.remote(x), timeout=240) + 10
 
-    assert ray_tpu.get(tr_parent.remote(5)) == 16
+    assert ray_tpu.get(tr_parent.remote(5), timeout=240) == 16
     # the worker flusher pushes buffers to the GCS every ~1s
     deadline = time.monotonic() + 15
     parent_ev = child_ev = None
@@ -62,7 +62,7 @@ def test_trace_ctx_rides_batched_submissions(cluster):
         return i
 
     refs = tb_noop.remote_batch([(i,) for i in range(4)])
-    assert ray_tpu.get(refs) == [0, 1, 2, 3]
+    assert ray_tpu.get(refs, timeout=240) == [0, 1, 2, 3]
     deadline = time.monotonic() + 15
     evs = []
     while time.monotonic() < deadline:
@@ -167,12 +167,12 @@ def test_nested_actor_task_chain_parents_under_caller(cluster):
 
     @ray_tpu.remote
     def na_mid(x):
-        return ray_tpu.get(na_leaf.remote(x)) + 10
+        return ray_tpu.get(na_leaf.remote(x), timeout=240) + 10
 
     @ray_tpu.remote
     class NaActor:
         def go(self, x):
-            return ray_tpu.get(na_mid.remote(x)) + 100
+            return ray_tpu.get(na_mid.remote(x), timeout=240) + 100
 
     a = NaActor.remote()
     assert ray_tpu.get(a.go.remote(1), timeout=60) == 112
@@ -304,8 +304,8 @@ def test_serve_request_trace_end_to_end(cluster):
     from ray_tpu.experimental.state import api as state
 
     class TrApp:
-        def __call__(self, x=None):
-            time.sleep(0.02)
+        def __call__(self, req):
+            time.sleep(req["sleep_s"])
             return {"ok": True}
 
     h = serve.run(serve.deployment(num_replicas=1)(TrApp).bind(),
@@ -313,12 +313,17 @@ def test_serve_request_trace_end_to_end(cluster):
                   http_port=None)
     try:
         for i in range(4):  # warm replica + router + codepaths
-            ray_tpu.get(h.remote({"x": 1},
+            ray_tpu.get(h.remote({"sleep_s": 0.02},
                                  __rtpu_request_id__=f"tr-warm-{i}"),
                         timeout=60)
         rid = "tr-e2e-final"
+        # the client's clock also holds what no span covers: the submit
+        # before the root span opens and the getter's wake-up, 1-5 ms on
+        # a box that five other test workers share. The request measured
+        # is long enough (0.5 s) for that to stay under the 5% the tree
+        # may leave unattributed; at 20 ms it read 17.5% in one run.
         t0 = time.time()
-        ray_tpu.get(h.remote({"x": 1}, __rtpu_request_id__=rid),
+        ray_tpu.get(h.remote({"sleep_s": 0.5}, __rtpu_request_id__=rid),
                     timeout=60)
         client_dt = time.time() - t0
 
@@ -339,7 +344,7 @@ def test_serve_request_trace_end_to_end(cluster):
         # >=95% of what the CLIENT measured lands in named phases
         assert cp["attributed_s"] >= 0.95 * client_dt, \
             (cp, client_dt)
-        assert cp["phases"].get("execute", 0) > 0.015  # the sleep
+        assert cp["phases"].get("execute", 0) > 0.45  # the sleep
         # the summary row is listable (explicit spans only: root +
         # replica.execute at minimum — no-wait assign/queue spans are
         # elided)

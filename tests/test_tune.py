@@ -1,6 +1,9 @@
 """Tune kernel tests (reference analogues: tune/tests/test_api.py,
 test_trial_scheduler.py — scaled down to the 1-box CI)."""
 
+import os
+import time
+
 import pytest
 
 import ray_tpu
@@ -110,16 +113,32 @@ def test_tuner_api_and_random_sampling(cluster):
     assert 1e-4 <= best.metrics["v"] <= 1e-1
 
 
-def test_pbt_exploit(cluster):
+def test_pbt_exploit(cluster, tmp_path):
+    strong_reported = str(tmp_path / "strong_reported")
+
     def train_fn(config):
         ckpt = session.get_checkpoint()
         score = ckpt.to_dict()["score"] if ckpt else 0.0
         lr = config["lr"]
+        if lr == 0.01 and ckpt is None:
+            # PBT exploits at the weak trial's third result, and only if
+            # the strong trial's score and checkpoint have reached the
+            # runner by then. Which actor starts first is the box's
+            # choice (under six test workers the weak one at times ran
+            # all ten steps alone and ended at 0.1), so the weak trial
+            # starts once the strong one has reported: a condition
+            # polled for, with a deadline, not an order hoped for.
+            deadline = time.time() + 120
+            while not os.path.exists(strong_reported) \
+                    and time.time() < deadline:
+                time.sleep(0.05)
         for i in range(10):
             score += lr
             from ray_tpu.air.checkpoint import Checkpoint
             session.report({"score": score},
                            checkpoint=Checkpoint.from_dict({"score": score}))
+        if lr == 1.0:
+            open(strong_reported, "w").close()
 
     pbt = tune.PopulationBasedTraining(
         metric="score", mode="max", perturbation_interval=3,

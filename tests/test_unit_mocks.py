@@ -195,3 +195,71 @@ def test_usage_stats_opt_in(tmp_path, monkeypatch):
     assert "tune" in doc["libraries_used"]
     assert doc["node_id"] == "n1"
     assert doc["python_version"]
+
+
+# --------------------------------------------------- the per-test limit
+
+def test_a_test_that_waits_for_ever_fails_alone(tmp_path):
+    """tests/conftest.py's limit, patched to 1 s in a pytest of its own:
+    a body that waits on an event nobody sets fails inside 5 s, the
+    failure names the phase and carries every thread's stack, a fixture
+    that waits in its teardown fails that teardown, and the tests after
+    them in the same file still run."""
+    import os
+    import re
+    import subprocess
+    import sys
+    conftest = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "conftest.py")
+    (tmp_path / "conftest.py").write_text(f"""
+import importlib.util
+spec = importlib.util.spec_from_file_location("suite_conftest", {conftest!r})
+suite = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(suite)
+suite.TEST_LIMIT_S = 1.0
+
+
+def pytest_configure(config):
+    config.pluginmanager.register(suite, "suite_conftest")
+""")
+    (tmp_path / "test_waits.py").write_text("""
+import threading
+import pytest
+
+
+def test_waits():
+    threading.Event().wait()
+
+
+@pytest.fixture
+def never_torn_down():
+    yield
+    threading.Event().wait()
+
+
+def test_teardown_waits(never_torn_down):
+    pass
+
+
+def test_next():
+    pass
+""")
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", "-p", "no:cacheprovider",
+         "-p", "no:xdist", "-p", "no:randomly", "-v", "--durations=0",
+         "test_waits.py"],
+        cwd=tmp_path, capture_output=True, text=True, timeout=120)
+    out = r.stdout + r.stderr
+    assert r.returncode == 1, out
+    assert "test_waits.py::test_waits FAILED" in out, out
+    assert "test_waits.py::test_teardown_waits ERROR" in out, out
+    assert "test_waits.py::test_next PASSED" in out, out
+    assert "1 failed, 2 passed, 1 error" in out, out
+    assert "test_waits: call still running after 1 s" in out, out
+    assert "test_teardown_waits: teardown still running after 1 s" in out
+    # every thread's stack, the waiting frame of the main thread in it
+    assert "Current thread" in out and "in test_waits" in out, out
+    took = {m[1]: float(m[0]) for m in re.findall(
+        r"([0-9.]+)s (?:call|teardown) +test_waits.py::(\w+)", out)}
+    assert 1.0 <= took["test_waits"] < 5.0, took
+    assert 1.0 <= took["test_teardown_waits"] < 5.0, took

@@ -47,7 +47,7 @@ def test_queue_across_tasks(ray_start_shared):
             q.put(i)
         return "done"
 
-    ray_tpu.get(producer.remote(q, 3))
+    ray_tpu.get(producer.remote(q, 3), timeout=240)
     assert [q.get(timeout=10) for _ in range(3)] == [0, 1, 2]
 
 
@@ -105,8 +105,8 @@ def test_dag_function_nodes(ray_start_shared):
     from ray_tpu.dag import InputNode
     with InputNode() as inp:
         dag = mul.bind(add.bind(inp, 10), 2)
-    assert ray_tpu.get(dag.execute(5)) == 30
-    assert ray_tpu.get(dag.execute(0)) == 20
+    assert ray_tpu.get(dag.execute(5), timeout=240) == 30
+    assert ray_tpu.get(dag.execute(0), timeout=240) == 20
 
 
 def test_dag_shared_subgraph_runs_once(ray_start_shared):
@@ -127,11 +127,11 @@ def test_dag_shared_subgraph_runs_once(ray_start_shared):
 
     @ray_tpu.remote
     def bump_via(c):
-        return ray_tpu.get(c.bump.remote())
+        return ray_tpu.get(c.bump.remote(), timeout=240)
 
     shared = bump_via.bind(c)
     dag = pair.bind(shared, shared)
-    a, b = ray_tpu.get(dag.execute())
+    a, b = ray_tpu.get(dag.execute(), timeout=240)
     # the shared node must execute once, both consumers see one value
     assert a == b == 1
 
@@ -150,7 +150,7 @@ def test_dag_actor_nodes(ray_start_shared):
     with InputNode() as inp:
         node = Acc.bind(100)
         dag = node.add.bind(inp)
-    assert ray_tpu.get(dag.execute(5)) == 105
+    assert ray_tpu.get(dag.execute(5), timeout=240) == 105
 
 
 def test_workflow_run_and_resume(ray_start_shared, tmp_path):

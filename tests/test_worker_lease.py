@@ -44,15 +44,15 @@ def test_lease_lane_engages_and_results_are_correct(one_cpu_cluster):
     def f(x):
         return x * 2
 
-    assert ray_tpu.get(f.remote(1)) == 2
+    assert ray_tpu.get(f.remote(1), timeout=240) == 2
     deadline = time.time() + 10
     w = _driver()
     while time.time() < deadline and not _lease_engaged(w):
-        ray_tpu.get(f.remote(0))
+        ray_tpu.get(f.remote(0), timeout=240)
     assert _lease_engaged(w), \
         "lease never engaged for a qualifying CPU task"
     # correctness through the leased path, including app errors
-    assert ray_tpu.get([f.remote(i) for i in range(50)]) == \
+    assert ray_tpu.get([f.remote(i) for i in range(50)], timeout=240) == \
         [i * 2 for i in range(50)]
 
     @ray_tpu.remote
@@ -60,9 +60,9 @@ def test_lease_lane_engages_and_results_are_correct(one_cpu_cluster):
         raise ValueError("expected")
 
     with pytest.raises(Exception, match="expected"):
-        ray_tpu.get(boom.remote())
+        ray_tpu.get(boom.remote(), timeout=240)
     # and still correct afterwards
-    assert ray_tpu.get(f.remote(21)) == 42
+    assert ray_tpu.get(f.remote(21), timeout=240) == 42
 
 
 def test_lease_skips_custom_resource_tasks(one_cpu_cluster):
@@ -87,7 +87,7 @@ def test_idle_lease_releases_capacity(one_cpu_cluster):
     def f():
         return 1
 
-    ray_tpu.get([f.remote() for _ in range(10)])
+    ray_tpu.get([f.remote() for _ in range(10)], timeout=240)
     w = _driver()
     deadline = time.time() + 15
     while time.time() < deadline and _lease_engaged(w):
@@ -109,7 +109,8 @@ def test_cancel_reaches_leased_tasks(one_cpu_cluster):
     def quick():
         return 1
 
-    ray_tpu.get([quick.remote() for _ in range(5)])  # lease engages
+    # the lease engages
+    ray_tpu.get([quick.remote() for _ in range(5)], timeout=240)
 
     @ray_tpu.remote
     def slow():
@@ -131,7 +132,7 @@ def test_mixed_workload_not_starved_by_leases(one_cpu_cluster):
         return x
 
     # keep the lease lane hot
-    ray_tpu.get([fast.remote(i) for i in range(20)])
+    ray_tpu.get([fast.remote(i) for i in range(20)], timeout=240)
 
     @ray_tpu.remote
     def other():

@@ -37,7 +37,8 @@ sys.path.insert(0, {os.getcwd()!r})
 import ray_tpu
 from ray_tpu import workflow
 workflow.set_storage({wf_storage!r})
-ray_tpu.init(num_cpus=2, object_store_memory=128*1024*1024)
+ctx = ray_tpu.init(num_cpus=2, object_store_memory=128*1024*1024)
+print("SESSION_DIR=" + ctx["session_dir"], flush=True)
 
 @ray_tpu.remote
 def step1(x):
@@ -68,8 +69,11 @@ workflow.run(dag, workflow_id="chaos")
                             start_new_session=True)
     deadline = time.time() + 120
     started = False
+    session_dir = None
     while time.time() < deadline:
         line = proc.stdout.readline()
+        if line.startswith("SESSION_DIR="):
+            session_dir = line.strip().split("=", 1)[1]
         if "STEP2_STARTED" in line:
             started = True
             break
@@ -79,15 +83,24 @@ workflow.run(dag, workflow_id="chaos")
     time.sleep(0.5)  # let step1's checkpoint land
     os.killpg(proc.pid, signal.SIGKILL)
     proc.wait(timeout=10)
-    # reap the dead driver's cluster
-    subprocess.run([sys.executable, "-c", (
-        "import os,signal\n"
-        "for p in os.listdir('/proc'):\n"
-        "  if not p.isdigit(): continue\n"
-        "  try: cmd=open(f'/proc/{p}/cmdline','rb').read()\n"
-        "  except OSError: continue\n"
-        "  if b'ray_tpu._private' in cmd:\n"
-        "    os.kill(int(p), signal.SIGKILL)\n")])
+    # reap the dead driver's cluster, and no other: its GCS, raylet and
+    # workers are the processes that carry its session directory. Under
+    # xdist the clusters of the other test workers run beside it, and a
+    # kill by command line (`ray_tpu._private`) takes those down too:
+    # their tests then fail with "raylet connection lost" or "cannot
+    # reach <gcs>", in whichever files happen to run at that moment.
+    assert session_dir, "driver never printed its session directory"
+    mark = f"RTPU_SESSION_DIR={session_dir}".encode()
+    for p in os.listdir("/proc"):
+        if not p.isdigit():
+            continue
+        try:
+            with open(f"/proc/{p}/environ", "rb") as f:
+                env = f.read().split(b"\0")
+        except OSError:
+            continue
+        if mark in env:
+            os.kill(int(p), signal.SIGKILL)
     time.sleep(1)
 
     with open(os.path.join(effects, "step1")) as f:
@@ -146,17 +159,19 @@ def test_management_actor_submit_status_list(wf_cluster):
         return a + b
 
     actor = get_management_actor()
-    assert ray_tpu.get(actor.ping.remote()) == "ok"
+    assert ray_tpu.get(actor.ping.remote(), timeout=240) == "ok"
     blob = cloudpickle.dumps((add.bind(2, 3), None))
-    wid = ray_tpu.get(actor.submit.remote(blob, "mgmt-wf"))
+    wid = ray_tpu.get(actor.submit.remote(blob, "mgmt-wf"), timeout=240)
     assert wid == "mgmt-wf"
     deadline = time.time() + 60
-    while time.time() < deadline and \
-            ray_tpu.get(actor.get_status.remote("mgmt-wf")) != "SUCCESSFUL":
+
+    def status():
+        return ray_tpu.get(actor.get_status.remote("mgmt-wf"), timeout=240)
+    while time.time() < deadline and status() != "SUCCESSFUL":
         time.sleep(0.2)
-    assert ray_tpu.get(actor.get_status.remote("mgmt-wf")) == "SUCCESSFUL"
+    assert status() == "SUCCESSFUL"
     assert workflow.get_output("mgmt-wf") == 5
-    rows = ray_tpu.get(actor.list_all.remote("SUCCESSFUL"))
+    rows = ray_tpu.get(actor.list_all.remote("SUCCESSFUL"), timeout=240)
     assert any(r["workflow_id"] == "mgmt-wf" for r in rows)
 
 
