@@ -1637,13 +1637,15 @@ def _llm_bench_main():
     r2 = np.random.RandomState(0)
     B, H, Hkv, D, bs, NB = 3, 8, 2, 16, 8, 4
     lengths = jnp.asarray([5, 17, 30], jnp.int32)
-    k_pages = jnp.asarray(r2.randn(1 + B * NB, bs, Hkv, D), jnp.float32)
-    v_pages = jnp.asarray(r2.randn(1 + B * NB, bs, Hkv, D), jnp.float32)
+    # the serving pool's form: [layers, pages, page, Hkv * D]
+    k_pages = jnp.asarray(r2.randn(2, 1 + B * NB, bs, Hkv * D), jnp.float32)
+    v_pages = jnp.asarray(r2.randn(2, 1 + B * NB, bs, Hkv * D), jnp.float32)
     bt = jnp.asarray(np.arange(1, 1 + B * NB).reshape(B, NB), jnp.int32)
     qq = jnp.asarray(r2.randn(B, H, D), jnp.float32)
-    ref = A.paged_attention_reference(qq, k_pages, v_pages, bt, lengths)
+    ref = A.paged_attention_reference(qq, k_pages, v_pages, bt, lengths,
+                                      layer=1)
     ker = A.paged_attention_decode(qq, k_pages, v_pages, bt, lengths,
-                                   interpret=True)
+                                   layer=1, interpret=True)
     max_err = float(jnp.max(jnp.abs(ref - ker)))
 
     ratio = round(cont["tokens_per_s"]

@@ -279,15 +279,17 @@ def check_task(config):
         rng = np.random.RandomState(SEED % 2**31)
         dt = jnp.float32 if rehearse else jnp.bfloat16
         P = 1 + B * NB
-        k = jnp.asarray(rng.randn(P, bs, Hkv, D), dt)
-        v = jnp.asarray(rng.randn(P, bs, Hkv, D), dt)
+        # the serving pool's form, two layers: [L, P, bs, Hkv * D]
+        k = jnp.asarray(rng.randn(2, P, bs, Hkv * D), dt)
+        v = jnp.asarray(rng.randn(2, P, bs, Hkv * D), dt)
         q = jnp.asarray(rng.randn(B, H, D), dt)
         bt = jnp.asarray(rng.permutation(np.arange(1, P)).reshape(B, NB),
                          jnp.int32)
         ln = jnp.asarray(rng.randint(1, NB * bs + 1, (B,)), jnp.int32)
-        ref = jax.jit(A.paged_attention_reference)(q, k, v, bt, ln)
+        ref = jax.jit(lambda *a: A.paged_attention_reference(
+            *a, layer=1))(q, k, v, bt, ln)
         got = jax.jit(lambda *a: A.paged_attention_decode(
-            *a, interpret=rehearse))(q, k, v, bt, ln)
+            *a, layer=1, interpret=rehearse))(q, k, v, bt, ln)
         paged[name] = float(jnp.max(jnp.abs(
             got.astype(jnp.float32) - ref.astype(jnp.float32))))
     out["paged_kernel_max_err"] = paged

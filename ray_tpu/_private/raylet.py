@@ -174,7 +174,10 @@ def detect_tpu_topology() -> Dict[str, Any]:
     accel_type = os.environ.get("TPU_ACCELERATOR_TYPE")
     if accel_type:
         out["topology"] = accel_type
-    out["worker_index"] = int(os.environ.get("TPU_WORKER_ID", 0))
+    # (a process that loaded libtpu where no worker number can be found
+    # leaves a warning text in this variable for its children to inherit)
+    worker_id = os.environ.get("TPU_WORKER_ID", "")
+    out["worker_index"] = int(worker_id) if worker_id.isdigit() else 0
     hostnames = os.environ.get("TPU_WORKER_HOSTNAMES", "")
     out["num_slice_hosts"] = len(hostnames.split(",")) if hostnames else 1
     slice_name = os.environ.get("TPU_SLICE_NAME")

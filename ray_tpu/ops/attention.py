@@ -758,11 +758,15 @@ def flash_attention(q, k, v, *, causal: bool = False,
 # tests/test_llm_kernels_decode.py):
 #   decode_attention            contiguous masked reference (XLA, CPU ok)
 #   paged_attention_reference   gather pages -> decode_attention
-#   paged_attention_decode      Pallas kernel: scalar-prefetched block
-#                               tables index pages straight from HBM,
-#                               flash-style online softmax per block —
-#                               the cache is never materialized
-#                               contiguously (interpret=True on CPU)
+#   paged_attention_decode      Pallas kernel over the serving pool: the
+#                               block tables ride scalar prefetch, each
+#                               row's live pages are copied from HBM a
+#                               chunk at a time under a flash-style
+#                               online softmax — the cache is never
+#                               materialized contiguously (interpret=True
+#                               on CPU). A served decode step (one token
+#                               a row) runs it on the chip:
+#                               ``cached_attention``
 
 
 def _repeat_kv(k, rep: int, axis: int = 1):
@@ -929,140 +933,229 @@ def latent_attention(q_nope, q_rope, latent, w_kvb, q_positions, *,
 
 
 def paged_attention_reference(q, k_pages, v_pages, block_tables,
-                              lengths, *,
+                              lengths, *, layer=0,
                               sm_scale: Optional[float] = None):
-    """Single-token decode against the paged cache, via gather (the
-    correctness baseline for the Pallas kernel and the CPU fallback).
+    """Single-token decode against one layer of the serving pool, via
+    gather (the correctness baseline for the Pallas kernel, and what it
+    falls back to off the chip).
 
-    q: [B, H, D] (one query token per sequence); returns [B, H, D].
+    q: [B, H, D] (one query token per sequence); k_pages/v_pages:
+    [L, P, bs, Hkv*D]; returns [B, H, D].
     """
-    out = decode_attention(q[:, :, None, :],
-                           paged_gather(k_pages, block_tables),
-                           paged_gather(v_pages, block_tables),
-                           lengths, sm_scale=sm_scale)
+    B, _, D = q.shape
+
+    def gathered(pages):                               # [B,NB*bs,Hkv,D]
+        return paged_gather(pages, block_tables, layer).reshape(
+            B, -1, pages.shape[-1] // D, D)
+    out = decode_attention(q[:, :, None, :], gathered(k_pages),
+                           gathered(v_pages), lengths, sm_scale=sm_scale)
     return out[:, :, 0, :]
 
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, block_size,
-                         num_blocks, head_dim):
-    """One (sequence, page) grid step of paged flash decode, all kv
-    heads of the page at once.
+# tokens the kernel attends to at a time: the pages that hold them are
+# copied into VMEM together while the chunk before is worked on
+_PAGED_CHUNK_TOKENS = 128
 
-    The page refs were DMA'd by the scalar-prefetched index map (the
-    block table picks the physical page per grid step) as one dense
-    [bs, Hkv*D] tile; each kv head is a static lane slice of it, and
-    its body is plain flash: one [G, bs] dot, online softmax, [G, D]
-    accumulate. Fully-masked pages (past the sequence length)
-    contribute zero because masked logits are a large-but-finite
-    negative, never -inf.
+
+def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, k_buf, v_buf, sem, qbd_ref, m_ref, l_ref,
+                         acc_ref, *, block_size, pages, head_dim, sm_scale):
+    """Every row's one query against its live pages, a chunk of
+    ``pages`` pages at a time, all heads at once.
+
+    The pools stay in HBM; the block table names the pages of a chunk,
+    each copied by a DMA of its own into one of two [T, Hkv*D] buffers
+    while the other is attended to, the first chunk of the next row
+    under the last of this one. A chunk wholly past a row's length is
+    neither copied nor looked at. Heads stay side by side on the lanes,
+    as the pool holds them: the G query heads of every kv head form a
+    block-diagonal [H, Hkv*D] matrix (row h holds head h's query under
+    its kv head's columns, zeros elsewhere), so the scores of all heads
+    are one product against the chunk's keys, probabilities x values one
+    product [H, T] x [T, Hkv*D], and a head's output the columns of its
+    own kv head. No head is sliced out of a lane tile.
     """
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    D = head_dim
+    B, G, C = q_ref.shape
+    NB = bt_ref.shape[1]
+    T = pages * block_size
+    layer = layer_ref[0]
 
-    @pl.when(j == 0)
+    def copies(b, c, slot, start):
+        for i in range(pages):
+            # (a wait needs the semaphore and the size, not the source)
+            page = bt_ref[b, jnp.minimum(c * pages + i, NB - 1)] \
+                if start else 0
+            for s, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                copy = pltpu.make_async_copy(
+                    hbm.at[layer, page],
+                    buf.at[slot, pl.ds(i * block_size, block_size)],
+                    sem.at[slot, s])
+                copy.start() if start else copy.wait()
+
+    def row_after(b):
+        """The next row that holds a token (B: none does)."""
+        return jax.lax.while_loop(
+            lambda r: (r < B) & (len_ref[jnp.minimum(r, B - 1)] == 0),
+            lambda r: r + 1, b + 1)
+
+    first = row_after(-1)
+
+    @pl.when(first < B)
     def _():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        copies(first, 0, 0, True)
 
-    for h in range(q_ref.shape[0]):               # static: Hkv heads
-        q = q_ref[h]                              # [G, D]
-        k = k_ref[:, h * D:(h + 1) * D]           # [bs, D]
-        v = v_ref[:, h * D:(h + 1) * D]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(pos < len_ref[b], s, _NEG_INF)
-        m_prev, l_prev = m_ref[h], l_ref[h]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[h] = m_new
-        l_ref[h] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    rows = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
+    kv_head = jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1), head_dim)
+    # own[j]: row h = g * G + j against the columns of kv head g
+    own = [rows == kv_head * G + j for j in range(G)]
 
-    @pl.when(j == num_blocks - 1)
-    def _():
-        o_ref[:] = (acc_ref[:]
-                    / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+    def row(b, slot):
+        length = len_ref[b]
+        n = (length + T - 1) // T
+        after = row_after(b)
+        qbd = jnp.zeros(acc_ref.shape, jnp.float32)
+        for j in range(G):
+            qbd = jnp.where(own[j], q_ref[b, pl.ds(j, 1), :], qbd)
+        qbd_ref[...] = qbd.astype(qbd_ref.dtype)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def chunk(c, slot):
+            more = c + 1 < n
+            nb = jnp.where(more, b, after)
+
+            @pl.when(nb < B)
+            def _():
+                copies(nb, jnp.where(more, c + 1, 0), 1 - slot, True)
+            copies(b, c, slot, False)
+            s = jax.lax.dot_general(
+                qbd_ref[...], k_buf[slot], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale   # [H, T]
+            pos = c * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(pos < length, s, _NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            m_ref[...] = m_new
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+                p.astype(v_buf.dtype), v_buf[slot],
+                preferred_element_type=jnp.float32)
+            return 1 - slot
+
+        slot = jax.lax.fori_loop(0, n, chunk, slot)
+        # (a row without a token: zeros, over a floor, not 0 / 0)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        for j in range(G):
+            o_ref[b, pl.ds(j, 1), :] = jnp.sum(
+                jnp.where(own[j], out, 0.0), axis=0, keepdims=True)
+        return slot
+
+    jax.lax.fori_loop(0, B, row, 0)
+
+
+def paged_decode_path(q_heads: int, head_dim: int, pages, S: int,
+                      layer=0) -> str:
+    """Which attention ``cached_attention`` runs over a paged cache, from
+    what it can observe: ``"paged_kernel"`` (``paged_attention_decode``)
+    for one new token a row (``S == 1``) over the serving pool
+    [L, P, bs, Hkv*D] on a TPU, where the pool's rows are whole lane
+    tiles and its pages whole sublane tiles and no mesh of several
+    devices is being traced for (a bare Mosaic call is refused there);
+    ``"gather"`` (``paged_gather`` + ``decode_attention``) for
+    everything else: prefill, a window of tokens, the CPU."""
+    if S != 1 or layer is None or not _use_pallas():
+        return "gather"
+    bs, C = pages.shape[2:]
+    mesh = getattr(_TRACE_MESH, "mesh", None)
+    fits = (C % 128 == 0 and C % head_dim == 0
+            and q_heads % (C // head_dim) == 0
+            and bs % (32 // jnp.dtype(pages.dtype).itemsize) == 0
+            and (mesh is None or mesh.size == 1))
+    return "paged_kernel" if fits else "gather"
 
 
 def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
-                           *, sm_scale: Optional[float] = None,
+                           *, layer=0, sm_scale: Optional[float] = None,
                            interpret: Optional[bool] = None):
-    """Pallas paged-attention decode: q [B, H, D] against block-table-
-    addressed pages, without gathering the cache into contiguous HBM.
+    """Pallas paged-attention decode: q [B, H, D], one query a row,
+    against layer ``layer`` (an int or a traced scalar) of the serving
+    pools [L, P, bs, Hkv*D], read where they lie: nothing is gathered,
+    sliced or laid out anew outside the kernel, and only the pages that
+    hold one of a row's ``lengths`` tokens are read (in chunks of
+    ``_PAGED_CHUNK_TOKENS``). MHA and GQA (H a multiple of Hkv); the
+    products are exact and summed in float32, the softmax is float32
+    and online, the probabilities meet V in the pool's dtype: the
+    mathematics of ``decode_attention``. A row of length 0 gives zeros.
 
-    Grid (B, NB); the block table + lengths ride scalar prefetch so
-    each grid step's BlockSpec index map DMAs exactly the page it
-    needs (pallas_guide: PrefetchScalarGridSpec). A page is viewed as
-    [bs, Hkv*D] (a free reshape of the pool), so the k/v block equals
-    the trailing array dims — the TPU lowering refuses a block that
-    squeezes the second-to-last dim, which a per-head page block would.
     Off-TPU (and not ``interpret``) this falls back to the gather
     reference — numerics are identical (gated in tests), so callers
     never branch.
-
-    GQA note: the G = H // Hkv query heads of one kv head form the
-    kernel's [G, D] q block; small G under-fills TPU sublanes — pad
-    query heads toward G >= 8 for peak MXU use on real hardware.
     """
     if interpret is None:
         interpret = False
         if not _use_pallas():
             return paged_attention_reference(
-                q, k_pages, v_pages, block_tables, lengths,
+                q, k_pages, v_pages, block_tables, lengths, layer=layer,
                 sm_scale=sm_scale)
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
-    P, bs, Hkv, _ = k_pages.shape
+    bs, C = k_pages.shape[2:]
     NB = block_tables.shape[1]
+    Hkv = C // D
     G = H // Hkv
     if sm_scale is None:
         sm_scale = D ** -0.5
-    qf = (q * sm_scale).astype(q.dtype).reshape(B, Hkv, G, D)
+    pages = max(1, min(_PAGED_CHUNK_TOKENS // bs, NB))
+    # row h of the kernel's matrices is head h; whole sublane tiles of
+    # the pool's dtype
+    Hp = -(-H // 16) * 16
     kernel = functools.partial(_paged_decode_kernel, block_size=bs,
-                               num_blocks=NB, head_dim=D)
-    page_spec = pl.BlockSpec((None, bs, Hkv * D),
-                             lambda b, j, bt, ln: (bt[b, j], 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, NB),
-        in_specs=[
-            pl.BlockSpec((None, Hkv, G, D),
-                         lambda b, j, bt, ln: (b, 0, 0, 0)),
-            page_spec,
-            page_spec,
-        ],
-        out_specs=pl.BlockSpec((None, Hkv, G, D),
-                               lambda b, j, bt, ln: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, G, 1), jnp.float32),
-            pltpu.VMEM((Hkv, G, 1), jnp.float32),
-            pltpu.VMEM((Hkv, G, D), jnp.float32),
-        ],
-    )
+                               pages=pages, head_dim=D,
+                               sm_scale=float(sm_scale))
+
+    def whole(*_):
+        return 0, 0, 0
     call = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(1,),
+            in_specs=[pl.BlockSpec((B, G, C), whole),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((B, G, C), whole),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bs, C), k_pages.dtype),
+                pltpu.VMEM((2, pages * bs, C), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((Hp, C), k_pages.dtype),
+                pltpu.VMEM((Hp, 1), jnp.float32),
+                pltpu.VMEM((Hp, 1), jnp.float32),
+                pltpu.VMEM((Hp, C), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, G, C), jnp.float32),
         interpret=interpret,
         name="paged_attention_decode",
     )
     with jax.named_scope("paged_attention_decode"):
-        out = call(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-                   qf, k_pages.reshape(P, bs, Hkv * D),
-                   v_pages.reshape(P, bs, Hkv * D))
-    return out.reshape(B, H, D)
+        # [B, H, D] -> [B, G, Hkv*D]: query j of every group, side by
+        # side as the pool holds the kv heads (float32: single rows of
+        # it are whole sublanes; every value is one of q's)
+        qf = q.astype(jnp.float32).reshape(B, Hkv, G, D).transpose(
+            0, 2, 1, 3).reshape(B, G, C)
+        out = call(jnp.asarray(layer, jnp.int32).reshape(1),
+                   block_tables.astype(jnp.int32),
+                   lengths.astype(jnp.int32), qf, k_pages, v_pages)
+        return out.reshape(B, G, Hkv, D).transpose(0, 2, 1, 3).reshape(
+            B, H, D).astype(q.dtype)
 
 
 def cached_attention(q, k_new, v_new, cache, seq_lengths, *,
@@ -1092,7 +1185,11 @@ def cached_attention(q, k_new, v_new, cache, seq_lengths, *,
     real tokens when the caller padded S to a bucket — padding kv is
     routed to the paged cache's null page and masked out of attention
     by the lengths. Appends the new kv, attends causally, and returns
-    (out [B, S, H, D], updated cache in the form given).
+    (out [B, S, H, D], updated cache in the form given). Over the
+    serving pool, which attention runs follows from the shapes
+    (``paged_decode_path``): one token a row on the chip reads its live
+    pages in place (``paged_attention_decode``); everything else gathers
+    every row to the padded context.
     """
     B, S = q.shape[:2]
     layers = cache if isinstance(cache, list) else None
@@ -1113,13 +1210,22 @@ def cached_attention(q, k_new, v_new, cache, seq_lengths, *,
             k_new, v_new, cache["k_pages"], cache["v_pages"], tables,
             seq_lengths, valid=valid, layer=layer)
 
+        new_cache = dict(cache, k_pages=k_pages, v_pages=v_pages)
+        if paged_decode_path(q.shape[2], q.shape[3], k_pages, S, layer) \
+                == "paged_kernel":
+            # one query a row (``valid`` can only mark whole rows, whose
+            # length is then 0): the live pages, read where they lie
+            out = paged_attention_decode(
+                q[:, 0], k_pages, v_pages, tables, new_len, layer=layer,
+                sm_scale=sm_scale)
+            return out[:, None], new_cache
+
         def gathered(pages):                               # [B,NB*bs,Hkv,D]
             return paged_gather(pages, tables, layer).reshape(
                 B, -1, *k_new.shape[2:])
         out = decode_attention(
             q.transpose(0, 2, 1, 3), gathered(k_pages), gathered(v_pages),
             new_len, sm_scale=sm_scale, q_positions=q_positions)
-        new_cache = dict(cache, k_pages=k_pages, v_pages=v_pages)
     else:
         pos = seq_lengths[:, None] + jnp.arange(S)[None, :]
         bidx = jnp.arange(B)[:, None]

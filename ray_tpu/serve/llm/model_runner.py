@@ -35,14 +35,17 @@ Two implementations:
   weights stacked, one block's program looped over them): bucketed
   (batch, length) jit shapes, one paged pool for all layers carried
   through ``ops.attention.cached_attention`` from block to block,
-  padding rows parked on the null page. Prefill and decode alike go
-  ``paged_gather`` + ``decode_attention`` (XLA) on every platform; the
-  ``paged_attention_decode`` Pallas kernel compiles for the chip and is
-  tested against that reference, but is not on this path (ROADMAP S2).
-  ``decode_window`` reuses the same paged path — the multi-token
-  incremental step is causal at the right offsets by construction
-  (``q_positions = seq_lengths[:, None] + arange(S)``), so batched
-  speculative verification is numerically the plain decode loop.
+  padding rows parked on the null page. A decode step is a program of
+  its own with one token a row (``S = 1``, ``llm_decode_b{B}``): on the
+  chip its attention is the ``paged_attention_decode`` Pallas kernel,
+  which reads each row's live pages where they lie in the pool; off the
+  chip the same program gathers (``cached_attention`` chooses by what it
+  can observe, ``ops.attention.paged_decode_path``). Prefill and
+  ``decode_window`` go ``paged_gather`` + ``decode_attention`` (XLA) on
+  every platform, padded to a bucket of at least 8 tokens — the
+  multi-token incremental step is causal at the right offsets by
+  construction (``q_positions = seq_lengths[:, None] + arange(S)``), so
+  batched speculative verification is numerically the plain decode loop.
 
 A model may state what it caches instead (``kind="kimi_linear"``:
 ``models.kimi_linear.cache_spec``): pages for some layers only, of
@@ -52,12 +55,11 @@ kind with a **state slot** per running sequence (slot 0 is the null
 slot, where padding rows read and write): a slot is taken and zeroed
 when a sequence is prefilled (``runner.state.admit``) and freed in
 ``release``. Pools and state go through the same ``_run``: bucketed,
-donated, written in place. A decode step of such a model is a program
-of its own with one token a row (``S = 1``: the one-token recurrence),
-not the shortest prefill bucket; in the bucket that is as wide as the
-state has slots, the rows go in slot order (``_by_slot``) and the state
-is updated where it lies. Such an adapter also finds each row's greedy
-token on the device (``greedy_on_device``): asked with
+donated, written in place. Its decode step is the one-token program
+too (``S = 1``: the one-token recurrence); in the bucket that is as
+wide as the state has slots, the rows go in slot order (``_by_slot``)
+and the state is updated where it lies. Such an adapter also finds
+each row's greedy token on the device (``greedy_on_device``): asked with
 ``tokens_only=True``, ``prefill`` / ``decode`` return tokens [B] and the
 logits are not fetched. What cannot work without snapshots of
 the state raises ``RecurrentStateError``: ``decode_window`` /
@@ -83,8 +85,8 @@ class RecurrentStateError(NotImplementedError):
     that token, and nothing takes such snapshots yet (ROADMAP R1)."""
 
 
-# the shortest token-axis bucket: a decode step (one new token a row) and
-# any prefill of at most this many tokens run the same program
+# the shortest token-axis bucket of a prefill or a verify step (a decode
+# step is a program of its own with one token a row)
 _MIN_S = 8
 
 
@@ -265,17 +267,18 @@ def bucket_name(B: int, S: int, full: bool = False) -> str:
     """The jitted step's name for one (batch, length) bucket."""
     if full:
         return f"llm_verify_b{B}_s{S}"
-    return f"llm_decode_b{B}" if S == _MIN_S else f"llm_prefill_b{B}_s{S}"
+    return f"llm_decode_b{B}" if S == 1 else f"llm_prefill_b{B}_s{S}"
 
 
 class FlaxModelAdapter:
     """GPT-2 / Llama incremental decode over the paged pool.
 
     jit shapes are bucketed (batch to a power of two, prompt length to
-    a power of two >= 8); padding rows carry zero lengths and
-    null-page block tables, so they scatter into scratch and attend to
-    nothing. Pages live as two jax arrays [L, P, bs, Hkv*D]: heads and
-    head dimension share the minor axis, which then fills whole lanes,
+    a power of two >= 8, a decode step one token); padding rows carry
+    zero lengths and null-page block tables, so they scatter into
+    scratch and attend to nothing. Pages live as two jax arrays
+    [L, P, bs, Hkv*D]: heads and head dimension share the minor axis,
+    which then fills whole lanes,
     so the chip keeps the pool in the order scatter and gather index it.
     The step donates both and every layer writes its B*S new rows into
     them, so the compiled program aliases the pools to its outputs and
@@ -301,7 +304,7 @@ class FlaxModelAdapter:
             from ray_tpu.models import gpt2
             self.cfg = config or gpt2.GPT2Config.tiny()
             self.model, self._blocks = gpt2.GPT2(self.cfg, stacked=True), "h"
-            self.n_kv_heads = self.cfg.n_head
+            self.n_heads = self.n_kv_heads = self.cfg.n_head
             self.head_dim = self.cfg.n_embd // self.cfg.n_head
             self.vocab_size = self.cfg.vocab_size
         elif kind == "llama":
@@ -309,6 +312,7 @@ class FlaxModelAdapter:
             self.cfg = config or llama.LlamaConfig.tiny()
             self.model = llama.LlamaModel(self.cfg, stacked=True)
             self._blocks = "layers"
+            self.n_heads = self.cfg.n_heads
             self.n_kv_heads = self.cfg.n_kv_heads
             self.head_dim = self.cfg.head_dim
             self.vocab_size = self.cfg.vocab_size
@@ -326,6 +330,7 @@ class FlaxModelAdapter:
             params = self.model.init(jax.random.PRNGKey(seed), dummy)
         self.params = params
         self._expert_tokens_total = self._expert_tokens_last = None
+        self._kv_pages_live = self._kv_pages_padded = 0
         self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
         self.bucket_first_calls = 0        # _fns misses: steps that compiled
         self._lock = threading.Lock()
@@ -371,11 +376,17 @@ class FlaxModelAdapter:
         jnp = self._jnp
         self.cache = cache
         dtype = self.cfg.dtype
+        # what a decode step's attention runs (its dispatch span says
+        # it): a model that states its cache gathers its own pools
+        self._decode_attention = "gather"
         if self._spec is None:
+            from ray_tpu.ops.attention import paged_decode_path
             shape = (self.n_layers, cache.num_blocks, cache.block_size,
                      self.n_kv_heads * self.head_dim)
             self.k_pages = jnp.zeros(shape, dtype)
             self.v_pages = jnp.zeros(shape, dtype)
+            self._decode_attention = paged_decode_path(
+                self.n_heads, self.head_dim, self.k_pages, 1)
         else:
             # one pool a page kind the model names; state arrays come
             # with ``bind_state``
@@ -405,13 +416,17 @@ class FlaxModelAdapter:
         self._free_slots = list(range(self.state_slots, 0, -1))
 
     def counters(self) -> Dict[str, Any]:
-        """What ``engine.metrics()`` adds for a model with state or
-        routed experts (docs/TRACING.md)."""
+        """What ``engine.metrics()`` adds of the model step: the pages
+        its decode steps' attention had to read and the pages of their
+        padded tables; for a model with state or routed experts, its
+        slots and its experts' tokens (docs/TRACING.md)."""
+        out = {"kv_pages_live_total": self._kv_pages_live,
+               "kv_pages_padded_total": self._kv_pages_padded}
         if self._spec is None:
-            return {}
-        out = {"state_slots_total": self.state_slots,
-               "state_slots_in_use": self.state_slots
-               - len(self._free_slots)}
+            return out
+        out.update(state_slots_total=self.state_slots,
+                   state_slots_in_use=self.state_slots
+                   - len(self._free_slots))
         if self._expert_tokens_total is not None:
             out["expert_tokens_total"] = self._expert_tokens_total.tolist()
             out["expert_tokens_last_step"] = self._expert_tokens_last.tolist()
@@ -553,8 +568,7 @@ class FlaxModelAdapter:
                 counts.reshape(-1).astype(jnp.int32)])
             return (logits, small, *(cache[n] for n in names))
 
-        step.__name__ = step.__qualname__ = (
-            f"llm_decode_b{B}" if S == 1 else f"llm_prefill_b{B}_s{S}")
+        step.__name__ = step.__qualname__ = bucket_name(B, S)
         donate = tuple(range(2, 2 + len(names))) \
             if jax.devices()[0].platform == "tpu" else ()
         return jax.jit(step, donate_argnums=donate)
@@ -569,9 +583,10 @@ class FlaxModelAdapter:
         jnp = self._jnp
         full = op == "verify"
         B = _pad_pow2(len(rows))
-        S = _pad_pow2(max(len(r["tokens"]) for r in rows), _MIN_S)
-        if self.has_state and op == "decode":
-            S = 1           # the one-token recurrence, its own program
+        # a decode step is the one-token program; a prefill or a window
+        # is padded to a bucket
+        S = 1 if op == "decode" else _pad_pow2(
+            max(len(r["tokens"]) for r in rows), _MIN_S)
         # where each row goes in the padded batch: in order, or (a full
         # decode bucket of a model with state) to its slot's place
         at = [r["slot"] - 1 for r in rows] if self._by_slot(B, S) \
@@ -592,7 +607,9 @@ class FlaxModelAdapter:
                     t = r["table"][:self.nb_max]
                     tables[i, :len(t)] = t
         with tracing.step_span("runner.dispatch", B=B, S=S,
-                               first_call=(B, S, full) not in self._fns):
+                               first_call=(B, S, full) not in self._fns,
+                               **(self._count_pages(rows, B)
+                                  if op == "decode" else {})):
             fn = self._step_fn(B, S, full)
             with self._lock:
                 if self._spec is None:
@@ -633,6 +650,20 @@ class FlaxModelAdapter:
             t = r["table"][:self.nb_max]
             packed[i, S + 3:S + 3 + len(t)] = t
         return packed
+
+    def _count_pages(self, rows, B: int) -> Dict[str, Any]:
+        """What a decode step's attention reads, for its dispatch span
+        and ``counters()``: which path it takes (the kernel over the
+        rows' live pages, or the gather to every row's padded table),
+        the pages that hold one of the rows' tokens, and the pages of
+        the ``B`` padded tables."""
+        bs = self.cache.block_size
+        live = sum(-(-(r["len"] + 1) // bs) for r in rows)
+        padded = B * self.nb_max
+        self._kv_pages_live += live
+        self._kv_pages_padded += padded
+        return {"attention": self._decode_attention, "kv_pages_live": live,
+                "kv_pages_padded": padded}
 
     def _count_experts(self, counts: np.ndarray) -> Dict[str, Any]:
         """counts [routed layers, experts held]: the step's tokens per

@@ -115,15 +115,18 @@ def test_bare_pallas_call_is_refused_on_a_mesh(topo):
 @pytest.mark.parametrize("H,Hkv,D", [(12, 12, 64), (32, 8, 128)],
                          ids=["gpt2-small", "llama-gqa"])
 def test_paged_attention_decode(one_chip, H, Hkv, D):
-    """B=8 sequences against 16-token pages, 64 pages each."""
+    """B=8 sequences against a pool of 4 layers of 16-token pages, 64
+    pages each, the layer a traced scalar."""
     B, bs, NB = 8, 16, 64
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    pages = sds((1 + B * NB, bs, Hkv, D), jnp.bfloat16)
-    _compile(lambda *a: A.paged_attention_decode(*a, interpret=False),
-             sds((B, H, D), jnp.bfloat16), pages, pages,
-             sds((B, NB), jnp.int32), sds((B,), jnp.int32))
+    pool = sds((4, 1 + B * NB, bs, Hkv * D), jnp.bfloat16)
+    _compile(lambda q, k, v, bt, ln, layer: A.paged_attention_decode(
+        q, k, v, bt, ln, layer=layer, interpret=False),
+             sds((B, H, D), jnp.bfloat16), pool, pool,
+             sds((B, NB), jnp.int32), sds((B,), jnp.int32),
+             sds((), jnp.int32))
 
 
 def _routed_experts(monkeypatch, T, sharding, mesh=None):
@@ -164,9 +167,23 @@ def test_routed_experts_shard_mapped_on_dp2_tp2(topo, monkeypatch):
         _routed_experts(monkeypatch, 64, NamedSharding(mesh, P()))
 
 
-def _served_step(one_chip, topo, monkeypatch, pages, B, S, full):
+def _moved(text, count):
+    """The program's copies, pads, update-slices and concatenates of at
+    least ``count`` elements: what a pool written in place has none of."""
+    import math
+    import re
+    return [
+        line.strip()[:120] for line in text.splitlines()
+        for m in [re.search(r" = \w+\[([\d,]+)\]\S* (copy|copy-start|pad|"
+                            r"dynamic-update-slice|concatenate)\(", line)]
+        if m and math.prod(map(int, m.group(1).split(","))) >= count]
+
+
+def _served_step(one_chip, topo, monkeypatch, pages, B, S, full,
+                 n_layer=4):
     """``FlaxModelAdapter``'s jitted step at GPT-2 large's width, cut to
-    4 layers, compiled for the chip over a pool of ``pages`` pages."""
+    4 layers, compiled for the chip over a pool of ``pages`` pages, its
+    attention as the chip chooses it (the paged kernel for ``S == 1``)."""
     from ray_tpu.models.gpt2 import GPT2Config
     from ray_tpu.serve.llm.kv_cache import PagedKVCache
     from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
@@ -174,7 +191,8 @@ def _served_step(one_chip, topo, monkeypatch, pages, B, S, full):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     adapter = FlaxModelAdapter(
-        "gpt2", GPT2Config(n_embd=1280, n_layer=4, n_head=20), params={})
+        "gpt2", GPT2Config(n_embd=1280, n_layer=n_layer, n_head=20),
+        params={})
     params = jax.tree_util.tree_map(
         lambda s: sds(s.shape, s.dtype),
         jax.eval_shape(adapter.model.init, jax.random.PRNGKey(0),
@@ -187,6 +205,7 @@ def _served_step(one_chip, topo, monkeypatch, pages, B, S, full):
         # the adapter donates the pools when its first device is a TPU
         m.setattr(jax, "devices", lambda *a, **k: topo.devices)
         fn = adapter._step_fn(B, S, full)
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
     with jax.default_matmul_precision("default"):
         return pool, fn.lower(
             params, sds((B, S), jnp.int32), pool, pool,
@@ -195,7 +214,7 @@ def _served_step(one_chip, topo, monkeypatch, pages, B, S, full):
 
 
 @pytest.mark.parametrize("B,S,full", [
-    (16, 8, False), (1, 256, False), (2, 8, True)],
+    (16, 1, False), (1, 256, False), (2, 8, True)],
     ids=["decode", "prefill", "verify"])
 def test_served_step_writes_the_pool_in_place(one_chip, topo, monkeypatch,
                                               B, S, full):
@@ -203,22 +222,42 @@ def test_served_step_writes_the_pool_in_place(one_chip, topo, monkeypatch,
     size is copied, laid out anew, padded or stacked, and what the
     program needs beside its arguments does not grow with the pool."""
     import math
-    import re
     pool, big = _served_step(one_chip, topo, monkeypatch, 1025, B, S, full)
     count = math.prod(pool.shape)
     memory = big.memory_analysis()
     assert memory.alias_size_in_bytes == 2 * count * pool.dtype.itemsize
-    moved = [
-        line.strip()[:120] for line in big.as_text().splitlines()
-        for m in [re.search(r" = \w+\[([\d,]+)\]\S* (copy|copy-start|pad|"
-                            r"dynamic-update-slice|concatenate)\(", line)]
-        if m and math.prod(map(int, m.group(1).split(","))) >= count]
-    assert not moved, moved
+    assert not _moved(big.as_text(), count)
     # (a pool of some tens of MB the compiler keeps in its fast memory,
     # which no served pool fits: hence no fewer pages than these)
     _, twice = _served_step(one_chip, topo, monkeypatch, 2049, B, S, full)
     assert memory.temp_size_in_bytes \
         == twice.memory_analysis().temp_size_in_bytes
+
+
+def test_decode_step_attends_to_its_live_pages_in_place(one_chip, topo,
+                                                        monkeypatch):
+    """GPT-2 large's decode program as served (16 rows, 36 layers, 1,025
+    pages of 16 x 1,280 bfloat16): the paged kernel is in it, no operand
+    or temporary spans the 16 rows' 1,024 padded positions (the gather,
+    its re-layout into heads, the scores), nothing of a pool's size is
+    copied, and the prefill program beside it still gathers."""
+    import math
+    import re
+    pool, step = _served_step(one_chip, topo, monkeypatch, 1025, 16, 1,
+                              False, n_layer=36)
+    text = step.as_text()
+    assert "tpu_custom_call" in text
+    count = math.prod(pool.shape)
+    shapes = [tuple(map(int, m.group(1).split(",")))
+              for m in re.finditer(r"\w+\[([\d,]+)\]", text)]
+    padded = {s for s in shapes if 1024 in s and 16 in s}
+    assert not padded, padded
+    assert not _moved(text, count)
+    assert step.memory_analysis().alias_size_in_bytes \
+        == 2 * count * pool.dtype.itemsize
+    _, prefill = _served_step(one_chip, topo, monkeypatch, 1025, 16, 8,
+                              False)
+    assert "tpu_custom_call" not in prefill.as_text()
 
 
 def _kimi_step(one_chip, topo, monkeypatch, pages, slots, B, S):

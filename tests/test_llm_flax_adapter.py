@@ -171,31 +171,76 @@ def test_flax_decode_window_and_rollback_match_the_plain_loop(kind):
 def test_stateless_kinds_keep_their_step_program(kind):
     """gpt2 and llama compile what they compiled before a model could
     state its cache: two donated pools [L, P, bs, Hkv*D] after params and
-    tokens, seven arguments, the same program names, and a decode step
-    that is the shortest prefill bucket."""
+    tokens, seven arguments, the same program names; a decode step is
+    the one-token program, and the counters say what its attention had
+    to read."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.serve.llm.model_runner import bucket_name
     adapter, _ = _flax_adapter(kind)
-    assert not adapter.has_state and adapter.counters() == {}
+    assert not adapter.has_state and adapter.counters() == {
+        "kv_pages_live_total": 0, "kv_pages_padded_total": 0}
     assert adapter.k_pages.shape == (
         adapter.n_layers, 32, PAGE, adapter.n_kv_heads * adapter.head_dim)
-    fn = adapter._step_fn(2, 8)
+    fn = adapter._step_fn(2, 1)
     lowered = fn.lower(
-        adapter.params, jnp.zeros((2, 8), jnp.int32), adapter.k_pages,
+        adapter.params, jnp.zeros((2, 1), jnp.int32), adapter.k_pages,
         adapter.v_pages, jnp.zeros((2, adapter.nb_max), jnp.int32),
-        jnp.zeros((2,), jnp.int32), jnp.zeros((2, 8), bool))
+        jnp.zeros((2,), jnp.int32), jnp.zeros((2, 1), bool))
     text = lowered.as_text()
     assert "module @jit_llm_decode_b2 " in text
-    assert bucket_name(2, 8) == "llm_decode_b2"
+    assert bucket_name(2, 1) == "llm_decode_b2"
+    assert bucket_name(2, 8) == "llm_prefill_b2_s8"
     assert bucket_name(4, 64) == "llm_prefill_b4_s64"
     assert bucket_name(2, 8, True) == "llm_verify_b2_s8"
     n_params = len(jax.tree_util.tree_leaves(adapter.params))
     assert len(jax.tree_util.tree_leaves(lowered.in_avals)) == n_params + 6
     logits, k, v = jax.eval_shape(
-        fn, adapter.params, jnp.zeros((2, 8), jnp.int32), adapter.k_pages,
+        fn, adapter.params, jnp.zeros((2, 1), jnp.int32), adapter.k_pages,
         adapter.v_pages, jnp.zeros((2, adapter.nb_max), jnp.int32),
-        jnp.zeros((2,), jnp.int32), jnp.zeros((2, 8), bool))
+        jnp.zeros((2,), jnp.int32), jnp.zeros((2, 1), bool))
     assert logits.shape == (2, adapter.vocab_size)
     assert k.shape == v.shape == adapter.k_pages.shape
+
+
+@pytest.mark.parametrize("kind", FLAX_KINDS)
+def test_decode_is_the_one_token_program(kind):
+    """``decode`` runs ``llm_decode_b{B}`` with one token a row (three
+    rows pad to four), says so on its dispatch span with the pages its
+    attention had to read, and its logits are those of the same tokens
+    through the 8-token bucket (``decode_window`` of one token) and of
+    the full forward."""
+    from ray_tpu._private import tracing
+    logits = {}
+    for how in ("decode", "window"):
+        adapter, cache = _flax_adapter(kind)
+        prompts = token_prompts(43, adapter.vocab_size, (12, 7, 17))
+        seqs = [flax_seq(cache, f"{how}-{i}", p, 4)
+                for i, p in enumerate(prompts)]
+        for s, row in zip(seqs, adapter.prefill(seqs)):
+            s.tokens.append(int(row.argmax()))
+        if how == "window":
+            rows = adapter.decode_window(seqs, [[s.tokens[-1]]
+                                                for s in seqs])
+            logits[how] = np.stack([r[0] for r in rows])
+            assert (4, 8, True) in adapter._fns
+            continue
+        with tracing.step_span("test.root") as root:
+            logits[how] = adapter.decode(seqs)
+        assert (4, 1, False) in adapter._fns \
+            and (4, 8, False) not in adapter._fns
+        assert adapter._fns[4, 1, False].__wrapped__.__name__ \
+            == "llm_decode_b4"
+        dispatch = next(c for c in root.rec["children"]
+                        if c["name"] == "runner.dispatch")["attrs"]
+        live = sum(-(-(len(p) + 1) // PAGE) for p in prompts)
+        assert dispatch["S"] == 1 and dispatch["attention"] == "gather"
+        assert dispatch["kv_pages_live"] == live
+        assert dispatch["kv_pages_padded"] == 4 * adapter.nb_max
+        assert adapter.counters()["kv_pages_live_total"] == live
+    np.testing.assert_allclose(logits["decode"], logits["window"],
+                               rtol=1e-5, atol=1e-5)
+    for row, s in zip(logits["decode"], seqs):
+        full = _full_forward(kind, list(s.prompt) + s.tokens)[-1]
+        np.testing.assert_allclose(row, full, rtol=2e-4, atol=2e-4)

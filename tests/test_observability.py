@@ -624,7 +624,9 @@ failed = []
 while time.time() < deadline:
     failed = list(state.list_tasks(filters={"state": "FAILED",
                                             "name": "victim"}))
-    if failed:
+    # the owner's FAILED event (no node) can be flushed a beat before
+    # the raylet's, which names the node: wait for both
+    if failed and failed[0].get("node_id"):
         break
     time.sleep(0.5)
 assert failed, "FAILED task never listed"

@@ -151,7 +151,7 @@ def test_first_call_is_true_once_a_bucket(run):
 
 
 @pytest.mark.parametrize("B,S,full,name", [
-    (2, 8, False, "llm_decode_b2"), (1, 32, False, "llm_prefill_b1_s32"),
+    (2, 1, False, "llm_decode_b2"), (1, 32, False, "llm_prefill_b1_s32"),
     (2, 8, True, "llm_verify_b2_s8")])
 def test_each_buckets_module_carries_its_name(B, S, full, name):
     import jax.numpy as jnp
@@ -394,8 +394,8 @@ def test_paged_decode_kernel_is_named():
     jaxpr = jax.make_jaxpr(
         lambda *a: paged_attention_decode(*a, interpret=True))(
         jax.ShapeDtypeStruct((2, 4, 64), jnp.float32),
-        jax.ShapeDtypeStruct((8, 16, 4, 64), jnp.float32),
-        jax.ShapeDtypeStruct((8, 16, 4, 64), jnp.float32),
+        jax.ShapeDtypeStruct((1, 8, 16, 4 * 64), jnp.float32),
+        jax.ShapeDtypeStruct((1, 8, 16, 4 * 64), jnp.float32),
         jax.ShapeDtypeStruct((2, 4), jnp.int32),
         jax.ShapeDtypeStruct((2,), jnp.int32))
     assert [name for name, _ in pallas_call_names(jaxpr.jaxpr)] == [

@@ -10,7 +10,7 @@ from unittest import mock
 import pytest
 
 from ray_tpu._private.raylet import (_chips_from_accel_type,
-                                     detect_tpu_chips)
+                                     detect_tpu_chips, detect_tpu_topology)
 from ray_tpu.common.config import SystemConfig
 
 
@@ -110,3 +110,20 @@ def test_declared_topology_caps_device_files():
                   "TPU_VISIBLE_DEVICES"):
             os.environ.pop(k, None)
         assert detect_tpu_chips(_cfg()) == 4
+
+
+@pytest.mark.parametrize("said,index", [
+    ("3", 3), (None, 0),
+    # what libtpu leaves in the environment of a process that loaded it
+    # where no worker number can be found (tests/test_chip_compile.py
+    # does), and a driver started from that process inherits: a raylet
+    # that died of it failed whichever test shared the pytest worker
+    ("WARNING: could not determine TPU worker number, please set env "
+     "var `TPU_WORKER_ID` manually, otherwise libtpu.so may not "
+     "properly initialize.", 0)], ids=["number", "unset", "libtpu-text"])
+def test_worker_index_from_the_environment(said, index):
+    env = {} if said is None else {"TPU_WORKER_ID": said}
+    with mock.patch.dict(os.environ, env):
+        if said is None:
+            os.environ.pop("TPU_WORKER_ID", None)
+        assert detect_tpu_topology()["worker_index"] == index

@@ -238,7 +238,13 @@ def test_cancel_stops_between_steps(wf_cluster, tmp_path):
 
     dag = never_step.bind(slow_step.bind(1), marker)
     ref = workflow.run_async(dag, workflow_id="cancel-wf")
-    time.sleep(0.8)  # inside slow_step
+    # (the workflow's task has to reach a worker and say RUNNING before
+    # there is anything to cancel: beside five other pytest workers that
+    # can take longer than a fixed nap)
+    deadline = time.time() + 60
+    while workflow.get_status("cancel-wf") != "RUNNING" \
+            and time.time() < deadline:
+        time.sleep(0.05)
     assert workflow.cancel("cancel-wf")
     with pytest.raises(Exception):
         ray_tpu.get(ref, timeout=60)
