@@ -3,7 +3,11 @@ latent-attention (MLA) layers in one stack, routed SwiGLU experts.
 
 Source: moonshotai/Kimi-Linear-48B-A3B-Instruct ``config.json``. Three
 kinds of block in one stack, pre-norm RMSNorm with a residual round the
-mixer and round the feed-forward, no position encoding anywhere:
+mixer and round the feed-forward. No position encoding anywhere in this
+family (``mla_use_nope``: the KDA layers carry order in their state);
+the MLA mixer is ``models/mla.py``'s, shared with ``models/kimi_k2.py``,
+which rotates its ``rope`` values and has a low-rank query, used here
+without either:
 
   KDA + dense SwiGLU     the ``first_k_dense`` leading layers
   KDA + routed experts   ``kda_layers`` (1-based, as the source counts)
@@ -41,7 +45,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import attention as A
+from ray_tpu.models.mla import MLAMixer, RMSNorm, dense as _dense, \
+    lanes as _lanes
 from ray_tpu.ops import linear_attention as LA
 from ray_tpu.parallel.moe import RoutedExperts, SwiGLU
 
@@ -65,6 +70,8 @@ class KimiLinearConfig:
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64      # carried without rotation: mla_use_nope
+    q_lora_rank: Optional[int] = None       # the source's null: one q matrix
+    rope = None                     # mla_use_nope (models/mla.py)
     v_head_dim: int = 128
     # feed-forward
     intermediate_size: int = 9216
@@ -140,30 +147,6 @@ def cache_spec(cfg: KimiLinearConfig) -> Dict[str, Any]:
     }
 
 
-def _lanes(n: int) -> int:
-    """A page row is whole lanes of 128, so that the chip keeps the pool
-    in the order scatter and gather index it (a row of 576 made it lay
-    the whole pool out anew twice a step: compiled for the described
-    chip, PR 28)."""
-    return -(-n // 128) * 128
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        x = x.astype(jnp.float32)
-        return x * jax.lax.rsqrt(
-            jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps) * scale
-
-
-def _dense(mod, name, shape, dtype, std=0.02):
-    return mod.param(name, nn.initializers.normal(std), shape, dtype)
-
-
 class KDAMixer(nn.Module):
     config: KimiLinearConfig
 
@@ -225,51 +208,6 @@ class KDAMixer(nn.Module):
         o = (o.reshape(B, S, H * d) * gate).astype(dt)
         return (jnp.matmul(o, _dense(self, "o_proj", (H * d, D), dt),
                            preferred_element_type=f32), new_state, new_tail)
-
-
-class MLAMixer(nn.Module):
-    config: KimiLinearConfig
-
-    @nn.compact
-    def __call__(self, x, pages=None, block_tables=None, seq_lengths=None,
-                 valid=None, layer=None):
-        """x [B, S, D]. Without ``pages``: causal attention over the
-        sequence's own tokens. With them: the new tokens' latent rows
-        are written to layer ``layer`` of the pool and the queries
-        attend to what the block tables reach. Returns (y, pages)."""
-        cfg = self.config
-        B, S, D = x.shape
-        H, R = cfg.num_attention_heads, cfg.kv_lora_rank
-        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
-            cfg.v_head_dim
-        dt = cfg.dtype
-        xb = x.astype(dt)
-        q = (xb @ _dense(self, "q_proj", (D, H * (dn + dr)), dt)
-             ).reshape(B, S, H, dn + dr)
-        kv = xb @ _dense(self, "kv_a", (D, R + dr), dt)
-        c = RMSNorm(cfg.rms_norm_eps, name="kv_norm")(kv[..., :R])
-        latent = jnp.concatenate([c.astype(dt), kv[..., R:]], axis=-1)
-        w_kvb = _dense(self, "kv_b", (R, H * (dn + dv)), dt
-                       ).reshape(R, H, dn + dv)
-        if pages is None:
-            context = latent
-            q_pos = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-        else:
-            row = jnp.pad(latent, ((0, 0), (0, 0),
-                                   (0, pages.shape[-1] - R - dr)))
-            pages = A.append_latent_pages(row, pages, block_tables,
-                                          seq_lengths, valid, layer)
-            context = A.paged_gather(pages, block_tables,
-                                     layer)[..., :R + dr]
-            q_pos = seq_lengths[:, None] + jnp.arange(S)[None, :]
-            if valid is not None:
-                q_pos = jnp.where(valid, q_pos, -1)
-        y = A.latent_attention(
-            q[..., :dn], q[..., dn:], context, w_kvb, q_pos, v_dim=dv,
-            absorbed=pages is not None and S == 1)
-        y = y.reshape(B, S, H * dv).astype(dt)
-        return jnp.matmul(y, _dense(self, "o_proj", (H * dv, D), dt),
-                          preferred_element_type=jnp.float32), pages
 
 
 class KimiBlock(nn.Module):

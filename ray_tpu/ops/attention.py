@@ -869,6 +869,102 @@ def append_latent_pages(rows, pages, block_tables, lengths, valid=None,
     return pages.at[(*lead, page, slot)].set(rows.astype(pages.dtype))
 
 
+# latent_attention: the most float32 logits [B, H, q_block, T] of one
+# query block against the whole context (four prompts of 1,024 tokens
+# over 3,072 positions and 32 heads, the largest that Kimi-Linear's cell
+# runs, hold 0.8 GB at 512 a block). Above it the logits stay inside the
+# core: ``latent_prefill_attention``.
+LATENT_LOGITS_BYTES = 1 << 30
+_KEY_BLOCKS = (512, 256, 128)
+
+
+def _latent_prefill_kernel(last_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
+                           *, block_k):
+    # refs: q [bq, d]; k [T, d]; v [T, dv]; pos [bq, 1]; o [bq, dv];
+    # last [B, S / bq] (scalar prefetch): the last key block a query of
+    # the block may see (-1: a block of padding, which visits none)
+    from jax.experimental import pallas as pl
+
+    bq = q_ref.shape[0]
+    q, pos = q_ref[:], pos_ref[:]
+    row = pl.program_id(0) // (pl.num_programs(0) // last_ref.shape[0])
+
+    def body(j, carry):
+        m, total, acc = carry
+        k = k_ref[pl.ds(j * block_k, block_k), :]
+        v = v_ref[pl.ds(j * block_k, block_k), :]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        k_pos = j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (bq, block_k), 1)
+        s = jnp.where(k_pos <= pos, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, total * alpha + jnp.sum(p, axis=-1, keepdims=True), acc
+
+    _, total, acc = jax.lax.fori_loop(
+        0, last_ref[row, pl.program_id(1)] + 1, body, (
+            jnp.full((bq, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((bq, 1), jnp.float32),
+            jnp.zeros((bq, v_ref.shape[1]), jnp.float32)))
+    o_ref[:] = (acc / jnp.maximum(total, 1e-30)).astype(o_ref.dtype)
+
+
+def latent_prefill_attention(q, keys, values, q_positions, sm_scale,
+                             block_q: int = 512, block_k: int = 512):
+    """Softmax attention of many queries over materialised keys and
+    values, as one Pallas kernel: a grid step holds one head's keys and
+    values whole and one block of its queries, walks the key blocks with
+    a running maximum, sum and output (the same mathematics as one
+    softmax over all of them), and stops at the last key block that holds
+    a position some query of the block may see; a block of padding
+    queries (positions -1) walks none and returns zeros. No logits leave
+    the core. q [B, S, H, d], keys [B, T, H, d], values [B, T, H, dv],
+    q_positions [B, S] -> [B, S, H, dv]. ``S`` and ``T`` are whole
+    blocks. Off the chip the same kernel runs interpreted. Forward only."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, S, H, d = q.shape
+    T, dv = keys.shape[1], values.shape[-1]
+    bq, bk = min(block_q, S), min(block_k, T)
+    assert S % bq == 0 and T % bk == 0, (S, bq, T, bk)
+
+    def heads_first(t):             # [B, N, H, w] -> [B * H, N, w]
+        return jnp.moveaxis(t, 2, 1).reshape(B * H, t.shape[1], t.shape[3])
+    last = jnp.minimum(jnp.max(q_positions.reshape(B, S // bq, bq), axis=-1)
+                       // bk, T // bk - 1).astype(jnp.int32)
+    pos = jnp.broadcast_to(q_positions[:, None, :, None].astype(jnp.int32),
+                           (B, H, S, 1)).reshape(B * H, S, 1)
+    item = jnp.dtype(keys.dtype).itemsize
+    need = 2 * T * (d + dv) * item + 6 * bq * bk * 4 + 4 * bq * (d + dv) * 4
+    call = pl.pallas_call(
+        functools.partial(_latent_prefill_kernel, block_k=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B * H, S // bq),
+            in_specs=[
+                pl.BlockSpec((None, bq, d), lambda i, j, last: (i, j, 0)),
+                pl.BlockSpec((None, T, d), lambda i, j, last: (i, 0, 0)),
+                pl.BlockSpec((None, T, dv), lambda i, j, last: (i, 0, 0)),
+                pl.BlockSpec((None, bq, 1), lambda i, j, last: (i, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, bq, dv),
+                                   lambda i, j, last: (i, j, 0))),
+        out_shape=jax.ShapeDtypeStruct((B * H, S, dv), values.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=int(need * 1.25) + (8 << 20)),
+        interpret=not _use_pallas(),
+        name="latent_prefill_attention")
+    out = call(last, heads_first((q * sm_scale).astype(q.dtype)),
+               heads_first(keys), heads_first(values), pos)
+    return jnp.moveaxis(out.reshape(B, H, S, dv), 1, 2)
+
+
 def latent_attention(q_nope, q_rope, latent, w_kvb, q_positions, *,
                      v_dim: int, absorbed: bool = False,
                      sm_scale: Optional[float] = None, q_block: int = 512):
@@ -887,20 +983,35 @@ def latent_attention(q_nope, q_rope, latent, w_kvb, q_positions, *,
     queries). ``absorbed=True`` folds the up-projection into the query
     and the output instead (``q_nope W_uk`` against ``c``, the
     probabilities' sum of ``c`` through ``W_uv``), so a decode step
-    reads the context's latents once and builds nothing per head."""
+    reads the context's latents once and builds nothing per head.
+
+    The queries go ``q_block`` at a time. Where a block's float32 logits
+    against the whole context would pass ``LATENT_LOGITS_BYTES`` (a
+    prompt of 8,192 tokens over 9,216 cached positions and 64 heads: 1.2
+    GB a block, several times over while the softmax runs, 19 GB a layer
+    written and read again and again: 1.7 s a prompt on the chip, PR 35)
+    the materialised form is one Pallas kernel that walks the keys in
+    blocks with a running softmax (``latent_prefill_attention``)."""
     B, S, H, dn = q_nope.shape
     R = w_kvb.shape[0]
+    T = latent.shape[1]
     if sm_scale is None:
         sm_scale = (dn + q_rope.shape[-1]) ** -0.5
     c, k_rope = latent[..., :R], latent[..., R:]
     w_uk, w_uv = w_kvb[..., :dn], w_kvb[..., dn:]
-    k_pos = jnp.arange(latent.shape[1])[None, None, None, :]
+    k_pos = jnp.arange(T)[None, None, None, :]
+    k_block = next((kb for kb in _KEY_BLOCKS if T % kb == 0 and kb < T), 0)
+    by_keys = not absorbed and k_block and \
+        B * H * min(S, q_block) * T * 4 > LATENT_LOGITS_BYTES
     if absorbed:
         keys = c
         values = c
     else:
         kv = jnp.einsum("btr,rhd->bthd", c, w_kvb)
         keys, values = kv[..., :dn], kv[..., dn:]
+        if by_keys:     # one product a key block: [k_n | k_r] a head
+            keys = jnp.concatenate([keys, jnp.broadcast_to(
+                k_rope[:, :, None, :], (B, T, H, k_rope.shape[-1]))], -1)
 
     def attend(qn, qr, pos):                    # a block of the queries
         if absorbed:
@@ -921,6 +1032,10 @@ def latent_attention(q_nope, q_rope, latent, w_kvb, q_positions, *,
             return jnp.einsum("bshr,rhd->bshd", out, w_uv)
         return jnp.einsum("bhst,bthd->bshd", probs, values)
 
+    if by_keys:
+        return latent_prefill_attention(
+            jnp.concatenate([q_nope, q_rope], -1), keys, values,
+            q_positions, sm_scale, q_block, k_block)
     if S <= q_block or S % q_block:
         return attend(q_nope, q_rope, q_positions)
 

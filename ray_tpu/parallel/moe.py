@@ -129,6 +129,14 @@ class SwiGLU(nn.Module):
 # by that and not by a sweep: nothing between was measured.
 WHOLE_ROWS_BELOW = 256
 BLOCK_ROWS = 256
+# The sorted product holds its rows for the worst case (every assignment
+# landing on the experts held here) in float32: [T * k + E * BLOCK_ROWS,
+# d]. Up to this many bytes of them it holds them (4,096 tokens at
+# Kimi-Linear's width: 453 MB). Above (8,192 tokens at Kimi-K2's width:
+# 1.97 GB of rows beside 0.98 of their inputs, refused by the compiler
+# for the described v5e, PR 35) it holds index arrays only and moves a
+# block's rows inside the loop: ``_experts_by_block``.
+SORTED_ROWS_BYTES = 640 << 20
 
 
 class RoutedExperts(nn.Module):
@@ -213,7 +221,10 @@ class RoutedExperts(nn.Module):
                 y = touched_experts(xb, combine, counts, w_gate, w_up,
                                     w_down)
             else:
-                y = _experts_sorted(xb, local, w, w_gate, w_up, w_down)
+                rows = T * self.top_k + count * BLOCK_ROWS
+                product = _experts_by_block \
+                    if rows * d * 4 > SORTED_ROWS_BYTES else _experts_sorted
+                y = product(xb, local, w, w_gate, w_up, w_down)
         if self.shared_d_ff:
             with jax.named_scope("moe/shared"):
                 y = y + SwiGLU(self.shared_d_ff, self.dtype,
@@ -221,13 +232,14 @@ class RoutedExperts(nn.Module):
         return y.reshape(*lead, d), counts
 
 
-def _experts_sorted(x, local, w, w_gate, w_up, w_down):
-    """Grouped products: the assignments sorted by expert, each expert's
-    group padded to whole blocks of ``BLOCK_ROWS`` rows, one SwiGLU a
-    block with that block's expert, blocks without a token skipped."""
-    T, d = x.shape
-    E, k, bm = w_gate.shape[0], local.shape[1], BLOCK_ROWS
-    A = T * k
+def _sorted_rows(local, E: int):
+    """The assignments sorted by expert, each expert's group padded to
+    whole blocks of ``BLOCK_ROWS`` rows: ``order`` (the assignments in
+    sorted order), ``dest`` (each one's row; ``rows`` for an assignment
+    that is not this chip's), ``rows`` (rows for the worst case), and
+    for each block where it starts, which expert's it is, and ``ends``
+    (where each expert's group ends: the last is the live rows)."""
+    A, bm = local.size, BLOCK_ROWS
     e = local.reshape(A)                                   # E: not here
     sizes = jnp.sum(jax.nn.one_hot(e, E + 1, dtype=jnp.int32), axis=0)
     padded = -(-sizes[:E] // bm) * bm
@@ -239,6 +251,17 @@ def _experts_sorted(x, local, w, w_gate, w_up, w_down):
     dest = jnp.where(sorted_e < E,
                      (ends - padded)[jnp.minimum(sorted_e, E - 1)] + rank,
                      rows)
+    return e, order, dest, rows, ends
+
+
+def _experts_sorted(x, local, w, w_gate, w_up, w_down):
+    """Grouped products: the assignments sorted by expert, each expert's
+    group padded to whole blocks of ``BLOCK_ROWS`` rows, one SwiGLU a
+    block with that block's expert, blocks without a token skipped."""
+    T, d = x.shape
+    E, k, bm = w_gate.shape[0], local.shape[1], BLOCK_ROWS
+    A = T * k
+    e, order, dest, rows, ends = _sorted_rows(local, E)
     xs = jnp.zeros((rows, d), x.dtype).at[dest].set(
         x[order // k], mode="drop")
     n_blocks = rows // bm
@@ -263,6 +286,37 @@ def _experts_sorted(x, local, w, w_gate, w_up, w_down):
     weight = jnp.where(e < E, w.reshape(A), 0.0)
     y = ys[back] * weight[:, None]
     return jnp.sum(y.reshape(T, k, d), axis=1)
+
+
+def _experts_by_block(x, local, w, w_gate, w_up, w_down):
+    """The same grouped products with nothing of the worst case's size
+    but index arrays: which token and what weight each sorted row has.
+    A loop over the LIVE blocks gathers a block's rows from ``x``,
+    multiplies them by the block's expert and adds the weighed result to
+    its tokens' rows of the output, so what moves is the assignments
+    that landed here (12 experts of 384 get a 32nd of them) and not all
+    ``T * k``."""
+    T, d = x.shape
+    E, k, bm = w_gate.shape[0], local.shape[1], BLOCK_ROWS
+    _, order, dest, rows, ends = _sorted_rows(local, E)
+    token = jnp.full((rows,), T, jnp.int32).at[dest].set(
+        (order // k).astype(jnp.int32), mode="drop")       # T: no token
+    weight = jnp.zeros((rows,), jnp.float32).at[dest].set(
+        w.reshape(T * k)[order], mode="drop")
+    block_expert = jnp.minimum(jnp.searchsorted(
+        ends, jnp.arange(rows // bm) * bm, side="right"), E - 1)
+
+    def block(b, y):
+        tok = jax.lax.dynamic_slice_in_dim(token, b * bm, bm)
+        xb = x[jnp.minimum(tok, T - 1)]
+        i = block_expert[b]
+        out = jnp.matmul(nn.silu(xb @ w_gate[i]) * (xb @ w_up[i]),
+                         w_down[i], preferred_element_type=jnp.float32)
+        out = out * jax.lax.dynamic_slice_in_dim(weight, b * bm, bm)[:, None]
+        return y.at[tok].add(out, mode="drop")
+
+    return jax.lax.fori_loop(0, ends[-1] // bm, block,
+                             jnp.zeros((T, d), jnp.float32))
 
 
 def expert_sharding_rule(mesh, path: Tuple[str, ...], shape, spec):

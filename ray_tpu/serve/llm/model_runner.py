@@ -47,9 +47,10 @@ Two implementations:
   construction (``q_positions = seq_lengths[:, None] + arange(S)``), so
   batched speculative verification is numerically the plain decode loop.
 
-A model may state what it caches instead (``kind="kimi_linear"``:
-``models.kimi_linear.cache_spec``): pages for some layers only, of
-the row width it names, and per-sequence *state* for others. The
+A model may state what it caches instead (``kind="kimi_linear"``,
+``kind="kimi_k2"``: the models' ``cache_spec``): pages for some or all
+layers, of the row width it names, and per-sequence *state* for others
+(Kimi-K2 names one latent pool and no state). The
 adapter then owns one pool a named page kind and one array a named state
 kind with a **state slot** per running sequence (slot 0 is the null
 slot, where padding rows read and write): a slot is taken and zeroed
@@ -64,7 +65,13 @@ each row's greedy token on the device (``greedy_on_device``): asked with
 logits are not fetched. What cannot work without snapshots of
 the state raises ``RecurrentStateError``: ``decode_window`` /
 ``rollback``, ``export_kv`` / ``import_kv`` (and the engine refuses
-``enable_prefix_cache`` and ``spec_k`` at construction).
+``enable_prefix_cache`` and ``spec_k`` at construction). A model that
+states pages and NO state may use all of them: every cached token of it
+is a page row, written at its absolute position, so a prefix's pages
+can be shared (a suffix is prefilled from a non-zero length), a window
+verified in one step (``llm_verify_b{B}_s{S}``) and rolled back by
+length, and a prompt's pages shipped
+(tests/test_llm_kimi_k2_serving.py).
 """
 
 from __future__ import annotations
@@ -323,6 +330,13 @@ class FlaxModelAdapter:
             self._blocks = None            # three block kinds: unrolled
             self.vocab_size = self.cfg.vocab_size
             self._spec = kimi_linear.cache_spec(self.cfg)
+        elif kind == "kimi_k2":
+            from ray_tpu.models import kimi_k2
+            self.cfg = config or kimi_k2.KimiK2Config.tiny()
+            self.model = kimi_k2.KimiK2Model(self.cfg)
+            self._blocks = None            # a dense layer, then routed ones
+            self.vocab_size = self.cfg.vocab_size
+            self._spec = kimi_k2.cache_spec(self.cfg)
         else:
             raise ValueError(f"unknown model kind {kind!r}")
         if params is None:
@@ -500,7 +514,7 @@ class FlaxModelAdapter:
         import jax
         jnp = self._jnp
         if self._spec is not None:
-            fn = self._fns[key] = self._spec_step_fn(B, S)
+            fn = self._fns[key] = self._spec_step_fn(B, S, full)
             self.bucket_first_calls += 1
             return fn
 
@@ -537,7 +551,7 @@ class FlaxModelAdapter:
         step 9 of its 29 ms on the device: my chip run, PR 28.)"""
         return self.has_state and S == 1 and B == self.state_slots
 
-    def _spec_step_fn(self, B: int, S: int):
+    def _spec_step_fn(self, B: int, S: int, full: bool = False):
         """The step of a model that states its cache: every pool and
         state array is an argument, donated, and comes back written in
         place. The rows' integers come as ONE array (``_pack``): each
@@ -546,7 +560,9 @@ class FlaxModelAdapter:
         returns ``small``: each row's greedy token, then the routed
         layers' per-expert token counts of the step, so that a step
         whose rows all sample greedily fetches B + layers x experts
-        integers and leaves the logits on the device."""
+        integers and leaves the logits on the device. ``full`` (a
+        speculative window's verify step) returns the logits after every
+        position, [B, S, V]."""
         import jax
         jnp = self._jnp
         names = list(self._arrays)
@@ -561,14 +577,16 @@ class FlaxModelAdapter:
             valid = jnp.arange(S)[None, :] < n_new[:, None]
             logits, cache, counts = self.model.apply(
                 params, tokens, cache=cache, seq_lengths=seq_lengths,
-                valid=valid, logits_at=jnp.maximum(n_new - 1, 0))
-            logits = logits[:, 0]
+                valid=valid,
+                logits_at=None if full else jnp.maximum(n_new - 1, 0))
+            rows = logits[:, 0]     # (a verify step's are not asked for)
             small = jnp.concatenate([
-                jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                jnp.argmax(rows, axis=-1).astype(jnp.int32),
                 counts.reshape(-1).astype(jnp.int32)])
-            return (logits, small, *(cache[n] for n in names))
+            return (logits if full else rows, small,
+                    *(cache[n] for n in names))
 
-        step.__name__ = step.__qualname__ = bucket_name(B, S)
+        step.__name__ = step.__qualname__ = bucket_name(B, S, full)
         donate = tuple(range(2, 2 + len(names))) \
             if jax.devices()[0].platform == "tpu" else ()
         return jax.jit(step, donate_argnums=donate)
@@ -609,7 +627,10 @@ class FlaxModelAdapter:
         with tracing.step_span("runner.dispatch", B=B, S=S,
                                first_call=(B, S, full) not in self._fns,
                                **(self._count_pages(rows, B)
-                                  if op == "decode" else {})):
+                                  if op == "decode" else
+                                  {"prompt_tokens": sum(len(r["tokens"])
+                                                        for r in rows),
+                                   "padded_tokens": B * S})):
             fn = self._step_fn(B, S, full)
             with self._lock:
                 if self._spec is None:
@@ -662,8 +683,9 @@ class FlaxModelAdapter:
         padded = B * self.nb_max
         self._kv_pages_live += live
         self._kv_pages_padded += padded
-        return {"attention": self._decode_attention, "kv_pages_live": live,
-                "kv_pages_padded": padded}
+        return {"attention": self._decode_attention,
+                "live_tokens": sum(r["len"] + 1 for r in rows),
+                "kv_pages_live": live, "kv_pages_padded": padded}
 
     def _count_experts(self, counts: np.ndarray) -> Dict[str, Any]:
         """counts [routed layers, experts held]: the step's tokens per
@@ -747,6 +769,12 @@ class FlaxModelAdapter:
         nb = -(-int(n_prompt) // bs)
         st = self._state[seq_id]
         idx = jnp.asarray(np.asarray(st["table"][:nb], np.int32))
+        if self._spec is not None:      # the pools the model names
+            with self._lock:
+                pages = {name: np.asarray(self._arrays[name][:, idx])
+                         for name in self._spec["pages"]}
+            return {"kind": f"flax:{self.kind}", "n": int(n_prompt),
+                    "pages": pages}
         heads = (self.n_layers, nb, bs, self.n_kv_heads, self.head_dim)
         with self._lock:
             k = np.asarray(self.k_pages[:, idx]).reshape(heads)
@@ -767,6 +795,14 @@ class FlaxModelAdapter:
         nb = -(-int(n_prompt) // bs)
         table = self.cache.block_table(seq_id)
         idx = jnp.asarray(np.asarray(table[:nb], np.int32))
+        if self._spec is not None:
+            with self._lock:
+                for name in self._spec["pages"]:
+                    a = self._arrays[name]
+                    self._arrays[name] = a.at[:, idx].set(
+                        jnp.asarray(blob["pages"][name], a.dtype))
+            self._state[seq_id] = {"table": table, "len": int(n_prompt)}
+            return
         merged = (self.n_layers, nb, bs, -1)
         with self._lock:
             self.k_pages = self.k_pages.at[:, idx].set(jnp.asarray(
@@ -784,12 +820,13 @@ class FlaxModelAdapter:
 def make_adapter(model: str = "toy",
                  model_config: Optional[Dict[str, Any]] = None):
     """Deployment-facing factory: ``model`` is ``toy`` |
-    ``gpt2`` | ``llama`` | ``kimi_linear`` (tiny test configs unless
-    ``model_config`` overrides)."""
+    ``gpt2`` | ``llama`` | ``kimi_linear`` | ``kimi_k2`` (tiny test
+    configs unless ``model_config`` overrides)."""
     model_config = dict(model_config or {})
     if model == "toy":
         return ToyAdapter(**model_config)
-    if model in ("gpt2", "llama", "kimi_linear"):
+    if model in ("gpt2", "llama", "kimi_linear", "kimi_k2"):
         return FlaxModelAdapter(kind=model, **model_config)
     raise ValueError(
-        f"unknown model {model!r} (toy | gpt2 | llama | kimi_linear)")
+        f"unknown model {model!r} "
+        "(toy | gpt2 | llama | kimi_linear | kimi_k2)")

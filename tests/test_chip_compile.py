@@ -328,3 +328,64 @@ def test_kimi_step_writes_pages_and_state_in_place(one_chip, topo,
         - memory.temp_size_in_bytes < 0.1 * growth
     if slots == B:      # and no loop over the rows writes the state back
         assert " while(" not in big.as_text()
+
+
+def _k2_step(one_chip, topo, monkeypatch, B, S):
+    """``FlaxModelAdapter``'s step for Kimi-K2 as the cell
+    kimi_k2_7_code.serve_closed32_ctx8k runs it: the published widths,
+    seven layers, 12 of 384 experts, 20,480 rows of the vocabulary, a
+    pool of 18,433 pages and block tables of 576."""
+    from ray_tpu.models.kimi_k2 import KimiK2Config
+    from ray_tpu.serve.llm.kv_cache import PagedKVCache
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    cfg = KimiK2Config(vocab_size=20480, num_hidden_layers=7,
+                       experts_held=(0, 12), max_seq_len=9216)
+    adapter = FlaxModelAdapter("kimi_k2", cfg, params={})
+    params = jax.tree_util.tree_map(
+        lambda s: sds(s.shape, s.dtype),
+        jax.eval_shape(adapter.model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8), jnp.int32)))
+    adapter.bind_cache(PagedKVCache(2, 16))
+    a = adapter._arrays["kv_pages"]
+    pool = sds((a.shape[0], 18433, *a.shape[2:]), a.dtype)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "devices", lambda *a, **k: topo.devices)
+        fn = adapter._step_fn(B, S)
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    with jax.default_matmul_precision("default"):
+        return params, pool, fn.lower(
+            params, sds((B, S + 3 + adapter.nb_max), jnp.int32),
+            pool).compile()
+
+
+@pytest.mark.parametrize("B,S,temp_gib", [(32, 1, 0.6), (1, 8192, 3.7)],
+                         ids=["decode_b32", "prefill_8192"])
+def test_kimi_k2_step_fits_the_chip_at_the_timed_shapes(
+        one_chip, topo, monkeypatch, B, S, temp_gib):
+    """The two programs the cell times, compiled for the described v5e:
+    9.03 GiB of weights and the 2.46 GiB pool as arguments, the pool
+    donated and written in place, and temporaries that leave the whole
+    under the chip's 15.75 GiB (``memory_analysis()``; the compiler
+    refuses a program that does not fit). The decode step's routed
+    experts are the Mosaic kernel (an expert of 88 MB at a 256-wide
+    tile); a prompt's attention is one (``latent_prefill_attention``, a
+    call a layer, a head's 9,216 keys and values resident) and holds no
+    [64, 512, 9216] of logits."""
+    import math
+    params, pool, step = _k2_step(one_chip, topo, monkeypatch, B, S)
+    memory = step.memory_analysis()
+    gib = 2.0 ** 30
+    held = sum(math.prod(s.shape) * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    assert 9.0 < held / gib < 9.1
+    assert memory.alias_size_in_bytes == math.prod(pool.shape) * 2
+    assert memory.temp_size_in_bytes < temp_gib * gib
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert total < 15.3 * gib
+    text = step.as_text()
+    assert text.count("tpu_custom_call") >= (6 if S == 1 else 7)
+    assert "[64,512,9216]" not in text and "[1,64,512,9216]" not in text
