@@ -460,6 +460,12 @@ class NetxClient:
             with self._lock:
                 cb = self._pending.pop((cid, seq), None)
                 if conn is not None and cb is not None:
+                    if conn.inflight:
+                        # a call's answer is use of the connection: one
+                        # that took longer than the idle limit was reaped
+                        # as idle in the same breath, and the caller's
+                        # next call met the redial backoff
+                        conn.last_used = conn.last_heard
                     conn.inflight = max(0, conn.inflight - 1)
             if cb is not None:
                 if mtype == _REPLY:

@@ -126,7 +126,7 @@ def cache_spec(cfg: KimiK2Config) -> Dict[str, Any]:
         "pages": {"kv_pages": {
             "layers": cfg.num_hidden_layers,
             "row": lanes(cfg.kv_lora_rank + cfg.qk_rope_head_dim),
-            "dtype": cfg.dtype}},
+            "latent_rank": cfg.kv_lora_rank, "dtype": cfg.dtype}},
         "state": {},
     }
 

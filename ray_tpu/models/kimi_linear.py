@@ -139,7 +139,7 @@ def cache_spec(cfg: KimiLinearConfig) -> Dict[str, Any]:
         "pages": {"kv_pages": {
             "layers": kinds.count("mla"),
             "row": _lanes(cfg.kv_lora_rank + cfg.qk_rope_head_dim),
-            "dtype": cfg.dtype}},
+            "latent_rank": cfg.kv_lora_rank, "dtype": cfg.dtype}},
         "state": {
             "kda_state": {"shape": (n_kda, H, d, d), "dtype": jnp.float32},
             "kda_conv": {"shape": (n_kda, (cfg.short_conv_kernel_size - 1)

@@ -15,13 +15,18 @@ What a configuration has or has not decides the form, nothing else:
 The cached row is ``(c, RoPE(k_r))``: the normalised compressed latent
 (``kv_lora_rank`` values) and the key part all heads share, in whole
 lanes of 128 (``lanes``). A decode step (one token a row over pages)
-attends in the absorbed form, everything else builds every head's keys
-and values (``ops.attention.latent_attention``).
+attends in the absorbed form: on the chip one Pallas kernel over the
+row's live pages where they lie (``ops.attention.
+latent_attention_decode``), off it over the rows gathered to the padded
+context; everything else gathers and builds every head's keys and values
+(``ops.attention.latent_attention``). Which it is follows from what the
+call can observe (``ops.attention.latent_decode_path``).
 
 Device-trace scopes (inside the block's scope ``mla``): ``mla/q_lora``
 (``mla/q`` without the bottleneck), ``mla/rope``, ``mla/write`` (the new
-rows into their pages), ``mla/attend`` (the gather of the context's rows
-and the attention), ``mla/out``.
+rows into their pages), ``mla/attend`` (the absorbed query and the
+kernel, or the gather of the context's rows and the attention),
+``mla/out``.
 """
 
 from __future__ import annotations
@@ -194,6 +199,7 @@ class MLAMixer(nn.Module):
             sm_scale = (dn + dr) ** -0.5 * rope.softmax_mscale ** 2
         w_kvb = dense(self, "kv_b", (R, H * (dn + dv)), dt
                       ).reshape(R, H, dn + dv)
+        in_place = A.latent_decode_path(pages, R, S, layer) == "latent_kernel"
         if pages is None:
             context = latent
             q_pos = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
@@ -203,17 +209,36 @@ class MLAMixer(nn.Module):
                                        (0, pages.shape[-1] - R - dr)))
                 pages = A.append_latent_pages(row, pages, block_tables,
                                               seq_lengths, valid, layer)
-            with jax.named_scope("mla/attend"):
-                context = A.paged_gather(pages, block_tables,
-                                         layer)[..., :R + dr]
+            if not in_place:
+                with jax.named_scope("mla/attend"):
+                    context = A.paged_gather(pages, block_tables,
+                                             layer)[..., :R + dr]
             q_pos = seq_lengths[:, None] + jnp.arange(S)[None, :]
             if valid is not None:
                 q_pos = jnp.where(valid, q_pos, -1)
         with jax.named_scope("mla/attend"):
-            y = A.latent_attention(
-                q[..., :dn], q[..., dn:] if q_rope is None else q_rope,
-                context, w_kvb, q_pos, v_dim=dv,
-                absorbed=pages is not None and S == 1, sm_scale=sm_scale)
+            if in_place:
+                # one token a row on the chip: the row's live pages, read
+                # where they lie (``valid`` can only mark whole rows; a
+                # padding row's length is 0). The absorbed query as the
+                # pool holds a row: (q_n W_uk | q_r | 0)
+                q_abs = jnp.concatenate([
+                    jnp.einsum("bhd,rhd->bhr", q[:, 0, :, :dn],
+                               w_kvb[..., :dn]),
+                    (q[..., dn:] if q_rope is None else q_rope)[:, 0],
+                    jnp.zeros((B, H, row.shape[-1] - R - dr), dt)], axis=-1)
+                out = A.latent_attention_decode(
+                    q_abs.astype(pages.dtype), pages, block_tables,
+                    jnp.max(q_pos, axis=1) + 1, rank=R, layer=layer,
+                    sm_scale=sm_scale or (dn + dr) ** -0.5)
+                y = jnp.einsum("bhr,rhd->bhd", out.astype(dt),
+                               w_kvb[..., dn:])
+            else:
+                y = A.latent_attention(
+                    q[..., :dn], q[..., dn:] if q_rope is None else q_rope,
+                    context, w_kvb, q_pos, v_dim=dv,
+                    absorbed=pages is not None and S == 1,
+                    sm_scale=sm_scale)
         with jax.named_scope("mla/out"):
             y = y.reshape(B, S, H * dv).astype(dt)
             return jnp.matmul(y, dense(self, "o_proj", (H * dv, D), dt),

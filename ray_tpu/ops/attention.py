@@ -843,7 +843,11 @@ def append_kv_pages(k_new, v_new, k_pages, v_pages, block_tables,
 def paged_gather(pages, block_tables, layer=None):
     """Pages -> per-sequence (padded) contiguous cache:
     [P, bs, Hkv, D] + [B, NB] -> [B, NB*bs, Hkv, D]; with ``layer``,
-    [L, P, bs, Hkv*D] -> that layer's [B, NB*bs, Hkv*D]."""
+    [L, P, bs, Hkv*D] -> that layer's [B, NB*bs, Hkv*D]. Every row's
+    whole table is read, whatever the row holds: what prompts, verify
+    windows and every step off the chip attend to. A decode step on the
+    chip reads its live pages in place instead
+    (``paged_attention_decode``, ``latent_attention_decode``)."""
     B, NB = block_tables.shape
     out = pages[block_tables] if layer is None \
         else pages[layer, block_tables]                    # [B,NB,bs,...]
@@ -983,7 +987,11 @@ def latent_attention(q_nope, q_rope, latent, w_kvb, q_positions, *,
     queries). ``absorbed=True`` folds the up-projection into the query
     and the output instead (``q_nope W_uk`` against ``c``, the
     probabilities' sum of ``c`` through ``W_uv``), so a decode step
-    reads the context's latents once and builds nothing per head.
+    reads the context's latents once and builds nothing per head. On the
+    chip a decode step over pages is ``latent_attention_decode``, the
+    same mathematics over the pool as stored, of which this form is the
+    reference and, off the chip, what runs; prompts and verify windows
+    come here on every platform.
 
     The queries go ``q_block`` at a time. Where a block's float32 logits
     against the whole context would pass ``LATENT_LOGITS_BYTES`` (a
@@ -1045,6 +1053,212 @@ def latent_attention(q_nope, q_rope, latent, w_kvb, q_positions, *,
     out = jax.lax.map(lambda a: attend(*a), (
         blocks(q_nope), blocks(q_rope), blocks(q_positions)))
     return jnp.moveaxis(out, 0, 1).reshape(B, S, H, v_dim)
+
+
+# latent rows the decode kernel attends to at a time, in bytes. A page of
+# this pool is small (16 rows of 640 bfloat16 values: 20 KB, 25 ns of the
+# chip's memory bandwidth), so a chunk is sized by what it holds, not by
+# a count of tokens: a chunk costs ~0.4 us beside its rows (the loop, the
+# rescaling of the sums; my chip runs, PR 36), which 64 such pages (1,024
+# tokens, 1.6 us of copies) carry and 16 would not
+_LATENT_CHUNK_BYTES = 1280 << 10
+
+
+def _latent_decode_kernel(layer_ref, bt_ref, len_ref, last_ref, chunks_ref,
+                          q_ref, pool, o_ref, buf, sem, slot_ref, m_ref,
+                          l_ref, acc_ref, *, block_size, pages, rank,
+                          sm_scale):
+    """Grid step ``b``: row b's absorbed query [H, W] against its live
+    latent pages, a chunk of ``pages`` pages at a time.
+
+    The pool stays in HBM. The block table names a chunk's pages, each
+    copied by a DMA of its own into one of two [T, W] buffers while the
+    other is attended to; the first chunk of the next row that holds a
+    token goes under the last chunk of this one (buffers, semaphores and
+    the slot in use outlive a grid step). A chunk wholly past a row's
+    length is neither copied nor looked at; the last chunk's places past
+    the row's last live page (``last_ref``: its place in the table;
+    like ``chunks_ref``, the row's chunks, reckoned outside: a division
+    costs the core's scalar unit a third of a microsecond) take that page
+    again, masked: a chunk is then always ``pages`` copies, issued as
+    straight-line code (a third less time on the chip than a loop over
+    the live ones; my chip runs, PR 36) and waited for at once, and no
+    page is read that does not hold one of the row's tokens. The values
+    are the first ``rank`` columns of the same buffer. A row of length 0
+    visits no chunk: zeros.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, B = pl.program_id(0), pl.num_programs(0)
+    T = pages * block_size
+    layer = layer_ref[0]
+
+    def start(r, c, slot):
+        """Chunk c of row r on its way into buffer ``slot``."""
+        first, last = c * pages, last_ref[r]
+
+        def page(i, _):
+            pltpu.make_async_copy(
+                pool.at[layer, bt_ref[r, jnp.minimum(first + i, last)]],
+                buf.at[slot, pl.ds(pl.multiple_of(i * block_size,
+                                                  block_size), block_size)],
+                sem.at[slot]).start()
+            return _
+        # (traced once, lowered to straight-line code)
+        jax.lax.fori_loop(0, pages, page, 0, unroll=True)
+
+    def wait(slot):
+        # a semaphore counts what its copies brought: a chunk's fill the
+        # buffer, so one wait for a buffer's worth sees them all in
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                              sem.at[slot]).wait()
+
+    def row_after(r):
+        """The next row that holds a token (B: none does)."""
+        return jax.lax.while_loop(
+            lambda n: (n < B) & (len_ref[jnp.minimum(n, B - 1)] == 0),
+            lambda n: n + 1, r + 1)
+
+    @pl.when(b == 0)
+    def _():
+        slot_ref[0] = 0
+        first = row_after(-1)
+
+        @pl.when(first < B)
+        def _():
+            start(first, 0, 0)
+
+    length, n = len_ref[b], chunks_ref[b]
+    after = row_after(b)
+    q = q_ref[...]
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def chunk(c, slot):
+        more = c + 1 < n
+        nr = jnp.where(more, b, after)
+
+        @pl.when(nr < B)
+        def _():
+            start(nr, jnp.where(more, c + 1, 0), 1 - slot)
+        wait(slot)
+        rows = buf.at[slot]
+        s = jax.lax.dot_general(
+            q, rows[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale       # [H, T]
+        pos = c * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < length, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(buf.dtype), rows[:, :rank],
+            preferred_element_type=jnp.float32)
+        return 1 - slot
+
+    slot_ref[0] = jax.lax.fori_loop(0, n, chunk, slot_ref[0])
+    # (a row without a token: zeros, over a floor, not 0 / 0)
+    o_ref[...] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def latent_decode_path(pages, rank: int, S: int, layer=0) -> str:
+    """Which attention ``models.mla.MLAMixer`` runs over latent pages,
+    from what it can observe: ``"latent_kernel"``
+    (``latent_attention_decode``) for one new token a row (``S == 1``)
+    over the latent pool [L, P, bs, W] on a TPU, where a row and its
+    first ``rank`` values (the latent, which is key and value) are whole
+    lane tiles, a page whole sublane tiles of the pool's dtype, and no
+    mesh of several devices is being traced for (a bare Mosaic call is
+    refused there); ``"gather"`` (``paged_gather`` +
+    ``latent_attention``) for everything else: a prompt, a window of
+    tokens, no pages, the CPU."""
+    if pages is None or S != 1 or layer is None or not _use_pallas():
+        return "gather"
+    bs, W = pages.shape[2:]
+    mesh = getattr(_TRACE_MESH, "mesh", None)
+    fits = (W % 128 == 0 and rank % 128 == 0 and rank <= W
+            and bs % (32 // jnp.dtype(pages.dtype).itemsize) == 0
+            and (mesh is None or mesh.size == 1))
+    return "latent_kernel" if fits else "gather"
+
+
+# (a function of its own under ``jit``: a model's latent layers are the
+# same call with another ``layer``, and a step program then traces the
+# kernel and lowers it to Mosaic once, not once a layer: seven layers'
+# took a decode program's first call from 2 s to 16; my chip runs, PR 36)
+@functools.partial(jax.jit,
+                   static_argnames=("rank", "sm_scale", "interpret"))
+def _latent_decode_call(layer, block_tables, lengths, q, pages, *, rank,
+                        sm_scale, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, W = q.shape
+    bs = pages.shape[2]
+    NB = block_tables.shape[1]
+    n_pages = max(1, min(
+        _LATENT_CHUNK_BYTES // (bs * W * pages.dtype.itemsize), NB))
+    kernel = functools.partial(_latent_decode_kernel, block_size=bs,
+                               pages=n_pages, rank=rank, sm_scale=sm_scale)
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(B,),
+            in_specs=[pl.BlockSpec((None, H, W), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, H, rank),
+                                   lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, n_pages * bs, W), pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, rank), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), jnp.float32),
+        # (the buffers and the slot in use go from one row to the next)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="latent_attention_decode",
+    )
+    return call(layer, block_tables, lengths,
+                jnp.maximum(lengths - 1, 0) // bs,
+                -(-lengths // (n_pages * bs)), q, pages)
+
+
+def latent_attention_decode(q, pages, block_tables, lengths, *, rank: int,
+                            sm_scale: float, layer=0,
+                            interpret: bool = False):
+    """Pallas latent-attention decode, the absorbed form of
+    ``latent_attention`` for one new token a row: q [B, H, W] in the
+    pool's dtype (``q_nope W_uk`` in the first ``rank`` columns, the
+    query's shared-key part in the next, zeros to W) against layer
+    ``layer`` (an int or a traced scalar) of the latent pool
+    [L, P, bs, W], read where it lies: nothing is gathered or sliced
+    outside the kernel, and only the pages that hold one of a row's
+    ``lengths`` tokens (counted after the write of the new one) are
+    read, in chunks of ``_LATENT_CHUNK_BYTES``. A cached row is key
+    (all W columns) and value (the first ``rank``) of every head, so a
+    row's scores are one product [H, W] x [T, W]^T and its sums one
+    product [H, T] x [T, rank] on the same buffer. Operands in the
+    pool's dtype, products summed in float32, the softmax float32 and
+    online, the probabilities cast to the pool's dtype before they meet
+    the latents: the mathematics of ``latent_attention(absorbed=True)``.
+    Returns the probabilities' sum of the latents [B, H, rank] float32
+    (the caller takes it through ``W_uv``); zeros for a row of length 0.
+    Off the chip: ``interpret=True`` (tests)."""
+    assert pages.shape[3] == q.shape[2] and q.dtype == pages.dtype, \
+        (q, pages)
+    return _latent_decode_call(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q, pages,
+        rank=rank, sm_scale=float(sm_scale), interpret=interpret)
 
 
 def paged_attention_reference(q, k_pages, v_pages, block_tables,

@@ -59,7 +59,13 @@ when a sequence is prefilled (``runner.state.admit``) and freed in
 donated, written in place. Its decode step is the one-token program
 too (``S = 1``: the one-token recurrence); in the bucket that is as
 wide as the state has slots, the rows go in slot order (``_by_slot``)
-and the state is updated where it lies. Such an adapter also finds
+and the state is updated where it lies. The latent (MLA) blocks of such
+a step attend as GPT-2's do: on the chip the ``latent_attention_decode``
+Pallas kernel reads each row's live latent pages where they lie in the
+pool, off the chip (and in every prefill and verify program)
+``paged_gather`` + ``latent_attention``; ``models.mla.MLAMixer`` chooses
+by ``ops.attention.latent_decode_path``, and ``bind_cache`` asks the
+same function what the dispatch spans will say. Such an adapter also finds
 each row's greedy token on the device (``greedy_on_device``): asked with
 ``tokens_only=True``, ``prefill`` / ``decode`` return tokens [B] and the
 logits are not fetched. What cannot work without snapshots of
@@ -391,10 +397,12 @@ class FlaxModelAdapter:
         self.cache = cache
         dtype = self.cfg.dtype
         # what a decode step's attention runs (its dispatch span says
-        # it): a model that states its cache gathers its own pools
+        # it): the path the model's own code will choose, asked of the
+        # same function with the same pool
+        from ray_tpu.ops.attention import latent_decode_path, \
+            paged_decode_path
         self._decode_attention = "gather"
         if self._spec is None:
-            from ray_tpu.ops.attention import paged_decode_path
             shape = (self.n_layers, cache.num_blocks, cache.block_size,
                      self.n_kv_heads * self.head_dim)
             self.k_pages = jnp.zeros(shape, dtype)
@@ -408,6 +416,10 @@ class FlaxModelAdapter:
                 name: jnp.zeros((p["layers"], cache.num_blocks,
                                  cache.block_size, p["row"]), p["dtype"])
                 for name, p in self._spec["pages"].items()}
+            for name, p in self._spec["pages"].items():
+                if "latent_rank" in p:      # MLAMixer's pool
+                    self._decode_attention = latent_decode_path(
+                        self._arrays[name], p["latent_rank"], 1)
             self._free_slots: List[int] = []
             self.state_slots = 0
         # NB: every block table is padded to the worst-case blocks per

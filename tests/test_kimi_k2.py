@@ -93,6 +93,7 @@ def test_cache_spec_states_one_pool_and_no_state():
                        experts_held=(0, 12))
     spec = cache_spec(cfg)
     assert spec["pages"]["kv_pages"] == {"layers": 7, "row": 640,
+                                         "latent_rank": 512,
                                          "dtype": jnp.bfloat16}
     assert spec["state"] == {} and spec["expert_counts"] == (6, 12)
     hash(cfg)       # flax wants a module's attributes hashable

@@ -421,6 +421,47 @@ def test_px_chunk_drop_at_tcp_boundary_resumes(monkeypatch, tmp_path):
 # ------------------------------------------------------------ bench smoke
 
 
+def test_a_call_longer_than_the_idle_limit_leaves_its_connection_usable(
+        monkeypatch):
+    """An actor call that outlasts ``RTPU_NET_IDLE_S`` and the call made
+    right after its answer: the connection carried an answer a moment
+    ago and is not idle. (Where the reaper's look fell on the answer's
+    arrival the connection was closed as idle, its address went into
+    redial backoff, and the next call failed ``ActorUnavailableError ...
+    in reconnect backoff``: two Kimi-Linear benchmark runs of PR 36
+    ended so after a 60-78 s reference check.)"""
+    _require_native()
+    monkeypatch.setenv("RTPU_NET_IDLE_S", "1")
+    ray_tpu.init(num_cpus=2)
+    stop = threading.Event()
+    try:
+        @ray_tpu.remote
+        class Slow:
+            def wait(self, seconds):
+                time.sleep(seconds)
+                return seconds
+
+            def ping(self):
+                return "pong"
+
+        a = Slow.remote()
+        assert ray_tpu.get(a.ping.remote(), timeout=60) == "pong"
+        client = netx.get_client()
+        assert client is not None and client.stats["requests"] >= 1
+
+        def hurry():    # the reaper looks at every wake, not once a second
+            while not stop.wait(0.0005):
+                client._last_tend = 0.0
+        threading.Thread(target=hurry, daemon=True).start()
+        for _ in range(3):
+            assert ray_tpu.get(a.wait.remote(1.5), timeout=60) == 1.5
+            assert ray_tpu.get(a.ping.remote(), timeout=60) == "pong"
+        assert client.stats["reaped"] == 0
+    finally:
+        stop.set()
+        ray_tpu.shutdown()
+
+
 def test_bench_net_smoke():
     """`_BENCH_NET=1 python bench.py` runs end to end in smoke mode and
     prints its keys. Its `gate_pull_63mibs` is not asserted: a throughput
