@@ -813,7 +813,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *,
 
 
 def append_kv_pages(k_new, v_new, k_pages, v_pages, block_tables,
-                    lengths, valid=None, layer=None):
+                    lengths, valid=None, layer=None, ring: bool = False):
     """Scatter new keys/values into their pages.
 
     k_new/v_new: [B, S, Hkv, D] written at logical positions
@@ -824,13 +824,17 @@ def append_kv_pages(k_new, v_new, k_pages, v_pages, block_tables,
     [L, P, bs, Hkv*D]: B*S rows of it are written, the rest is not
     touched. Returns updated (k_pages, v_pages). Distinct sequences own
     distinct pages, so the scatter indices never collide except in the
-    null page (scratch).
+    null page (scratch). ``ring``: the tables are the rows' rings (a
+    windowed page group): logical page ``lp`` is ring page ``lp % NB``,
+    and the caller writes no two positions a ring apart in one call.
     """
     B, S = k_new.shape[:2]
     lead = () if layer is None else (layer,)
     bs, row = k_pages.shape[len(lead) + 1], k_pages.shape[len(lead) + 2:]
     pos = lengths[:, None] + jnp.arange(S)[None, :]        # [B, S]
-    page = jnp.take_along_axis(block_tables, pos // bs, axis=1)
+    page = jnp.take_along_axis(
+        block_tables, (pos // bs) % block_tables.shape[1] if ring
+        else pos // bs, axis=1)
     slot = pos % bs
     if valid is not None:
         page = jnp.where(valid, page, 0)
@@ -882,13 +886,17 @@ LATENT_LOGITS_BYTES = 1 << 30
 _KEY_BLOCKS = (512, 256, 128)
 
 
-def _latent_prefill_kernel(last_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
-                           *, block_k):
+def _latent_prefill_kernel(last_ref, *refs, block_k, window=None):
     # refs: q [bq, d]; k [T, d]; v [T, dv]; pos [bq, 1]; o [bq, dv];
     # last [B, S / bq] (scalar prefetch): the last key block a query of
-    # the block may see (-1: a block of padding, which visits none)
+    # the block may see (-1: a block of padding, which visits none); with
+    # ``window``, first [B, S / bq] before them: the first it may see
     from jax.experimental import pallas as pl
 
+    first_ref = None
+    if window is not None:
+        first_ref, *refs = refs
+    q_ref, k_ref, v_ref, pos_ref, o_ref = refs
     bq = q_ref.shape[0]
     q, pos = q_ref[:], pos_ref[:]
     row = pl.program_id(0) // (pl.num_programs(0) // last_ref.shape[0])
@@ -901,9 +909,16 @@ def _latent_prefill_kernel(last_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
                                 preferred_element_type=jnp.float32)
         k_pos = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (bq, block_k), 1)
-        s = jnp.where(k_pos <= pos, s, _NEG_INF)
+        seen = k_pos <= pos
+        if window is not None:
+            seen = seen & (k_pos > pos - window)
+        s = jnp.where(seen, s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
+        if window is not None:
+            # (a query whose first keys all lie behind its window: its
+            # running maximum is still the floor, and exp(0) is not 0)
+            p = jnp.where(seen, p, 0.0)
         alpha = jnp.exp(m - m_new)
         acc = acc * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -911,7 +926,8 @@ def _latent_prefill_kernel(last_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
         return m_new, total * alpha + jnp.sum(p, axis=-1, keepdims=True), acc
 
     _, total, acc = jax.lax.fori_loop(
-        0, last_ref[row, pl.program_id(1)] + 1, body, (
+        0 if first_ref is None else first_ref[row, pl.program_id(1)],
+        last_ref[row, pl.program_id(1)] + 1, body, (
             jnp.full((bq, 1), _NEG_INF, jnp.float32),
             jnp.zeros((bq, 1), jnp.float32),
             jnp.zeros((bq, v_ref.shape[1]), jnp.float32)))
@@ -919,7 +935,9 @@ def _latent_prefill_kernel(last_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
 
 
 def latent_prefill_attention(q, keys, values, q_positions, sm_scale,
-                             block_q: int = 512, block_k: int = 512):
+                             block_q: int = 512, block_k: int = 512,
+                             window: Optional[int] = None,
+                             name: str = "latent_prefill_attention"):
     """Softmax attention of many queries over materialised keys and
     values, as one Pallas kernel: a grid step holds one head's keys and
     values whole and one block of its queries, walks the key blocks with
@@ -927,46 +945,133 @@ def latent_prefill_attention(q, keys, values, q_positions, sm_scale,
     softmax over all of them), and stops at the last key block that holds
     a position some query of the block may see; a block of padding
     queries (positions -1) walks none and returns zeros. No logits leave
-    the core. q [B, S, H, d], keys [B, T, H, d], values [B, T, H, dv],
-    q_positions [B, S] -> [B, S, H, dv]. ``S`` and ``T`` are whole
-    blocks. Off the chip the same kernel runs interpreted. Forward only."""
+    the core. q [B, S, H, d], keys [B, T, Hkv, d], values [B, T, Hkv, dv],
+    q_positions [B, S] -> [B, S, H, dv]. ``H`` is ``Hkv`` or a multiple
+    of it (grouped heads: query head ``h`` reads key/value head ``h //
+    (H / Hkv)`` where it lies, nothing is repeated). With ``window`` a
+    query at position ``p`` sees the keys at ``p - window + 1 .. p``
+    only, and the walk starts at the first key block some query of the
+    block may see. ``S`` and ``T`` are whole blocks. Off the chip the
+    same kernel runs interpreted. Forward only."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, S, H, d = q.shape
-    T, dv = keys.shape[1], values.shape[-1]
+    T, Hkv, dv = keys.shape[1], keys.shape[2], values.shape[-1]
+    G = H // Hkv
     bq, bk = min(block_q, S), min(block_k, T)
-    assert S % bq == 0 and T % bk == 0, (S, bq, T, bk)
+    assert S % bq == 0 and T % bk == 0 and H == G * Hkv, (S, bq, T, bk, H)
 
     def heads_first(t):             # [B, N, H, w] -> [B * H, N, w]
-        return jnp.moveaxis(t, 2, 1).reshape(B * H, t.shape[1], t.shape[3])
-    last = jnp.minimum(jnp.max(q_positions.reshape(B, S // bq, bq), axis=-1)
+        return jnp.moveaxis(t, 2, 1).reshape(-1, t.shape[1], t.shape[3])
+    blocks = q_positions.reshape(B, S // bq, bq)
+    last = jnp.minimum(jnp.max(blocks, axis=-1)
                        // bk, T // bk - 1).astype(jnp.int32)
+    scalars = [last]
+    if window is not None:
+        lowest = jnp.min(jnp.where(blocks >= 0, blocks, T), axis=-1)
+        scalars.append((jnp.maximum(lowest - window + 1, 0) // bk
+                        ).astype(jnp.int32))
     pos = jnp.broadcast_to(q_positions[:, None, :, None].astype(jnp.int32),
                            (B, H, S, 1)).reshape(B * H, S, 1)
     item = jnp.dtype(keys.dtype).itemsize
     need = 2 * T * (d + dv) * item + 6 * bq * bk * 4 + 4 * bq * (d + dv) * 4
+
+    def kv_head(i, j, *_):          # query head i of the grid: its keys
+        return (i if G == 1 else i // G, 0, 0)
+
+    def own(i, j, *_):
+        return (i, j, 0)
     call = pl.pallas_call(
-        functools.partial(_latent_prefill_kernel, block_k=bk),
+        functools.partial(_latent_prefill_kernel, block_k=bk, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B * H, S // bq),
+            num_scalar_prefetch=len(scalars), grid=(B * H, S // bq),
             in_specs=[
-                pl.BlockSpec((None, bq, d), lambda i, j, last: (i, j, 0)),
-                pl.BlockSpec((None, T, d), lambda i, j, last: (i, 0, 0)),
-                pl.BlockSpec((None, T, dv), lambda i, j, last: (i, 0, 0)),
-                pl.BlockSpec((None, bq, 1), lambda i, j, last: (i, j, 0)),
+                pl.BlockSpec((None, bq, d), own),
+                pl.BlockSpec((None, T, d), kv_head),
+                pl.BlockSpec((None, T, dv), kv_head),
+                pl.BlockSpec((None, bq, 1), own),
             ],
-            out_specs=pl.BlockSpec((None, bq, dv),
-                                   lambda i, j, last: (i, j, 0))),
+            out_specs=pl.BlockSpec((None, bq, dv), own)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, dv), values.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=int(need * 1.25) + (8 << 20)),
         interpret=not _use_pallas(),
-        name="latent_prefill_attention")
-    out = call(last, heads_first((q * sm_scale).astype(q.dtype)),
+        name=name)
+    out = call(*scalars, heads_first((q * sm_scale).astype(q.dtype)),
                heads_first(keys), heads_first(values), pos)
     return jnp.moveaxis(out.reshape(B, H, S, dv), 1, 2)
+
+
+def ring_positions(lengths, ring: int, block_size: int):
+    """The absolute position each row of a sequence's gathered ring holds
+    once the sequence is ``lengths`` [B] tokens long: ring page ``s``
+    holds the newest logical page ``lp <= (length - 1) // block_size``
+    with ``lp % ring == s`` -> [B, ring * block_size] int32 (negative
+    where the ring page has not been written by this sequence yet)."""
+    newest = (lengths[:, None] - 1) // block_size              # [B, 1]
+    s = jnp.arange(ring)[None, :]
+    page = newest - (newest - s) % ring                        # [B, ring]
+    return (page[:, :, None] * block_size
+            + jnp.arange(block_size)[None, None, :]).reshape(
+                lengths.shape[0], ring * block_size).astype(jnp.int32)
+
+
+def prefill_attention_path(q, keys) -> str:
+    """Which attention ``prefill_attention`` runs, from what it can
+    observe: ``"blocked_kernel"`` (``latent_prefill_attention``'s kernel:
+    key blocks walked with a running softmax, no logits outside the core)
+    on a TPU where heads are whole lane tiles and the tokens whole blocks
+    and no mesh of several devices is being traced for; ``"plain"`` (one
+    masked softmax in ``jax.numpy``) for everything else."""
+    S, d = q.shape[1], q.shape[3]
+    T = keys.shape[1]
+    mesh = getattr(_TRACE_MESH, "mesh", None)
+    fits = (_use_pallas() and d % 128 == 0 and S % 8 == 0 and T % 8 == 0
+            and S % min(512, S) == 0 and T % min(512, T) == 0
+            and (mesh is None or mesh.size == 1))
+    return "blocked_kernel" if fits else "plain"
+
+
+def prefill_attention(q, keys, values, q_positions, *,
+                      window: Optional[int] = None,
+                      sm_scale: Optional[float] = None,
+                      key_positions=None):
+    """Causal softmax attention of a prompt's queries over keys and
+    values with grouped heads, q [B, S, H, d], keys / values
+    [B, T, Hkv, d], q_positions [B, S] (-1: padding) -> [B, S, H, d]. Key
+    ``t`` stands at position ``t`` (or ``key_positions`` [B, T]: a
+    gathered ring's, ``ring_positions``); a query at ``p`` sees the keys
+    at positions ``<= p`` and, with ``window``, ``> p - window``. On the
+    chip one Pallas kernel (``prefill_attention_path``); elsewhere the
+    plain form of the same mathematics, float32 softmax, the groups read
+    where they lie."""
+    B, S, H, d = q.shape
+    T, Hkv = keys.shape[1:3]
+    if sm_scale is None:
+        sm_scale = d ** -0.5
+    if key_positions is None and \
+            prefill_attention_path(q, keys) == "blocked_kernel":
+        return latent_prefill_attention(
+            q, keys, values, q_positions, sm_scale, window=window,
+            name="prefill_attention")
+    G = H // Hkv
+    logits = jnp.einsum("bsngd,btnd->bngst", q.reshape(B, S, Hkv, G, d),
+                        keys, preferred_element_type=jnp.float32) * sm_scale
+    k_pos = jnp.arange(T)[None, :] if key_positions is None \
+        else key_positions
+    k_pos = k_pos[:, None, :]                                  # [B|1, 1, T]
+    q_pos = q_positions[:, :, None]                            # [B, S, 1]
+    seen = (k_pos <= q_pos) & (k_pos >= 0)
+    if window is not None:
+        seen = seen & (k_pos > q_pos - window)
+    seen = seen[:, None, None]                                 # [B,1,1,S,T]
+    probs = jax.nn.softmax(jnp.where(seen, logits, _NEG_INF), axis=-1)
+    # (a row that sees nothing, padding: zeros, not a mean of the values)
+    probs = jnp.where(seen, probs, 0.0).astype(values.dtype)
+    out = jnp.einsum("bngst,btnd->bsngd", probs, values)
+    return out.reshape(B, S, H, values.shape[-1])
 
 
 def latent_attention(q_nope, q_rope, latent, w_kvb, q_positions, *,
@@ -1263,19 +1368,29 @@ def latent_attention_decode(q, pages, block_tables, lengths, *, rank: int,
 
 def paged_attention_reference(q, k_pages, v_pages, block_tables,
                               lengths, *, layer=0,
-                              sm_scale: Optional[float] = None):
+                              sm_scale: Optional[float] = None,
+                              window: Optional[int] = None):
     """Single-token decode against one layer of the serving pool, via
     gather (the correctness baseline for the Pallas kernel, and what it
     falls back to off the chip).
 
     q: [B, H, D] (one query token per sequence); k_pages/v_pages:
-    [L, P, bs, Hkv*D]; returns [B, H, D].
+    [L, P, bs, Hkv*D]; returns [B, H, D]. With ``window`` the block
+    tables are the rows' rings (``ring_positions``) and a row's query, at
+    position ``length - 1``, sees the last ``window`` positions only.
     """
     B, _, D = q.shape
 
     def gathered(pages):                               # [B,NB*bs,Hkv,D]
         return paged_gather(pages, block_tables, layer).reshape(
             B, -1, pages.shape[-1] // D, D)
+    if window is not None:
+        out = prefill_attention(
+            q[:, None], gathered(k_pages), gathered(v_pages),
+            lengths[:, None] - 1, window=window, sm_scale=sm_scale,
+            key_positions=ring_positions(
+                lengths, block_tables.shape[1], k_pages.shape[2]))
+        return out[:, 0].astype(q.dtype)
     out = decode_attention(q[:, :, None, :], gathered(k_pages),
                            gathered(v_pages), lengths, sm_scale=sm_scale)
     return out[:, :, 0, :]
@@ -1288,9 +1403,16 @@ _PAGED_CHUNK_TOKENS = 128
 
 def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
                          o_ref, k_buf, v_buf, sem, qbd_ref, m_ref, l_ref,
-                         acc_ref, *, block_size, pages, head_dim, sm_scale):
+                         acc_ref, *, block_size, pages, head_dim, sm_scale,
+                         window=None):
     """Every row's one query against its live pages, a chunk of
     ``pages`` pages at a time, all heads at once.
+
+    With ``window`` the block table is the row's ring of ``NB`` pages
+    (logical page ``lp`` lies in ring page ``lp % NB``): the chunks start
+    at the logical page that holds position ``length - window``, the
+    positions before it and from ``length`` on are masked, and no page
+    behind the window is copied.
 
     The pools stay in HBM; the block table names the pages of a chunk,
     each copied by a DMA of its own into one of two [T, Hkv*D] buffers
@@ -1312,11 +1434,23 @@ def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
     T = pages * block_size
     layer = layer_ref[0]
 
+    def first_page(b):
+        """The logical page a row's walk starts at."""
+        if window is None:
+            return 0
+        return jnp.maximum(len_ref[b] - window, 0) // block_size
+
     def copies(b, c, slot, start):
+        if start and window is not None:
+            at = first_page(b) + c * pages
         for i in range(pages):
             # (a wait needs the semaphore and the size, not the source)
-            page = bt_ref[b, jnp.minimum(c * pages + i, NB - 1)] \
-                if start else 0
+            if not start:
+                page = 0
+            elif window is None:
+                page = bt_ref[b, jnp.minimum(c * pages + i, NB - 1)]
+            else:
+                page = bt_ref[b, jax.lax.rem(at + i, NB)]
             for s, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
                 copy = pltpu.make_async_copy(
                     hbm.at[layer, page],
@@ -1344,7 +1478,11 @@ def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
 
     def row(b, slot):
         length = len_ref[b]
-        n = (length + T - 1) // T
+        if window is None:
+            n = (length + T - 1) // T
+        else:
+            n = ((length + block_size - 1) // block_size - first_page(b)
+                 + pages - 1) // pages
         after = row_after(b)
         qbd = jnp.zeros(acc_ref.shape, jnp.float32)
         for j in range(G):
@@ -1366,7 +1504,12 @@ def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
                 qbd_ref[...], k_buf[slot], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale   # [H, T]
             pos = c * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(pos < length, s, _NEG_INF)
+            if window is None:
+                seen = pos < length
+            else:
+                pos = pos + first_page(b) * block_size
+                seen = (pos < length) & (pos >= length - window)
+            s = jnp.where(seen, s, _NEG_INF)
             m_prev = m_ref[...]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -1413,7 +1556,8 @@ def paged_decode_path(q_heads: int, head_dim: int, pages, S: int,
 
 def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
                            *, layer=0, sm_scale: Optional[float] = None,
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None,
+                           window: Optional[int] = None):
     """Pallas paged-attention decode: q [B, H, D], one query a row,
     against layer ``layer`` (an int or a traced scalar) of the serving
     pools [L, P, bs, Hkv*D], read where they lie: nothing is gathered,
@@ -1424,6 +1568,14 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
     and online, the probabilities meet V in the pool's dtype: the
     mathematics of ``decode_attention``. A row of length 0 gives zeros.
 
+    ``window`` (static): the layer only ever reads a position's last
+    ``window`` predecessors, and the block tables are the rows' rings of
+    ``window // bs + 1`` pages (position ``p`` in ring page ``(p // bs) %
+    ring``, ``serve/llm/kv_cache.py``): a row's chunks start at the page
+    that holds position ``length - window``, positions behind the window
+    are masked, nothing behind it is copied. Without it the kernel is the
+    code it was.
+
     Off-TPU (and not ``interpret``) this falls back to the gather
     reference — numerics are identical (gated in tests), so callers
     never branch.
@@ -1433,7 +1585,7 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
         if not _use_pallas():
             return paged_attention_reference(
                 q, k_pages, v_pages, block_tables, lengths, layer=layer,
-                sm_scale=sm_scale)
+                sm_scale=sm_scale, window=window)
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1448,9 +1600,10 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
     # row h of the kernel's matrices is head h; whole sublane tiles of
     # the pool's dtype
     Hp = -(-H // 16) * 16
+    assert window is None or NB == window // bs + 1, (NB, window, bs)
     kernel = functools.partial(_paged_decode_kernel, block_size=bs,
                                pages=pages, head_dim=D,
-                               sm_scale=float(sm_scale))
+                               sm_scale=float(sm_scale), window=window)
 
     def whole(*_):
         return 0, 0, 0

@@ -172,9 +172,21 @@ class LLMEngine:
     def __init__(self, adapter, config: Optional[EngineConfig] = None):
         self.adapter = adapter
         self.config = config or EngineConfig()
-        self.cache = PagedKVCache(self.config.num_blocks,
-                                  self.config.block_size)
+        # a model's windowed page kinds are page groups of their own: a
+        # ring a running sequence (kv_cache.py)
+        self._windows = tuple(getattr(adapter, "page_windows", ()))
+        self.cache = PagedKVCache(
+            self.config.num_blocks, self.config.block_size,
+            windows=self._windows, max_sequences=self.config.max_running)
         adapter.bind_cache(self.cache)
+        self._refuse_with_window(
+            self.config.enable_prefix_cache, "enable_prefix_cache",
+            "a ring page is overwritten as its sequence grows, so a "
+            "prefix's pages cannot be shared")
+        self._refuse_with_window(
+            self.config.spec_k > 0, "spec_k (speculative decoding)",
+            "a rejected draft token has overwritten the ring row a ring "
+            "before it")
         self._stateful = bool(getattr(adapter, "has_state", False))
         if self._stateful:
             # a recurrent state cannot be cut back, shared by page or
@@ -249,6 +261,13 @@ class LLMEngine:
                 f"{why}. Needs snapshots of the state at page boundaries "
                 "(ROADMAP R1).")
 
+    def _refuse_with_window(self, asked: bool, what: str, why: str):
+        if asked and self._windows:
+            from ray_tpu.serve.llm.model_runner import WindowedPagesError
+            raise WindowedPagesError(
+                f"{what}: the model has a windowed page group (a ring of "
+                f"pages a sequence); {why}.")
+
     # ------------------------------------------------------------ intake
 
     def add_request(self, prompt_tokens: List[int],
@@ -313,6 +332,10 @@ class LLMEngine:
         self._refuse_with_state(
             True, "prefill_export (export_kv)",
             "the prompt's pages alone do not carry it to another replica")
+        self._refuse_with_window(
+            True, "prefill_export (export_kv)",
+            "a blob holds a prompt's whole pages in order, which a ring "
+            "is not")
         sampling = sampling or SamplingParams()
         one = dataclasses.replace(sampling, max_new_tokens=1)
         return self.add_request(prompt_tokens, one, request_id,
@@ -349,6 +372,10 @@ class LLMEngine:
         self._refuse_with_state(
             True, "adopt_request (import_kv)",
             "a blob of pages alone does not restore a prompt")
+        self._refuse_with_window(
+            True, "adopt_request (import_kv)",
+            "a blob holds a prompt's whole pages in order, which a ring "
+            "is not")
         sampling = sampling or SamplingParams()
         n_prompt = len(prompt_tokens)
         if n_prompt == 0:

@@ -23,6 +23,18 @@ copy of a shared page before it writes into it, and a page returns to
 the free list only when its last reference (sequence table or cache
 branch) drops.
 
+A model may cache some layers over a *window*: a layer that only ever
+reads the last ``window`` positions (a sliding-attention layer). Those
+layers' pages form a **page group** of their own (``windows=``): a
+sequence holds ``window // block_size + 1`` pages of the group as a
+*ring* (position ``p`` lies in ring page ``(p // block_size) % ring``,
+so a page is overwritten once every position it held has left the
+window), taken with the sequence's other pages at admission and returned
+with them at release. The group's pool is ``max_sequences`` rings and a
+null page of its own; admission counts every group, so it stays exact.
+A ring page is overwritten in place: it is never refcounted, shared or
+copied on write.
+
 The pool itself is storage-agnostic (``make_pages`` builds numpy or
 jax arrays per layer on demand) — the allocator tracks only indices,
 so the same bookkeeping serves the numpy toy adapter and the jitted
@@ -48,7 +60,8 @@ class PagedKVCache:
     read occupancy for admission and telemetry.
     """
 
-    def __init__(self, num_blocks: int, block_size: int):
+    def __init__(self, num_blocks: int, block_size: int,
+                 windows: Iterable[int] = (), max_sequences: int = 0):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (page 0 is reserved)")
         self.num_blocks = int(num_blocks)
@@ -57,6 +70,11 @@ class PagedKVCache:
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
         self._tables: Dict[str, List[int]] = {}   # seq id -> pages
         self._refs: Dict[int, int] = {}           # page -> reference count
+        # window -> its page group: a ring a sequence, page 0 null
+        self._rings: Dict[int, _RingGroup] = {
+            int(w): _RingGroup(int(w) // self.block_size + 1,
+                               int(max_sequences))
+            for w in sorted(set(windows))}
         self._lock = threading.Lock()
 
     # ---- sizing ----
@@ -66,7 +84,39 @@ class PagedKVCache:
 
     def can_allocate(self, num_tokens: int) -> bool:
         with self._lock:
-            return len(self._free) >= self.blocks_for(num_tokens)
+            return len(self._free) >= self.blocks_for(num_tokens) \
+                and not self._ring_short_locked()
+
+    # ---- window groups ----
+
+    @property
+    def windows(self) -> Tuple[int, ...]:
+        return tuple(self._rings)
+
+    def ring_blocks(self, window: int) -> int:
+        """Pages a sequence holds of the window's group."""
+        return self._rings[window].ring
+
+    def group_blocks(self, window: int) -> int:
+        """Pages of the window group's pool, its null page included."""
+        return self._rings[window].num_blocks
+
+    def ring_table(self, seq_id: str, window: int) -> Optional[List[int]]:
+        with self._lock:
+            t = self._rings[window].tables.get(seq_id)
+            return list(t) if t else None
+
+    def _ring_short_locked(self) -> Optional[str]:
+        """The first window group that cannot give one more ring."""
+        for w, g in self._rings.items():
+            if len(g.free) < g.ring:
+                return (f"need {g.ring} pages of the window-{w} group, "
+                        f"{len(g.free)} free (pool {g.num_blocks - 1})")
+        return None
+
+    def _take_rings_locked(self, seq_id: str):
+        for g in self._rings.values():
+            g.tables[seq_id] = [g.free.pop() for _ in range(g.ring)]
 
     def free_blocks(self) -> int:
         with self._lock:
@@ -86,10 +136,14 @@ class PagedKVCache:
                 raise OutOfKVBlocksError(
                     f"need {need} KV blocks, {len(self._free)} free "
                     f"(pool {self.num_blocks - 1})")
+            short = self._ring_short_locked()
+            if short:
+                raise OutOfKVBlocksError(short)
             pages = [self._free.pop() for _ in range(need)]
             for p in pages:
                 self._refs[p] = 1
             self._tables[seq_id] = pages
+            self._take_rings_locked(seq_id)
             return list(pages)
 
     def allocate_with_prefix(self, seq_id: str, num_tokens: int,
@@ -99,6 +153,12 @@ class PagedKVCache:
         table by refcount, and only the remainder comes from the free
         list.  The caller must not write into a shared page without
         ``copy_on_write`` first."""
+        if self._rings and shared_pages:
+            raise ValueError(
+                "a shared prefix cannot be mapped into a sequence of a "
+                "model with a windowed page group: a ring page is "
+                "overwritten as the sequence grows, so what it held of "
+                "the prefix cannot be shared")
         need = self.blocks_for(num_tokens)
         n_shared = len(shared_pages)
         if n_shared > need:
@@ -115,6 +175,9 @@ class PagedKVCache:
                 raise OutOfKVBlocksError(
                     f"need {fresh_need} fresh KV blocks "
                     f"({n_shared} shared), {len(self._free)} free")
+            short = self._ring_short_locked()
+            if short:
+                raise OutOfKVBlocksError(short)
             for p in shared_pages:
                 self._refs[p] += 1
             fresh = [self._free.pop() for _ in range(fresh_need)]
@@ -122,6 +185,7 @@ class PagedKVCache:
                 self._refs[p] = 1
             pages = list(shared_pages) + fresh
             self._tables[seq_id] = pages
+            self._take_rings_locked(seq_id)
             return list(pages)
 
     def incref(self, pages: Iterable[int]) -> None:
@@ -183,6 +247,8 @@ class PagedKVCache:
         with the prefix cache or other sequences stay resident, the
         rest are admittable on the very next engine step."""
         with self._lock:
+            for g in self._rings.values():
+                g.free.extend(g.tables.pop(seq_id, ()))
             pages = self._tables.pop(seq_id, None)
             if not pages:
                 return 0
@@ -205,8 +271,34 @@ class PagedKVCache:
         with self._lock:
             usable = self.num_blocks - 1
             used = usable - len(self._free)
-            return {"kv_blocks_total": usable,
-                    "kv_blocks_used": used,
-                    "kv_block_size": self.block_size,
-                    "kv_occupancy": used / max(1, usable),
-                    "kv_sequences": len(self._tables)}
+            out = {"kv_blocks_total": usable,
+                   "kv_blocks_used": used,
+                   "kv_block_size": self.block_size,
+                   "kv_occupancy": used / max(1, usable),
+                   "kv_sequences": len(self._tables)}
+            if self._rings:
+                out["kv_window_groups"] = {
+                    w: g.stats() for w, g in self._rings.items()}
+            return out
+
+
+class _RingGroup:
+    """One window's pages: ``max_sequences`` rings of ``ring`` pages and
+    the null page 0 (the allocator's lock guards it)."""
+
+    def __init__(self, ring: int, max_sequences: int):
+        if max_sequences < 1:
+            raise ValueError(
+                "a windowed page group is sized by the sequences that "
+                "may run at once: max_sequences >= 1")
+        self.ring = ring
+        self.num_blocks = max_sequences * ring + 1
+        self.free: List[int] = list(range(self.num_blocks - 1, 0, -1))
+        self.tables: Dict[str, List[int]] = {}
+
+    def stats(self) -> Dict[str, float]:
+        usable = self.num_blocks - 1
+        used = usable - len(self.free)
+        return {"ring_blocks": self.ring, "blocks_total": usable,
+                "blocks_used": used, "occupancy": used / max(1, usable),
+                "sequences": len(self.tables)}

@@ -78,6 +78,24 @@ can be shared (a suffix is prefilled from a non-zero length), a window
 verified in one step (``llm_verify_b{B}_s{S}``) and rolled back by
 length, and a prompt's pages shipped
 (tests/test_llm_kimi_k2_serving.py).
+
+A page kind may carry a ``window`` (``kind="laguna"``: the sliding
+layers' ``k_window`` / ``v_window``): the positions a layer of that kind
+ever reads. Such kinds form a **page group** of their own in the
+allocator (``kv_cache.py``): its pool holds ``max_running`` rings of
+``window / block_size + 1`` pages and a null page, a sequence's ring is
+taken with its other pages at admission, and ``_pack`` carries one table
+a group (the full group's padded table, then each window group's ring).
+Position ``p`` lies in ring page ``(p // block_size) % ring``, so a page
+is overwritten once what it held has left the window. That is also what
+such a model cannot do, and every entry point says so
+(``WindowedPagesError``): a ring page cannot be shared with another
+sequence (``enable_prefix_cache``), a prompt's suffix cannot be prefilled
+behind a cached prefix (its window layers would need the prefix's last
+``window`` rows, which no ring holds for it), a speculative window cannot
+be rolled back (``decode_window`` / ``rollback``: the rejected positions
+have overwritten the rows a ring apart), and ``export_kv`` / ``import_kv``
+ship whole-prompt pages, which a ring is not.
 """
 
 from __future__ import annotations
@@ -96,6 +114,13 @@ class RecurrentStateError(NotImplementedError):
     a model that keeps recurrent state per sequence. Pages can be cut at
     any token; a state can only be restored from a snapshot taken at
     that token, and nothing takes such snapshots yet (ROADMAP R1)."""
+
+
+class WindowedPagesError(NotImplementedError):
+    """A feature that shares, rolls back or ships cached tokens was asked
+    of a model with a windowed page group. A ring page is overwritten as
+    its sequence grows, so it cannot be shared with another sequence or
+    rolled back past its span."""
 
 
 # the shortest token-axis bucket of a prefill or a verify step (a decode
@@ -343,6 +368,13 @@ class FlaxModelAdapter:
             self._blocks = None            # a dense layer, then routed ones
             self.vocab_size = self.cfg.vocab_size
             self._spec = kimi_k2.cache_spec(self.cfg)
+        elif kind == "laguna":
+            from ray_tpu.models import laguna
+            self.cfg = config or laguna.LagunaConfig.tiny()
+            self.model = laguna.LagunaModel(self.cfg)
+            self._blocks = None            # layers differ by their type
+            self.vocab_size = self.cfg.vocab_size
+            self._spec = laguna.cache_spec(self.cfg)
         else:
             raise ValueError(f"unknown model kind {kind!r}")
         if params is None:
@@ -351,6 +383,7 @@ class FlaxModelAdapter:
         self.params = params
         self._expert_tokens_total = self._expert_tokens_last = None
         self._kv_pages_live = self._kv_pages_padded = 0
+        self._window_pages_live = self._window_pages_padded = 0
         self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
         self.bucket_first_calls = 0        # _fns misses: steps that compiled
         self._lock = threading.Lock()
@@ -368,6 +401,14 @@ class FlaxModelAdapter:
     def has_state(self) -> bool:
         """The model keeps per-sequence recurrent state beside pages."""
         return bool(self._spec and self._spec.get("state"))
+
+    @property
+    def page_windows(self) -> tuple:
+        """The windows of the model's windowed page kinds (each a page
+        group of its own in the allocator); () for every other model."""
+        pages = (self._spec or {}).get("pages", {})
+        return tuple(sorted({p["window"] for p in pages.values()
+                             if p.get("window")}))
 
     @property
     def greedy_on_device(self) -> bool:
@@ -396,6 +437,14 @@ class FlaxModelAdapter:
         jnp = self._jnp
         self.cache = cache
         dtype = self.cfg.dtype
+        if set(self.page_windows) != set(getattr(cache, "windows", ())):
+            raise ValueError(
+                f"the model's page windows {list(self.page_windows)} are "
+                f"not the cache's groups {list(cache.windows)}: build it "
+                "with PagedKVCache(..., windows=adapter.page_windows, "
+                "max_sequences=...)")
+        # the window groups' rings, behind the padded table in ``_pack``
+        self._rings = {w: cache.ring_blocks(w) for w in self.page_windows}
         # what a decode step's attention runs (its dispatch span says
         # it): the path the model's own code will choose, asked of the
         # same function with the same pool
@@ -412,14 +461,20 @@ class FlaxModelAdapter:
         else:
             # one pool a page kind the model names; state arrays come
             # with ``bind_state``
+            # (a kind with a window lies in its window group's pool)
             self._arrays: Dict[str, Any] = {
-                name: jnp.zeros((p["layers"], cache.num_blocks,
+                name: jnp.zeros((p["layers"],
+                                 cache.group_blocks(p["window"])
+                                 if p.get("window") else cache.num_blocks,
                                  cache.block_size, p["row"]), p["dtype"])
                 for name, p in self._spec["pages"].items()}
             for name, p in self._spec["pages"].items():
                 if "latent_rank" in p:      # MLAMixer's pool
                     self._decode_attention = latent_decode_path(
                         self._arrays[name], p["latent_rank"], 1)
+                elif "q_heads" in p:        # K and V pages of grouped heads
+                    self._decode_attention = paged_decode_path(
+                        p["q_heads"], p["head_dim"], self._arrays[name], 1)
             self._free_slots: List[int] = []
             self.state_slots = 0
         # NB: every block table is padded to the worst-case blocks per
@@ -450,6 +505,10 @@ class FlaxModelAdapter:
                "kv_pages_padded_total": self._kv_pages_padded}
         if self._spec is None:
             return out
+        if self._rings:
+            out.update(
+                kv_window_pages_live_total=self._window_pages_live,
+                kv_window_pages_padded_total=self._window_pages_padded)
         out.update(state_slots_total=self.state_slots,
                    state_slots_in_use=self.state_slots
                    - len(self._free_slots))
@@ -579,11 +638,19 @@ class FlaxModelAdapter:
         jnp = self._jnp
         names = list(self._arrays)
         by_slot = self._by_slot(B, S)
+        rings = dict(self._rings)
 
         def step(params, packed, *arrays):
             tokens, n_new = packed[:, :S], packed[:, S]
             seq_lengths, slots = packed[:, S + 1], packed[:, S + 2]
             cache = dict(zip(names, arrays), block_tables=packed[:, S + 3:])
+            if rings:       # one table a page group: the padded, the rings
+                at = S + 3 + self.nb_max
+                cache["block_tables"] = packed[:, S + 3:at]
+                cache["window_tables"] = {}
+                for w, ring in rings.items():
+                    cache["window_tables"][w] = packed[:, at:at + ring]
+                    at += ring
             if not by_slot:
                 cache["slots"] = slots
             valid = jnp.arange(S)[None, :] < n_new[:, None]
@@ -671,17 +738,23 @@ class FlaxModelAdapter:
         return out
 
     def _pack(self, rows, at, B: int, S: int) -> np.ndarray:
-        """The rows' integers as one int32 array [B, S + 3 + nb_max]: a
-        row's new tokens, how many of them are real, the tokens cached
-        before them, its state slot, its block table. A padding row is
-        zeros: nothing real, the null slot, the null page."""
-        packed = np.zeros((B, S + 3 + self.nb_max), np.int32)
+        """The rows' integers as one int32 array [B, S + 3 + nb_max
+        (+ each window group's ring)]: a row's new tokens, how many of
+        them are real, the tokens cached before them, its state slot, its
+        block table, then its ring in each window group. A padding row
+        is zeros: nothing real, the null slot, the null pages."""
+        packed = np.zeros(
+            (B, S + 3 + self.nb_max + sum(self._rings.values())), np.int32)
         for i, r in zip(at, rows):
             n = len(r["tokens"])
             packed[i, :n] = r["tokens"]
             packed[i, S:S + 3] = n, r["len"], r.get("slot", 0)
             t = r["table"][:self.nb_max]
             packed[i, S + 3:S + 3 + len(t)] = t
+            col = S + 3 + self.nb_max
+            for w, ring in self._rings.items():
+                packed[i, col:col + ring] = r["rings"][w]
+                col += ring
         return packed
 
     def _count_pages(self, rows, B: int) -> Dict[str, Any]:
@@ -691,13 +764,25 @@ class FlaxModelAdapter:
         the pages that hold one of the rows' tokens, and the pages of
         the ``B`` padded tables."""
         bs = self.cache.block_size
-        live = sum(-(-(r["len"] + 1) // bs) for r in rows)
+        lens = [r["len"] + 1 for r in rows]
+        live = sum(-(-n // bs) for n in lens)
         padded = B * self.nb_max
         self._kv_pages_live += live
         self._kv_pages_padded += padded
-        return {"attention": self._decode_attention,
-                "live_tokens": sum(r["len"] + 1 for r in rows),
-                "kv_pages_live": live, "kv_pages_padded": padded}
+        out = {"attention": self._decode_attention,
+               "live_tokens": sum(lens),
+               "kv_pages_live": live, "kv_pages_padded": padded}
+        for w, ring in self._rings.items():
+            # a window layer reads a row's last ``w`` positions: the ring
+            # pages that hold one of them, of the ``ring`` a row holds
+            w_live = sum(-(-n // bs) - max(n - w, 0) // bs for n in lens)
+            self._window_pages_live += w_live
+            self._window_pages_padded += B * ring
+            out.update(window_tokens=sum(min(n, w) for n in lens),
+                       kv_window_pages_live=w_live,
+                       kv_window_pages_held=len(rows) * ring,
+                       kv_window_pages_padded=B * ring)
+        return out
 
     def _count_experts(self, counts: np.ndarray) -> Dict[str, Any]:
         """counts [routed layers, experts held]: the step's tokens per
@@ -720,6 +805,11 @@ class FlaxModelAdapter:
             if self.has_state else [0] * len(seqs)
         for s, slot in zip(seqs, slots):
             cached = _cached_tokens(s)
+            if cached:
+                self._refuse_with_window(
+                    "prefill from a non-zero length", "the suffix's window "
+                    "layers would read the prefix's last rows, which no "
+                    "ring holds for this sequence")
             if cached % self.cache.block_size:
                 # copy-on-extend: the suffix write lands in the last
                 # shared prefix page — privatize it first
@@ -728,18 +818,20 @@ class FlaxModelAdapter:
                 if new != old:
                     self.copy_page(old, new)
             table = self.cache.block_table(s.seq_id)
-            self._state[s.seq_id] = {"table": table,
-                                     "len": len(s.prompt), "slot": slot}
-            rows.append({"tokens": s.prompt[cached:], "len": cached,
-                         "table": table, "slot": slot})
+            st = self._state[s.seq_id] = {
+                "table": table, "len": len(s.prompt), "slot": slot}
+            if self._rings:
+                st["rings"] = {w: self.cache.ring_table(s.seq_id, w)
+                               for w in self._rings}
+            rows.append(dict(st, tokens=s.prompt[cached:], len=cached))
         return self._run(rows, "prefill", tokens_only)
 
     def decode(self, seqs, tokens_only: bool = False) -> np.ndarray:
         rows = []
         for s in seqs:
             st = self._state[s.seq_id]
-            rows.append({"tokens": [s.tokens[-1]], "len": st["len"],
-                         "table": st["table"], "slot": st.get("slot", 0)})
+            rows.append(dict(st, tokens=[s.tokens[-1]],
+                             slot=st.get("slot", 0)))
             st["len"] += 1
         return self._run(rows, "decode", tokens_only)
 
@@ -750,6 +842,9 @@ class FlaxModelAdapter:
         window[:j+1] — the speculative verify contract."""
         self._refuse_with_state("decode_window", "a rejected position "
                                 "cannot be taken out of the state again")
+        self._refuse_with_window(
+            "decode_window", "a window's positions overwrite the ring rows "
+            "a ring before them, which a rejected position cannot restore")
         rows = []
         for s, win in zip(seqs, windows):
             st = self._state[s.seq_id]
@@ -766,9 +861,18 @@ class FlaxModelAdapter:
                 f"per sequence, and {why}; it needs a snapshot of the "
                 "state at the token in question, which nothing takes yet")
 
+    def _refuse_with_window(self, what: str, why: str):
+        if self._spec is not None and self.page_windows:
+            raise WindowedPagesError(
+                f"{what}: model kind {self.kind!r} has a windowed page "
+                f"group (a ring of pages a sequence), and {why}")
+
     def rollback(self, seq_id: str, n: int):
         self._refuse_with_state("rollback", "the last n tokens cannot be "
                                 "taken out of the state again")
+        self._refuse_with_window(
+            "rollback", "the rows the last n positions overwrote in the "
+            "ring are gone")
         st = self._state.get(seq_id)
         if st is not None and n > 0:
             st["len"] = max(0, st["len"] - int(n))
@@ -776,6 +880,9 @@ class FlaxModelAdapter:
     def export_kv(self, seq_id: str, n_prompt: int) -> Dict[str, Any]:
         self._refuse_with_state("export_kv", "the pages alone do not "
                                 "carry a prompt to another replica")
+        self._refuse_with_window(
+            "export_kv", "a blob holds a prompt's whole pages in order, "
+            "which a ring is not")
         jnp = self._jnp
         bs = self.cache.block_size
         nb = -(-int(n_prompt) // bs)
@@ -798,6 +905,9 @@ class FlaxModelAdapter:
                   blob: Dict[str, Any]):
         self._refuse_with_state("import_kv", "a blob of pages alone does "
                                 "not restore a prompt")
+        self._refuse_with_window(
+            "import_kv", "a blob holds a prompt's whole pages in order, "
+            "which a ring is not")
         jnp = self._jnp
         if blob.get("kind") != f"flax:{self.kind}":
             raise ValueError(
@@ -832,13 +942,13 @@ class FlaxModelAdapter:
 def make_adapter(model: str = "toy",
                  model_config: Optional[Dict[str, Any]] = None):
     """Deployment-facing factory: ``model`` is ``toy`` |
-    ``gpt2`` | ``llama`` | ``kimi_linear`` | ``kimi_k2`` (tiny test
-    configs unless ``model_config`` overrides)."""
+    ``gpt2`` | ``llama`` | ``kimi_linear`` | ``kimi_k2`` | ``laguna``
+    (tiny test configs unless ``model_config`` overrides)."""
     model_config = dict(model_config or {})
     if model == "toy":
         return ToyAdapter(**model_config)
-    if model in ("gpt2", "llama", "kimi_linear", "kimi_k2"):
+    if model in ("gpt2", "llama", "kimi_linear", "kimi_k2", "laguna"):
         return FlaxModelAdapter(kind=model, **model_config)
     raise ValueError(
         f"unknown model {model!r} "
-        "(toy | gpt2 | llama | kimi_linear | kimi_k2)")
+        "(toy | gpt2 | llama | kimi_linear | kimi_k2 | laguna)")

@@ -414,3 +414,124 @@ def test_kimi_k2_step_fits_the_chip_at_the_timed_shapes(
         # 9,216 padded positions ([18432, 16, 640] or [32, 9216, ...])
         assert text.count("latent_attention_decode") >= 7
         assert "[18432,16,640]" not in text and "[32,9216," not in text
+
+
+def _laguna_cell():
+    """(decode slots, pages of the full layers' pools) of the cell
+    laguna_xs_2.serve_closed64_ctx8k, from its configuration file."""
+    import json
+    import os
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "laguna_xs_2.json")) as f:
+        engine = json.load(f)["serve"]["engine"]
+    return engine["max_running"], engine["num_blocks"]
+
+
+def _laguna_step(one_chip, topo, monkeypatch, B, S, window_pages=None):
+    """``FlaxModelAdapter``'s step for Laguna as the cell
+    laguna_xs_2.serve_closed64_ctx8k runs it: the published widths,
+    layers 0-4 whole (all 256 experts, the whole vocabulary), the full
+    layers' pools of ``num_blocks`` pages under tables of 576, the
+    sliding layers' of ``window_pages`` (``max_running`` rings of 33 and
+    the null page, as the engine sizes them) under rings of 33."""
+    from ray_tpu.models.laguna import LagunaConfig
+    from ray_tpu.serve.llm.kv_cache import PagedKVCache
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    slots, full_pages = _laguna_cell()
+    cfg = LagunaConfig(num_hidden_layers=5, max_seq_len=9216)
+    adapter = FlaxModelAdapter("laguna", cfg, params={})
+    params = jax.tree_util.tree_map(
+        lambda s: sds(s.shape, s.dtype),
+        jax.eval_shape(adapter.model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8), jnp.int32)))
+    adapter.bind_cache(PagedKVCache(2, 16, windows=adapter.page_windows,
+                                    max_sequences=1))
+    assert adapter._rings == {512: 33}
+    pools = [sds((a.shape[0], (window_pages or slots * 33 + 1)
+                  if "window" in name else full_pages, *a.shape[2:]),
+                 a.dtype)
+             for name, a in adapter._arrays.items()]
+    with monkeypatch.context() as m:
+        m.setattr(jax, "devices", lambda *a, **k: topo.devices)
+        fn = adapter._step_fn(B, S)
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    with jax.default_matmul_precision("default"):
+        return params, pools, fn.lower(
+            params, sds((B, S + 3 + adapter.nb_max + 33), jnp.int32),
+            *pools).compile()
+
+
+@pytest.mark.parametrize("S,temp_gib", [(1, 0.05), (8192, 2.0)],
+                         ids=["decode", "prefill_8192"])
+def test_laguna_step_fits_the_chip_at_the_timed_shapes(
+        one_chip, topo, monkeypatch, S, temp_gib):
+    """The two programs the cell times (a decode step of every slot, a
+    prompt of 8,192), compiled for the described v5e: 7.21 GiB of
+    weights, the full layers' pools and the sliding layers' rings as
+    arguments, all four pools donated and written in place, and
+    temporaries that leave the whole under the chip's 15.75 GiB. A decode
+    step's attention is the paged kernel in all five layers (groups of 6
+    over the live pages, groups of 8 over the ring: no row's table is
+    gathered) and its routed experts the Mosaic kernel over 256 experts;
+    a prompt's attention is the blocked kernel, a call a layer, and
+    holds no [heads, block, 8192] of logits."""
+    import math
+    slots, full_pages = _laguna_cell()
+    B = slots if S == 1 else 1
+    params, pools, step = _laguna_step(one_chip, topo, monkeypatch, B, S)
+    memory = step.memory_analysis()
+    gib = 2.0 ** 30
+    held = sum(math.prod(s.shape) * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    assert 7.2 < held / gib < 7.23
+    assert memory.alias_size_in_bytes == sum(
+        math.prod(p.shape) * 2 for p in pools)
+    assert memory.temp_size_in_bytes < temp_gib * gib
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert total < 14.0 * gib
+    text = step.as_text()
+    assert "[64,512,8192]" not in text and "[48,512,8192]" not in text
+    if S == 1:
+        assert text.count("tpu_custom_call") >= 9       # 5 + 4
+        assert f"[{full_pages - 1},16,1024]" not in text
+        assert f"[{B},9216," not in text
+        assert f"[{B},528," not in text     # nor a ring to its 528 rows
+    else:
+        assert text.count("tpu_custom_call") >= 5
+
+
+def test_laguna_whole_context_pools_would_not_fit(one_chip, topo,
+                                                  monkeypatch):
+    """Were the sliding layers to keep every position as the full ones
+    do (their pools as long as the full layers'), the prompt's program
+    would not fit the chip's 15.75 GiB beside them: the compiler refuses
+    it. The cell's sequences of 9,216 positions fit only because the
+    window group holds a ring a sequence."""
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
+        _laguna_step(one_chip, topo, monkeypatch, 1, 8192,
+                     window_pages=_laguna_cell()[1])
+
+
+@pytest.mark.parametrize("H,window", [(48, None), (64, 512)],
+                         ids=["full_g6", "window_g8"])
+def test_paged_attention_decode_grouped_heads_and_ring(one_chip, H, window):
+    """The paged kernel alone at the Laguna cell's shapes: a row a slot,
+    8 key/value heads of 128, groups of 6 over tables of 576 pages and
+    groups of 8 over a ring of 33."""
+    B, full_pages = _laguna_cell()
+    NB = 576 if window is None else 33
+    P = full_pages if window is None else B * 33 + 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    _compile(lambda q, k, v, bt, ln: A.paged_attention_decode(
+        q, k, v, bt, ln, layer=1, window=window, interpret=False),
+        sds((B, H, 128), jnp.bfloat16),
+        sds((2, P, 16, 1024), jnp.bfloat16),
+        sds((2, P, 16, 1024), jnp.bfloat16), sds((B, NB), jnp.int32),
+        sds((B,), jnp.int32))
