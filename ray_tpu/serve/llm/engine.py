@@ -40,6 +40,13 @@ Three fleet-efficiency features compose as engine flags
   pages into fresh ones (``disagg.py`` carries them over plasmax) and
   the sequence enters the decode batch mid-flight.
 
+**Look-ahead** (docs/LLM_SERVING.md, "The decode step's order"): where
+the adapter can feed a row's greedy token on the device
+(``decode_ahead``) and every row of the step is greedy, the engine
+dispatches decode step n + 1 before it fetches step n, so the device
+holds its next program when one ends; the tokens, pools and state are
+those of the synchronous order (``_decode``).
+
 Tokens stream out through per-sequence cursors (``poll``), which the
 replica exposes as ``__llm_next__`` and the router/proxy turn into
 handle iterators and SSE (docs/LLM_SERVING.md).
@@ -165,6 +172,14 @@ class Sequence:
         return len(self.prompt) + self.sampling.max_new_tokens
 
 
+@dataclass
+class _Flying:
+    """The decode step the device has and the host has not fetched."""
+    seqs: List[Sequence]
+    step: Any               # the adapter's DecodeStep
+    t0: float               # when its program could start
+
+
 class LLMEngine:
     """Continuous-batching scheduler + paged KV cache + streaming
     cursors around one model adapter (``model_runner.py``)."""
@@ -243,6 +258,9 @@ class LLMEngine:
         self._step_log: deque = deque(maxlen=tracing.STEP_RING)
         self._request_log: deque = deque(maxlen=tracing.STEP_RING)
         self._steps_total = 0
+        self._decode_steps_ahead_total = 0
+        self._decode_tokens_discarded_total = 0
+        self._flying: Optional[_Flying] = None      # the engine thread's
         self._prefill_steps_total = 0
         self._decode_rows_total = 0
         self._prefill_seqs_total = 0
@@ -590,6 +608,9 @@ class LLMEngine:
                 "itl_p50_s": round(q(itl, 0.50), 6),
                 "itl_p99_s": round(q(itl, 0.99), 6),
                 "steps_total": self._steps_total,
+                "decode_steps_ahead_total": self._decode_steps_ahead_total,
+                "decode_tokens_discarded_total":
+                    self._decode_tokens_discarded_total,
                 "prefill_steps_total": self._prefill_steps_total,
                 "decode_rows_total": self._decode_rows_total,
                 "prefill_seqs_total": self._prefill_seqs_total,
@@ -634,7 +655,8 @@ class LLMEngine:
             with self._lock:
                 if self._stopped:
                     return
-                if not self._running and not self._waiting:
+                if not self._running and not self._waiting \
+                        and self._flying is None:
                     self._work_cv.wait(timeout=0.5)
                     continue
             try:
@@ -726,11 +748,11 @@ class LLMEngine:
             with self._lock:
                 decode_seqs = [self._seqs[sid] for sid in self._running
                                if sid in self._seqs]
-            if decode_seqs:
-                if self._draft is not None:
+            if self._draft is not None:
+                if decode_seqs:
                     self._decode_spec(decode_seqs)
-                else:
-                    self._decode(decode_seqs)
+            elif decode_seqs or self._flying is not None:
+                self._decode(decode_seqs)
             with tracing.step_span("llm.step.admit") as span:
                 with self._lock:
                     admitted = self._admit_locked()
@@ -747,13 +769,76 @@ class LLMEngine:
         self._step_seconds_total += time.time() - t0
 
     def _decode(self, seqs: List[Sequence]):
+        """One decode step, a step ahead of the host where it can be.
+
+        Where the adapter feeds a row's greedy token on the device
+        (``decode_ahead``) and no row of the step samples, step n + 1 is
+        dispatched BEFORE step n is fetched and committed: its rows are
+        step n's less those whose budget step n fills (the host can count
+        that), each fed step n's token on the device, and the sequences
+        prefilled since, fed from the host. Pages never move (a
+        sequence's whole budget is allocated at admission) and lengths
+        grow by one, so nothing else of step n is needed. Any other step
+        (an adapter that returns logits, a row with temperature > 0) first
+        fetches and commits the step in flight and then runs as ever.
+
+        A row that step n ends by its ``stop_token``, or that was
+        cancelled meanwhile, is in step n + 1 all the same. Its token of
+        that step is discarded in ``_commit``, and its write is harmless:
+        it lands at a position inside the sequence's own budget, in pages
+        it owned when the step was dispatched, and every program takes
+        the pools and the state from the program before it, so the device
+        runs them in dispatch order. A prompt admitted into the freed
+        *pages* is therefore written after it; so is a sequence that takes
+        the freed *ring* (it reads no ring row it has not written
+        itself), and one that takes the freed *state slot* (zeroed by
+        ``llm_state_admit`` after it). (``_retire`` waits for the step in
+        flight before it releases a sequence, but for the device's
+        memory, not for this.)"""
+        flying, self._flying = self._flying, None
+        if flying is not None:
+            over = {s.seq_id for s in flying.seqs
+                    if len(s.tokens) + 1 >= s.sampling.max_new_tokens}
+            seqs = [s for s in seqs if s.seq_id not in over]
         t0 = time.time()
-        with tracing.step_span("llm.step.decode", n=len(seqs)):
+        if seqs and self._looks_ahead(seqs):
+            with tracing.step_span("llm.step.decode", n=len(seqs),
+                                   ahead=flying is not None):
+                step = self.adapter.decode(seqs, tokens_only=True,
+                                           fetch=False)
+                self._flying = _Flying(seqs, step, t0)
+                self._decode_rows_total += len(seqs)
+                if flying is not None:
+                    self._decode_steps_ahead_total += 1
+                    tokens = flying.step.fetch()
+                    # its program starts when the one before it ends
+                    self._flying.t0 = time.time()
+            self._runner_seconds_total += time.time() - t0
+            if flying is not None:
+                self._commit(flying.seqs, tokens, step_t0=flying.t0)
+            return
+        if flying is not None:      # nothing to dispatch ahead: it lands
+            with tracing.step_span("llm.step.decode", n=0):
+                tokens = flying.step.fetch()
+            self._runner_seconds_total += time.time() - t0
+            self._commit(flying.seqs, tokens, step_t0=flying.t0)
+            with self._lock:
+                seqs = [self._seqs[sid] for sid in self._running
+                        if sid in self._seqs]
+            if not seqs:
+                return
+            t0 = time.time()
+        with tracing.step_span("llm.step.decode", n=len(seqs),
+                               ahead=False):
             logits = self.adapter.decode(
                 seqs, **self._tokens_only(seqs))    # [B, V] np.ndarray
         self._decode_rows_total += len(seqs)
         self._runner_seconds_total += time.time() - t0
         self._commit(seqs, logits, step_t0=t0)
+
+    def _looks_ahead(self, seqs: List[Sequence]) -> bool:
+        return bool(getattr(self.adapter, "decode_ahead", False)) \
+            and all(self._greedy(s) for s in seqs)
 
     def _decode_spec(self, seqs: List[Sequence]):
         """Speculative step: draft proposes per greedy sequence, the
@@ -865,12 +950,17 @@ class LLMEngine:
         with tracing.step_span("llm.step.commit", n=len(seqs)) as span:
             now = time.time()
             finished: List[Sequence] = []
+            committed = 0
             with self._lock:
                 for i, seq in enumerate(seqs):
                     sid = seq.seq_id
                     if sid not in self._seqs or seq.status not in (RUNNING,
                                                                    WAITING):
+                        # ended (a stop token seen a step late) or
+                        # cancelled since the step was dispatched
+                        self._decode_tokens_discarded_total += 1
                         continue
+                    committed += 1
                     tok = self._sample(seq, logits[i])
                     if seq.t_first_token is None:
                         seq.t_first_token = now
@@ -887,7 +977,7 @@ class LLMEngine:
                         except ValueError:
                             pass
                         finished.append(seq)
-                self._rate_win.append((now, len(seqs)))
+                self._rate_win.append((now, committed))
                 self._out_cv.notify_all()
             self._retire(finished)
             span.set(finished=len(finished))
@@ -954,6 +1044,11 @@ class LLMEngine:
             span.set(finished=len(finished))
 
     def _retire(self, finished: List[Sequence]):
+        if finished and self._flying is not None:
+            # what a release runs on the device (a snapshot to export, a
+            # deployment's probe of the rows a sequence leaves) may need
+            # the memory the step in flight holds until it ends
+            self._flying.step.wait()
         for seq in finished:
             if seq.export_kv:
                 self._maybe_export(seq)
@@ -1005,6 +1100,7 @@ class LLMEngine:
     def _fail_all(self, err: Exception):
         """A model-step failure fails the sequences it was computing —
         pollers see an explicit error, never a silent truncation."""
+        self._flying = None     # its sequences fail with the others
         with self._lock:
             ids = list(self._running) + list(self._waiting)
             self._running.clear()
@@ -1018,7 +1114,11 @@ class LLMEngine:
                 seq.finish_reason = "error"
                 seq.t_finish = time.time()
                 self._total_failed += 1
-                self.adapter.release(sid)
+                try:
+                    self.adapter.release(sid)
+                except Exception as e:  # noqa: BLE001 — what failed the
+                    # step must not end the engine thread with it
+                    seq.error += f"; release: {type(e).__name__}: {e}"
                 self.cache.free(sid)
             self._out_cv.notify_all()
 

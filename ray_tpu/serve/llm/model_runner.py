@@ -68,7 +68,15 @@ by ``ops.attention.latent_decode_path``, and ``bind_cache`` asks the
 same function what the dispatch spans will say. Such an adapter also finds
 each row's greedy token on the device (``greedy_on_device``): asked with
 ``tokens_only=True``, ``prefill`` / ``decode`` return tokens [B] and the
-logits are not fetched. What cannot work without snapshots of
+logits are not fetched. It can also run a decode step **ahead**
+(``decode_ahead``): ``decode(seqs, tokens_only=True, fetch=False)``
+dispatches the step and returns it unfetched (``DecodeStep``); a row that
+is in the step still in flight feeds that step's greedy token, taken on
+the device from ``_last_tokens`` (every decode program writes its rows'
+greedy tokens there and reads the rows' inputs from there where the host
+says so), so the engine can dispatch step n + 1 before it fetches step n.
+It is the same program a bucket either way: a synchronous ``decode`` is
+that dispatch, then the fetch. What cannot work without snapshots of
 the state raises ``RecurrentStateError``: ``decode_window`` /
 ``rollback``, ``export_kv`` / ``import_kv`` (and the engine refuses
 ``enable_prefix_cache`` and ``spec_k`` at construction). A model that
@@ -308,6 +316,35 @@ def bucket_name(B: int, S: int, full: bool = False) -> str:
     return f"llm_decode_b{B}" if S == 1 else f"llm_prefill_b{B}_s{S}"
 
 
+class DecodeStep:
+    """A decode step the device has been given and the host has not
+    fetched (``FlaxModelAdapter.decode(..., fetch=False)``). ``at`` says
+    where each sequence's row lies in the padded batch: the step
+    dispatched after it reads a row's input token there, on the device.
+    ``fetch()`` waits for the program and returns what a synchronous
+    ``decode`` would have; only then do the step's tokens count into the
+    sequences' cached lengths (``_state[seq]["len"]``), so a sequence
+    released with a step still in flight shows the length the host
+    knows of. ``wait()`` only waits: the program has ended, and the
+    device holds nothing of it but the integers ``fetch()`` will read."""
+
+    def __init__(self, adapter, at: Dict[str, int], states, take, pending):
+        self.at = at
+        self._adapter, self._states = adapter, states
+        self._take, self._pending = take, pending
+
+    def fetch(self) -> np.ndarray:
+        out = self._take()
+        for st in self._states:
+            st["len"] += 1
+        if self._adapter._flying is self:
+            self._adapter._flying = None
+        return out
+
+    def wait(self):
+        self._pending.block_until_ready()
+
+
 class FlaxModelAdapter:
     """GPT-2 / Llama incremental decode over the paged pool.
 
@@ -385,6 +422,7 @@ class FlaxModelAdapter:
         self._kv_pages_live = self._kv_pages_padded = 0
         self._window_pages_live = self._window_pages_padded = 0
         self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
+        self._flying: Optional[DecodeStep] = None   # dispatched, unfetched
         self.bucket_first_calls = 0        # _fns misses: steps that compiled
         self._lock = threading.Lock()
 
@@ -419,6 +457,14 @@ class FlaxModelAdapter:
         return self._spec is not None
 
     @property
+    def decode_ahead(self) -> bool:
+        """``decode`` takes ``fetch=False``: it dispatches the step and
+        returns it unfetched (``DecodeStep``), and a row that is in the
+        step still in flight feeds that step's greedy token without the
+        host having seen it."""
+        return self._spec is not None
+
+    @property
     def n_layers(self) -> int:
         return getattr(self.cfg, "n_layer",
                        getattr(self.cfg, "n_layers", 0))
@@ -436,6 +482,7 @@ class FlaxModelAdapter:
     def bind_cache(self, cache):
         jnp = self._jnp
         self.cache = cache
+        self._flying = None
         dtype = self.cfg.dtype
         if set(self.page_windows) != set(getattr(cache, "windows", ())):
             raise ValueError(
@@ -477,6 +524,11 @@ class FlaxModelAdapter:
                         p["q_heads"], p["head_dim"], self._arrays[name], 1)
             self._free_slots: List[int] = []
             self.state_slots = 0
+            # the last decode program's greedy tokens by row of its padded
+            # batch (no batch has more rows than the pool has pages, and
+            # one shape serves every bucket's program)
+            self._last_tokens = jnp.zeros(
+                (_pad_pow2(cache.num_blocks),), jnp.int32)
         # NB: every block table is padded to the worst-case blocks per
         # sequence so decode jits once per batch bucket
         self.nb_max = cache.blocks_for(
@@ -633,15 +685,24 @@ class FlaxModelAdapter:
         whose rows all sample greedily fetches B + layers x experts
         integers and leaves the logits on the device. ``full`` (a
         speculative window's verify step) returns the logits after every
-        position, [B, S, V]."""
+        position, [B, S, V]. A decode program (``S == 1``) also takes
+        ``_last_tokens`` before the arrays, donated like them: a row whose
+        token comes as ``-1 - r`` feeds row ``r``'s greedy token of the
+        decode program before it, and the program leaves its own rows'
+        there; a row with a token from the host runs the same program."""
         import jax
         jnp = self._jnp
         names = list(self._arrays)
         by_slot = self._by_slot(B, S)
         rings = dict(self._rings)
+        feeds = S == 1
 
         def step(params, packed, *arrays):
             tokens, n_new = packed[:, :S], packed[:, S]
+            if feeds:
+                last, *arrays = arrays
+                tokens = jnp.where(
+                    tokens < 0, last[jnp.maximum(-1 - tokens, 0)], tokens)
             seq_lengths, slots = packed[:, S + 1], packed[:, S + 2]
             cache = dict(zip(names, arrays), block_tables=packed[:, S + 3:])
             if rings:       # one table a page group: the padded, the rings
@@ -659,20 +720,31 @@ class FlaxModelAdapter:
                 valid=valid,
                 logits_at=None if full else jnp.maximum(n_new - 1, 0))
             rows = logits[:, 0]     # (a verify step's are not asked for)
+            greedy = jnp.argmax(rows, axis=-1).astype(jnp.int32)
             small = jnp.concatenate([
-                jnp.argmax(rows, axis=-1).astype(jnp.int32),
-                counts.reshape(-1).astype(jnp.int32)])
+                greedy, counts.reshape(-1).astype(jnp.int32)])
             return (logits if full else rows, small,
+                    *([last.at[:B].set(greedy)] if feeds else []),
                     *(cache[n] for n in names))
 
         step.__name__ = step.__qualname__ = bucket_name(B, S, full)
-        donate = tuple(range(2, 2 + len(names))) \
+        donate = tuple(range(2, 2 + feeds + len(names))) \
             if jax.devices()[0].platform == "tpu" else ()
         return jax.jit(step, donate_argnums=donate)
 
     def _run(self, rows: List[Dict[str, Any]], op: str,
              tokens_only: bool = False) -> np.ndarray:
-        """rows: [{tokens: [ints], len: cache length, table: [pages]}]
+        """``_dispatch``, then the fetch."""
+        _, fetch, _ = self._dispatch(rows, op, tokens_only)
+        return fetch()
+
+    def _dispatch(self, rows: List[Dict[str, Any]], op: str,
+                  tokens_only: bool = False):
+        """rows: [{tokens: [ints], len: cache length, table: [pages]}];
+        builds the padded batch and gives the device its program. Returns
+        where each row lies in the padded batch, the output ``fetch()``
+        will read (ready when the program has ended), and ``fetch()``,
+        which waits for the program:
         -> last-token logits [B, V] (or full [B, S, V] when ``op`` is
         ``verify``) for the real rows; with ``tokens_only`` (a model
         that states its cache) each row's greedy token [B] instead.
@@ -718,24 +790,35 @@ class FlaxModelAdapter:
                         self.v_pages, jnp.asarray(tables),
                         jnp.asarray(lengths), jnp.asarray(valid))
                 else:
+                    fed = (self._last_tokens,) if S == 1 else ()
                     logits, small, *arrays = fn(
-                        self.params, jnp.asarray(packed),
+                        self.params, jnp.asarray(packed), *fed,
                         *self._arrays.values())
+                    if fed:
+                        self._last_tokens, *arrays = arrays
                     self._arrays = dict(zip(self._arrays, arrays))
-        with tracing.step_span("runner.fetch") as span:
-            if self._spec is None:
-                out = np.asarray(logits[:len(rows)], np.float32)
-            else:
-                # whole arrays, cut on the host: a slice on the device
-                # is a program a row count
-                small = np.asarray(small)
-                out = small[:B][at] if tokens_only \
-                    else np.asarray(logits, np.float32)[at]
-                if small.size > B:
-                    span.set(**self._count_experts(
-                        small[B:].reshape(self._spec["expert_counts"])))
-            span.set(bytes=out.nbytes)
-        return out
+                    if tokens_only:
+                        # nobody will read them: the device frees them
+                        # when the program ends (64 x 100,352 float32 a
+                        # step in flight are 25.7 MB)
+                        logits = None
+
+        def fetch() -> np.ndarray:
+            with tracing.step_span("runner.fetch") as span:
+                if self._spec is None:
+                    out = np.asarray(logits[:len(rows)], np.float32)
+                else:
+                    # whole arrays, cut on the host: a slice on the device
+                    # is a program a row count
+                    counts = np.asarray(small)
+                    out = counts[:B][at] if tokens_only \
+                        else np.asarray(logits, np.float32)[at]
+                    if counts.size > B:
+                        span.set(**self._count_experts(counts[B:].reshape(
+                            self._spec["expert_counts"])))
+                span.set(bytes=out.nbytes)
+            return out
+        return at, fetch, (logits if self._spec is None else small)
 
     def _pack(self, rows, at, B: int, S: int) -> np.ndarray:
         """The rows' integers as one int32 array [B, S + 3 + nb_max
@@ -826,14 +909,28 @@ class FlaxModelAdapter:
             rows.append(dict(st, tokens=s.prompt[cached:], len=cached))
         return self._run(rows, "prefill", tokens_only)
 
-    def decode(self, seqs, tokens_only: bool = False) -> np.ndarray:
+    def decode(self, seqs, tokens_only: bool = False, fetch: bool = True):
+        """One token a sequence. ``fetch=False`` (``decode_ahead``)
+        returns the dispatched ``DecodeStep`` in place of its result. A
+        sequence that is in the step still in flight has no
+        ``s.tokens[-1]`` for this step yet: its row feeds that step's
+        greedy token on the device and lies one token further on. At most
+        one step may be in flight when the next is dispatched, and it is
+        fetched before any other."""
+        flying = self._flying
+        states = [self._state[s.seq_id] for s in seqs]
         rows = []
-        for s in seqs:
-            st = self._state[s.seq_id]
-            rows.append(dict(st, tokens=[s.tokens[-1]],
-                             slot=st.get("slot", 0)))
-            st["len"] += 1
-        return self._run(rows, "decode", tokens_only)
+        for s, st in zip(seqs, states):
+            src = flying.at.get(s.seq_id) if flying else None
+            rows.append(dict(
+                st, slot=st.get("slot", 0),
+                tokens=[s.tokens[-1] if src is None else -1 - src],
+                len=st["len"] + (src is not None)))
+        at, take, pending = self._dispatch(rows, "decode", tokens_only)
+        step = self._flying = DecodeStep(
+            self, dict(zip((s.seq_id for s in seqs), at)), states, take,
+            pending)
+        return step.fetch() if fetch else step
 
     def decode_window(self, seqs, windows) -> List[np.ndarray]:
         """One batched multi-token incremental step; causal masking at

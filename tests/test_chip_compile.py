@@ -277,6 +277,14 @@ def test_decode_step_attends_to_its_live_pages_in_place(one_chip, topo,
     assert "tpu_custom_call" not in prefill.as_text()
 
 
+def _last_tokens(sds, pages, S):
+    """What a decode program (``S == 1``) of a model that states its
+    cache takes before its pools, donated like them: the last decode
+    step's greedy tokens by row (``FlaxModelAdapter._last_tokens``)."""
+    from ray_tpu.serve.llm.model_runner import _pad_pow2
+    return [sds((_pad_pow2(pages),), jnp.int32)] if S == 1 else []
+
+
 def _kimi_step(one_chip, topo, monkeypatch, pages, slots, B, S):
     """``FlaxModelAdapter``'s step for Kimi-Linear at the published
     widths, cut to one period (K K K M), 8 experts and 2048 rows of the
@@ -309,7 +317,7 @@ def _kimi_step(one_chip, topo, monkeypatch, pages, slots, B, S):
     with jax.default_matmul_precision("default"):
         return arrays, fn.lower(
             params, sds((B, S + 3 + adapter.nb_max), jnp.int32),
-            *arrays).compile()
+            *_last_tokens(sds, pages, S), *arrays).compile()
 
 
 @pytest.mark.parametrize("B,S,slots", [(16, 1, 32), (16, 1, 16),
@@ -375,7 +383,7 @@ def _k2_step(one_chip, topo, monkeypatch, B, S):
     with jax.default_matmul_precision("default"):
         return params, pool, fn.lower(
             params, sds((B, S + 3 + adapter.nb_max), jnp.int32),
-            pool).compile()
+            *_last_tokens(sds, 18433, S), pool).compile()
 
 
 @pytest.mark.parametrize("B,S,temp_gib", [(32, 1, 0.05), (1, 8192, 3.7)],
@@ -400,7 +408,9 @@ def test_kimi_k2_step_fits_the_chip_at_the_timed_shapes(
     held = sum(math.prod(s.shape) * s.dtype.itemsize
                for s in jax.tree_util.tree_leaves(params))
     assert 9.0 < held / gib < 9.1
-    assert memory.alias_size_in_bytes == math.prod(pool.shape) * 2
+    # (a decode program's other donated argument: 32,768 tokens by row)
+    assert memory.alias_size_in_bytes == math.prod(pool.shape) * 2 \
+        + (4 * 32768 if S == 1 else 0)
     assert memory.temp_size_in_bytes < temp_gib * gib
     total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
              + memory.output_size_in_bytes - memory.alias_size_in_bytes)
@@ -462,7 +472,7 @@ def _laguna_step(one_chip, topo, monkeypatch, B, S, window_pages=None):
     with jax.default_matmul_precision("default"):
         return params, pools, fn.lower(
             params, sds((B, S + 3 + adapter.nb_max + 33), jnp.int32),
-            *pools).compile()
+            *_last_tokens(sds, full_pages, S), *pools).compile()
 
 
 @pytest.mark.parametrize("S,temp_gib", [(1, 0.05), (8192, 2.0)],
@@ -489,7 +499,8 @@ def test_laguna_step_fits_the_chip_at_the_timed_shapes(
                for s in jax.tree_util.tree_leaves(params))
     assert 7.2 < held / gib < 7.23
     assert memory.alias_size_in_bytes == sum(
-        math.prod(p.shape) * 2 for p in pools)
+        math.prod(p.shape) * 2 for p in pools) \
+        + (4 * 65536 if S == 1 else 0)      # (and the tokens by row)
     assert memory.temp_size_in_bytes < temp_gib * gib
     total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
              + memory.output_size_in_bytes - memory.alias_size_in_bytes)
@@ -535,3 +546,63 @@ def test_paged_attention_decode_grouped_heads_and_ring(one_chip, H, window):
         sds((2, P, 16, 1024), jnp.bfloat16),
         sds((2, P, 16, 1024), jnp.bfloat16), sds((B, NB), jnp.int32),
         sds((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("kind,config", [
+    ("kimi_linear", "KimiLinearConfig"), ("kimi_k2", "KimiK2Config"),
+    ("laguna", "LagunaConfig")])
+def test_a_step_dispatched_ahead_runs_the_program_the_warm_up_compiled(
+        kind, config):
+    """The benchmark warms a decode bucket by a synchronous
+    ``adapter.decode(seqs)`` with tokens from the host. A step dispatched
+    ahead (``fetch=False``), with its tokens from the host or from the
+    step in flight, is that very program: no entry more in ``_fns``,
+    ``bucket_first_calls`` as it was, the jitted function traced once.
+    (The tiny presets, on the CPU: nothing here depends on the chip.)"""
+    import importlib
+
+    import numpy as np
+
+    from ray_tpu.serve.llm import PagedKVCache, SamplingParams
+    from ray_tpu.serve.llm.engine import Sequence
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+    glue = importlib.import_module(f"benchmark.reference.{kind}_glue")
+    cfg = getattr(importlib.import_module(f"ray_tpu.models.{kind}"),
+                  config).tiny()
+    adapter = FlaxModelAdapter(kind, cfg, glue.init_for(cfg, 7))
+    assert adapter.decode_ahead
+
+    def served(ahead):
+        cache = PagedKVCache(64, 8, windows=adapter.page_windows,
+                             max_sequences=4)
+        adapter.bind_cache(cache)
+        if adapter.has_state:
+            adapter.bind_state(4)
+        seqs = []
+        for i, n in enumerate((11, 5, 20)):
+            cache.allocate(f"s{i}", n + 8)
+            seqs.append(Sequence(f"s{i}", None, list(range(1, n + 1)),
+                                 SamplingParams(max_new_tokens=8)))
+        out = [adapter.prefill(seqs, tokens_only=True)]
+
+        def commit(tokens):
+            for s, t in zip(seqs, tokens):
+                s.tokens.append(int(t))
+        commit(out[0])
+        out.append(adapter.decode(seqs, tokens_only=True))   # the warm-up's
+        commit(out[1])
+        if not ahead:
+            for _ in range(2):
+                out.append(adapter.decode(seqs, tokens_only=True))
+                commit(out[-1])
+            return np.stack(out)
+        before = (adapter.bucket_first_calls, set(adapter._fns))
+        first = adapter.decode(seqs, tokens_only=True, fetch=False)
+        second = adapter.decode(seqs, tokens_only=True, fetch=False)
+        out += [first.fetch(), second.fetch()]
+        assert (adapter.bucket_first_calls, set(adapter._fns)) == before
+        assert adapter._fns[4, 1, False]._cache_size() == 1
+        assert [adapter._state[s.seq_id]["len"] for s in seqs] \
+            == [len(s.prompt) + 3 for s in seqs]
+        return np.stack(out)
+    np.testing.assert_array_equal(served(True), served(False))
