@@ -52,11 +52,15 @@ sampled and not shipped anywhere: each is a
 ``jax.profiler.TraceAnnotation`` (so it lies on the device trace's clock
 whenever a profiler session is open) and a host-clock record hung under
 the span open on the same thread; finished root spans land in a bounded
-ring their owner reads (docs/TRACING.md, "Step spans").
+ring their owner reads (docs/TRACING.md, "Step spans"). ``step_event``
+hangs a span whose length is known only when it is over (a collector's
+pause, a compile) into the same trees; ``watch_process`` makes the
+interpreter and jax report theirs.
 """
 
 from __future__ import annotations
 
+import gc
 import os
 import sys
 import threading
@@ -101,6 +105,14 @@ def enabled() -> bool:
     if _enabled is None:
         refresh()
     return _enabled
+
+
+def slow_s() -> float:
+    """``RTPU_TRACE_SLOW_S``: what ran longer is always kept (a request's
+    spans here, an engine step's record in ``serve/llm/step_watch.py``)."""
+    if _slow_s is None:
+        refresh()
+    return _slow_s
 
 
 def sampled(trace_id: Optional[str]) -> bool:
@@ -425,11 +437,88 @@ class step_span:
             self._ann.__exit__(exc_type, exc, tb)
 
 
-def step_roots(name: Optional[str] = None) -> List[Dict[str, Any]]:
+def step_roots(*names: str) -> List[Dict[str, Any]]:
     """Finished root step spans of this process that were given no ring
-    of their own (the feed's), oldest first."""
+    of their own (the feed's, and the events of threads with no span
+    open), oldest first; all of them, or those of the names given."""
     return [r for r in list(_step_roots)
-            if name is None or r["name"] == name]
+            if not names or r["name"] in names]
+
+
+def step_event(name: str, seconds: float, **attrs) -> None:
+    """A span that is over when its length is known: ``{name, t0 = now -
+    seconds, t1 = now, attrs, children: []}`` hung under the span open on
+    the calling thread or, where none is open, put into the module's ring
+    with the thread's name among its attributes. No profiler annotation
+    (the time has passed)."""
+    if not enabled():
+        return
+    now = time.time()
+    rec = {"name": name, "t0": now - seconds, "t1": now, "attrs": attrs,
+           "children": []}
+    stack = getattr(_step_tls, "stack", None)
+    if stack:
+        rec["t0"] = max(rec["t0"], stack[-1]["t0"])
+        stack[-1]["children"].append(rec)
+    else:
+        attrs["thread"] = threading.current_thread().name
+        _step_roots.append(rec)
+
+
+# what ``watch_process`` counts, whatever RTPU_TRACING says
+gc_seconds_total = 0.0
+gc_collections_total = 0
+compiles_total = 0
+compile_seconds_total = 0.0
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_gc_t0: Optional[float] = None      # the collection under way (one at a time)
+_watching_jax = False
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    # runs on the thread that triggered the collection, the interpreter
+    # held: every other Python thread of the process waits it out
+    global _gc_t0, gc_seconds_total, gc_collections_total
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+    elif _gc_t0 is not None:
+        seconds, _gc_t0 = time.perf_counter() - _gc_t0, None
+        gc_seconds_total += seconds
+        gc_collections_total += 1
+        step_event("py.gc", seconds, generation=info.get("generation"),
+                   collected=info.get("collected"))
+
+
+def _on_jax_duration(event: str, duration: float, **_) -> None:
+    global compiles_total, compile_seconds_total
+    if event == COMPILE_EVENT:
+        compiles_total += 1
+        compile_seconds_total += duration
+        step_event("jax.compile", float(duration))
+
+
+def watch_process() -> None:
+    """Make this process report its collector's pauses and its compiles
+    as step events (``py.gc`` with ``generation`` and ``collected``;
+    ``jax.compile``, one a backend compile request, cache hit or not) and
+    count them (``process_counters``). Idempotent. jax is never imported
+    from here: a process that has it by then gets the listener."""
+    global _watching_jax
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    jax = sys.modules.get("jax")
+    if jax is not None and not _watching_jax:
+        _watching_jax = True
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+
+
+def process_counters() -> Dict[str, Any]:
+    return {"gc_seconds_total": round(gc_seconds_total, 6),
+            "gc_collections_total": gc_collections_total,
+            "compiles_total": compiles_total,
+            "compile_seconds_total": round(compile_seconds_total, 6)}
 
 
 # ------------------------------------------------- task-span synthesis

@@ -10,7 +10,8 @@ shedding, ledgers, tracing, HA and drain all apply unchanged:
   ``__llm_next__(sid, cursor, w)`` cursor poll -> token delta
   ``__llm_cancel__(sid)``          abandon a stream
   ``__llm_metrics__()``            engine metrics + token ledger +
-                                   step_log + request_log
+                                   step_log + request_log + slow_steps
+                                   + process_events
   ``__llm_profile__(dir, s)``      jax.profiler capture of a live replica
   ``__llm_prefill__(payload)``     disagg hop 1: prompt + first token,
                                    returns a KV handoff descriptor
@@ -47,6 +48,7 @@ import os
 import time
 from typing import Any, Dict, List, Optional, Union
 
+from ray_tpu._private import tracing
 from ray_tpu.serve.llm.disagg import KVShipError, KVShipper
 from ray_tpu.serve.llm.engine import (EngineConfig, LLMEngine,
                                       SamplingParams)
@@ -271,6 +273,10 @@ class LLMServer:
         m["token_ledger"] = self.engine.token_ledger()
         m["step_log"] = self.engine.step_log()
         m["request_log"] = self.engine.request_log()
+        m["slow_steps"] = self.engine.slow_steps()
+        # the collections and compiles of threads that had no step span
+        # open (those of the engine thread lie in the step trees)
+        m["process_events"] = tracing.step_roots("py.gc", "jax.compile")
         m["device"] = self.adapter.device_info()
         return m
 

@@ -70,7 +70,12 @@ is an ``llm.step`` tree (``llm.step.decode`` / ``.admit`` / ``.prefill``
 ring of 4096 (``step_log``), and every finished request leaves one
 record in ``request_log``. Neither is sampled or shipped; both are empty
 under ``RTPU_TRACING=0``. The cumulative ``*_total`` step counters in
-``metrics()`` are counted whatever the switch says.
+``metrics()`` are counted whatever the switch says. A step accounts for
+its waits: ``cpu_ms`` and ``lock_wait_ms`` on its spans, ``runner.wait``
+and ``runner.release`` under ``llm.step.retire``, the process's ``py.gc``
+and ``jax.compile`` events where they fell, and a step of
+``RTPU_TRACE_SLOW_S`` or more leaves a record in ``slow_steps`` that says
+what it waited for (``step_watch.py``).
 """
 
 from __future__ import annotations
@@ -78,6 +83,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -87,6 +93,7 @@ from typing import Any, Dict, List, Optional
 from ray_tpu._private import tracing
 from ray_tpu.serve.exceptions import ReplicaOverloadedError
 from ray_tpu.serve.llm.kv_cache import OutOfKVBlocksError, PagedKVCache
+from ray_tpu.serve.llm.step_watch import StepWatch
 
 # sequence states
 WAITING, RUNNING, FINISHED, FAILED = ("waiting", "running", "finished",
@@ -180,6 +187,35 @@ class _Flying:
     t0: float               # when its program could start
 
 
+class _Locked:
+    """``with _Locked(engine, span):`` the engine lock on the engine
+    thread. Where it is not free at once, the wait to acquire it is
+    summed into ``lock_wait_ms`` of ``span`` (a ``tracing.step_span`` or
+    None) and into ``lock_wait_seconds_total``."""
+
+    __slots__ = ("engine", "span")
+
+    def __init__(self, engine: "LLMEngine", span):
+        self.engine, self.span = engine, span
+
+    def __enter__(self):
+        engine = self.engine
+        if engine._lock.acquire(False):
+            return
+        t0 = time.perf_counter()
+        engine._lock.acquire()
+        waited = time.perf_counter() - t0
+        engine._lock_wait_seconds_total += waited
+        rec = self.span.rec if self.span is not None else None
+        if rec is not None:
+            attrs = rec["attrs"]
+            attrs["lock_wait_ms"] = attrs.get("lock_wait_ms", 0.0) \
+                + waited * 1e3
+
+    def __exit__(self, exc_type, exc, tb):
+        self.engine._lock.release()
+
+
 class LLMEngine:
     """Continuous-batching scheduler + paged KV cache + streaming
     cursors around one model adapter (``model_runner.py``)."""
@@ -267,9 +303,15 @@ class LLMEngine:
         self._prefill_tokens_total = 0
         self._step_seconds_total = 0.0
         self._runner_seconds_total = 0.0
+        self._lock_wait_seconds_total = 0.0
+        self._step_span = None          # the open llm.step
+        tracing.watch_process()
+        self._watch = StepWatch(
+            sys.modules[type(adapter).__module__].__file__)
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="rtpu-llm-engine")
         self._thread.start()
+        self._watch.start()
 
     def _refuse_with_state(self, asked: bool, what: str, why: str):
         if asked and self._stateful:
@@ -562,6 +604,7 @@ class LLMEngine:
             self._work_cv.notify_all()
             self._out_cv.notify_all()
         self._thread.join(timeout=5.0)
+        self._watch.stop()
 
     def in_flight(self) -> int:
         with self._lock:
@@ -620,7 +663,11 @@ class LLMEngine:
                     self._runner_seconds_total, 6),
                 "bucket_first_calls_total": int(getattr(
                     self.adapter, "bucket_first_calls", 0)),
+                "lock_wait_seconds_total": round(
+                    self._lock_wait_seconds_total, 6),
+                "slow_steps_total": self._watch.total,
             }
+        out.update(tracing.process_counters())
         out.update(self.cache.stats())
         counters = getattr(self.adapter, "counters", None)
         if counters is not None:
@@ -648,11 +695,19 @@ class LLMEngine:
         clock, and the token counts of the ledger."""
         return list(self._request_log)
 
+    def slow_steps(self) -> List[Dict[str, Any]]:
+        """One record per step of ``RTPU_TRACE_SLOW_S`` (1 s) or more,
+        the last 64: the step's tree, its CPU time, every thread's stack
+        taken while it was open, how late the watcher woke, the
+        collections and compiles that overlap it, and a ``verdict``
+        (docs/TRACING.md, "A slow step")."""
+        return list(self._watch.records)
+
     # ------------------------------------------------------------ engine
 
     def _loop(self):
         while True:
-            with self._lock:
+            with _Locked(self, None):
                 if self._stopped:
                     return
                 if not self._running and not self._waiting \
@@ -740,33 +795,47 @@ class LLMEngine:
         this step's admissions (decode first — admission cost must
         never delay in-flight tokens)."""
         t0 = time.time()
+        cpu0 = time.thread_time()
+        cpu_ms = None
         self._steps_total += 1
-        with tracing.step_span("llm.step", self._step_log,
-                               i=self._steps_total,
-                               running=len(self._running),
-                               waiting=len(self._waiting)):
-            with self._lock:
-                decode_seqs = [self._seqs[sid] for sid in self._running
-                               if sid in self._seqs]
-            if self._draft is not None:
-                if decode_seqs:
-                    self._decode_spec(decode_seqs)
-            elif decode_seqs or self._flying is not None:
-                self._decode(decode_seqs)
-            with tracing.step_span("llm.step.admit") as span:
-                with self._lock:
-                    admitted = self._admit_locked()
-                    waiting_left = len(self._waiting)
-                tokens = sum(len(s.prompt) - s.cached_tokens
-                             for s in admitted)
-                span.set(admitted=len(admitted), prefill_tokens=tokens,
-                         waiting_left=waiting_left)
-            if admitted:
-                self._prefill_steps_total += 1
-                self._prefill_seqs_total += len(admitted)
-                self._prefill_tokens_total += tokens
-                self._prefill(admitted, tokens)
-        self._step_seconds_total += time.time() - t0
+        self._watch.begin(self._steps_total, t0)
+        step = self._step_span = tracing.step_span(
+            "llm.step", self._step_log, i=self._steps_total,
+            running=len(self._running), waiting=len(self._waiting),
+            lock_wait_ms=0.0)
+        try:
+            with step:
+                with _Locked(self, step):
+                    decode_seqs = [self._seqs[sid] for sid in self._running
+                                   if sid in self._seqs]
+                if self._draft is not None:
+                    if decode_seqs:
+                        self._decode_spec(decode_seqs)
+                elif decode_seqs or self._flying is not None:
+                    self._decode(decode_seqs)
+                with tracing.step_span("llm.step.admit") as span:
+                    with _Locked(self, span):
+                        admitted = self._admit_locked()
+                        waiting_left = len(self._waiting)
+                    tokens = sum(len(s.prompt) - s.cached_tokens
+                                 for s in admitted)
+                    span.set(admitted=len(admitted), prefill_tokens=tokens,
+                             waiting_left=waiting_left)
+                if admitted:
+                    self._prefill_steps_total += 1
+                    self._prefill_seqs_total += len(admitted)
+                    self._prefill_tokens_total += tokens
+                    self._prefill(admitted, tokens)
+                # a step of seconds with milliseconds of CPU waited; one
+                # with seconds of CPU computed
+                cpu_ms = (time.thread_time() - cpu0) * 1e3
+                step.set(cpu_ms=cpu_ms)
+        finally:
+            t1 = time.time()
+            if cpu_ms is None:          # the step raised
+                cpu_ms = (time.thread_time() - cpu0) * 1e3
+            self._step_seconds_total += t1 - t0
+            self._watch.end(t1, cpu_ms, step.rec)
 
     def _decode(self, seqs: List[Sequence]):
         """One decode step, a step ahead of the host where it can be.
@@ -822,7 +891,7 @@ class LLMEngine:
                 tokens = flying.step.fetch()
             self._runner_seconds_total += time.time() - t0
             self._commit(flying.seqs, tokens, step_t0=flying.t0)
-            with self._lock:
+            with _Locked(self, self._step_span):
                 seqs = [self._seqs[sid] for sid in self._running
                         if sid in self._seqs]
             if not seqs:
@@ -884,7 +953,7 @@ class LLMEngine:
     def _prefill(self, seqs: List[Sequence], tokens: int):
         t0 = time.time()
         with tracing.step_span("llm.step.prefill", n=len(seqs),
-                               tokens=tokens):
+                               tokens=tokens) as span:
             for s in seqs:
                 s.t_prefill_start = t0
             logits = self.adapter.prefill(
@@ -898,7 +967,7 @@ class LLMEngine:
                     table = self.cache.block_table(s.seq_id)
                     if table:
                         self.prefix_cache.insert(s.prompt, table)
-            with self._lock:
+            with _Locked(self, span):
                 for s in seqs:
                     s.t_prefill_end = t1
                     s.status = RUNNING
@@ -951,7 +1020,7 @@ class LLMEngine:
             now = time.time()
             finished: List[Sequence] = []
             committed = 0
-            with self._lock:
+            with _Locked(self, span):
                 for i, seq in enumerate(seqs):
                     sid = seq.seq_id
                     if sid not in self._seqs or seq.status not in (RUNNING,
@@ -995,7 +1064,7 @@ class LLMEngine:
             finished: List[Sequence] = []
             rollbacks: List[tuple] = []
             total_committed = 0
-            with self._lock:
+            with _Locked(self, span):
                 for seq, win, row in zip(seqs, windows, rows):
                     sid = seq.seq_id
                     if sid not in self._seqs or seq.status != RUNNING:
@@ -1044,19 +1113,30 @@ class LLMEngine:
             span.set(finished=len(finished))
 
     def _retire(self, finished: List[Sequence]):
-        if finished and self._flying is not None:
-            # what a release runs on the device (a snapshot to export, a
-            # deployment's probe of the rows a sequence leaves) may need
-            # the memory the step in flight holds until it ends
-            self._flying.step.wait()
-        for seq in finished:
-            if seq.export_kv:
-                self._maybe_export(seq)
-            self.adapter.release(seq.seq_id)
-            self.cache.free(seq.seq_id)
-            self._finalize(seq)
+        """What a finished request costs, under ``llm.step.retire``: the
+        wait for the step in flight (``runner.wait``), then for each
+        sequence ``runner.release`` (the snapshot to export, the
+        adapter's release and whatever a deployment hooked onto it) and
+        ``llm.step.finalize`` (its pages back to the pool, its ledger
+        line, its record and its request spans)."""
+        if not finished:
+            return
+        with tracing.step_span("llm.step.retire", n=len(finished)) as span:
+            if self._flying is not None:
+                # what a release runs on the device (a snapshot to export,
+                # a deployment's probe of the rows a sequence leaves) may
+                # need the memory the step in flight holds until it ends
+                self._flying.step.wait()
+            for seq in finished:
+                with tracing.step_span("runner.release"):
+                    if seq.export_kv:
+                        self._maybe_export(seq, span)
+                    self.adapter.release(seq.seq_id)
+                with tracing.step_span("llm.step.finalize"):
+                    self.cache.free(seq.seq_id)
+                    self._finalize(seq, span)
 
-    def _maybe_export(self, seq: Sequence):
+    def _maybe_export(self, seq: Sequence, span):
         """Prefill-role finish: snapshot the prompt's KV pages BEFORE
         release/free recycles them; ``__llm_prefill__`` picks the
         snapshot up via ``take_export``."""
@@ -1064,7 +1144,7 @@ class LLMEngine:
             blob = self.adapter.export_kv(seq.seq_id, len(seq.prompt))
         except Exception:
             blob = None
-        with self._lock:
+        with _Locked(self, span):
             self._exports[seq.seq_id] = {
                 "prompt": list(seq.prompt),
                 "first_token": seq.tokens[0] if seq.tokens else None,
@@ -1076,8 +1156,10 @@ class LLMEngine:
                 self._exports.pop(next(iter(self._exports)))
             self._out_cv.notify_all()
 
-    def _finalize(self, seq: Sequence):
-        with self._lock:
+    def _finalize(self, seq: Sequence, span=None):
+        """``span``: the engine thread's open ``llm.step.retire`` (None
+        from a caller's thread, which takes the lock as callers do)."""
+        with (self._lock if span is None else _Locked(self, span)):
             self._total_finished += 1
             self._total_cache_hit += seq.cached_tokens
             reason = seq.finish_reason

@@ -342,7 +342,8 @@ class DecodeStep:
         return out
 
     def wait(self):
-        self._pending.block_until_ready()
+        with tracing.step_span("runner.wait"):
+            self._pending.block_until_ready()
 
 
 class FlaxModelAdapter:
@@ -806,8 +807,17 @@ class FlaxModelAdapter:
         def fetch() -> np.ndarray:
             with tracing.step_span("runner.fetch") as span:
                 if self._spec is None:
+                    # one call waits and copies: this path's host is
+                    # exposed and its stream threads contend for the
+                    # interpreter, so a return to Python between the wait
+                    # and the copy costs a step ~0.25 ms (PERF.md, PR 39)
                     out = np.asarray(logits[:len(rows)], np.float32)
                 else:
+                    # the wait for the program apart from the copy and
+                    # the cutting on the host
+                    t0 = time.perf_counter()
+                    small.block_until_ready()
+                    span.set(wait_ms=(time.perf_counter() - t0) * 1e3)
                     # whole arrays, cut on the host: a slice on the device
                     # is a program a row count
                     counts = np.asarray(small)

@@ -5,10 +5,13 @@ feed's spans, and the names the kernels and the train step put on a
 device trace."""
 
 import dataclasses
+import gc
+import logging
 import os
 import sys
 import threading
 import time
+from collections import deque
 
 import numpy as np
 import pytest
@@ -26,7 +29,13 @@ ENGINE = dict(max_running=4, num_blocks=64, block_size=16, max_seq_len=128,
               max_prefill_tokens=64)
 
 
+# spans that are over when they are known (tracing.step_event)
+EVENTS = ("py.gc", "jax.compile")
+
+
 def make_adapter(kind):
+    if kind == "ahead":     # states its cache: the engine looks ahead
+        return FlaxModelAdapter("kimi_k2")
     return ToyAdapter() if kind == "toy" else FlaxModelAdapter("gpt2")
 
 
@@ -57,7 +66,7 @@ def serve(engine, prompts, new_tokens=5):
 PROMPTS = [list(range(1, 10 + 3 * i)) for i in range(7)]
 
 
-@pytest.fixture(scope="module", params=["toy", "flax", "toy-prefix"])
+@pytest.fixture(scope="module", params=["toy", "flax", "toy-prefix", "ahead"])
 def run(request):
     kind = request.param.split("-")[0]
     config = EngineConfig(enable_prefix_cache="prefix" in request.param,
@@ -67,7 +76,7 @@ def run(request):
     served = serve(engine, PROMPTS) + serve(engine, PROMPTS)
     out = {"served": served, "metrics": engine.metrics(),
            "steps": engine.step_log(), "requests": engine.request_log(),
-           "kind": request.param}
+           "slow": engine.slow_steps(), "kind": request.param}
     yield out
     engine.stop()
 
@@ -78,6 +87,16 @@ def walk(span):
         yield from walk(child)
 
 
+def ms(span):
+    return (span["t1"] - span["t0"]) * 1e3
+
+
+def spans_of(span):
+    """A span's children that were spans; its events apart."""
+    return ([c for c in span["children"] if c["name"] not in EVENTS],
+            [c for c in span["children"] if c["name"] in EVENTS])
+
+
 def test_step_trees_nest(run):
     assert run["steps"] and all(s["name"] == "llm.step" for s in run["steps"])
     assert [s["attrs"]["i"] for s in run["steps"]] == list(
@@ -85,21 +104,64 @@ def test_step_trees_nest(run):
     for step in run["steps"]:
         for span in walk(step):
             assert span["t0"] <= span["t1"]
+            children, events = spans_of(span)
             end = span["t0"]
-            for child in span["children"]:      # in order, inside, disjoint
+            for child in children:              # in order, inside, disjoint
                 assert end <= child["t0"] <= child["t1"] <= span["t1"]
                 end = child["t1"]
-        names = [c["name"] for c in step["children"]]
+            # an event is hung where it ends, a clock read or two from
+            # the span's own: inside its step all the same
+            for event in events:
+                assert event["children"] == []
+                assert step["t0"] <= event["t0"] <= event["t1"] \
+                    <= step["t1"] + 1e-3
+        names = [c["name"] for c in spans_of(step)[0]]
         assert names.count("llm.step.admit") == 1
+        admit = spans_of(step)[0][names.index("llm.step.admit")]
         assert ("llm.step.prefill" in names) == (
-            step["children"][names.index("llm.step.admit")]
-            ["attrs"]["admitted"] > 0)
+            admit["attrs"]["admitted"] > 0)
     if run["kind"] == "flax":
         calls = [s for st in run["steps"] for s in walk(st)
                  if s["name"] in ("llm.step.decode", "llm.step.prefill")]
-        assert all([c["name"] for c in call["children"]] == [
+        assert all([c["name"] for c in spans_of(call)[0]] == [
             "runner.build_inputs", "runner.dispatch", "runner.fetch"]
             for call in calls)
+
+
+def test_every_step_accounts_for_its_waits(run):
+    """``cpu_ms`` and ``lock_wait_ms`` on every step, one
+    ``llm.step.retire`` a commit that finished a request, with the wait
+    for the step in flight and each sequence's release under it."""
+    retired = waits = 0
+    for step in run["steps"]:
+        attrs = step["attrs"]
+        assert attrs["lock_wait_ms"] >= 0.0
+        assert 0.0 <= attrs["cpu_ms"] <= ms(step) + 5.0
+        for commit in (s for s in walk(step)
+                       if s["name"] == "llm.step.commit"):
+            kids, _ = spans_of(commit)
+            assert [k["name"] for k in kids] == (
+                ["llm.step.retire"] if commit["attrs"]["finished"] else [])
+            for retire in kids:
+                n = retire["attrs"]["n"]
+                assert n == commit["attrs"]["finished"]
+                names = [k["name"] for k in spans_of(retire)[0]]
+                waits += names.count("runner.wait")
+                assert names[names.count("runner.wait"):] == [
+                    "runner.release", "llm.step.finalize"] * n
+                assert names.count("runner.wait") <= 1
+                retired += n
+        for fetch in (s for s in walk(step) if s["name"] == "runner.fetch"):
+            # the wait apart from the copy, where the model states its
+            # cache; the path that returns logits waits and copies in one
+            assert ("wait_ms" in fetch["attrs"]) == (run["kind"] == "ahead")
+            assert 0.0 <= fetch["attrs"].get("wait_ms", 0.0) \
+                <= ms(fetch) + 0.1
+    assert retired == run["metrics"]["finished_total"]
+    # a request that finishes while a step is in flight waits for it
+    assert (waits > 0) == (run["kind"] == "ahead")
+    assert run["metrics"]["lock_wait_seconds_total"] >= 0.0
+    assert run["metrics"]["slow_steps_total"] == len(run["slow"])
 
 
 def test_span_counts_are_the_engines_counters(run):
@@ -139,7 +201,7 @@ def test_request_log_is_ordered_and_complete(run):
 
 
 def test_first_call_is_true_once_a_bucket(run):
-    if run["kind"] != "flax":
+    if run["kind"].startswith("toy"):
         assert run["metrics"]["bucket_first_calls_total"] == 0
         return
     dispatches = [s["attrs"] for st in run["steps"] for s in walk(st)
@@ -166,31 +228,66 @@ def test_each_buckets_module_carries_its_name(B, S, full, name):
     assert f"module @jit_{name} " in lowered.as_text()
 
 
+class Stalling(ToyAdapter):
+    """A toy adapter whose ``at``-th decode first runs ``stall``."""
+
+    def __init__(self, stall, at=2):
+        super().__init__()
+        self.stall, self.at, self.calls = stall, at, 0
+        self.entered = threading.Event()
+
+    def decode(self, seqs):
+        self.calls += 1
+        if self.calls == self.at:
+            self.entered.set()
+            self.stall()
+        return super().decode(seqs)
+
+
 @pytest.mark.parametrize("kind", ["toy", "flax"])
 def test_tracing_off_leaves_the_logs_empty_and_the_tokens_equal(
         kind, monkeypatch):
     def once():
-        engine = LLMEngine(make_adapter(kind), EngineConfig(**ENGINE))
+        # the toy's second decode step takes 1.1 s: a slow step
+        adapter = Stalling(lambda: time.sleep(1.1)) if kind == "toy" \
+            else make_adapter(kind)
+        engine = LLMEngine(adapter, EngineConfig(**ENGINE))
+        ring = len(tracing.step_roots())
         try:
-            return (serve(engine, PROMPTS), engine.metrics(),
-                    engine.step_log(), engine.request_log())
+            served = serve(engine, PROMPTS)
+            gc.collect()                # on a thread with no span open
+            return (served, engine.metrics(), engine.step_log(),
+                    engine.request_log(), engine.slow_steps(),
+                    len(tracing.step_roots()) - ring)
         finally:
             engine.stop()
 
-    served_on, _, steps_on, requests_on = once()
+    served_on, _, steps_on, requests_on, slow_on, ring_on = once()
     monkeypatch.setenv("RTPU_TRACING", "0")
     tracing.refresh()
     try:
-        served_off, metrics, steps_off, requests_off = once()
+        before = tracing.process_counters()
+        served_off, metrics, steps_off, requests_off, slow_off, ring_off \
+            = once()
     finally:
         monkeypatch.undo()
         tracing.refresh()
-    assert steps_on and len(requests_on) == len(PROMPTS)
-    assert steps_off == [] and requests_off == []
+    assert steps_on and len(requests_on) == len(PROMPTS) and ring_on > 0
+    # (a flax step that compiles may be a slow one too, whatever else the
+    # machine does meanwhile)
+    from ray_tpu.serve.llm.step_watch import VERDICTS
+    assert len(slow_on) == 1 if kind == "toy" else all(
+        r["verdict"] in VERDICTS for r in slow_on)
+    assert steps_off == [] and requests_off == [] and slow_off == []
+    assert ring_off == 0
     assert served_off == served_on
     # the counters are counted whatever the switch says
     assert metrics["steps_total"] > 0
     assert metrics["prefill_seqs_total"] == len(PROMPTS)
+    assert metrics["slow_steps_total"] >= (kind == "toy")
+    assert metrics["gc_collections_total"] > before["gc_collections_total"]
+    assert metrics["gc_seconds_total"] > before["gc_seconds_total"]
+    assert metrics["lock_wait_seconds_total"] >= 0.0
 
 
 def test_a_profiler_capture_holds_the_step_spans(tmp_path):
@@ -243,7 +340,6 @@ def test_a_profiler_capture_holds_the_step_spans(tmp_path):
 # ------------------------------------------------------------ step_span
 
 def test_step_span_nests_by_thread_and_fills_the_ring():
-    from collections import deque
     ring = deque(maxlen=2)
     other = []
 
@@ -282,6 +378,186 @@ def test_step_span_without_a_ring_lands_in_the_modules(monkeypatch):
     finally:
         monkeypatch.undo()
         tracing.refresh()
+
+
+def test_step_event_hangs_under_the_open_span_or_in_the_modules_ring():
+    ring = deque()
+    with tracing.step_span("root", ring):
+        time.sleep(0.05)
+        tracing.step_event("test.event", 0.02, k=1)
+    (event,) = ring[0]["children"]
+    assert (event["name"], event["attrs"], event["children"]) == (
+        "test.event", {"k": 1}, [])
+    assert event["t1"] - event["t0"] == pytest.approx(0.02)
+    assert ring[0]["t0"] <= event["t0"] <= event["t1"] <= ring[0]["t1"]
+    # one that claims to be older than the open span starts with it
+    with tracing.step_span("root", ring):
+        tracing.step_event("test.event", 5.0)
+    assert ring[1]["children"][0]["t0"] == ring[1]["t0"]
+
+    def elsewhere():        # no span open on this thread
+        tracing.step_event("test.event", 0.5, k=2)
+
+    t = threading.Thread(target=elsewhere, name="test-elsewhere")
+    t.start()
+    t.join(timeout=10.0)
+    last = tracing.step_roots("test.event")[-1]
+    assert last["attrs"] == {"k": 2, "thread": "test-elsewhere"}
+    assert last["t1"] - last["t0"] == pytest.approx(0.5)
+    assert tracing.step_roots("test.event", "no.such")[-1] is last
+
+
+def test_a_collection_inside_a_span_is_its_child_and_is_counted():
+    tracing.watch_process()
+    tracing.watch_process()                     # idempotent
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    before = tracing.process_counters()
+    ring = deque()
+    with tracing.step_span("root", ring):
+        with tracing.step_span("child"):
+            gc.collect()
+    (child,) = spans_of(ring[0])[0]
+    pauses = [e for e in child["children"] if e["name"] == "py.gc"]
+    assert pauses and pauses[-1]["attrs"]["generation"] == 2
+    assert pauses[-1]["attrs"]["collected"] >= 0
+    assert child["t0"] <= pauses[-1]["t0"] < pauses[-1]["t1"] <= child["t1"]
+    after = tracing.process_counters()
+    assert after["gc_collections_total"] > before["gc_collections_total"]
+    assert after["gc_seconds_total"] > before["gc_seconds_total"]
+
+
+def test_a_first_call_compiles_under_the_span_that_made_it():
+    import jax
+    import jax.numpy as jnp
+    tracing.watch_process()
+    fn = jax.jit(lambda x: jnp.tanh(x * 3.0 + 39.0))
+    x = jnp.ones((3, 5))
+    x.block_until_ready()
+    before = tracing.process_counters()
+    ring = deque()
+    for _ in range(2):
+        with tracing.step_span("llm.step", ring):
+            with tracing.step_span("runner.dispatch", B=3, S=5):
+                fn(x).block_until_ready()
+    first, second = (
+        [e for e in step["children"][0]["children"] if e["name"] != "py.gc"]
+        for step in ring)
+    assert [e["name"] for e in first] == ["jax.compile"] and second == []
+    dispatch = ring[0]["children"][0]
+    assert dispatch["t0"] <= first[0]["t0"] < first[0]["t1"] \
+        <= dispatch["t1"]
+    after = tracing.process_counters()
+    assert after["compiles_total"] == before["compiles_total"] + 1
+    assert after["compile_seconds_total"] > before["compile_seconds_total"]
+
+
+# ----------------------------------------------------------- a slow step
+
+def slow_run(stall, beside=None):
+    """Two requests through an engine whose second decode step runs
+    ``stall``; ``beside(engine, adapter)`` meanwhile on a thread of its
+    own. -> the engine's slow-step records and its metrics."""
+    adapter = Stalling(stall)
+    engine = LLMEngine(adapter, EngineConfig(**ENGINE))
+    try:
+        worker = None
+        if beside is not None:
+            worker = threading.Thread(target=beside, args=(engine, adapter),
+                                      name="test-beside")
+            worker.start()
+        served = serve(engine, PROMPTS[:2], new_tokens=6)
+        if worker is not None:
+            worker.join(timeout=60.0)
+            assert not worker.is_alive()
+        assert list(map(len, served)) == [6, 6]
+        return engine.slow_steps(), engine.metrics()
+    finally:
+        engine.stop()
+
+
+def engine_frames(record):
+    return record["stacks"][record["engine_thread"]]
+
+
+def test_a_step_the_adapter_held_says_device_or_runtime(caplog):
+    with caplog.at_level(logging.WARNING,
+                         logger="ray_tpu.serve.llm.step_watch"):
+        records, metrics = slow_run(lambda: time.sleep(1.2))
+    (rec,) = records
+    assert metrics["slow_steps_total"] == 1
+    assert rec["verdict"] == "device or runtime", rec["why"]
+    assert set(rec) >= {"i", "t0", "t1", "cpu_ms", "tree", "stacks",
+                        "stacks_at", "watch_late_ms", "events", "verdict"}
+    assert rec["t1"] - rec["t0"] >= 1.2 and rec["cpu_ms"] < 300.0
+    assert rec["tree"]["name"] == "llm.step" \
+        and rec["tree"]["attrs"]["i"] == rec["i"]
+    assert rec["t0"] + 1.0 <= rec["stacks_at"] <= rec["t1"]
+    # the engine thread's stack names the adapter, innermost first
+    frames = engine_frames(rec)
+    assert len(frames) <= 12
+    assert any(f.split(":")[0] == __file__ and f.endswith(" decode")
+               for f in frames)
+    assert rec["watch_late_ms"] < 600.0
+    (line,) = [r.getMessage() for r in caplog.records
+               if "llm.step" in r.getMessage()]
+    assert f"llm.step {rec['i']} took" in line \
+        and "device or runtime" in line
+
+
+def test_a_step_that_waited_for_the_interpreter_says_who_held_it():
+    # one C call that keeps the interpreter for ~1.8 s: sized by the
+    # fastest of three samples (a slow sample would size it too short)
+    def sample():
+        t0 = time.perf_counter()
+        sum(range(3_000_000))
+        return time.perf_counter() - t0
+
+    n = int(3_000_000 * 1.8 / min(sample() for _ in range(3)))
+
+    def hog(engine, adapter):
+        assert adapter.entered.wait(timeout=60.0)
+        sum(range(n))
+
+    records, _ = slow_run(lambda: time.sleep(0.05), beside=hog)
+    (rec,) = records
+    assert rec["verdict"] == "interpreter held", rec["why"]
+    assert rec["watch_late_ms"] > 500.0 * (rec["t1"] - rec["t0"])
+    assert rec["cpu_ms"] < 300.0
+    assert "test-beside" in rec["why"] or "moved on" in rec["why"]
+
+
+def test_a_step_that_waited_for_the_engine_lock_says_lock():
+    def hold(engine, adapter):
+        assert adapter.entered.wait(timeout=60.0)
+        with engine._lock:              # as a caller's thread would
+            time.sleep(1.2)
+
+    records, metrics = slow_run(lambda: time.sleep(0.05), beside=hold)
+    (rec,) = records
+    assert rec["verdict"] == "lock", rec["why"]
+    waited = sum(s["attrs"].get("lock_wait_ms", 0.0)
+                 for s in walk(rec["tree"]))
+    assert waited > 1000.0
+    assert metrics["lock_wait_seconds_total"] > 1.0
+
+
+def test_a_step_the_collector_held_says_gc():
+    graph = [[i] for i in range(1_500_000)]     # what a collection walks
+
+    def collect():
+        end = time.monotonic() + 1.2
+        while time.monotonic() < end:
+            gc.collect()
+
+    try:
+        records, _ = slow_run(collect)
+    finally:
+        del graph
+    (rec,) = records
+    assert rec["verdict"] == "gc", rec["why"]
+    pauses = [s for s in walk(rec["tree"]) if s["name"] == "py.gc"]
+    assert sum(ms(p) for p in pauses) > 600.0
+    assert all(p["attrs"]["generation"] == 2 for p in pauses)
 
 
 def test_the_feed_records_its_batches():
