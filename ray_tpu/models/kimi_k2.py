@@ -123,6 +123,9 @@ def cache_spec(cfg: KimiK2Config) -> Dict[str, Any]:
     return {
         "expert_counts": (max(cfg.num_hidden_layers
                               - cfg.first_k_dense_replace, 0), held),
+        # ``moe.expert_product``'s arguments beside a step's tokens
+        "routed_experts": (cfg.num_experts_per_tok, cfg.n_routed_experts,
+                           held, cfg.hidden_size, jnp.dtype(cfg.dtype).itemsize),
         "pages": {"kv_pages": {
             "layers": cfg.num_hidden_layers,
             "row": lanes(cfg.kv_lora_rank + cfg.qk_rope_head_dim),

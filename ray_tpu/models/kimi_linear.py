@@ -136,6 +136,9 @@ def cache_spec(cfg: KimiLinearConfig) -> Dict[str, Any]:
         # the step's per-expert token counts: [routed layers, held]
         "expert_counts": (max(len(kinds) - cfg.first_k_dense_replace, 0),
                           held),
+        # ``moe.expert_product``'s arguments beside a step's tokens
+        "routed_experts": (cfg.num_experts_per_token, cfg.num_experts,
+                           held, cfg.hidden_size, jnp.dtype(cfg.dtype).itemsize),
         "pages": {"kv_pages": {
             "layers": kinds.count("mla"),
             "row": _lanes(cfg.kv_lora_rank + cfg.qk_rope_head_dim),

@@ -224,6 +224,9 @@ def cache_spec(cfg: LagunaConfig) -> Dict[str, Any]:
     return {
         "expert_counts": (sum(t == "sparse" for t in cfg.mlp_layer_types),
                           cfg.num_experts),
+        # ``moe.expert_product``'s arguments beside a step's tokens
+        "routed_experts": (cfg.num_experts_per_tok, cfg.num_experts,
+                           cfg.num_experts, cfg.hidden_size, jnp.dtype(cfg.dtype).itemsize),
         "pages": pages,
         "state": {},
     }

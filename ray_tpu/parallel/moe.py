@@ -22,19 +22,21 @@ scores over all experts, top-k, renormalised, no capacity and no drop,
 SwiGLU experts, computed for the experts one chip holds (docs/
 LLM_SERVING.md, "Routed experts"). Its product multiplies the groups
 that hold a token and reads no other expert's weights: whole rows
-through the touched experts for few tokens (``ops/routed_experts.py``),
-sorted row blocks for many.
+through the touched experts for few tokens, sorted row blocks for many
+(both kernels: ``ops/routed_experts.py``).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from ray_tpu.ops.routed_experts import touched_experts
+from ray_tpu.ops.routed_experts import ROW_TILE, grouped_experts, \
+    touched_experts
 
 
 class MoE(nn.Module):
@@ -122,21 +124,63 @@ class SwiGLU(nn.Module):
 
 
 # RoutedExperts: the most tokens that go as whole rows through the touched
-# experts, and the rows of a sorted product's block. Up to WHOLE_ROWS_BELOW
-# an expert's weights (read once) outweigh the rows it did not get; above,
-# rows are worth sorting. What is served lies far to either side (a decode
-# step has at most 64 tokens, a prefill at least 512), so the two were set
-# by that and not by a sweep: nothing between was measured.
+# experts. Up to WHOLE_ROWS_BELOW an expert's weights (read once) outweigh
+# the rows it did not get; above, rows are worth sorting. What is served
+# lies far to either side (a decode step has at most 64 tokens, a prefill
+# at least 512), so it was set by that and not by a sweep: nothing between
+# was measured.
 WHOLE_ROWS_BELOW = 256
-BLOCK_ROWS = 256
-# The sorted product holds its rows for the worst case (every assignment
-# landing on the experts held here) in float32: [T * k + E * BLOCK_ROWS,
-# d]. Up to this many bytes of them it holds them (4,096 tokens at
-# Kimi-Linear's width: 453 MB). Above (8,192 tokens at Kimi-K2's width:
-# 1.97 GB of rows beside 0.98 of their inputs, refused by the compiler
-# for the described v5e, PR 35) it holds index arrays only and moves a
-# block's rows inside the loop: ``_experts_by_block``.
-SORTED_ROWS_BYTES = 640 << 20
+# The grouped product's sorted rows (bfloat16 in, float32 out) for the
+# worst case (every assignment landing on the experts held here) are held
+# whole up to ROWS_BYTES: one gather, one kernel call, and each token
+# gathers its results back (a Laguna prompt: 1.5 GiB of rows beside 12.1
+# GiB of weights and pools; tests/test_chip_compile.py holds that it
+# fits). Above (Kimi-K2's prompt: 2.75 GiB, refused by the compiler) a loop
+# over the live chunks of CHUNK_BYTES of them holds a chunk at a time and
+# adds its results to their tokens' rows: that scatter-add costs a Laguna
+# layer 5.5 ms where the gather back costs 2.7, and a Kimi-K2 layer more
+# the larger the chunk (PERF.md, PR 40: 2-4 blocks of 256 rows read best
+# there). Index arrays alone always have the worst case's length.
+ROWS_BYTES = 2 << 30
+CHUNK_BYTES = 32 << 20
+
+
+class ExpertProduct(NamedTuple):
+    """Which product ``RoutedExperts`` runs for ``T`` tokens, and the
+    rows of its blocks: what ``expert_product`` chose from the shapes."""
+    name: str           # "touched_kernel" | "grouped_kernel"
+    block_rows: int     # touched: all the (padded) rows; grouped: a block's
+    chunk_rows: int     # grouped: sorted rows held at a time
+
+    def rows_multiplied(self, counts) -> int:
+        """The rows that went through an expert's three products, from
+        the per-expert token counts [..., experts] of a call: the rows of
+        the live blocks (whole rows for every touched expert)."""
+        counts = np.asarray(counts)
+        if self.name == "touched_kernel":
+            return int((counts > 0).sum()) * self.block_rows
+        return int((-(-counts // self.block_rows)).sum()) * self.block_rows
+
+
+def expert_product(T: int, top_k: int, num_experts: int, held: int, d: int,
+                   itemsize: int = 2) -> ExpertProduct:
+    """The product for ``T`` tokens of width ``d``, each sent to
+    ``top_k`` of ``num_experts``, ``held`` of them here. Above
+    ``WHOLE_ROWS_BELOW`` tokens the block is 128 rows where an expert
+    expects no more (``T * top_k / num_experts``), else 256 (the kernel
+    moves bytes, not FLOPs: a block's rows and its expert's weights in 16
+    us at 256 rows a block and 6.3 MB an expert, two blocks of 128 in 21,
+    a block of 512 in 25 while it pads twice as much: PERF.md, PR 40);
+    the rows held at a time are the worst case's where they fit
+    ``ROWS_BYTES``, else the whole blocks that fit ``CHUNK_BYTES``."""
+    if T <= WHOLE_ROWS_BELOW:
+        rows = -(-T // ROW_TILE) * ROW_TILE
+        return ExpertProduct("touched_kernel", rows, rows)
+    bm = 128 if T * top_k <= 128 * num_experts else 256
+    rows = _worst_rows(T * top_k, held, bm)
+    if rows * d * (itemsize + 4) > ROWS_BYTES:
+        rows = max(1, CHUNK_BYTES // (bm * d * (itemsize + 4))) * bm
+    return ExpertProduct("grouped_kernel", bm, rows)
 
 
 class RoutedExperts(nn.Module):
@@ -155,17 +199,17 @@ class RoutedExperts(nn.Module):
     expert this call.
 
     One algorithm, "multiply the groups that hold a token", in two
-    forms by the number of tokens (static). Up to ``WHOLE_ROWS_BELOW``
-    tokens (a decode step) each expert that got a token multiplies all
-    the rows, the rows that did not choose it weighed zero, and an expert
-    without a token is not read at all: what such a step costs is the
-    weights it reads, so its time follows ``counts > 0`` (the step's
-    ``experts_touched``) and its result does not. Forward only
-    (``ops/routed_experts.py``). Above it the assignments are sorted by
-    expert into row blocks of ``BLOCK_ROWS`` that each belong to one
-    expert, and a loop multiplies the blocks that hold a token (rows for
-    the worst case, every assignment landing here, so the load changes
-    the time and never the result).
+    forms by the number of tokens (static; ``expert_product`` chooses).
+    Up to ``WHOLE_ROWS_BELOW`` tokens (a decode step) each expert that
+    got a token multiplies all the rows, the rows that did not choose it
+    weighed zero, and an expert without a token is not read at all: what
+    such a step costs is the weights it reads, so its time follows
+    ``counts > 0`` (the step's ``experts_touched``) and its result does
+    not. Above it the assignments are sorted by expert into row blocks
+    that each belong to one expert, and one kernel multiplies the blocks
+    that hold a token (index arrays for the worst case, every assignment
+    landing here, so the load changes the time and never the result).
+    Both forward only (``ops/routed_experts.py``).
     """
     num_experts: int
     d_ff: int
@@ -202,9 +246,6 @@ class RoutedExperts(nn.Module):
             # the held experts are 0..count-1 here; `count` means "not
             # this chip's"
             local = jnp.where(here, chosen - first, count)
-            counts = jnp.sum(jax.nn.one_hot(local, count + 1,
-                                            dtype=jnp.int32),
-                             axis=(0, 1))[:count]
 
         init = nn.initializers.normal(0.02)
         w_gate = self.param("w_gate", init, (count, d, self.d_ff),
@@ -213,18 +254,21 @@ class RoutedExperts(nn.Module):
         w_down = self.param("w_down", init, (count, self.d_ff, d),
                             self.dtype)
         xb = xf.astype(self.dtype)
+        plan = expert_product(T, self.top_k, self.num_experts, count, d,
+                              jnp.dtype(self.dtype).itemsize)
         with jax.named_scope("moe/experts"):
-            if T <= WHOLE_ROWS_BELOW:
+            if plan.name == "touched_kernel":
+                counts = jnp.sum(jax.nn.one_hot(local, count + 1,
+                                                dtype=jnp.int32),
+                                 axis=(0, 1))[:count]
                 combine = jnp.sum(
                     jax.nn.one_hot(local, count, dtype=jnp.float32)
                     * w[..., None], axis=1)                    # [T, count]
                 y = touched_experts(xb, combine, counts, w_gate, w_up,
                                     w_down)
             else:
-                rows = T * self.top_k + count * BLOCK_ROWS
-                product = _experts_by_block \
-                    if rows * d * 4 > SORTED_ROWS_BYTES else _experts_sorted
-                y = product(xb, local, w, w_gate, w_up, w_down)
+                y, counts = _experts_grouped(xb, local, w, w_gate, w_up,
+                                             w_down, plan)
         if self.shared_d_ff:
             with jax.named_scope("moe/shared"):
                 y = y + SwiGLU(self.shared_d_ff, self.dtype,
@@ -232,91 +276,113 @@ class RoutedExperts(nn.Module):
         return y.reshape(*lead, d), counts
 
 
-def _sorted_rows(local, E: int):
-    """The assignments sorted by expert, each expert's group padded to
-    whole blocks of ``BLOCK_ROWS`` rows: ``order`` (the assignments in
-    sorted order), ``dest`` (each one's row; ``rows`` for an assignment
-    that is not this chip's), ``rows`` (rows for the worst case), and
-    for each block where it starts, which expert's it is, and ``ends``
-    (where each expert's group ends: the last is the live rows)."""
-    A, bm = local.size, BLOCK_ROWS
-    e = local.reshape(A)                                   # E: not here
-    sizes = jnp.sum(jax.nn.one_hot(e, E + 1, dtype=jnp.int32), axis=0)
-    padded = -(-sizes[:E] // bm) * bm
-    ends = jnp.cumsum(padded)                              # [E]
-    order = jnp.argsort(e, stable=True)
-    sorted_e = e[order]
-    rank = jnp.arange(A) - (jnp.cumsum(sizes) - sizes)[sorted_e]
-    rows = -(-A // bm) * bm + E * bm                       # worst case
-    dest = jnp.where(sorted_e < E,
-                     (ends - padded)[jnp.minimum(sorted_e, E - 1)] + rank,
-                     rows)
-    return e, order, dest, rows, ends
+def _worst_rows(A: int, E: int, bm: int) -> int:
+    """The rows ``A`` assignments take when every one lands on the ``E``
+    experts held here, each group padded to whole blocks of ``bm``."""
+    return (-(-A // bm) + E) * bm
 
 
-def _experts_sorted(x, local, w, w_gate, w_up, w_down):
-    """Grouped products: the assignments sorted by expert, each expert's
-    group padded to whole blocks of ``BLOCK_ROWS`` rows, one SwiGLU a
-    block with that block's expert, blocks without a token skipped."""
-    T, d = x.shape
-    E, k, bm = w_gate.shape[0], local.shape[1], BLOCK_ROWS
+def _sorted_rows(local, w, E: int, bm: int):
+    """The assignments sorted by expert (``local`` is ``E`` for one that
+    is not this chip's), each expert's group padded to whole blocks of
+    ``bm`` rows. For each of the worst case's ``rows`` rows its token
+    (``T`` for a group's padding and past the live rows) and, where ``w``
+    is given, its weight; for each block its expert; the live blocks'
+    count; ``sizes`` (the assignments each expert got); and ``order``
+    (the assignments in sorted order) with ``dest`` (each one's row;
+    ``rows`` for one that is not this chip's). One sort (it carries the
+    weights), arithmetic over the sorted keys and ONE scatter: a scalar
+    gather or scatter of ``T * k`` elements, or a one-hot count of them,
+    costs the chip as much as the sort (PERF.md, PR 40)."""
+    T, k = local.shape
     A = T * k
-    e, order, dest, rows, ends = _sorted_rows(local, E)
-    xs = jnp.zeros((rows, d), x.dtype).at[dest].set(
-        x[order // k], mode="drop")
-    n_blocks = rows // bm
-    starts = jnp.arange(n_blocks) * bm
-    block_expert = jnp.minimum(
-        jnp.searchsorted(ends, starts, side="right"), E - 1)
-
-    def block(_, b):
-        def run():
-            xb = jax.lax.dynamic_slice_in_dim(xs, b * bm, bm)
-            i = block_expert[b]
-            return jnp.matmul(nn.silu(xb @ w_gate[i]) * (xb @ w_up[i]),
-                              w_down[i], preferred_element_type=jnp.float32)
-        return None, jax.lax.cond(
-            starts[b] < ends[-1], run,
-            lambda: jnp.zeros((bm, d), jnp.float32))
-
-    _, ys = jax.lax.scan(block, None, jnp.arange(n_blocks))
-    ys = ys.reshape(rows, d)
-    back = jnp.zeros((A,), jnp.int32).at[order].set(
-        jnp.minimum(dest, rows - 1).astype(jnp.int32))
-    weight = jnp.where(e < E, w.reshape(A), 0.0)
-    y = ys[back] * weight[:, None]
-    return jnp.sum(y.reshape(T, k, d), axis=1)
-
-
-def _experts_by_block(x, local, w, w_gate, w_up, w_down):
-    """The same grouped products with nothing of the worst case's size
-    but index arrays: which token and what weight each sorted row has.
-    A loop over the LIVE blocks gathers a block's rows from ``x``,
-    multiplies them by the block's expert and adds the weighed result to
-    its tokens' rows of the output, so what moves is the assignments
-    that landed here (12 experts of 384 get a 32nd of them) and not all
-    ``T * k``."""
-    T, d = x.shape
-    E, k, bm = w_gate.shape[0], local.shape[1], BLOCK_ROWS
-    _, order, dest, rows, ends = _sorted_rows(local, E)
-    token = jnp.full((rows,), T, jnp.int32).at[dest].set(
-        (order // k).astype(jnp.int32), mode="drop")       # T: no token
-    weight = jnp.zeros((rows,), jnp.float32).at[dest].set(
-        w.reshape(T * k)[order], mode="drop")
+    keys = (local.reshape(A), jnp.arange(A, dtype=jnp.int32))
+    sorted_e, order, *sorted_w = jax.lax.sort(
+        keys if w is None else keys + (w.reshape(A).astype(jnp.float32),),
+        num_keys=1, is_stable=True)
+    first = jnp.searchsorted(sorted_e, jnp.arange(E + 1)).astype(jnp.int32)
+    sizes = first[1:] - first[:-1]
+    padded = -(-sizes // bm) * bm
+    ends = jnp.cumsum(padded)
+    shift = ends - padded - first[:-1]      # padding before a group
+    rows = _worst_rows(A, E, bm)
+    dest = jnp.where(
+        sorted_e < E,
+        jnp.arange(A) + jnp.sum(jnp.where(
+            sorted_e[:, None] == jnp.arange(E)[None, :], shift[None, :], 0),
+            axis=1), rows)
+    # a row's token (T: none) and the bits of its weight (zero), placed
+    # together
+    columns = [order // k] + [jax.lax.bitcast_convert_type(v, jnp.int32)
+                              for v in sorted_w]
+    placed = jnp.broadcast_to(
+        jnp.array([T, 0][:len(columns)], jnp.int32),
+        (rows, len(columns))).at[dest].set(jnp.stack(columns, axis=1),
+                                           mode="drop")
+    token = placed[:, 0]
+    weight = jax.lax.bitcast_convert_type(placed[:, 1], jnp.float32) \
+        if sorted_w else None
     block_expert = jnp.minimum(jnp.searchsorted(
-        ends, jnp.arange(rows // bm) * bm, side="right"), E - 1)
+        ends, jnp.arange(rows // bm) * bm, side="right"),
+        E - 1).astype(jnp.int32)
+    return token, weight, block_expert, (ends[-1] // bm).astype(jnp.int32), \
+        sizes, order, dest
 
-    def block(b, y):
-        tok = jax.lax.dynamic_slice_in_dim(token, b * bm, bm)
-        xb = x[jnp.minimum(tok, T - 1)]
-        i = block_expert[b]
-        out = jnp.matmul(nn.silu(xb @ w_gate[i]) * (xb @ w_up[i]),
-                         w_down[i], preferred_element_type=jnp.float32)
-        out = out * jax.lax.dynamic_slice_in_dim(weight, b * bm, bm)[:, None]
-        return y.at[tok].add(out, mode="drop")
 
-    return jax.lax.fori_loop(0, ends[-1] // bm, block,
-                             jnp.zeros((T, d), jnp.float32))
+def _experts_grouped(x, local, w, w_gate, w_up, w_down, plan):
+    """Grouped products: the assignments sorted by expert, each expert's
+    group padded to whole blocks of ``plan.block_rows`` rows, and
+    ``grouped_experts`` (one kernel) over the blocks that hold a token.
+    Index arrays have the worst case's length (every assignment landing
+    here); rows are held ``plan.chunk_rows`` at a time. Where that is
+    the worst case's rows, they are gathered once, multiplied once, and
+    each token gathers its ``k`` results back and weighs them. Where it
+    is not (an 8,192-token prompt at Kimi-K2's width), a loop over the
+    LIVE chunks gathers a chunk's rows, multiplies them and adds the
+    weighed results to their tokens' rows, so what is held is a chunk's
+    and what moves is the assignments that landed here. Returns ``(y,
+    sizes)``, sizes [E] the assignments each held expert got."""
+    T, d = x.shape
+    E, k = w_gate.shape[0], local.shape[1]
+    A = T * k
+    bm, C = plan.block_rows, plan.chunk_rows
+    rows = _worst_rows(A, E, bm)
+
+    if C >= rows:
+        token, _, block_expert, live, sizes, order, dest = _sorted_rows(
+            local, None, E, bm)
+        ys = grouped_experts(x[jnp.minimum(token, T - 1)],
+                             (token < T).astype(jnp.float32), block_expert,
+                             live.reshape(1), w_gate, w_up, w_down, bm)
+        # each assignment's row (0 for one that is not this chip's); the
+        # rows of a block past the live ones were NOT WRITTEN
+        back = jnp.zeros((A,), jnp.int32).at[order].set(
+            jnp.where(dest < rows, dest, 0).astype(jnp.int32))
+        here = local.reshape(A) < E
+        y = jnp.where(here[:, None], ys[back] * w.reshape(A, 1), 0.0)
+        return jnp.sum(y.reshape(T, k, d), axis=1), sizes
+
+    token, weight, block_expert, live, sizes, _, _ = _sorted_rows(
+        local, w, E, bm)
+    pad = -rows % C
+    token = jnp.pad(token, (0, pad), constant_values=T)
+    weight = jnp.pad(weight, (0, pad))
+    block_expert = jnp.pad(block_expert, (0, pad // bm))
+
+    def chunk(c, y):
+        tok = jax.lax.dynamic_slice_in_dim(token, c * C, C)
+        ys = grouped_experts(
+            x[jnp.minimum(tok, T - 1)],
+            jax.lax.dynamic_slice_in_dim(weight, c * C, C),
+            jax.lax.dynamic_slice_in_dim(block_expert, c * (C // bm),
+                                         C // bm),
+            (live - c * (C // bm)).reshape(1), w_gate, w_up, w_down, bm)
+        # the rows of a block past the live ones were NOT WRITTEN and
+        # have no token: dropped
+        return y.at[tok].add(ys, mode="drop")
+
+    return jax.lax.fori_loop(0, -(-live * bm // C), chunk,
+                             jnp.zeros((T, d), jnp.float32)), sizes
 
 
 def expert_sharding_rule(mesh, path: Tuple[str, ...], shape, spec):

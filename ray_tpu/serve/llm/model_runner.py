@@ -420,6 +420,7 @@ class FlaxModelAdapter:
             params = self.model.init(jax.random.PRNGKey(seed), dummy)
         self.params = params
         self._expert_tokens_total = self._expert_tokens_last = None
+        self._expert_products: Dict[int, Any] = {}
         self._kv_pages_live = self._kv_pages_padded = 0
         self._window_pages_live = self._window_pages_padded = 0
         self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
@@ -776,8 +777,13 @@ class FlaxModelAdapter:
                     valid[i, :n] = True
                     t = r["table"][:self.nb_max]
                     tables[i, :len(t)] = t
+        # which product the routed experts of this many rows will run:
+        # the layer's own chooser, asked with the same shapes
+        product = self._expert_product(B * S)
         with tracing.step_span("runner.dispatch", B=B, S=S,
                                first_call=(B, S, full) not in self._fns,
+                               **({"expert_product": product.name}
+                                  if product and op != "decode" else {}),
                                **(self._count_pages(rows, B)
                                   if op == "decode" else
                                   {"prompt_tokens": sum(len(r["tokens"])
@@ -825,7 +831,7 @@ class FlaxModelAdapter:
                         else np.asarray(logits, np.float32)[at]
                     if counts.size > B:
                         span.set(**self._count_experts(counts[B:].reshape(
-                            self._spec["expert_counts"])))
+                            self._spec["expert_counts"]), product))
                 span.set(bytes=out.nbytes)
             return out
         return at, fetch, (logits if self._spec is None else small)
@@ -877,11 +883,23 @@ class FlaxModelAdapter:
                        kv_window_pages_padded=B * ring)
         return out
 
-    def _count_experts(self, counts: np.ndarray) -> Dict[str, Any]:
+    def _expert_product(self, T: int):
+        """``moe.expert_product`` for a step of ``T`` rows (None: the
+        model has no routed experts)."""
+        shapes = (self._spec or {}).get("routed_experts")
+        if shapes is None:
+            return None
+        if T not in self._expert_products:      # a bucket's first call
+            from ray_tpu.parallel.moe import expert_product
+            self._expert_products[T] = expert_product(T, *shapes)
+        return self._expert_products[T]
+
+    def _count_experts(self, counts: np.ndarray, product) -> Dict[str, Any]:
         """counts [routed layers, experts held]: the step's tokens per
         expert. Kept cumulatively for ``counters()``; the step span gets
-        how many experts the step touched and how uneven the load was
-        (largest over mean, the median of the layers)."""
+        how many experts the step touched, how uneven the load was
+        (largest over mean, the median of the layers) and the rows that
+        went through an expert (the live blocks' of ``product``)."""
         if self._expert_tokens_total is None:
             self._expert_tokens_total = np.zeros(counts.shape, np.int64)
         self._expert_tokens_total += counts
@@ -889,6 +907,7 @@ class FlaxModelAdapter:
         mean = counts.mean(axis=1)
         return {"experts_touched": int((counts > 0).sum()),
                 "expert_tokens": int(counts.sum()),
+                "expert_rows_multiplied": product.rows_multiplied(counts),
                 "moe_max_over_mean": float(np.median(
                     counts.max(axis=1) / np.maximum(mean, 1e-9)))}
 

@@ -258,30 +258,59 @@ def test_the_32_shares_add_up_to_the_uncut_layer():
                                atol=TOL)
 
 
-def test_the_product_by_block_equals_the_sorted_one(monkeypatch):
-    """2,048 tokens whose worst-case rows pass the budget hold index
-    arrays only and move a block's rows inside a loop over the live
-    blocks; under the default budget the rows are held. Both give what
-    the reference's dense loop gives, padding tokens left out."""
+@pytest.mark.parametrize("chunk_bytes", [None, 100_000, 1],
+                         ids=["whole", "chunks_of_2_blocks",
+                              "chunks_of_1_block"])
+def test_the_grouped_product_a_chunk_at_a_time_equals_the_reference(
+        monkeypatch, chunk_bytes):
+    """2,048 tokens, 4 of 16 experts held: whether the sorted rows are
+    held whole (the default budget holds these: one gather, one kernel
+    call, each token gathers its results back) or a chunk of two blocks
+    or of one at a time (the loop over the live chunks that an 8,192-token
+    prompt at Kimi-K2's width takes: the budget set under these rows),
+    the layer gives what the reference's dense loop gives, padding
+    tokens left out, and the kernel is traced once a layer whatever the
+    chunks."""
     x = _rows(np.random.default_rng(6), 2048, 24)
     layer = _layer((0, 4), experts=16, top_k=4, d_ff=32)
     params = layer.init(jax.random.PRNGKey(0), x)
     valid = jnp.arange(2048) < 2000
     want = _ref_layer(params["params"], x, (0, 4), top_k=4)
-    used = []
-    for name in ("_experts_sorted", "_experts_by_block"):
-        fn = getattr(moe, name)
-        monkeypatch.setattr(moe, name, lambda *a, fn=fn, name=name:
-                            used.append(name) or fn(*a))
+    worst = 2048 * 4 + 4 * 256
+    if chunk_bytes:
+        monkeypatch.setattr(moe, "ROWS_BYTES", worst * 24 * 8 - 1)
+        monkeypatch.setattr(moe, "CHUNK_BYTES", chunk_bytes)
+    plan = moe.expert_product(2048, 4, 16, 4, 24, 4)
+    assert plan == ("grouped_kernel", 256,
+                    {None: worst, 100_000: 512, 1: 256}[chunk_bytes])
+    calls = []
+    fn = moe.grouped_experts
+    monkeypatch.setattr(moe, "grouped_experts",
+                        lambda *a: calls.append(a[0].shape) or fn(*a))
     y, counts = layer.apply(params, x, valid=valid)
-    monkeypatch.setattr(moe, "SORTED_ROWS_BYTES", 1 << 16)
-    y_by_block, _ = layer.apply(params, x, valid=valid)
-    assert used == ["_experts_sorted", "_experts_by_block"]
-    for got in (y, y_by_block):
-        np.testing.assert_allclose(got[:2000], want[:2000], atol=TOL)
-    assert int(counts.sum()) > 0
-    # which of the two the cells' shapes take
-    rows = lambda T, k, E, d: (T * k + E * 256) * d * 4     # noqa: E731
-    monkeypatch.undo()
-    assert rows(8192, 8, 12, 7168) > moe.SORTED_ROWS_BYTES      # Kimi-K2's
-    assert rows(4096, 8, 64, 2304) < moe.SORTED_ROWS_BYTES      # Kimi-Linear's
+    # traced once: the chunks are a loop's steps, not unrolled Python
+    assert calls == [(plan.chunk_rows, 24)]
+    np.testing.assert_allclose(y[:2000], want[:2000], atol=TOL)
+    assert int(counts.sum()) > 256 * 4      # more live rows than a chunk's
+    assert plan.rows_multiplied(counts) >= int(counts.sum())
+
+
+def test_the_rows_held_at_a_time_at_the_cells_shapes():
+    """What the grouped product holds of the sorted rows at the three
+    cells' shapes (bfloat16 rows in, float32 results out): the worst
+    case's (every assignment landing here) where they fit ``ROWS_BYTES``
+    (Laguna's 1.5 GiB, Kimi-Linear's 0.32), a chunk of at most
+    ``CHUNK_BYTES`` where they do not (2.75 GiB at Kimi-K2's width, which
+    the compiler refused in float32 alone, PR 35)."""
+    gib = 2.0 ** 30
+    for T, k, experts, held, d, worst_gib in (
+            (8192, 8, 384, 12, 7168, 2.75),     # Kimi-K2
+            (8192, 8, 256, 256, 2048, 1.5),     # Laguna
+            (2048, 8, 256, 64, 2304, 0.32)):    # Kimi-Linear
+        plan = moe.expert_product(T, k, experts, held, d)
+        worst = (T * k + held * plan.block_rows) * d * 6
+        assert abs(worst / gib - worst_gib) < 0.01
+        if worst > moe.ROWS_BYTES:
+            assert plan.chunk_rows * d * 6 <= moe.CHUNK_BYTES
+        else:
+            assert plan.chunk_rows * d * 6 == worst

@@ -149,13 +149,25 @@ def test_k2_engine_shares_a_prefix_and_says_what_its_steps_read():
         for child in span.get("children", ()):
             yield from walk(child)
     dispatch = {"llm.step.prefill": [], "llm.step.decode": []}
+    fetched = []
     for step in log:
         for s in walk(step):
             if s["name"] in dispatch:
                 dispatch[s["name"]] += [
                     d["attrs"] for d in walk(s)
                     if d["name"] == "runner.dispatch"]
+            if s["name"] == "llm.step.prefill":
+                fetched += [d["attrs"] for d in walk(s)
+                            if d["name"] == "runner.fetch"]
     pre = dispatch["llm.step.prefill"]
+    # which product a prompt's routed experts run (buckets of 64 and 16
+    # rows go whole through the touched experts), and the rows that
+    # went through an expert: a touched expert multiplies all of them
+    assert [d["expert_product"] for d in pre] == ["touched_kernel"] * 3
+    assert [f["expert_rows_multiplied"] for f in fetched] == [
+        f["experts_touched"] * rows for f, rows in zip(fetched, (64, 16, 16))]
+    assert all(f["expert_rows_multiplied"] >= f["expert_tokens"] > 0
+               for f in fetched)
     # (the third shares four whole pages with the first: 24 + 8 tokens)
     assert [d["prompt_tokens"] for d in pre] == [33, 14, 15]
     assert [d["padded_tokens"] for d in pre] == [64, 16, 16]

@@ -30,18 +30,7 @@ def _layer(held, num_experts=E_ALL, dtype=jnp.float32):
 def _dense_reference(p, x, held, valid):
     """(y without the shared expert, counts): all held experts over all
     rows, a pair the router did not choose weighed zero."""
-    first, count = held
-    scores = jax.nn.sigmoid(x @ p["router"])
-    _, chosen = jax.lax.top_k(scores + p["router_bias"], TOP_K)
-    w = jnp.take_along_axis(scores, chosen, axis=1)
-    w = w / jnp.sum(w, axis=-1, keepdims=True) * SCALING
-    picked = jax.nn.one_hot(chosen, scores.shape[1]) * valid[:, None, None]
-    combine = jnp.sum(picked * w[..., None], axis=1)[:, first:first + count]
-    counts = jnp.sum(picked, axis=(0, 1))[first:first + count]
-    h = jax.nn.silu(jnp.einsum("td,edf->etf", x, p["w_gate"])) \
-        * jnp.einsum("td,edf->etf", x, p["w_up"])
-    y = jnp.einsum("etf,efd->td", h * combine.T[..., None], p["w_down"])
-    return y, counts.astype(jnp.int32)
+    return _dense_many(p, x, held, valid, TOP_K, SCALING)
 
 
 def _params(layer, key, x, bias=None):
@@ -210,3 +199,168 @@ def test_the_index_map_walks_the_touched_list_and_then_stays():
     assert n.tolist() == [0]
     assert {tuple(map(int, RE.live_block(i, j, order, n, tiles - 1)))
             for i in range(16) for j in range(tiles)} == {(0, 1)}
+
+
+# ---- many tokens: the grouped product over the sorted row blocks ----
+
+def _dense_many(p, x, held, valid, top_k, scaling):
+    """``_dense_reference`` at any ``top_k`` and scaling: all held
+    experts over all rows, the pairs the router did not choose (and the
+    rows that are not real) weighed zero."""
+    first, count = held
+    scores = jax.nn.sigmoid(x @ p["router"])
+    _, chosen = jax.lax.top_k(scores + p["router_bias"], top_k)
+    w = jnp.take_along_axis(scores, chosen, axis=1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * scaling
+    picked = jax.nn.one_hot(chosen, scores.shape[1]) * valid[:, None, None]
+    combine = jnp.sum(picked * w[..., None], axis=1)[:, first:first + count]
+    counts = jnp.sum(picked, axis=(0, 1))[first:first + count]
+    h = jax.nn.silu(jnp.einsum("td,edf->etf", x, p["w_gate"])) \
+        * jnp.einsum("td,edf->etf", x, p["w_up"])
+    y = jnp.einsum("etf,efd->td", h * combine.T[..., None], p["w_down"])
+    return y, counts.astype(jnp.int32)
+
+
+# the three cells' proportions at toy widths:
+# name: (experts, held, top_k, d_ff, tokens, real tokens)
+CELLS = {
+    # Laguna: many small experts, all of them held
+    "many_small_experts_all_held": (64, (0, 64), 8, 16, 600, 441),
+    # Kimi-K2: a few wide experts of which a 32nd is held
+    "a_32nd_of_wide_experts_held": (128, (8, 4), 8, 96, 600, 431),
+    # Kimi-Linear: 64 of 256
+    "a_quarter_held": (256, (64, 64), 8, 48, 512, 512),
+    # every assignment lands on the experts held here: the worst case
+    "every_assignment_lands_here": (4, (0, 4), 4, 32, 700, 700),
+    # one row more than goes as whole rows through the touched experts
+    "just_above_whole_rows": (16, (4, 8), 4, 32, 257, 250),
+}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_many_tokens_equal_the_dense_product(cell):
+    """The grouped kernel over the sorted row blocks gives what the
+    dense product gives, with padding rows, an expert that gets no token
+    (a bias of -10 on the second held expert), and an expert whose rows
+    span several blocks (a bias of +10 on the first: every real row)."""
+    experts, held, top_k, d_ff, T, n_real = CELLS[cell]
+    first, count = held
+    assert T > moe.WHOLE_ROWS_BELOW
+    x = jnp.asarray(np.random.default_rng(T).normal(size=(T, D)),
+                    jnp.float32)
+    layer = RoutedExperts(experts, d_ff, top_k, held=held, scaling=SCALING,
+                          dtype=jnp.float32)
+    bias = jnp.zeros((experts,)).at[first].set(10.0)
+    if experts > top_k:         # else every expert is chosen
+        bias = bias.at[first + 1].set(-10.0)
+    p = _params(layer, T, x, bias)
+    valid = jnp.arange(T) < n_real
+    y, counts = jax.jit(layer.apply)({"params": p}, x, valid=valid)
+    want, want_counts = _dense_many(p, x, held, valid, top_k, SCALING)
+    np.testing.assert_array_equal(counts, want_counts)
+    plan = moe.expert_product(T, top_k, experts, count, D, 4)
+    assert plan.name == "grouped_kernel"
+    assert int(counts[0]) == n_real > plan.block_rows   # several blocks
+    if experts > top_k:
+        assert int(counts[1]) == 0
+    else:
+        assert int(counts.sum()) == n_real * top_k      # the worst case
+    assert plan.rows_multiplied(counts) >= int(counts.sum())
+    np.testing.assert_allclose(y, want, atol=TOL)
+    assert float(jnp.max(jnp.abs(want))) > 1000 * TOL
+    # a padding row gets nothing, not even another row's product
+    assert not bool(jnp.any(y[n_real:]))
+
+
+def test_the_grouped_product_never_reads_an_expert_without_a_token():
+    """NaN in the weights of every expert that got no token changes
+    nothing: no block names such an expert."""
+    experts, held, top_k, d_ff, T, _ = CELLS["a_quarter_held"]
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(T, D)),
+                    jnp.float32)
+    layer = RoutedExperts(experts, d_ff, top_k, held=held, scaling=SCALING,
+                          dtype=jnp.float32)
+    bias = jnp.zeros((experts,)).at[jnp.arange(64, 128, 2)].set(-10.0)
+    p = _params(layer, 3, x, bias)
+    y, counts = layer.apply({"params": p}, x)
+    idle = np.asarray(counts) == 0
+    assert idle.sum() >= 32, counts
+    poisoned = dict(p, **{
+        k: jnp.where(idle.reshape(-1, 1, 1), jnp.nan, p[k])
+        for k in ("w_gate", "w_up", "w_down")})
+    again, _ = layer.apply({"params": poisoned}, x)
+    assert bool(jnp.all(jnp.isfinite(again)))
+    np.testing.assert_array_equal(again, y)
+
+
+def test_the_row_blocks_index_map_walks_the_live_blocks_and_then_stays():
+    """Over five live blocks of three experts the index maps name each
+    block with its expert, tile after tile (two blocks of one expert
+    name the same weights), and from then on the block that is resident
+    (no new DMA); with no live block, one block."""
+    block_expert = jnp.array([2, 2, 5, 9, 9, 9, 9, 9], jnp.int32)
+    n, tiles = jnp.array([5], jnp.int32), 2
+    walk = [tuple(map(int, RE.live_rows(i, j, block_expert, n, tiles - 1)))
+            for i in range(8) for j in range(tiles)]
+    assert walk[:10] == [(0, 2, 0), (0, 2, 1), (1, 2, 0), (1, 2, 1),
+                         (2, 5, 0), (2, 5, 1), (3, 9, 0), (3, 9, 1),
+                         (4, 9, 0), (4, 9, 1)]
+    assert set(walk[10:]) == {(4, 9, 1)}
+    none = jnp.array([0], jnp.int32)
+    assert {tuple(map(int, RE.live_rows(i, j, block_expert, none,
+                                        tiles - 1)))
+            for i in range(8) for j in range(tiles)} == {(0, 2, 1)}
+
+
+def test_the_blocks_past_the_live_ones_are_not_computed():
+    """``grouped_experts`` writes the rows of its first ``n`` blocks and
+    multiplies no other: NaN rows past them leave the live rows as they
+    are, and each live row is its block's expert's SwiGLU times its
+    weight."""
+    bm, d, d_ff = 128, D, 16
+    rng = np.random.default_rng(5)
+    xs = jnp.asarray(rng.normal(size=(4 * bm, d)), jnp.float32)
+    weight = jnp.asarray(rng.random(4 * bm), jnp.float32)
+    gate, up = (jnp.asarray(rng.normal(size=(3, d, d_ff)), jnp.float32)
+                for _ in range(2))
+    down = jnp.asarray(rng.normal(size=(3, d_ff, d)), jnp.float32)
+    block_expert = jnp.array([0, 2, 2, 1], jnp.int32)
+    two = jnp.array([2], jnp.int32)
+    ys = RE.grouped_experts(xs.at[2 * bm:].set(jnp.nan), weight,
+                            block_expert, two, gate, up, down, bm)
+    for b, e in ((0, 0), (1, 2)):
+        rows = xs[b * bm:(b + 1) * bm]
+        want = (jax.nn.silu(rows @ gate[e]) * (rows @ up[e])) @ down[e] \
+            * weight[b * bm:(b + 1) * bm, None]
+        np.testing.assert_allclose(ys[b * bm:(b + 1) * bm], want,
+                                   atol=1e-3, rtol=1e-5)
+    assert bool(jnp.all(jnp.isfinite(ys[:2 * bm])))
+
+
+@pytest.mark.parametrize("T,top_k,experts,held,d,block,held_rows", [
+    (64, 8, 256, 64, 2304, 64, 64),             # a decode step
+    (1, 8, 384, 12, 7168, 16, 16),
+    (8192, 8, 256, 256, 2048, 256, 131072),     # a Laguna prompt: whole
+    (8192, 8, 384, 12, 7168, 256, 768),         # Kimi-K2's: 3 blocks
+    (2048, 8, 256, 64, 2304, 128, 24576),       # Kimi-Linear's: whole
+    (512, 8, 256, 64, 2304, 128, 12288),
+])
+def test_the_product_and_its_block_follow_the_shapes(T, top_k, experts, held,
+                                                     d, block, held_rows):
+    """``expert_product`` reads shapes only: the block is 128 rows where
+    an expert expects no more, and the rows held at a time are the worst
+    case's (every assignment landing here) where they fit ``ROWS_BYTES``
+    as bfloat16 rows and their float32 results, else the whole blocks
+    that fit ``CHUNK_BYTES``."""
+    plan = moe.expert_product(T, top_k, experts, held, d)
+    grouped = T > moe.WHOLE_ROWS_BELOW
+    assert plan == ("grouped_kernel" if grouped else "touched_kernel",
+                    block, held_rows)
+    if grouped:
+        worst = T * top_k + held * block
+        assert (held_rows == worst and worst * d * 6 <= moe.ROWS_BYTES) or (
+            worst * d * 6 > moe.ROWS_BYTES
+            and moe.CHUNK_BYTES - block * d * 6
+            < held_rows * d * 6 <= moe.CHUNK_BYTES)
+    counts = np.array([[0, 1, block, block + 1], [0, 0, 0, 5]])
+    assert plan.rows_multiplied(counts) == (5 if grouped else 4) * block
