@@ -1,5 +1,6 @@
 """Multi-head latent attention (MLA) as a flax mixer, for every model
-that has it (``models/kimi_linear.py``, ``models/kimi_k2.py``).
+that has it (``models/kimi_linear.py``, ``models/kimi_k2.py``,
+``models/longcat_flash.py``).
 
 What a configuration has or has not decides the form, nothing else:
 
@@ -11,16 +12,27 @@ What a configuration has or has not decides the form, nothing else:
                     are carried as they are); a ``YarnRope``: those
                     values are rotated at the token's absolute position,
                     k's once, before its row is cached
+  ``q_scale``,      absent or 1: nothing; else (LongCat-Flash's
+  ``latent_scale``  ``mla_scale_q_lora`` / ``mla_scale_kv_lora``) ``q`` is
+                    multiplied by ``q_scale`` after its up-projection
+                    (both parts) and the normalised latent ``c`` by
+                    ``latent_scale`` before anything reads it
+  ``prompt_logits_bytes``  absent: ``ops.attention.LATENT_LOGITS_BYTES``;
+                    else the float32 logits of one block of a prompt's
+                    queries above which the keys are walked in blocks
+                    inside one kernel (``latent_prefill_attention``)
 
 The cached row is ``(c, RoPE(k_r))``: the normalised compressed latent
-(``kv_lora_rank`` values) and the key part all heads share, in whole
-lanes of 128 (``lanes``). A decode step (one token a row over pages)
-attends in the absorbed form: on the chip one Pallas kernel over the
-row's live pages where they lie (``ops.attention.
-latent_attention_decode``), off it over the rows gathered to the padded
-context; everything else gathers and builds every head's keys and values
-(``ops.attention.latent_attention``). Which it is follows from what the
-call can observe (``ops.attention.latent_decode_path``).
+(``kv_lora_rank`` values, times ``latent_scale``: the row as ``W_kvb``
+multiplies it, so that every attention path reads the pool as it is) and
+the key part all heads share, in whole lanes of 128 (``lanes``). A decode
+step (one token a row over pages) attends in the absorbed form: on the
+chip one Pallas kernel over the row's live pages where they lie
+(``ops.attention.latent_attention_decode``), off it over the rows
+gathered to the padded context; everything else gathers and builds every
+head's keys and values (``ops.attention.latent_attention``). Which it is
+follows from what the call can observe
+(``ops.attention.latent_decode_path``).
 
 Device-trace scopes (inside the block's scope ``mla``): ``mla/q_lora``
 (``mla/q`` without the bottleneck), ``mla/rope``, ``mla/write`` (the new
@@ -156,7 +168,10 @@ class MLAMixer(nn.Module):
     """``config`` names ``num_attention_heads``, ``kv_lora_rank``,
     ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
     ``rms_norm_eps``, ``dtype``, ``q_lora_rank`` (or None) and ``rope``
-    (a ``YarnRope`` or None)."""
+    (a ``YarnRope`` or None); it may name ``q_scale`` and
+    ``latent_scale`` (floats; absent = 1), ``latent_norm_eps`` (the
+    two inner norms'; absent = ``rms_norm_eps``) and
+    ``prompt_logits_bytes`` (absent: ``ops.attention``'s)."""
     config: object
 
     @nn.compact
@@ -173,18 +188,23 @@ class MLAMixer(nn.Module):
             cfg.v_head_dim
         dt = cfg.dtype
         rope, q_rank = cfg.rope, cfg.q_lora_rank
+        eps = getattr(cfg, "latent_norm_eps", cfg.rms_norm_eps)
         xb = x.astype(dt)
         with jax.named_scope("mla/q_lora" if q_rank else "mla/q"):
             if q_rank:
-                c_q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(
+                c_q = RMSNorm(eps, name="q_norm")(
                     xb @ dense(self, "q_a", (D, q_rank), dt))
                 q = c_q.astype(dt) @ dense(self, "q_b",
                                            (q_rank, H * (dn + dr)), dt)
             else:
                 q = xb @ dense(self, "q_proj", (D, H * (dn + dr)), dt)
             q = q.reshape(B, S, H, dn + dr)
+            if getattr(cfg, "q_scale", 1.0) != 1.0:
+                q = q * jnp.asarray(cfg.q_scale, q.dtype)
         kv = xb @ dense(self, "kv_a", (D, R + dr), dt)
-        c = RMSNorm(cfg.rms_norm_eps, name="kv_norm")(kv[..., :R])
+        c = RMSNorm(eps, name="kv_norm")(kv[..., :R])
+        if getattr(cfg, "latent_scale", 1.0) != 1.0:
+            c = c * cfg.latent_scale            # float32, before it is cast
         q_rope = sm_scale = None
         if rope is None:
             latent = jnp.concatenate([c.astype(dt), kv[..., R:]], axis=-1)
@@ -238,7 +258,8 @@ class MLAMixer(nn.Module):
                     q[..., :dn], q[..., dn:] if q_rope is None else q_rope,
                     context, w_kvb, q_pos, v_dim=dv,
                     absorbed=pages is not None and S == 1,
-                    sm_scale=sm_scale)
+                    sm_scale=sm_scale,
+                    logits_bytes=getattr(cfg, "prompt_logits_bytes", None))
         with jax.named_scope("mla/out"):
             y = y.reshape(B, S, H * dv).astype(dt)
             return jnp.matmul(y, dense(self, "o_proj", (H * dv, D), dt),

@@ -1076,7 +1076,8 @@ def prefill_attention(q, keys, values, q_positions, *,
 
 def latent_attention(q_nope, q_rope, latent, w_kvb, q_positions, *,
                      v_dim: int, absorbed: bool = False,
-                     sm_scale: Optional[float] = None, q_block: int = 512):
+                     sm_scale: Optional[float] = None, q_block: int = 512,
+                     logits_bytes: Optional[int] = None):
     """Multi-head latent attention (MLA) over cached latents.
 
     q_nope [B, S, H, dn], q_rope [B, S, H, dr]: the new tokens' queries;
@@ -1104,7 +1105,11 @@ def latent_attention(q_nope, q_rope, latent, w_kvb, q_positions, *,
     GB a block, several times over while the softmax runs, 19 GB a layer
     written and read again and again: 1.7 s a prompt on the chip, PR 35)
     the materialised form is one Pallas kernel that walks the keys in
-    blocks with a running softmax (``latent_prefill_attention``)."""
+    blocks with a running softmax (``latent_prefill_attention``).
+    ``logits_bytes`` puts a model's own budget in that one's place
+    (LongCat-Flash's: a 2,048-token prompt over 3,072 positions stays
+    under the general one and cost 12 ms a sublayer so, 1.9 by the
+    kernel: PR 41)."""
     B, S, H, dn = q_nope.shape
     R = w_kvb.shape[0]
     T = latent.shape[1]
@@ -1115,7 +1120,8 @@ def latent_attention(q_nope, q_rope, latent, w_kvb, q_positions, *,
     k_pos = jnp.arange(T)[None, None, None, :]
     k_block = next((kb for kb in _KEY_BLOCKS if T % kb == 0 and kb < T), 0)
     by_keys = not absorbed and k_block and \
-        B * H * min(S, q_block) * T * 4 > LATENT_LOGITS_BYTES
+        B * H * min(S, q_block) * T * 4 > (
+            LATENT_LOGITS_BYTES if logits_bytes is None else logits_bytes)
     if absorbed:
         keys = c
         values = c

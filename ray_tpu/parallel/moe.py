@@ -18,12 +18,15 @@ over ICI when the expert dimension is sharded P("ep", ...).
 Top-1 (Switch) routing with the standard load-balance auxiliary loss.
 
 ``RoutedExperts`` beside it is the layer the served models use: sigmoid
-scores over all experts, top-k, renormalised, no capacity and no drop,
-SwiGLU experts, computed for the experts one chip holds (docs/
-LLM_SERVING.md, "Routed experts"). Its product multiplies the groups
-that hold a token and reads no other expert's weights: whole rows
-through the touched experts for few tokens, sorted row blocks for many
-(both kernels: ``ops/routed_experts.py``).
+or softmax scores over the router's whole width, top-k, renormalised or
+not, no capacity and no drop, SwiGLU experts, computed for the experts
+one chip holds (docs/LLM_SERVING.md, "Routed experts"). Its product
+multiplies the groups that hold a token and reads no other expert's
+weights: whole rows through the touched experts for few tokens, sorted
+row blocks for many (both kernels: ``ops/routed_experts.py``). A router
+may have ``zero_experts`` outputs beyond its real experts (LongCat-Flash's
+zero-compute experts): a choice of one gives the token itself, takes no
+row in either product and reads no weight.
 """
 
 from __future__ import annotations
@@ -141,8 +144,17 @@ WHOLE_ROWS_BELOW = 256
 # layer 5.5 ms where the gather back costs 2.7, and a Kimi-K2 layer more
 # the larger the chunk (PERF.md, PR 40: 2-4 blocks of 256 rows read best
 # there). Index arrays alone always have the worst case's length.
+# The loop also takes the rows where the worst case is more than
+# ROWS_OVER_EXPECTED times what even routing sends here (groups padded):
+# whole, the rows gathered and each assignment's result gathered back are
+# the worst case's whatever landed. LongCat-Flash's share (16 of a router's
+# 768 outputs held: 26,624 rows for the ~2,560 of a 2,048-token prompt,
+# 10.4 times) cost 10.4 ms a layer so and 3.8 by the loop (PERF.md, PR
+# 41); Kimi-Linear's (64 of 256: twice) and Laguna's (all: once) stay
+# whole. Nothing between 2 and 10.4 was measured.
 ROWS_BYTES = 2 << 30
 CHUNK_BYTES = 32 << 20
+ROWS_OVER_EXPECTED = 8
 
 
 class ExpertProduct(NamedTuple):
@@ -163,22 +175,28 @@ class ExpertProduct(NamedTuple):
 
 
 def expert_product(T: int, top_k: int, num_experts: int, held: int, d: int,
-                   itemsize: int = 2) -> ExpertProduct:
+                   itemsize: int = 2, zero_experts: int = 0) -> ExpertProduct:
     """The product for ``T`` tokens of width ``d``, each sent to
-    ``top_k`` of ``num_experts``, ``held`` of them here. Above
-    ``WHOLE_ROWS_BELOW`` tokens the block is 128 rows where an expert
-    expects no more (``T * top_k / num_experts``), else 256 (the kernel
+    ``top_k`` of the router's ``num_experts + zero_experts`` outputs,
+    ``held`` of the real ones here. Above ``WHOLE_ROWS_BELOW`` tokens the
+    block is 128 rows where an expert expects no more (``T * top_k`` over
+    the router's width: a zero-compute output takes its share of the
+    assignments and no row), else 256 (the kernel
     moves bytes, not FLOPs: a block's rows and its expert's weights in 16
     us at 256 rows a block and 6.3 MB an expert, two blocks of 128 in 21,
     a block of 512 in 25 while it pads twice as much: PERF.md, PR 40);
     the rows held at a time are the worst case's where they fit
-    ``ROWS_BYTES``, else the whole blocks that fit ``CHUNK_BYTES``."""
+    ``ROWS_BYTES`` and are at most ``ROWS_OVER_EXPECTED`` times what even
+    routing sends here, else the whole blocks that fit ``CHUNK_BYTES``."""
     if T <= WHOLE_ROWS_BELOW:
         rows = -(-T // ROW_TILE) * ROW_TILE
         return ExpertProduct("touched_kernel", rows, rows)
-    bm = 128 if T * top_k <= 128 * num_experts else 256
+    width = num_experts + zero_experts
+    bm = 128 if T * top_k <= 128 * width else 256
     rows = _worst_rows(T * top_k, held, bm)
-    if rows * d * (itemsize + 4) > ROWS_BYTES:
+    expected = _worst_rows(-(-T * top_k * held // width), held, bm)
+    if rows * d * (itemsize + 4) > ROWS_BYTES \
+            or rows > ROWS_OVER_EXPECTED * expected:
         rows = max(1, CHUNK_BYTES // (bm * d * (itemsize + 4))) * bm
     return ExpertProduct("grouped_kernel", bm, rows)
 
@@ -187,16 +205,26 @@ class RoutedExperts(nn.Module):
     """A routed-experts layer that drops no token, for the experts held
     HERE (the share of one chip under expert parallelism).
 
-    The router scores every token against all ``num_experts`` (sigmoid,
-    float32), chooses the ``top_k`` largest of ``score + bias``, and
-    weighs the chosen by ``score / sum(chosen scores)`` (if
-    ``renormalize``) times ``scaling``. Of the chosen, this layer
-    computes those in ``held = (first, count)``: the part of the result
-    that its own SwiGLU experts give, plus ``shared_d_ff`` wide shared
-    expert(s) that every chip computes alike. What the absent experts
-    would add is left out; nothing stands in for them. Returns ``(y,
-    counts)``, counts [count] int32 the real tokens sent to each held
-    expert this call.
+    The router scores every token against its ``num_experts +
+    zero_experts`` outputs (``score``: ``sigmoid``, or ``softmax`` over
+    the whole width; float32), chooses the ``top_k`` largest of ``score +
+    bias``, and weighs the chosen by ``score / sum(chosen scores)`` (if
+    ``renormalize``; else the score as it is) times ``scaling``. Of the
+    chosen, this layer computes those in ``held = (first, count)``: the
+    part of the result that its own SwiGLU experts give, plus
+    ``shared_d_ff`` wide shared expert(s) that every chip computes alike.
+    What the absent experts would add is left out; nothing stands in for
+    them. Returns ``(y, counts)``, counts [count] int32 the real tokens
+    sent to each held expert this call.
+
+    An output ``>= num_experts`` is a zero-compute expert: it gives the
+    token itself, so a token's choices of them add ``x * sum(their
+    weights)`` (scope ``moe/zero``), computed for every real token HERE,
+    on the token's own chip, as a shared expert would be. Such a choice
+    is "not this chip's" to both products: no row, no weight read, and
+    not in ``counts``. With ``zero_experts`` the layer returns ``(y,
+    counts, zero_tokens)``, zero_tokens [] int32 the real tokens'
+    assignments to a zero-compute expert this call.
 
     One algorithm, "multiply the groups that hold a token", in two
     forms by the number of tokens (static; ``expert_product`` chooses).
@@ -219,6 +247,8 @@ class RoutedExperts(nn.Module):
     renormalize: bool = True
     shared_d_ff: int = 0
     dtype: Any = jnp.bfloat16
+    score: str = "sigmoid"                       # | "softmax"
+    zero_experts: int = 0           # router outputs beyond the real experts
 
     @nn.compact
     def __call__(self, x, valid=None):
@@ -227,13 +257,16 @@ class RoutedExperts(nn.Module):
         T = xf.shape[0]
         first, count = self.held or (0, self.num_experts)
         real = jnp.ones((T,), bool) if valid is None else valid.reshape(T)
+        width = self.num_experts + self.zero_experts
+        squash = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[
+            self.score]
 
         with jax.named_scope("moe/router"):
             w_r = self.param("router", nn.initializers.normal(0.02),
-                             (d, self.num_experts), jnp.float32)
+                             (d, width), jnp.float32)
             bias = self.param("router_bias", nn.initializers.zeros,
-                              (self.num_experts,), jnp.float32)
-            scores = jax.nn.sigmoid(jnp.matmul(
+                              (width,), jnp.float32)
+            scores = squash(jnp.matmul(
                 xf.astype(jnp.float32), w_r,
                 precision=jax.lax.Precision.HIGHEST))          # [T, E]
             _, chosen = jax.lax.top_k(scores + bias, self.top_k)
@@ -255,7 +288,8 @@ class RoutedExperts(nn.Module):
                             self.dtype)
         xb = xf.astype(self.dtype)
         plan = expert_product(T, self.top_k, self.num_experts, count, d,
-                              jnp.dtype(self.dtype).itemsize)
+                              jnp.dtype(self.dtype).itemsize,
+                              self.zero_experts)
         with jax.named_scope("moe/experts"):
             if plan.name == "touched_kernel":
                 counts = jnp.sum(jax.nn.one_hot(local, count + 1,
@@ -273,7 +307,13 @@ class RoutedExperts(nn.Module):
             with jax.named_scope("moe/shared"):
                 y = y + SwiGLU(self.shared_d_ff, self.dtype,
                                name="shared")(xb)
-        return y.reshape(*lead, d), counts
+        if not self.zero_experts:
+            return y.reshape(*lead, d), counts
+        with jax.named_scope("moe/zero"):
+            zero = (chosen >= self.num_experts) & real[:, None]
+            y = y + xf.astype(jnp.float32) * jnp.sum(
+                jnp.where(zero, w, 0.0), axis=1, keepdims=True)
+        return y.reshape(*lead, d), counts, jnp.sum(zero, dtype=jnp.int32)
 
 
 def _worst_rows(A: int, E: int, bm: int) -> int:

@@ -413,6 +413,13 @@ class FlaxModelAdapter:
             self._blocks = None            # layers differ by their type
             self.vocab_size = self.cfg.vocab_size
             self._spec = laguna.cache_spec(self.cfg)
+        elif kind == "longcat_flash":
+            from ray_tpu.models import longcat_flash
+            self.cfg = config or longcat_flash.LongcatFlashConfig.tiny()
+            self.model = longcat_flash.LongcatFlashModel(self.cfg)
+            self._blocks = None            # two cached sublayers a layer
+            self.vocab_size = self.cfg.vocab_size
+            self._spec = longcat_flash.cache_spec(self.cfg)
         else:
             raise ValueError(f"unknown model kind {kind!r}")
         if params is None:
@@ -420,6 +427,8 @@ class FlaxModelAdapter:
             params = self.model.init(jax.random.PRNGKey(seed), dummy)
         self.params = params
         self._expert_tokens_total = self._expert_tokens_last = None
+        self._zero_expert_tokens_total = None   # [routed layers]
+        self._routed_tokens_total = 0           # real tokens through a router
         self._expert_products: Dict[int, Any] = {}
         self._kv_pages_live = self._kv_pages_padded = 0
         self._window_pages_live = self._window_pages_padded = 0
@@ -569,6 +578,10 @@ class FlaxModelAdapter:
         if self._expert_tokens_total is not None:
             out["expert_tokens_total"] = self._expert_tokens_total.tolist()
             out["expert_tokens_last_step"] = self._expert_tokens_last.tolist()
+            out["routed_tokens_total"] = self._routed_tokens_total
+        if self._zero_expert_tokens_total is not None:
+            out["zero_expert_tokens_total"] = \
+                self._zero_expert_tokens_total.tolist()
         return out
 
     def _take_slots(self, seq_ids: List[str]) -> List[int]:
@@ -683,9 +696,10 @@ class FlaxModelAdapter:
         upload is a place where the engine's thread gives way to the
         threads that answer the streams. Beside the logits the program
         returns ``small``: each row's greedy token, then the routed
-        layers' per-expert token counts of the step, so that a step
-        whose rows all sample greedily fetches B + layers x experts
-        integers and leaves the logits on the device. ``full`` (a
+        layers' per-expert token counts of the step (then, of a router
+        with zero-compute outputs, each layer's assignments to them), so
+        that a step whose rows all sample greedily fetches B + layers x
+        experts integers and leaves the logits on the device. ``full`` (a
         speculative window's verify step) returns the logits after every
         position, [B, S, V]. A decode program (``S == 1``) also takes
         ``_last_tokens`` before the arrays, donated like them: a row whose
@@ -717,14 +731,14 @@ class FlaxModelAdapter:
             if not by_slot:
                 cache["slots"] = slots
             valid = jnp.arange(S)[None, :] < n_new[:, None]
-            logits, cache, counts = self.model.apply(
+            logits, cache, *counts = self.model.apply(
                 params, tokens, cache=cache, seq_lengths=seq_lengths,
                 valid=valid,
                 logits_at=None if full else jnp.maximum(n_new - 1, 0))
             rows = logits[:, 0]     # (a verify step's are not asked for)
             greedy = jnp.argmax(rows, axis=-1).astype(jnp.int32)
-            small = jnp.concatenate([
-                greedy, counts.reshape(-1).astype(jnp.int32)])
+            small = jnp.concatenate([greedy] + [
+                c.reshape(-1).astype(jnp.int32) for c in counts])
             return (logits if full else rows, small,
                     *([last.at[:B].set(greedy)] if feeds else []),
                     *(cache[n] for n in names))
@@ -830,8 +844,12 @@ class FlaxModelAdapter:
                     out = counts[:B][at] if tokens_only \
                         else np.asarray(logits, np.float32)[at]
                     if counts.size > B:
-                        span.set(**self._count_experts(counts[B:].reshape(
-                            self._spec["expert_counts"]), product))
+                        held = B + int(np.prod(self._spec["expert_counts"]))
+                        span.set(**self._count_experts(
+                            counts[B:held].reshape(
+                                self._spec["expert_counts"]), product,
+                            sum(len(r["tokens"]) for r in rows),
+                            counts[held:]))
                 span.set(bytes=out.nbytes)
             return out
         return at, fetch, (logits if self._spec is None else small)
@@ -894,22 +912,39 @@ class FlaxModelAdapter:
             self._expert_products[T] = expert_product(T, *shapes)
         return self._expert_products[T]
 
-    def _count_experts(self, counts: np.ndarray, product) -> Dict[str, Any]:
+    def _count_experts(self, counts: np.ndarray, product, tokens: int,
+                       zeros: np.ndarray) -> Dict[str, Any]:
         """counts [routed layers, experts held]: the step's tokens per
-        expert. Kept cumulatively for ``counters()``; the step span gets
-        how many experts the step touched, how uneven the load was
-        (largest over mean, the median of the layers) and the rows that
-        went through an expert (the live blocks' of ``product``)."""
+        expert (real experts held here); ``tokens`` the step's real
+        tokens, each through every router; ``zeros`` [routed layers] (or
+        empty: the router has no such output) its assignments to a
+        zero-compute expert, which cost nothing. Kept cumulatively for
+        ``counters()``; the step span gets how many experts the step
+        touched, how uneven the load was (largest over mean, the median
+        of the layers) and the rows that went through an expert (the
+        live blocks' of ``product``)."""
         if self._expert_tokens_total is None:
             self._expert_tokens_total = np.zeros(counts.shape, np.int64)
         self._expert_tokens_total += counts
         self._expert_tokens_last = counts
+        self._routed_tokens_total += tokens
         mean = counts.mean(axis=1)
-        return {"experts_touched": int((counts > 0).sum()),
-                "expert_tokens": int(counts.sum()),
-                "expert_rows_multiplied": product.rows_multiplied(counts),
-                "moe_max_over_mean": float(np.median(
-                    counts.max(axis=1) / np.maximum(mean, 1e-9)))}
+        out = {"experts_touched": int((counts > 0).sum()),
+               "expert_tokens": int(counts.sum()),
+               "expert_rows_multiplied": product.rows_multiplied(counts),
+               "moe_max_over_mean": float(np.median(
+                   counts.max(axis=1) / np.maximum(mean, 1e-9)))}
+        if zeros.size:
+            if self._zero_expert_tokens_total is None:
+                self._zero_expert_tokens_total = np.zeros(zeros.shape,
+                                                          np.int64)
+            self._zero_expert_tokens_total += zeros
+            # of the step's tokens x routed layers x experts a token
+            out.update(zero_expert_tokens=int(zeros.sum()),
+                       routed_tokens=tokens,
+                       routed_assignments=tokens * zeros.size
+                       * self._spec["routed_experts"][0])
+        return out
 
     def prefill(self, seqs, tokens_only: bool = False) -> np.ndarray:
         rows = []
@@ -1068,13 +1103,16 @@ class FlaxModelAdapter:
 def make_adapter(model: str = "toy",
                  model_config: Optional[Dict[str, Any]] = None):
     """Deployment-facing factory: ``model`` is ``toy`` |
-    ``gpt2`` | ``llama`` | ``kimi_linear`` | ``kimi_k2`` | ``laguna``
+    ``gpt2`` | ``llama`` | ``kimi_linear`` | ``kimi_k2`` | ``laguna`` |
+    ``longcat_flash``
     (tiny test configs unless ``model_config`` overrides)."""
     model_config = dict(model_config or {})
     if model == "toy":
         return ToyAdapter(**model_config)
-    if model in ("gpt2", "llama", "kimi_linear", "kimi_k2", "laguna"):
+    if model in ("gpt2", "llama", "kimi_linear", "kimi_k2", "laguna",
+                 "longcat_flash"):
         return FlaxModelAdapter(kind=model, **model_config)
     raise ValueError(
         f"unknown model {model!r} "
-        "(toy | gpt2 | llama | kimi_linear | kimi_k2 | laguna)")
+        "(toy | gpt2 | llama | kimi_linear | kimi_k2 | laguna | "
+        "longcat_flash)")

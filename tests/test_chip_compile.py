@@ -487,6 +487,106 @@ def test_kimi_k2_step_fits_the_chip_at_the_timed_shapes(
         assert "[18432,16,640]" not in text and "[32,9216," not in text
 
 
+def _longcat_cell():
+    """(configuration kwargs, decode slots, pages) of the cell
+    longcat_flash_omni.serve_closed64_ctx2k, from its configuration
+    file."""
+    import json
+    import os
+    with open(os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmark", "configs", "longcat_flash_omni.json")) as f:
+        data = json.load(f)
+    kwargs = dict(data["model"]["kwargs"])
+    kwargs["dtype"] = getattr(jnp, kwargs["dtype"])
+    engine = data["serve"]["engine"]
+    return kwargs, engine["max_running"], engine["num_blocks"]
+
+
+def _longcat_step(one_chip, topo, monkeypatch, B, S):
+    """``FlaxModelAdapter``'s step for LongCat-Flash as the cell
+    longcat_flash_omni.serve_closed64_ctx2k runs it: the published
+    widths, four double layers (eight cached sublayers), 16 of 512 real
+    experts, 16,384 rows of the vocabulary, the pool and block tables
+    its configuration file gives."""
+    from ray_tpu.models.longcat_flash import LongcatFlashConfig
+    from ray_tpu.serve.llm.kv_cache import PagedKVCache
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    kwargs, _, pages = _longcat_cell()
+    cfg = LongcatFlashConfig(**kwargs)
+    adapter = FlaxModelAdapter("longcat_flash", cfg, params={})
+    params = jax.tree_util.tree_map(
+        lambda s: sds(s.shape, s.dtype),
+        jax.eval_shape(adapter.model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8), jnp.int32)))
+    adapter.bind_cache(PagedKVCache(2, 16))
+    a = adapter._arrays["kv_pages"]
+    pool = sds((a.shape[0], pages, *a.shape[2:]), a.dtype)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "devices", lambda *a, **k: topo.devices)
+        fn = adapter._step_fn(B, S)
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    with jax.default_matmul_precision("default"):
+        return params, pool, fn.lower(
+            params, sds((B, S + 3 + adapter.nb_max), jnp.int32),
+            *_last_tokens(sds, pages, S), pool).compile()
+
+
+@pytest.mark.parametrize("B,S,temp_gib", [(64, 1, 0.1), (1, 2048, 3.0)],
+                         ids=["decode_b64", "prefill_2048"])
+def test_longcat_flash_step_fits_the_chip_at_the_timed_shapes(
+        one_chip, topo, monkeypatch, B, S, temp_gib):
+    """The two programs the cell times, compiled for the described v5e:
+    the weights and the eight-sublayer pool as arguments, the pool
+    donated and written in place, and temporaries that leave the whole
+    under the chip's 15.75 GiB (``memory_analysis()``). A decode step's
+    attention is the kernel over the pool as stored, a call a SUBLAYER
+    (eight), its routed experts the touched-experts kernel, a call a
+    layer; a prompt's attention walks the keys in blocks inside one
+    kernel, a call a sublayer (no [64, 512, 3072] of logits), and its
+    routed product is the grouped kernel in a loop over the live chunks
+    of 896 sorted rows (the worst case's 26,624 rows of 6144 held whole
+    were 0.91 GiB, kept alive across the dense SwiGLU, the second
+    attention and the second SwiGLU that the shortcut sets between its
+    start and its use: 1.90 GiB of temporaries, now 0.82)."""
+    import math
+
+    from ray_tpu.serve.llm.model_runner import _pad_pow2
+    params, pool, step = _longcat_step(one_chip, topo, monkeypatch, B, S)
+    memory = _MEMORY["longcat_flash", B, S] = step.memory_analysis()
+    gib = 2.0 ** 30
+    held = sum(math.prod(s.shape) * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    print(f"\n[longcat] B={B} S={S}: weights {held / gib:.3f} GiB, pool "
+          f"{math.prod(pool.shape) * 2 / gib:.3f} GiB, arguments "
+          f"{memory.argument_size_in_bytes / gib:.3f}, temporaries "
+          f"{memory.temp_size_in_bytes / gib:.3f}, outputs "
+          f"{memory.output_size_in_bytes / gib:.3f}, aliased "
+          f"{memory.alias_size_in_bytes / gib:.3f}, total "
+          f"{total / gib:.3f} GiB")
+    assert 9.6 < held / gib < 9.7
+    # (a decode program's other donated argument: the tokens by row)
+    assert memory.alias_size_in_bytes == math.prod(pool.shape) * 2 \
+        + (4 * _pad_pow2(pool.shape[1]) if S == 1 else 0)
+    assert memory.temp_size_in_bytes < temp_gib * gib
+    assert total < 15.3 * gib
+    text = step.as_text()
+    if S == 1:
+        assert text.count("latent_attention_decode") >= 8
+        assert text.count("routed_experts_touched") >= 4
+        assert "[64,3072," not in text      # no gather to the padded table
+    else:
+        assert text.count("routed_experts_grouped") >= 4
+        assert text.count("latent_prefill_attention") >= 8
+        assert "[64,512,3072]" not in text
+        assert memory.temp_size_in_bytes < 1.0 * gib
+
+
 def _laguna_cell():
     """(decode slots, pages of the full layers' pools) of the cell
     laguna_xs_2.serve_closed64_ctx8k, from its configuration file."""

@@ -344,23 +344,30 @@ def test_the_blocks_past_the_live_ones_are_not_computed():
     (8192, 8, 384, 12, 7168, 256, 768),         # Kimi-K2's: 3 blocks
     (2048, 8, 256, 64, 2304, 128, 24576),       # Kimi-Linear's: whole
     (512, 8, 256, 64, 2304, 128, 12288),
+    (2048, 8, 256, 8, 2304, 128, 2304),         # a 32nd held: 18 blocks
+    (2048, 8, 256, 16, 2304, 128, 18432),       # a 16th: whole (6 times)
+    (2048, 12, 768, 16, 6144, 128, 896),        # LongCat-Flash's: 7 blocks
 ])
 def test_the_product_and_its_block_follow_the_shapes(T, top_k, experts, held,
                                                      d, block, held_rows):
     """``expert_product`` reads shapes only: the block is 128 rows where
     an expert expects no more, and the rows held at a time are the worst
     case's (every assignment landing here) where they fit ``ROWS_BYTES``
-    as bfloat16 rows and their float32 results, else the whole blocks
-    that fit ``CHUNK_BYTES``."""
+    as bfloat16 rows and their float32 results and are at most
+    ``ROWS_OVER_EXPECTED`` times the rows of even routing, else the whole
+    blocks that fit ``CHUNK_BYTES``."""
     plan = moe.expert_product(T, top_k, experts, held, d)
     grouped = T > moe.WHOLE_ROWS_BELOW
     assert plan == ("grouped_kernel" if grouped else "touched_kernel",
                     block, held_rows)
     if grouped:
         worst = T * top_k + held * block
-        assert (held_rows == worst and worst * d * 6 <= moe.ROWS_BYTES) or (
-            worst * d * 6 > moe.ROWS_BYTES
-            and moe.CHUNK_BYTES - block * d * 6
+        even = -(-T * top_k * held // experts // block) * block \
+            + held * block
+        whole = worst * d * 6 <= moe.ROWS_BYTES \
+            and worst <= moe.ROWS_OVER_EXPECTED * even
+        assert (held_rows == worst and whole) or (
+            not whole and moe.CHUNK_BYTES - block * d * 6
             < held_rows * d * 6 <= moe.CHUNK_BYTES)
     counts = np.array([[0, 1, block, block + 1], [0, 0, 0, 5]])
     assert plan.rows_multiplied(counts) == (5 if grouped else 4) * block
