@@ -144,7 +144,10 @@ def cache_spec(cfg: KimiLinearConfig) -> Dict[str, Any]:
             "row": _lanes(cfg.kv_lora_rank + cfg.qk_rope_head_dim),
             "latent_rank": cfg.kv_lora_rank, "dtype": cfg.dtype}},
         "state": {
-            "kda_state": {"shape": (n_kda, H, d, d), "dtype": jnp.float32},
+            # (``recurrence``: a decode step runs ``LA.kda_decode_path``'s
+            # choice over this array)
+            "kda_state": {"shape": (n_kda, H, d, d), "dtype": jnp.float32,
+                          "recurrence": "kda"},
             "kda_conv": {"shape": (n_kda, (cfg.short_conv_kernel_size - 1)
                                    * 3 * H * d), "dtype": cfg.dtype}},
     }
@@ -154,17 +157,21 @@ class KDAMixer(nn.Module):
     config: KimiLinearConfig
 
     @nn.compact
-    def __call__(self, x, state=None, conv_tail=None, valid=None):
+    def __call__(self, x, state=None, conv_tail=None, valid=None,
+                 pool=None):
         """x [B, S, D]; state [B, H, dk, dv] and conv_tail [B, K-1,
         3*H*dk] are what the rows' sequences carried here (None: the
-        start of a sequence). Returns (y, new state, new tail)."""
+        start of a sequence). Returns (y, new state, new tail). A served
+        decode step (one token a row) hands ``pool = (kda_state, layer,
+        slots)`` in ``state``'s place, ``LA.kda_decode_step``'s
+        arguments, and gets the pool back in the new state's place."""
         cfg = self.config
         B, S, D = x.shape
         H, d, K = cfg.kda_num_heads, cfg.kda_head_dim, \
             cfg.short_conv_kernel_size
         r, dt = cfg.kda_gate_rank, cfg.dtype
         f32 = jnp.float32
-        if state is None:
+        if state is None and pool is None:
             state = jnp.zeros((B, H, d, d), f32)
             conv_tail = jnp.zeros((B, K - 1, 3 * H * d), dt)
         xb = x.astype(dt)
@@ -196,9 +203,10 @@ class KDAMixer(nn.Module):
             g = jnp.where(valid[..., None, None], g, 0.0)
             beta = jnp.where(valid[..., None], beta, 0.0)
         if S == 1:
+            one = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
             with jax.named_scope("kda/recurrence"):
-                o, new_state = LA.kda_recurrent_step(
-                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state)
+                o, new_state = LA.kda_recurrent_step(*one, state) \
+                    if pool is None else LA.kda_decode_step(*one, *pool)
                 o = o[:, None]
         else:
             with jax.named_scope("kda/chunk"):
@@ -285,14 +293,18 @@ class KimiLinearModel(nn.Module):
             kw: Dict[str, Any] = {"valid": valid}
             # reading and writing the rows' state belongs to the recurrence
             # (its roofline share counts the state in and out once: a
-            # write outside the scope read 161% of it, my chip run, PR 28)
-            state_scope = "kda/recurrence" if S == 1 else "kda/chunk"
+            # write outside the scope read 161% of it, my chip run, PR 28).
+            # A decode step hands the mixer the pool: its recurrence
+            # (``LA.kda_decode_step``, under the mixer's own
+            # ``kda/recurrence``) reads and writes the rows' slots itself.
             if served and kind == "kda":
-                with jax.named_scope(state_scope):
-                    rows_state = state[i_kda, slots]
-                kw.update(state=rows_state,
-                          conv_tail=conv[i_kda, slots].reshape(
-                              B, cfg.short_conv_kernel_size - 1, -1))
+                if S == 1:
+                    kw.update(pool=(state, i_kda, cache.get("slots")))
+                else:
+                    with jax.named_scope("kda/chunk"):
+                        kw.update(state=state[i_kda, slots])
+                kw.update(conv_tail=conv[i_kda, slots].reshape(
+                    B, cfg.short_conv_kernel_size - 1, -1))
             elif served:
                 kw.update(pages=pages, block_tables=cache["block_tables"],
                           seq_lengths=seq_lengths, layer=i_mla)
@@ -303,8 +315,11 @@ class KimiLinearModel(nn.Module):
                 counts.append(c)
             if kind == "kda":
                 if served:
-                    with jax.named_scope(state_scope):
-                        state = state.at[i_kda, slots].set(carried[0])
+                    if S == 1:
+                        state = carried[0]
+                    else:
+                        with jax.named_scope("kda/chunk"):
+                            state = state.at[i_kda, slots].set(carried[0])
                     conv = conv.at[i_kda, slots].set(
                         carried[1].astype(conv.dtype).reshape(B, -1))
                 i_kda += 1

@@ -431,6 +431,8 @@ class FlaxModelAdapter:
         self._routed_tokens_total = 0           # real tokens through a router
         self._expert_products: Dict[int, Any] = {}
         self._kv_pages_live = self._kv_pages_padded = 0
+        self._decode_recurrence: Optional[str] = None   # ``bind_state``
+        self._kda_kernel_steps = 0
         self._window_pages_live = self._window_pages_padded = 0
         self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
         self._flying: Optional[DecodeStep] = None   # dispatched, unfetched
@@ -557,6 +559,13 @@ class FlaxModelAdapter:
             layers, *rest = p["shape"]
             self._arrays[name] = jnp.zeros(
                 (layers, self.state_slots + 1, *rest), p["dtype"])
+            if p.get("recurrence") == "kda":
+                # what a decode step's recurrence runs (its dispatch
+                # span says it): the model's own chooser, asked with
+                # the same pool, as ``_decode_attention`` is
+                from ray_tpu.ops.linear_attention import kda_decode_path
+                self._decode_recurrence = kda_decode_path(
+                    self._arrays[name], 1)
         self._free_slots = list(range(self.state_slots, 0, -1))
 
     def counters(self) -> Dict[str, Any]:
@@ -575,6 +584,8 @@ class FlaxModelAdapter:
         out.update(state_slots_total=self.state_slots,
                    state_slots_in_use=self.state_slots
                    - len(self._free_slots))
+        if self._decode_recurrence is not None:
+            out["kda_kernel_steps_total"] = self._kda_kernel_steps
         if self._expert_tokens_total is not None:
             out["expert_tokens_total"] = self._expert_tokens_total.tolist()
             out["expert_tokens_last_step"] = self._expert_tokens_last.tolist()
@@ -877,7 +888,8 @@ class FlaxModelAdapter:
     def _count_pages(self, rows, B: int) -> Dict[str, Any]:
         """What a decode step's attention reads, for its dispatch span
         and ``counters()``: which path it takes (the kernel over the
-        rows' live pages, or the gather to every row's padded table),
+        rows' live pages, or the gather to every row's padded table; for
+        a model with a recurrent state, which path its recurrence takes),
         the pages that hold one of the rows' tokens, and the pages of
         the ``B`` padded tables."""
         bs = self.cache.block_size
@@ -889,6 +901,9 @@ class FlaxModelAdapter:
         out = {"attention": self._decode_attention,
                "live_tokens": sum(lens),
                "kv_pages_live": live, "kv_pages_padded": padded}
+        if self._decode_recurrence is not None:
+            out["recurrence"] = self._decode_recurrence
+            self._kda_kernel_steps += self._decode_recurrence == "kda_kernel"
         for w, ring in self._rings.items():
             # a window layer reads a row's last ``w`` positions: the ring
             # pages that hold one of them, of the ``ring`` a row holds

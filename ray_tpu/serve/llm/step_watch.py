@@ -35,10 +35,12 @@ EVENTS = ("py.gc", "jax.compile")
 
 # a thread whose innermost Python frame stands at such a call waits for
 # something else than the interpreter (the last two: this repo's RPC
-# core, native and with a timeout, and asyncio's wake-up socket)
+# core, native and with a timeout, and asyncio's wake-up socket;
+# ``self._read(`` / ``self._recv(``: execnet's reader thread, which every
+# pytest-xdist worker has, stands in such a wrapper of a socket's read)
 _WAIT_CALL = re.compile(
-    r"\b(wait\w*|acquire|sleep|select|poll|recv\w*|accept|join|get|read\w*"
-    r"|block_until_ready|result|asarray|\w*next_batch|send\w*)\(")
+    r"\b(wait\w*|acquire|sleep|select|poll|_?recv\w*|accept|join|get"
+    r"|_?read\w*|block_until_ready|result|asarray|\w*next_batch|send\w*)\(")
 _WAIT_FUNCS = frozenset({"wait", "acquire", "select", "poll", "join",
                          "_wait_for_tstate_lock"})
 

@@ -146,6 +146,54 @@ def test_latent_attention_decode(one_chip, L, P, B, H, NB):
              sds((B,), jnp.int32), sds((), jnp.int32))
 
 
+def _kda_step(sharding, B=64, slots=65, layers=6, H=32, d=128):
+    """The KDA decode kernel at the Kimi-Linear cell's shape: 64 rows
+    over the state pool [6, 65, 32, 128, 128] float32 where it lies, the
+    layer a traced scalar, each row's slot an array."""
+    from ray_tpu.ops import linear_attention as LA
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    vec = sds((B, H, d))
+    return (lambda q, k, v, g, beta, pool, layer, at:
+            LA.kda_recurrent_step_in_place(q, k, v, g, beta, pool, layer,
+                                           at)), (
+        vec, vec, vec, vec, sds((B, H)), sds((layers, slots, H, d, d)),
+        sds((), jnp.int32), sds((B,), jnp.int32))
+
+
+def test_kda_recurrence_decode(one_chip):
+    """One Mosaic call; the pool is its own output (donated: no bytes
+    beside the arguments), and nothing of a layer's rows of state is
+    copied, sliced or written back outside it."""
+    import math
+    fn, args = _kda_step(one_chip)
+    with jax.default_matmul_precision("default"):
+        big = jax.jit(fn, donate_argnums=(5,)).lower(*args).compile()
+    text = big.as_text()
+    assert text.count("tpu_custom_call") == 1 and "kda_recurrence" in text
+    memory = big.memory_analysis()
+    pool = math.prod(args[5].shape) * 4
+    assert memory.alias_size_in_bytes == pool
+    assert memory.temp_size_in_bytes < (1 << 20)
+    assert not _moved(text, 64 * 32 * 128 * 128)
+
+
+def test_bare_kda_kernel_is_refused_on_a_mesh(topo, monkeypatch):
+    """As the other kernels': a Mosaic call cannot be partitioned, so
+    ``kda_decode_path`` says ``xla`` where a mesh of several devices is
+    being traced for, and the bare call is refused there."""
+    from ray_tpu.ops import linear_attention as LA
+    mesh, _ = _mesh4(topo)
+    fn, args = _kda_step(NamedSharding(mesh, P()), B=8, slots=9, layers=1)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _compile(fn, *args)
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    assert LA.kda_decode_path(args[5], 1) == "kda_kernel"
+    with A.attention_mesh(mesh):
+        assert LA.kda_decode_path(args[5], 1) == "xla"
+
+
 def _routed_experts(monkeypatch, T, sharding, mesh=None):
     """The few-token product at Kimi-Linear's widths (64 held experts of
     3 x 2304 x 1024 bfloat16) as the chip runs it: the Mosaic kernel,
@@ -379,11 +427,27 @@ def test_kimi_step_writes_pages_and_state_in_place(one_chip, topo,
     """The latent pool and both state arrays are donated and come back
     as the program's outputs; nothing of the pool's or the state's size
     is copied, laid out anew, padded or stacked; what the program needs
-    beside its arguments grows with neither."""
+    beside its arguments grows with neither. A decode step's recurrence
+    is one Mosaic call a KDA layer over the state pool where it lies,
+    rows by ``slots`` and rows in slot order alike: nothing of a layer's
+    rows of state (B x H x dk x dv) or more is sliced, gathered, copied
+    or written back outside it."""
     import math
     import re
     arrays, big = _kimi_step(one_chip, topo, monkeypatch, 2049, slots, B, S)
     memory = big.memory_analysis()
+    if S == 1:
+        text = big.as_text()
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and "kda/recurrence" in line]
+        assert len(calls) == arrays[1].shape[0], calls      # a KDA layer
+        of_state = [
+            line.strip()[:120] for line in text.splitlines()
+            for m in [re.search(
+                r" = f32\[([\d,]+),32,128,128\]\S* (slice|dynamic-slice|"
+                r"gather|scatter|copy|dynamic-update-slice)\(", line)]
+            if m and math.prod(map(int, m.group(1).split(","))) >= B]
+        assert not of_state, of_state
     held = sum(math.prod(a.shape) * a.dtype.itemsize for a in arrays)
     # (the slot axis of the small arrays is padded to whole tiles)
     assert held <= memory.alias_size_in_bytes <= 1.02 * held

@@ -12,6 +12,8 @@ size <= 1 (a triangular solve of 64 unknowns against 64 sequential
 updates). A bfloat16 run of the same sizes differs by ~1e-2: three
 orders above either."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -39,13 +41,12 @@ def _scan_kda(q, k, v, g, beta, state):
     return np.stack(outs), state
 
 
-def _kda_inputs(S, seed, decay):
+def _kda_inputs(S, seed, decay, H=2, d=16):
     """decay 'slow': log-decays of -1e-3 .. -3e-2 a token, so the first
     token still weighs ~0.05-0.9 after 100; 'fast': down to -6 a token
     (exp(-G) of a chunk would overflow float32: the chunked form must
     never take it); 'mixed': both in one head."""
     rng = np.random.default_rng(seed)
-    H, d = 2, 16
     q, k, v = (rng.normal(size=(S, H, d)) for _ in range(3))
     k /= np.linalg.norm(k, axis=-1, keepdims=True)
     q /= np.linalg.norm(q, axis=-1, keepdims=True) * 4
@@ -96,6 +97,202 @@ def test_one_token_recurrence_and_empty_positions():
     _, s_pad = LA.kda_chunked(pad(q), pad(k), pad(v), pad(g), pad(beta),
                               f(state)[None])
     np.testing.assert_allclose(s_pad[0], want_s, atol=TOL)
+
+
+# The decode kernel (``kda_recurrent_step_in_place``), interpreted. It is
+# float32 throughout: the state's products are multiplied and summed on
+# the vector unit in float32, never rounded to bfloat16 on the way, so it
+# is held to the float64 scan at the tolerance of the XLA step (a 128-term
+# float32 sum), and the same step with single-pass bfloat16 products must
+# FAIL that tolerance: the benchmark's ``correct`` cannot see the
+# difference (PERF.md section 7), this test can.
+TILES = {"tiny": (2, 16), "published": (32, 128)}
+# rows' slots in a pool of 6: out of order with the null slot twice (two
+# padding rows), and rows in slot order
+SLOTS = {"by_slots": (4, 0, 1, 0, 3), "in_slot_order": (1, 2, 3, 4, 5)}
+
+
+def _pool_steps(tile, decay, slots, step, T=4):
+    """``T`` tokens of len(slots) rows through ``step(q, k, v, g, beta,
+    pool, layer, slots)`` on layer 1 of a pool [3, 6, H, d, d]; a row at
+    slot 0 is a padding row (g = 0, beta = 0). Returns the largest
+    differences to the float64 scan (outputs, final states), the first
+    pool and the last."""
+    H, d = TILES[tile]
+    rows = [_kda_inputs(T, 7 * b + len(decay), decay, H, d)
+            for b in range(len(slots))]
+    rng = np.random.default_rng(3)
+    first = rng.normal(size=(3, 6, H, d, d)).astype(np.float32) * 0.3
+    for b, slot in enumerate(slots):
+        if slot:
+            first[1, slot] = rows[b][5]
+        else:
+            rows[b][3] *= 0.0
+            rows[b][4] *= 0.0
+    f = lambda i, t: jnp.asarray(                           # noqa: E731
+        np.stack([r[i][t] for r in rows]), jnp.float32)
+    pool, err_o = jnp.asarray(first), 0.0
+    want = [_scan_kda(*r[:5], first[1, slot].astype(np.float64))
+            for r, slot in zip(rows, slots)]
+    for t in range(T):
+        o, pool = step(f(0, t), f(1, t), f(2, t), f(3, t), f(4, t), pool,
+                       1, jnp.asarray(slots, jnp.int32))
+        for b, slot in enumerate(slots):
+            if slot:
+                err_o = max(err_o, float(np.abs(o[b] - want[b][0][t]).max()))
+    err_s = max(float(np.abs(pool[1, slot] - w[1]).max())
+                for w, slot in zip(want, slots) if slot)
+    return err_o, err_s, first, np.asarray(pool)
+
+
+def _xla_step(single_pass_bf16=False):
+    """``kda_recurrent_step`` over gathered rows, scattered back; or its
+    mathematics with the state's two products in ONE bfloat16 pass
+    (what an MXU product at default precision would be)."""
+    def step(q, k, v, g, beta, pool, layer, slots):
+        state = pool[layer, slots]
+        if not single_pass_bf16:
+            o, new = LA.kda_recurrent_step(q, k, v, g, beta, state)
+        else:
+            bf = jnp.bfloat16
+            a = jnp.exp(g)
+            sk, sq = (jnp.einsum("bhij,bhi->bhj", state.astype(bf),
+                                 (a * x).astype(bf),
+                                 preferred_element_type=jnp.float32)
+                      for x in (k, q))
+            r = (v - sk) * beta[..., None]
+            o = sq + jnp.sum(k * q, axis=-1, keepdims=True) * r
+            new = a[..., None] * state + k[..., None] * r[..., None, :]
+        real = (slots > 0)[:, None, None, None]
+        return o, pool.at[layer, slots].set(jnp.where(real, new, state))
+    return step
+
+
+_KERNEL = functools.partial(LA.kda_recurrent_step_in_place, interpret=True)
+
+
+@pytest.mark.parametrize("slots", list(SLOTS))
+@pytest.mark.parametrize("decay", ["slow", "mixed", "fast"])
+@pytest.mark.parametrize("tile", list(TILES))
+def test_decode_kernel_equals_the_step_and_the_scan(tile, decay, slots):
+    """Float32 throughout: the kernel is as close to the float64 scan
+    as the XLA step is, over several tokens, through a pool."""
+    err_o, err_s, first, last = _pool_steps(tile, decay, SLOTS[slots],
+                                            _KERNEL)
+    assert err_o < TOL and err_s < TOL, (err_o, err_s)
+    ref_o, ref_s, _, ref_last = _pool_steps(tile, decay, SLOTS[slots],
+                                            _xla_step())
+    assert ref_o < TOL and ref_s < TOL, (ref_o, ref_s)
+    np.testing.assert_allclose(last, ref_last, atol=TOL)
+    # slots the steps did not name, the null slot of the padding rows
+    # and the other layers are bit for bit what they were
+    named = np.zeros(first.shape[:2], bool)
+    named[1, [s for s in SLOTS[slots] if s]] = True
+    assert not named[1, 0]
+    np.testing.assert_array_equal(last[~named], first[~named])
+    assert np.abs(last[named] - first[named]).max() > 1e-3
+
+
+@pytest.mark.parametrize("decay", ["slow", "mixed"])
+def test_single_pass_bfloat16_products_fail_the_kernels_tolerance(decay):
+    """The same inputs with the state's products rounded to bfloat16
+    once: outside the tolerance the kernel passes, by an order and more.
+    (Fast decays forget the state within a token, and hide it.)"""
+    slots = SLOTS["by_slots"]
+    err_o, err_s, _, _ = _pool_steps("published", decay, slots, _KERNEL)
+    bf_o, bf_s, _, _ = _pool_steps("published", decay, slots,
+                                   _xla_step(single_pass_bf16=True))
+    assert max(err_o, err_s) < TOL
+    assert bf_o > 10 * TOL and bf_s > 10 * TOL, (bf_o, bf_s)
+
+
+def test_decode_kernel_leaves_an_empty_row_bit_for_bit():
+    """``g = 0, beta = 0`` on a row that names a real slot (a free slot
+    of the by-slot bucket): its state comes back bit for bit."""
+    H, d = TILES["published"]
+    q, k, v, g, beta, _ = (jnp.asarray(np.stack([x] * 2)[:, 0], jnp.float32)
+                           for x in _kda_inputs(1, 2, "mixed", H, d))
+    pool = jnp.asarray(np.random.default_rng(4).normal(
+        size=(2, 3, H, d, d)), jnp.float32)
+    live = jnp.array([1.0, 0.0])
+    o, new = _KERNEL(q, k, v, g * live[:, None, None], beta * live[:, None],
+                     pool, 0, jnp.array([2, 1]))
+    np.testing.assert_array_equal(new[0, 1], pool[0, 1])
+    np.testing.assert_array_equal(new[1], pool[1])
+    np.testing.assert_array_equal(new[0, 0], pool[0, 0])
+    assert float(jnp.abs(new[0, 2] - pool[0, 2]).max()) > 1e-3
+    # and that row's output is the state's answer to its query
+    np.testing.assert_allclose(o[1], jnp.einsum(
+        "hij,hi->hj", pool[0, 1], q[1], precision="highest"), atol=TOL)
+
+
+def _pool(H=32, d=128, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct((6, 65, H, d, d), dtype)
+
+
+@pytest.mark.parametrize("case,want", [
+    ("chip", "kda_kernel"), ("cpu", "xla"), ("prompt", "xla"),
+    ("no_pool", "xla"), ("mesh_2x2", "xla"), ("one_device_mesh",
+                                              "kda_kernel"),
+    ("head_size_16", "xla"), ("two_heads", "xla"), ("bfloat16", "xla")])
+def test_kda_decode_path(monkeypatch, case, want):
+    """The chooser, from what it can observe: the kernel for one token
+    a row on a TPU over a float32 pool of whole tiles, outside a mesh
+    of several devices; the XLA step for everything else."""
+    from jax.sharding import Mesh
+    monkeypatch.setattr(A, "_use_pallas", lambda: case != "cpu")
+    pool = {"no_pool": None, "head_size_16": _pool(d=16),
+            "two_heads": _pool(H=2),
+            "bfloat16": _pool(dtype=jnp.bfloat16)}.get(case, _pool())
+    devices = {"mesh_2x2": np.array(jax.devices()[:4]).reshape(2, 2),
+               "one_device_mesh": np.array(jax.devices()[:1]).reshape(1, 1)}
+    if case in devices:
+        with A.attention_mesh(Mesh(devices[case], ("dp", "tp"))):
+            got = LA.kda_decode_path(pool, 1)
+    else:
+        got = LA.kda_decode_path(pool, 64 if case == "prompt" else 1)
+    assert got == want
+
+
+@pytest.mark.parametrize("by_slot", [True, False],
+                         ids=["in_slot_order", "by_slots"])
+def test_served_decode_step_through_the_kernel(monkeypatch, by_slot):
+    """The model's call site: a decode step of three rows (one a padding
+    row) where the chooser says ``kda_kernel`` returns the logits, the
+    state pool and the tails of the step that gathers and scatters."""
+    cfg = KimiLinearConfig.tiny(kda_num_heads=8, kda_head_dim=128,
+                                num_hidden_layers=3, kda_layers=(1, 2),
+                                full_attn_layers=(3,))
+    params = glue.init_for(cfg, 5)
+    spec, rng = cache_spec(cfg), np.random.default_rng(6)
+    B, n_slots = 3, 4
+    cache = {"kv_pages": jnp.zeros((1, 4, 8, 128)),
+             "block_tables": jnp.array([[1], [2], [0]]),
+             "kda_state": jnp.asarray(rng.normal(size=(
+                 2, n_slots, 8, 128, 128)) * 0.3, jnp.float32),
+             "kda_conv": jnp.asarray(rng.normal(size=(
+                 2, n_slots, spec["state"]["kda_conv"]["shape"][1])),
+                 jnp.float32)}
+    if not by_slot:
+        cache["slots"] = jnp.array([3, 1, 0])
+    kw = dict(cache=cache, seq_lengths=jnp.array([2, 5, 0]),
+              valid=jnp.array([[True], [True], [False]]))
+    ids = jnp.array([[7], [11], [0]])
+    want, want_cache, _ = KimiLinearModel(cfg).apply(params, ids, **kw)
+    monkeypatch.setattr(LA, "kda_decode_path",
+                        lambda pool, S: "kda_kernel" if S == 1 else "xla")
+    monkeypatch.setattr(LA, "kda_recurrent_step_in_place", _KERNEL)
+    got, got_cache, _ = KimiLinearModel(cfg).apply(params, ids, **kw)
+    np.testing.assert_allclose(got[:2], want[:2], atol=TOL)
+    for name in ("kda_state", "kda_conv", "kv_pages"):
+        np.testing.assert_allclose(got_cache[name], want_cache[name],
+                                   atol=TOL)
+    # the padding row's slot (slot 3 in slot order, the null slot
+    # otherwise) and the slot no row names are bit for bit
+    for slot in ((3,) if by_slot else (0, 2)):
+        np.testing.assert_array_equal(got_cache["kda_state"][:, slot],
+                                      cache["kda_state"][:, slot])
+    assert B == got.shape[0]
 
 
 def test_short_conv_carries_its_tail():

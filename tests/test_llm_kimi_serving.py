@@ -212,6 +212,11 @@ def test_kimi_engine_counts_slots_experts_and_the_admit_span():
     admits = [c["name"] for step in log for s in walk(step)
               if s["name"] == "llm.step.prefill" for c in walk(s)]
     assert admits.count("runner.state.admit") >= 3
+    # off the chip the recurrence is the XLA step, and the spans say so
+    said = {s["attrs"].get("recurrence") for step in log for d in walk(step)
+            if d["name"] == "llm.step.decode" for s in walk(d)
+            if s["name"] == "runner.dispatch"}
+    assert said == {"xla"} and m["kda_kernel_steps_total"] == 0
     fetches = [s for step in log for d in walk(step)
                if d["name"] == "llm.step.decode" for s in walk(d)
                if s["name"] == "runner.fetch"]
@@ -219,6 +224,54 @@ def test_kimi_engine_counts_slots_experts_and_the_admit_span():
         0 <= f["attrs"]["experts_touched"] <= 12
         and f["attrs"]["moe_max_over_mean"] >= 1.0
         for f in fetches if f["attrs"].get("expert_tokens"))
+
+
+def test_kimi_decode_steps_through_the_recurrence_kernel(monkeypatch):
+    """Where the chooser says ``kda_kernel`` (here: patched, the kernel
+    interpreted; heads of whole 128 x 128 tiles) the adapter's decode
+    steps, rows in slot order (a bucket as wide as the slots) and rows
+    by ``slots`` (a narrower one), return the logits of the steps that
+    gather and scatter, leave the same state in the slots, and are
+    counted."""
+    import functools
+
+    from benchmark.reference import kimi_linear_glue
+    from ray_tpu.models.kimi_linear import KimiLinearConfig
+    from ray_tpu.ops import linear_attention as LA
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+    cfg = KimiLinearConfig.tiny(kda_num_heads=8, kda_head_dim=128)
+    params = kimi_linear_glue.init_for(cfg, 9)
+
+    def serve():
+        adapter = FlaxModelAdapter("kimi_linear", cfg, params)
+        cache = PagedKVCache(num_blocks=64, block_size=PAGE)
+        adapter.bind_cache(cache)
+        adapter.bind_state(4)
+        prompts = token_prompts(43, adapter.vocab_size, (21, 5, 33))
+        seqs = [flax_seq(cache, f"s{i}", p, budget=8)
+                for i, p in enumerate(prompts)]
+        rows = _kimi_serve(adapter, seqs, 2)             # bucket 4: in order
+        adapter.release("s1")
+        cache.free("s1")
+        rows = _kimi_serve(adapter, [seqs[0], seqs[2]], 2,
+                           rows=[rows[0], rows[2]])      # bucket 2: slots
+        return adapter, np.stack([np.stack(r) for r in rows])
+
+    plain, want = serve()
+    assert plain._decode_recurrence == "xla"
+    assert plain.counters()["kda_kernel_steps_total"] == 0
+    monkeypatch.setattr(LA, "kda_decode_path",
+                        lambda pool, S: "kda_kernel" if S == 1 else "xla")
+    monkeypatch.setattr(LA, "kda_recurrent_step_in_place", functools.partial(
+        LA.kda_recurrent_step_in_place, interpret=True))
+    kernel, got = serve()
+    assert kernel._decode_recurrence == "kda_kernel"
+    assert kernel.counters()["kda_kernel_steps_total"] == 4
+    np.testing.assert_allclose(got, want, atol=KIMI_TOL)
+    np.testing.assert_allclose(kernel._arrays["kda_state"],
+                               plain._arrays["kda_state"], atol=KIMI_TOL)
+    # the null slot is as it was made
+    assert float(np.abs(kernel._arrays["kda_state"][:, 0]).max()) == 0.0
 
 
 @pytest.mark.parametrize("what", [

@@ -526,6 +526,24 @@ def test_a_step_that_waited_for_the_interpreter_says_who_held_it():
     assert "test-beside" in rec["why"] or "moved on" in rec["why"]
 
 
+@pytest.mark.parametrize("line,waits", [
+    ("data = self._read(numbytes - len(buf))", True),
+    ("item = self._queue.get(timeout=1.0)", True),
+    ("ready.wait_for(lambda: done)", True),
+    ("total = sum(range(n))", False),
+    ("value = self._result(key)", False),
+    ("spread(values)", False)])
+def test_what_counts_as_a_wait(tmp_path, line, waits):
+    """A thread whose innermost frame stands at a wait (``self._read(``
+    too: execnet's reader thread of an xdist worker) is not named as the
+    one that held the interpreter; one busy in another private helper
+    is."""
+    from ray_tpu.serve.llm import step_watch
+    src = tmp_path / "held.py"
+    src.write_text(line + "\n")
+    assert step_watch._waits([(str(src), 1, "run")]) is waits
+
+
 def test_a_step_that_waited_for_the_engine_lock_says_lock():
     def hold(engine, adapter):
         assert adapter.entered.wait(timeout=60.0)
