@@ -1,0 +1,12 @@
+"""Device time of the operations traced under the attn_full scope (projections, the page write, the paged kernel, the output) over the decode steps' device time."""
+
+NAME = "smallthinker_attn_full_step_share.serve"
+UNIT = "%"
+LAYER = "kernels"
+MOVES = "serve_tokens_per_s"
+SOURCE = "device_trace"
+
+
+def read(obs):
+    from benchmark.harness import decode_scopes as ds
+    return ds.scope_share(obs, ("attn_full",))

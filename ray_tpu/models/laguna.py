@@ -234,8 +234,11 @@ def cache_spec(cfg: LagunaConfig) -> Dict[str, Any]:
 
 class LagunaAttention(nn.Module):
     """One layer's attention: ``heads`` query heads; ``window`` None (a
-    full layer) or the positions a sliding layer reads."""
-    config: LagunaConfig
+    full layer) or the positions a sliding layer reads. ``config`` is a
+    ``LagunaConfig``, or another family's with the same names
+    (``models/smallthinker.py``: its ``rope_of`` gives None for a layer
+    type without rotary, and it has no gate)."""
+    config: Any
     heads: int
     window: Optional[int]
 
@@ -266,9 +269,10 @@ class LagunaAttention(nn.Module):
         served = k_pages is not None
         at = jnp.arange(S)[None, :] + (
             seq_lengths[:, None] if served else jnp.zeros((B, 1), jnp.int32))
-        with jax.named_scope(f"{scope}/rope"):
-            turn = rope.cos_sin(at)
-            q, k = rope.rotate(q, turn), rope.rotate(k, turn)
+        if rope is not None:        # (None: no position encoding, NoPE)
+            with jax.named_scope(f"{scope}/rope"):
+                turn = rope.cos_sin(at)
+                q, k = rope.rotate(q, turn), rope.rotate(k, turn)
         q_pos = at if valid is None else jnp.where(valid, at, -1)
         if not served or S > 1:
             with jax.named_scope(f"{scope}/attend"):
@@ -352,7 +356,8 @@ class LagunaBlock(nn.Module):
 
 
 class LagunaModel(nn.Module):
-    config: LagunaConfig
+    config: Any                 # LagunaConfig (or a subclass's own)
+    block = LagunaBlock         # what a subclass replaces: one layer
 
     @nn.compact
     def __call__(self, input_ids, cache=None, seq_lengths=None, valid=None,
@@ -386,7 +391,7 @@ class LagunaModel(nn.Module):
                     block_tables=cache["block_tables"] if kind == FULL
                     else cache["window_tables"][cfg.sliding_window],
                     seq_lengths=seq_lengths, layer=nth[kind])
-            x, k_pages, v_pages, c = LagunaBlock(
+            x, k_pages, v_pages, c = self.block(
                 cfg, i, name=f"layers_{i}")(x, kw, valid=valid)
             if served:
                 cache[f"k_{name}"], cache[f"v_{name}"] = k_pages, v_pages

@@ -1403,8 +1403,24 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables,
 
 
 # tokens the kernel attends to at a time: the pages that hold them are
-# copied into VMEM together while the chunk before is worked on
+# copied into VMEM together while the chunk before is worked on. A chunk
+# costs ~0.5 us whatever it holds, so a pool whose rows (all kv heads of
+# a token) are narrower than 2 KiB takes four times the tokens: at 1 KiB
+# a row (4 kv heads of 128, bfloat16) 96 rows of the contexts a mixed
+# queue holds read 1.93 ms a layer in chunks of 128, 1.53 in 256, 1.32
+# in 512 (39 / 49 / 57% of the time the bytes take; a window of 4,096:
+# 1.59 / 1.29 / 1.16 ms; my chip runs, PR 43). At 2 KiB and over the
+# chunk is what it was.
 _PAGED_CHUNK_TOKENS = 128
+_PAGED_NARROW_ROW_BYTES = 2048
+_PAGED_NARROW_CHUNK_TOKENS = 512
+
+
+def paged_chunk_tokens(row_bytes: int) -> int:
+    """Tokens a chunk of ``paged_attention_decode`` for a pool whose rows
+    hold ``row_bytes``."""
+    return (_PAGED_CHUNK_TOKENS if row_bytes >= _PAGED_NARROW_ROW_BYTES
+            else _PAGED_NARROW_CHUNK_TOKENS)
 
 
 def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
@@ -1569,7 +1585,7 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
     pools [L, P, bs, Hkv*D], read where they lie: nothing is gathered,
     sliced or laid out anew outside the kernel, and only the pages that
     hold one of a row's ``lengths`` tokens are read (in chunks of
-    ``_PAGED_CHUNK_TOKENS``). MHA and GQA (H a multiple of Hkv); the
+    ``paged_chunk_tokens``). MHA and GQA (H a multiple of Hkv); the
     products are exact and summed in float32, the softmax is float32
     and online, the probabilities meet V in the pool's dtype: the
     mathematics of ``decode_attention``. A row of length 0 gives zeros.
@@ -1602,7 +1618,8 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
     G = H // Hkv
     if sm_scale is None:
         sm_scale = D ** -0.5
-    pages = max(1, min(_PAGED_CHUNK_TOKENS // bs, NB))
+    pages = max(1, min(paged_chunk_tokens(
+        C * jnp.dtype(k_pages.dtype).itemsize) // bs, NB))
     # row h of the kernel's matrices is head h; whole sublane tiles of
     # the pool's dtype
     Hp = -(-H // 16) * 16

@@ -37,6 +37,9 @@ import jax.numpy as jnp
 from ray_tpu.ops import attention as A
 
 ROW_TILE = 16       # rows are padded to whole bfloat16 sublane tiles
+# the gate's activation, a field of the layer (``RoutedExperts.act``):
+# SwiGLU's, or ReGLU's
+ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 # The widest d_ff tile whose three weight blocks, twice (the pipeline's
 # two buffers), stay under this much VMEM (a v5e core has 128 MiB). At
 # the Kimi-Linear widths that is a whole expert a grid step (28 MB), which
@@ -81,7 +84,7 @@ def live_block(i, j, order, n, last_tile):
 
 
 def _kernel(order_ref, n_ref, x_ref, cw_ref, gate_ref, up_ref, down_ref,
-            y_ref):
+            y_ref, *, act):
     from jax.experimental import pallas as pl
     i, j = pl.program_id(0), pl.program_id(1)
 
@@ -94,13 +97,15 @@ def _kernel(order_ref, n_ref, x_ref, cw_ref, gate_ref, up_ref, down_ref,
         x = x_ref[...]
         g = jnp.dot(x, gate_ref[...], preferred_element_type=jnp.float32)
         u = jnp.dot(x, up_ref[...], preferred_element_type=jnp.float32)
-        h = jax.nn.silu(g) * u * cw_ref[...]
+        h = ACTS[act](g) * u * cw_ref[...]
         y_ref[...] += jnp.dot(h.astype(x.dtype), down_ref[...],
                               preferred_element_type=jnp.float32)
 
 
-def touched_experts(x, combine, counts, w_gate, w_up, w_down):
-    """``sum_e combine[:, e] * SwiGLU_e(x)`` over the experts with
+def touched_experts(x, combine, counts, w_gate, w_up, w_down,
+                    act: str = "silu"):
+    """``sum_e combine[:, e] * GLU_e(x)`` (the gate through ``act``: SiLU
+    or ReLU) over the experts with
     ``counts[e] > 0``. x [T, d] in the weights' dtype, combine [T, E]
     float32 (zero where a row did not choose the expert; it must be zero
     in every column whose count is zero), counts [E], w_gate and w_up
@@ -135,7 +140,7 @@ def touched_experts(x, combine, counts, w_gate, w_up, w_down):
     need = 2 * (3 * d * tf * item + rows * 128 * 4) \
         + 2 * rows * d * (item + 4)
     call = pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(E, tiles),
             in_specs=[
@@ -167,7 +172,7 @@ def live_rows(i, j, block_expert, n, last_tile):
 
 
 def _grouped_kernel(expert_ref, n_ref, x_ref, w_ref, gate_ref, up_ref,
-                    down_ref, y_ref):
+                    down_ref, y_ref, *, act):
     from jax.experimental import pallas as pl
     i, j = pl.program_id(0), pl.program_id(1)
 
@@ -176,7 +181,7 @@ def _grouped_kernel(expert_ref, n_ref, x_ref, w_ref, gate_ref, up_ref,
         x = x_ref[...]
         g = jnp.dot(x, gate_ref[...], preferred_element_type=jnp.float32)
         u = jnp.dot(x, up_ref[...], preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        h = (ACTS[act](g) * u).astype(x.dtype)
         y = jnp.dot(h, down_ref[...],
                     preferred_element_type=jnp.float32) * w_ref[...]
 
@@ -190,8 +195,9 @@ def _grouped_kernel(expert_ref, n_ref, x_ref, w_ref, gate_ref, up_ref,
 
 
 def grouped_experts(xs, weight, block_expert, n, w_gate, w_up, w_down,
-                    block_rows: int):
-    """``weight[r] * SwiGLU_e(xs[r])`` for the rows of the first ``n[0]``
+                    block_rows: int, act: str = "silu"):
+    """``weight[r] * GLU_e(xs[r])`` (the gate through ``act``: SiLU or
+    ReLU) for the rows of the first ``n[0]``
     blocks of ``block_rows`` rows, ``e = block_expert[r // block_rows]``:
     the rows sorted by expert, each expert's group padded to whole
     blocks. xs [R, d] in the weights' dtype, weight [R] float32 (zero on
@@ -205,7 +211,7 @@ def grouped_experts(xs, weight, block_expert, n, w_gate, w_up, w_down,
     one expert name the same weights, which stay resident (where an
     expert is one tile); the next expert's tiles are fetched under the
     current block's products; steps past the last live block name what
-    is resident and compute nothing. Gate, up, SiLU and down run on a
+    is resident and compute nothing. Gate, up, the activation and down run on a
     block in VMEM: bfloat16 operands, float32 sums, ``h`` rounded once."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -233,7 +239,7 @@ def grouped_experts(xs, weight, block_expert, n, w_gate, w_up, w_down,
     need = 2 * (3 * d * tf * item + bm * d * (item + 4) + bm * 128 * 4) \
         + bm * (3 * tf + d) * 4
     call = pl.pallas_call(
-        _grouped_kernel,
+        functools.partial(_grouped_kernel, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(R // bm, tiles),
             in_specs=[

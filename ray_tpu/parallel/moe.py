@@ -19,8 +19,12 @@ Top-1 (Switch) routing with the standard load-balance auxiliary loss.
 
 ``RoutedExperts`` beside it is the layer the served models use: sigmoid
 or softmax scores over the router's whole width, top-k, renormalised or
-not, no capacity and no drop, SwiGLU experts, computed for the experts
-one chip holds (docs/LLM_SERVING.md, "Routed experts"). Its product
+not, no capacity and no drop, gated experts (``act``: SwiGLU's SiLU, or
+ReGLU's ReLU, in both kernels and the shared expert), computed for the
+experts one chip holds (docs/LLM_SERVING.md, "Routed experts"). The
+router may read another tensor than the experts multiply (``router_x``: a
+layer that routes on its attention's input, so that the choice and the
+sort do not wait for the attention). Its product
 multiplies the groups that hold a token and reads no other expert's
 weights: whole rows through the touched experts for few tokens, sorted
 row blocks for many (both kernels: ``ops/routed_experts.py``). A router
@@ -38,7 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops.routed_experts import ROW_TILE, grouped_experts, \
+from ray_tpu.ops.routed_experts import ACTS, ROW_TILE, grouped_experts, \
     touched_experts
 
 
@@ -109,10 +113,11 @@ class MoE(nn.Module):
 
 class SwiGLU(nn.Module):
     """``W_d (SiLU(W_g x) * W_u x)``: a dense feed-forward, and the
-    shared expert of ``RoutedExperts``. Weights are stored in
-    ``dtype``."""
+    shared expert of ``RoutedExperts`` (``act="relu"``: ReGLU). Weights
+    are stored in ``dtype``."""
     d_ff: int
     dtype: Any = jnp.bfloat16
+    act: str = "silu"
 
     @nn.compact
     def __call__(self, x):
@@ -122,7 +127,7 @@ class SwiGLU(nn.Module):
         up = self.param("up", init, (d, self.d_ff), self.dtype)
         down = self.param("down", init, (self.d_ff, d), self.dtype)
         x = x.astype(self.dtype)
-        return jnp.matmul(nn.silu(x @ gate) * (x @ up), down,
+        return jnp.matmul(ACTS[self.act](x @ gate) * (x @ up), down,
                           preferred_element_type=jnp.float32)
 
 
@@ -211,8 +216,11 @@ class RoutedExperts(nn.Module):
     bias``, and weighs the chosen by ``score / sum(chosen scores)`` (if
     ``renormalize``; else the score as it is) times ``scaling``. Of the
     chosen, this layer computes those in ``held = (first, count)``: the
-    part of the result that its own SwiGLU experts give, plus
+    part of the result that its own gated experts give (``act``: the
+    gate's activation, ``"silu"`` or ``"relu"``), plus
     ``shared_d_ff`` wide shared expert(s) that every chip computes alike.
+    ``router_x`` (the shape of ``x``): what the router scores in place of
+    ``x``, which the experts still multiply.
     What the absent experts would add is left out; nothing stands in for
     them. Returns ``(y, counts)``, counts [count] int32 the real tokens
     sent to each held expert this call.
@@ -249,11 +257,13 @@ class RoutedExperts(nn.Module):
     dtype: Any = jnp.bfloat16
     score: str = "sigmoid"                       # | "softmax"
     zero_experts: int = 0           # router outputs beyond the real experts
+    act: str = "silu"                            # | "relu"
 
     @nn.compact
-    def __call__(self, x, valid=None):
+    def __call__(self, x, valid=None, router_x=None):
         *lead, d = x.shape
         xf = x.reshape(-1, d)
+        rf = xf if router_x is None else router_x.reshape(-1, d)
         T = xf.shape[0]
         first, count = self.held or (0, self.num_experts)
         real = jnp.ones((T,), bool) if valid is None else valid.reshape(T)
@@ -267,7 +277,7 @@ class RoutedExperts(nn.Module):
             bias = self.param("router_bias", nn.initializers.zeros,
                               (width,), jnp.float32)
             scores = squash(jnp.matmul(
-                xf.astype(jnp.float32), w_r,
+                rf.astype(jnp.float32), w_r,
                 precision=jax.lax.Precision.HIGHEST))          # [T, E]
             _, chosen = jax.lax.top_k(scores + bias, self.top_k)
             w = jnp.take_along_axis(scores, chosen, axis=1)    # [T, k]
@@ -299,13 +309,13 @@ class RoutedExperts(nn.Module):
                     jax.nn.one_hot(local, count, dtype=jnp.float32)
                     * w[..., None], axis=1)                    # [T, count]
                 y = touched_experts(xb, combine, counts, w_gate, w_up,
-                                    w_down)
+                                    w_down, self.act)
             else:
                 y, counts = _experts_grouped(xb, local, w, w_gate, w_up,
-                                             w_down, plan)
+                                             w_down, plan, self.act)
         if self.shared_d_ff:
             with jax.named_scope("moe/shared"):
-                y = y + SwiGLU(self.shared_d_ff, self.dtype,
+                y = y + SwiGLU(self.shared_d_ff, self.dtype, self.act,
                                name="shared")(xb)
         if not self.zero_experts:
             return y.reshape(*lead, d), counts
@@ -369,7 +379,8 @@ def _sorted_rows(local, w, E: int, bm: int):
         sizes, order, dest
 
 
-def _experts_grouped(x, local, w, w_gate, w_up, w_down, plan):
+def _experts_grouped(x, local, w, w_gate, w_up, w_down, plan,
+                     act: str = "silu"):
     """Grouped products: the assignments sorted by expert, each expert's
     group padded to whole blocks of ``plan.block_rows`` rows, and
     ``grouped_experts`` (one kernel) over the blocks that hold a token.
@@ -393,7 +404,7 @@ def _experts_grouped(x, local, w, w_gate, w_up, w_down, plan):
             local, None, E, bm)
         ys = grouped_experts(x[jnp.minimum(token, T - 1)],
                              (token < T).astype(jnp.float32), block_expert,
-                             live.reshape(1), w_gate, w_up, w_down, bm)
+                             live.reshape(1), w_gate, w_up, w_down, bm, act)
         # each assignment's row (0 for one that is not this chip's); the
         # rows of a block past the live ones were NOT WRITTEN
         back = jnp.zeros((A,), jnp.int32).at[order].set(
@@ -416,7 +427,8 @@ def _experts_grouped(x, local, w, w_gate, w_up, w_down, plan):
             jax.lax.dynamic_slice_in_dim(weight, c * C, C),
             jax.lax.dynamic_slice_in_dim(block_expert, c * (C // bm),
                                          C // bm),
-            (live - c * (C // bm)).reshape(1), w_gate, w_up, w_down, bm)
+            (live - c * (C // bm)).reshape(1), w_gate, w_up, w_down, bm,
+            act)
         # the rows of a block past the live ones were NOT WRITTEN and
         # have no token: dropped
         return y.at[tok].add(ys, mode="drop")

@@ -132,6 +132,9 @@ class EngineConfig:
     max_prefill_tokens: int = 512  # prompt tokens attachable per step
     max_seq_len: int = 2048        # prompt + generation hard cap
     num_blocks: int = 512          # KV pool pages (+1 reserved null)
+    # pages of a windowed page group's pool, its null page included (a
+    # model with sliding-window layers); None: max_running whole rings
+    window_blocks: Optional[int] = None
     block_size: int = 16           # tokens per page
     policy: str = "continuous"     # continuous | static
     enable_prefix_cache: bool = False   # radix prefix KV sharing
@@ -223,12 +226,16 @@ class LLMEngine:
     def __init__(self, adapter, config: Optional[EngineConfig] = None):
         self.adapter = adapter
         self.config = config or EngineConfig()
-        # a model's windowed page kinds are page groups of their own: a
-        # ring a running sequence (kv_cache.py)
+        # a model's windowed page kinds are page groups of their own: what
+        # a running sequence needs of a ring (kv_cache.py)
         self._windows = tuple(getattr(adapter, "page_windows", ()))
         self.cache = PagedKVCache(
             self.config.num_blocks, self.config.block_size,
-            windows=self._windows, max_sequences=self.config.max_running)
+            windows=self._windows, max_sequences=self.config.max_running,
+            window_blocks=self.config.window_blocks)
+        # sequences whose admission waited for pages, by the page group
+        # that was short ("full", or a window); each counted once
+        self._admissions_waited: Dict[Any, int] = {}
         adapter.bind_cache(self.cache)
         self._refuse_with_window(
             self.config.enable_prefix_cache, "enable_prefix_cache",
@@ -666,6 +673,10 @@ class LLMEngine:
                 "lock_wait_seconds_total": round(
                     self._lock_wait_seconds_total, 6),
                 "slow_steps_total": self._watch.total,
+                # {"full" | "window_<w>": sequences that waited on it}
+                "admissions_waited_total": {
+                    g if g == "full" else f"window_{g}": n
+                    for g, n in self._admissions_waited.items()},
             }
         out.update(tracing.process_counters())
         out.update(self.cache.stats())
@@ -778,7 +789,11 @@ class LLMEngine:
                                             seq.budget_tokens())
                 seq.t_alloc = time.time()
                 seq._t_alloc_start = t0  # type: ignore[attr-defined]
-            except OutOfKVBlocksError:
+            except OutOfKVBlocksError as e:
+                if not getattr(seq, "_kv_waited", False):
+                    seq._kv_waited = True  # type: ignore[attr-defined]
+                    self._admissions_waited[e.group] = \
+                        self._admissions_waited.get(e.group, 0) + 1
                 break  # pages free up as running sequences finish
             seq.cached_tokens = cached
             if cached:

@@ -26,14 +26,24 @@ branch) drops.
 A model may cache some layers over a *window*: a layer that only ever
 reads the last ``window`` positions (a sliding-attention layer). Those
 layers' pages form a **page group** of their own (``windows=``): a
-sequence holds ``window // block_size + 1`` pages of the group as a
-*ring* (position ``p`` lies in ring page ``(p // block_size) % ring``,
-so a page is overwritten once every position it held has left the
-window), taken with the sequence's other pages at admission and returned
-with them at release. The group's pool is ``max_sequences`` rings and a
-null page of its own; admission counts every group, so it stays exact.
-A ring page is overwritten in place: it is never refcounted, shared or
-copied on write.
+sequence's *ring* there is ``window // block_size + 1`` pages (position
+``p`` lies in ring page ``(p // block_size) % ring``, so a page is
+overwritten once every position it held has left the window), and the
+sequence takes **what it needs of it** with its other pages at admission:
+``min(ring, blocks_for(num_tokens))`` pages, returned with them at
+release. A sequence whose whole budget fits in fewer pages than a ring
+never wraps (position ``p`` lies in its page ``p // block_size``), so the
+rest of its ring's table is the group's null page and nothing reads or
+writes it. The group's pool has a stated size as the full group's has
+(``window_blocks=``, its null page included; default ``max_sequences``
+whole rings and the null page); admission counts every group by the need
+above, so it stays exact on each: a request that any group cannot hold is
+refused (``OutOfKVBlocksError``, whose ``group`` names the group that was
+short) and waits. A ring page is overwritten in place: it is never
+refcounted, shared or copied on write. Not done here: pages behind the
+window are not given back while the sequence runs, a ring does not grow
+on demand, nothing is preempted, and a table does not slide (ROADMAP R3,
+R8).
 
 The pool itself is storage-agnostic (``make_pages`` builds numpy or
 jax arrays per layer on demand) — the allocator tracks only indices,
@@ -50,7 +60,12 @@ from typing import Dict, Iterable, List, Optional, Tuple
 class OutOfKVBlocksError(Exception):
     """The pool cannot satisfy an allocation — the engine keeps the
     sequence WAITING (or sheds it) rather than admitting work it
-    cannot finish."""
+    cannot finish. ``group``: the page group that was short (``"full"``,
+    or a window)."""
+
+    def __init__(self, message: str, group="full"):
+        super().__init__(message)
+        self.group = group
 
 
 class PagedKVCache:
@@ -61,7 +76,8 @@ class PagedKVCache:
     """
 
     def __init__(self, num_blocks: int, block_size: int,
-                 windows: Iterable[int] = (), max_sequences: int = 0):
+                 windows: Iterable[int] = (), max_sequences: int = 0,
+                 window_blocks: Optional[int] = None):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (page 0 is reserved)")
         self.num_blocks = int(num_blocks)
@@ -70,10 +86,11 @@ class PagedKVCache:
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
         self._tables: Dict[str, List[int]] = {}   # seq id -> pages
         self._refs: Dict[int, int] = {}           # page -> reference count
-        # window -> its page group: a ring a sequence, page 0 null
+        # window -> its page group: a ring (or what a sequence needs of
+        # one) a sequence, page 0 null
         self._rings: Dict[int, _RingGroup] = {
             int(w): _RingGroup(int(w) // self.block_size + 1,
-                               int(max_sequences))
+                               int(max_sequences), window_blocks)
             for w in sorted(set(windows))}
         self._lock = threading.Lock()
 
@@ -85,7 +102,7 @@ class PagedKVCache:
     def can_allocate(self, num_tokens: int) -> bool:
         with self._lock:
             return len(self._free) >= self.blocks_for(num_tokens) \
-                and not self._ring_short_locked()
+                and not self._ring_short_locked(num_tokens)
 
     # ---- window groups ----
 
@@ -94,8 +111,15 @@ class PagedKVCache:
         return tuple(self._rings)
 
     def ring_blocks(self, window: int) -> int:
-        """Pages a sequence holds of the window's group."""
+        """Pages of a whole ring of the window's group: the width of a
+        sequence's table there, and the most it holds."""
         return self._rings[window].ring
+
+    def ring_need(self, window: int, num_tokens: int) -> int:
+        """Pages a sequence of ``num_tokens`` (prompt + budget) takes of
+        the window's group: a whole ring, or the pages of its tokens where
+        they are fewer (it then never wraps)."""
+        return min(self._rings[window].ring, self.blocks_for(num_tokens))
 
     def group_blocks(self, window: int) -> int:
         """Pages of the window group's pool, its null page included."""
@@ -106,17 +130,22 @@ class PagedKVCache:
             t = self._rings[window].tables.get(seq_id)
             return list(t) if t else None
 
-    def _ring_short_locked(self) -> Optional[str]:
-        """The first window group that cannot give one more ring."""
+    def _ring_short_locked(self, num_tokens: int
+                           ) -> Optional[OutOfKVBlocksError]:
+        """The first window group that cannot give a sequence of
+        ``num_tokens`` its pages."""
         for w, g in self._rings.items():
-            if len(g.free) < g.ring:
-                return (f"need {g.ring} pages of the window-{w} group, "
-                        f"{len(g.free)} free (pool {g.num_blocks - 1})")
+            need = self.ring_need(w, num_tokens)
+            if len(g.free) < need:
+                return OutOfKVBlocksError(
+                    f"need {need} pages of the window-{w} group, "
+                    f"{len(g.free)} free (pool {g.num_blocks - 1})", group=w)
         return None
 
-    def _take_rings_locked(self, seq_id: str):
-        for g in self._rings.values():
-            g.tables[seq_id] = [g.free.pop() for _ in range(g.ring)]
+    def _take_rings_locked(self, seq_id: str, num_tokens: int):
+        for w, g in self._rings.items():
+            g.tables[seq_id] = [g.free.pop() for _ in range(
+                self.ring_need(w, num_tokens))]
 
     def free_blocks(self) -> int:
         with self._lock:
@@ -136,14 +165,14 @@ class PagedKVCache:
                 raise OutOfKVBlocksError(
                     f"need {need} KV blocks, {len(self._free)} free "
                     f"(pool {self.num_blocks - 1})")
-            short = self._ring_short_locked()
+            short = self._ring_short_locked(num_tokens)
             if short:
-                raise OutOfKVBlocksError(short)
+                raise short
             pages = [self._free.pop() for _ in range(need)]
             for p in pages:
                 self._refs[p] = 1
             self._tables[seq_id] = pages
-            self._take_rings_locked(seq_id)
+            self._take_rings_locked(seq_id, num_tokens)
             return list(pages)
 
     def allocate_with_prefix(self, seq_id: str, num_tokens: int,
@@ -175,9 +204,9 @@ class PagedKVCache:
                 raise OutOfKVBlocksError(
                     f"need {fresh_need} fresh KV blocks "
                     f"({n_shared} shared), {len(self._free)} free")
-            short = self._ring_short_locked()
+            short = self._ring_short_locked(num_tokens)
             if short:
-                raise OutOfKVBlocksError(short)
+                raise short
             for p in shared_pages:
                 self._refs[p] += 1
             fresh = [self._free.pop() for _ in range(fresh_need)]
@@ -185,7 +214,7 @@ class PagedKVCache:
                 self._refs[p] = 1
             pages = list(shared_pages) + fresh
             self._tables[seq_id] = pages
-            self._take_rings_locked(seq_id)
+            self._take_rings_locked(seq_id, num_tokens)
             return list(pages)
 
     def incref(self, pages: Iterable[int]) -> None:
@@ -283,16 +312,24 @@ class PagedKVCache:
 
 
 class _RingGroup:
-    """One window's pages: ``max_sequences`` rings of ``ring`` pages and
-    the null page 0 (the allocator's lock guards it)."""
+    """One window's pages: ``num_blocks`` pages (default: ``max_sequences``
+    rings of ``ring`` pages) with the null page 0 among them; a sequence's
+    table holds a ring's pages or fewer (the allocator's lock guards
+    it)."""
 
-    def __init__(self, ring: int, max_sequences: int):
-        if max_sequences < 1:
-            raise ValueError(
-                "a windowed page group is sized by the sequences that "
-                "may run at once: max_sequences >= 1")
+    def __init__(self, ring: int, max_sequences: int,
+                 num_blocks: Optional[int] = None):
+        if num_blocks is None:
+            if max_sequences < 1:
+                raise ValueError(
+                    "a windowed page group is sized by the sequences that "
+                    "may run at once (max_sequences >= 1) or by its "
+                    "stated pages (window_blocks)")
+            num_blocks = max_sequences * ring + 1
+        if num_blocks < 2:
+            raise ValueError("need >= 2 blocks (page 0 is reserved)")
         self.ring = ring
-        self.num_blocks = max_sequences * ring + 1
+        self.num_blocks = int(num_blocks)
         self.free: List[int] = list(range(self.num_blocks - 1, 0, -1))
         self.tables: Dict[str, List[int]] = {}
 
@@ -301,4 +338,6 @@ class _RingGroup:
         used = usable - len(self.free)
         return {"ring_blocks": self.ring, "blocks_total": usable,
                 "blocks_used": used, "occupancy": used / max(1, usable),
-                "sequences": len(self.tables)}
+                "sequences": len(self.tables),
+                # what the running sequences' whole rings would be
+                "blocks_whole_rings": len(self.tables) * self.ring}

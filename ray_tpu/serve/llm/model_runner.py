@@ -87,15 +87,21 @@ verified in one step (``llm_verify_b{B}_s{S}``) and rolled back by
 length, and a prompt's pages shipped
 (tests/test_llm_kimi_k2_serving.py).
 
-A page kind may carry a ``window`` (``kind="laguna"``: the sliding
-layers' ``k_window`` / ``v_window``): the positions a layer of that kind
-ever reads. Such kinds form a **page group** of their own in the
-allocator (``kv_cache.py``): its pool holds ``max_running`` rings of
-``window / block_size + 1`` pages and a null page, a sequence's ring is
-taken with its other pages at admission, and ``_pack`` carries one table
-a group (the full group's padded table, then each window group's ring).
-Position ``p`` lies in ring page ``(p // block_size) % ring``, so a page
-is overwritten once what it held has left the window. That is also what
+A page kind may carry a ``window`` (``kind="laguna"``,
+``kind="smallthinker"``: the sliding layers' ``k_window`` /
+``v_window``): the positions a layer of that kind ever reads. Such kinds
+form a **page group** of their own in the allocator (``kv_cache.py``):
+its pool has a stated size (the engine's ``window_blocks``; by default
+``max_running`` rings of ``window / block_size + 1`` pages and a null
+page), a sequence takes what it needs of a ring (``min(ring,
+blocks_for(budget))`` pages) with its other pages at admission, and
+``_pack`` carries one table a group (the full group's padded table, then
+each window group's ring, a short ring's filled up with the group's null
+page: such a sequence never wraps, so the program reads position ``p``
+of it in ring page ``p // block_size`` by the same rule and no program
+differs). Position ``p`` lies in ring page ``(p // block_size) % ring``,
+so a page is overwritten once what it held has left the window. That is
+also what
 such a model cannot do, and every entry point says so
 (``WindowedPagesError``): a ring page cannot be shared with another
 sequence (``enable_prefix_cache``), a prompt's suffix cannot be prefilled
@@ -413,6 +419,13 @@ class FlaxModelAdapter:
             self._blocks = None            # layers differ by their type
             self.vocab_size = self.cfg.vocab_size
             self._spec = laguna.cache_spec(self.cfg)
+        elif kind == "smallthinker":
+            from ray_tpu.models import smallthinker
+            self.cfg = config or smallthinker.SmallThinkerConfig.tiny()
+            self.model = smallthinker.SmallThinkerModel(self.cfg)
+            self._blocks = None            # layers differ by their layout
+            self.vocab_size = self.cfg.vocab_size
+            self._spec = smallthinker.cache_spec(self.cfg)
         elif kind == "longcat_flash":
             from ray_tpu.models import longcat_flash
             self.cfg = config or longcat_flash.LongcatFlashConfig.tiny()
@@ -434,6 +447,7 @@ class FlaxModelAdapter:
         self._decode_recurrence: Optional[str] = None   # ``bind_state``
         self._kda_kernel_steps = 0
         self._window_pages_live = self._window_pages_padded = 0
+        self._window_pages_held = self._window_pages_whole = 0
         self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
         self._flying: Optional[DecodeStep] = None   # dispatched, unfetched
         self.bucket_first_calls = 0        # _fns misses: steps that compiled
@@ -580,7 +594,11 @@ class FlaxModelAdapter:
         if self._rings:
             out.update(
                 kv_window_pages_live_total=self._window_pages_live,
-                kv_window_pages_padded_total=self._window_pages_padded)
+                kv_window_pages_padded_total=self._window_pages_padded,
+                # over the decode steps: the window pages their rows held,
+                # and what whole rings would have been
+                kv_window_pages_held_total=self._window_pages_held,
+                kv_window_pages_whole_rings_total=self._window_pages_whole)
         out.update(state_slots_total=self.state_slots,
                    state_slots_in_use=self.state_slots
                    - len(self._free_slots))
@@ -869,8 +887,9 @@ class FlaxModelAdapter:
         """The rows' integers as one int32 array [B, S + 3 + nb_max
         (+ each window group's ring)]: a row's new tokens, how many of
         them are real, the tokens cached before them, its state slot, its
-        block table, then its ring in each window group. A padding row
-        is zeros: nothing real, the null slot, the null pages."""
+        block table, then its ring in each window group (the pages it
+        holds of it, the rest the group's null page). A padding row is
+        zeros: nothing real, the null slot, the null pages."""
         packed = np.zeros(
             (B, S + 3 + self.nb_max + sum(self._rings.values())), np.int32)
         for i, r in zip(at, rows):
@@ -881,7 +900,8 @@ class FlaxModelAdapter:
             packed[i, S + 3:S + 3 + len(t)] = t
             col = S + 3 + self.nb_max
             for w, ring in self._rings.items():
-                packed[i, col:col + ring] = r["rings"][w]
+                t = r["rings"][w]
+                packed[i, col:col + len(t)] = t
                 col += ring
         return packed
 
@@ -908,11 +928,15 @@ class FlaxModelAdapter:
             # a window layer reads a row's last ``w`` positions: the ring
             # pages that hold one of them, of the ``ring`` a row holds
             w_live = sum(-(-n // bs) - max(n - w, 0) // bs for n in lens)
+            held = sum(len(r["rings"][w]) for r in rows)
             self._window_pages_live += w_live
             self._window_pages_padded += B * ring
+            self._window_pages_held += held
+            self._window_pages_whole += len(rows) * ring
             out.update(window_tokens=sum(min(n, w) for n in lens),
                        kv_window_pages_live=w_live,
-                       kv_window_pages_held=len(rows) * ring,
+                       kv_window_pages_held=held,
+                       kv_window_pages_whole_rings=len(rows) * ring,
                        kv_window_pages_padded=B * ring)
         return out
 
@@ -1119,15 +1143,15 @@ def make_adapter(model: str = "toy",
                  model_config: Optional[Dict[str, Any]] = None):
     """Deployment-facing factory: ``model`` is ``toy`` |
     ``gpt2`` | ``llama`` | ``kimi_linear`` | ``kimi_k2`` | ``laguna`` |
-    ``longcat_flash``
+    ``longcat_flash`` | ``smallthinker``
     (tiny test configs unless ``model_config`` overrides)."""
     model_config = dict(model_config or {})
     if model == "toy":
         return ToyAdapter(**model_config)
     if model in ("gpt2", "llama", "kimi_linear", "kimi_k2", "laguna",
-                 "longcat_flash"):
+                 "longcat_flash", "smallthinker"):
         return FlaxModelAdapter(kind=model, **model_config)
     raise ValueError(
         f"unknown model {model!r} "
         "(toy | gpt2 | llama | kimi_linear | kimi_k2 | laguna | "
-        "longcat_flash)")
+        "longcat_flash | smallthinker)")

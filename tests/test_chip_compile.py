@@ -746,23 +746,6 @@ def test_laguna_step_fits_the_chip_at_the_timed_shapes(
         assert text.count("routed_experts_grouped") >= 4
 
 
-@pytest.mark.parametrize("model,read_gib", [("laguna", 2.57),
-                                            ("kimi_k2", 2.85)])
-def test_a_prompts_temporaries_are_what_was_read(one_chip, topo, monkeypatch,
-                                                 model, read_gib):
-    """The (1, 8192) prefill programs' temporaries stay within a tenth
-    of what ``memory_analysis()`` read when the grouped product went in
-    (PR 40): a later product that quietly holds more rows (Kimi-K2's
-    worst case in float32 is 1.97 GB) fails here and not in a cell,
-    whose probe of ``correct`` has ~10 MB of the chip to spare."""
-    memory = _MEMORY.get((model, 1, 8192))
-    if memory is None:
-        step = (_laguna_step if model == "laguna" else _k2_step)(
-            one_chip, topo, monkeypatch, 1, 8192)[2]
-        memory = step.memory_analysis()
-    assert memory.temp_size_in_bytes < 1.1 * read_gib * 2.0 ** 30
-
-
 def test_laguna_whole_context_pools_would_not_fit(one_chip, topo,
                                                   monkeypatch):
     """Were the sliding layers to keep every position as the full ones
@@ -795,9 +778,163 @@ def test_paged_attention_decode_grouped_heads_and_ring(one_chip, H, window):
         sds((B,), jnp.int32))
 
 
+def _smallthinker_cell():
+    """(engine, model kwargs) of the cell smallthinker_21b_a3b.
+    serve_closed96_mix8k, from its configuration file."""
+    import json
+    import os
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "smallthinker_21b_a3b.json")) as f:
+        cfg = json.load(f)
+    return cfg["serve"]["engine"], cfg["model"]["kwargs"]
+
+
+def _smallthinker_step(one_chip, topo, monkeypatch, B, S):
+    """``FlaxModelAdapter``'s step for SmallThinker as the cell
+    smallthinker_21b_a3b.serve_closed96_mix8k runs it: the published
+    widths, layers 0-7 whole (all 64 experts, the whole vocabulary), the
+    full layers' pools of ``num_blocks`` pages under tables of 576, the
+    window layers' of ``window_blocks`` (stated, fewer than 96 whole
+    rings) under rings of 257."""
+    from benchmark.reference import smallthinker_glue as glue
+    from ray_tpu.serve.llm.kv_cache import PagedKVCache
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    engine, kwargs = _smallthinker_cell()
+    cfg = glue.model_config({
+        "factory": "ray_tpu.models.smallthinker:SmallThinkerConfig",
+        "kwargs": kwargs})
+    adapter = FlaxModelAdapter("smallthinker", cfg, params={})
+    params = jax.tree_util.tree_map(
+        lambda s: sds(s.shape, s.dtype),
+        jax.eval_shape(adapter.model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8), jnp.int32)))
+    adapter.bind_cache(PagedKVCache(2, 16, windows=adapter.page_windows,
+                                    window_blocks=2))
+    assert adapter._rings == {4096: 257} and adapter.nb_max == 576
+    pools = [sds((a.shape[0], engine["window_blocks"] if "window" in name
+                  else engine["num_blocks"], *a.shape[2:]), a.dtype)
+             for name, a in adapter._arrays.items()]
+    with monkeypatch.context() as m:
+        m.setattr(jax, "devices", lambda *a, **k: topo.devices)
+        fn = adapter._step_fn(B, S)
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    with jax.default_matmul_precision("default"):
+        return params, pools, fn.lower(
+            params, sds((B, S + 3 + 576 + 257), jnp.int32),
+            *_last_tokens(sds, engine["num_blocks"], S), *pools).compile()
+
+
+# the 13 programs the cell's warm-up compiles: five prompts (one a step:
+# max_prefill_tokens 256 under prompts of 257 and more), eight decode
+# buckets to 128 rows (96 slots)
+@pytest.mark.parametrize("B,S", [
+    (1, 512), (1, 1024), (1, 2048), (1, 4096), (1, 8192),
+    (1, 1), (2, 1), (4, 1), (8, 1), (16, 1), (32, 1), (64, 1), (128, 1)],
+    ids=lambda v: str(v))
+def test_smallthinker_programs_fit_the_chip_with_the_stated_pools(
+        one_chip, topo, monkeypatch, B, S):
+    """Each of the cell's 13 programs, compiled for the described v5e:
+    7.39 GiB of weights, the full layers' pools (24,577 pages, 1.50 GiB)
+    and the window layers' (18,433 pages where 96 whole rings would be
+    24,673: 3.375 GiB) as arguments, all four pools donated and written
+    in place, and temporaries that leave the whole under the chip's
+    15.75 GiB. A decode step's attention is the paged kernel in all eight
+    layers (groups of 7 over the live pages and over rings of 257: no
+    row's table is gathered) and its routed experts the touched kernel
+    over 64 ReGLU experts of three 256-wide tiles; a prompt's attention
+    is the blocked kernel and its routed experts the grouped kernel (256
+    rows and fewer: the touched one), a call a layer, over the worst
+    case's 65,536 sorted rows held whole (0.94 GiB of bfloat16 rows and
+    float32 results at 8,192 tokens). As read: 12.267 GiB of arguments;
+    temporaries 0.010-0.017 GiB (1 to 128 rows) and 0.29, 0.44, 0.78,
+    1.29 and 2.34 GiB (prompts of 512 to 8,192): 14.61 of 15.75 GiB at
+    the most."""
+    import math
+    engine, _ = _smallthinker_cell()
+    assert engine["max_running"] == 96 and engine["window_blocks"] \
+        < 96 * 257 + 1
+    params, pools, step = _smallthinker_step(one_chip, topo, monkeypatch,
+                                             B, S)
+    memory = _MEMORY["smallthinker", B, S] = step.memory_analysis()
+    gib = 2.0 ** 30
+    held = sum(math.prod(s.shape) * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    assert 7.38 < held / gib < 7.40
+    assert sum(math.prod(p.shape) * 2 for p in pools) / gib == 4.875 \
+        + 8 * 32768 / gib       # (the null page of each layer's pool)
+    assert memory.alias_size_in_bytes == sum(
+        math.prod(p.shape) * 2 for p in pools) \
+        + (4 * 32768 if S == 1 else 0)      # (and the tokens by row)
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    print(f"smallthinker b{B} s{S}: arguments "
+          f"{memory.argument_size_in_bytes / gib:.3f} GiB, temporaries "
+          f"{memory.temp_size_in_bytes / gib:.3f}, total {total / gib:.3f}")
+    # (what was read, and a tenth)
+    read = {1: 0.017, 512: 0.29, 1024: 0.44, 2048: 0.78, 4096: 1.29,
+            8192: 2.34}[S]
+    assert memory.temp_size_in_bytes < 1.1 * read * gib
+    assert total < (12.4 if S == 1 else 14.9) * gib
+    text = step.as_text()
+    assert text.count("tpu_custom_call") >= 16          # 8 + 8
+    if S == 1:
+        assert text.count("routed_experts_touched") >= 8
+        assert f"[{B},9216," not in text    # no table gathered whole
+        assert f"[{B},4112," not in text    # nor a ring to its 4,112 rows
+    else:
+        assert text.count("routed_experts_grouped") >= 8
+        assert f"[28,512,{S}]" not in text  # no [heads, block, S] logits
+
+
+@pytest.mark.parametrize("model,read_gib", [("laguna", 2.57),
+                                            ("kimi_k2", 2.85),
+                                            ("smallthinker", 2.34)])
+def test_a_prompts_temporaries_are_what_was_read(one_chip, topo, monkeypatch,
+                                                 model, read_gib):
+    """The (1, 8192) prefill programs' temporaries stay within a tenth
+    of what ``memory_analysis()`` read when the grouped product went in
+    (PR 40): a later product that quietly holds more rows (Kimi-K2's
+    worst case in float32 is 1.97 GB) fails here and not in a cell,
+    whose probe of ``correct`` has ~10 MB of the chip to spare."""
+    memory = _MEMORY.get((model, 1, 8192))
+    if memory is None:
+        step = {"laguna": _laguna_step, "kimi_k2": _k2_step,
+                "smallthinker": _smallthinker_step}[model](
+            one_chip, topo, monkeypatch, 1, 8192)[2]
+        memory = step.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.1 * read_gib * 2.0 ** 30
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_paged_attention_decode_groups_of_seven_and_a_ring_of_257(
+        one_chip, window):
+    """The paged kernel alone at the SmallThinker cell's shapes: 128 rows
+    (96 slots padded), 4 key/value heads of 128, groups of 7 (28 heads in
+    32-row matrices) over tables of 576 pages and over a ring of 257 (a
+    prime: nothing but the ring rule's ``lp % 257`` divides it)."""
+    engine, _ = _smallthinker_cell()
+    NB = 576 if window is None else 257
+    P = engine["num_blocks"] if window is None else engine["window_blocks"]
+    L = 2 if window is None else 6
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    _compile(lambda q, k, v, bt, ln: A.paged_attention_decode(
+        q, k, v, bt, ln, layer=1, window=window, interpret=False),
+        sds((128, 28, 128), jnp.bfloat16),
+        sds((L, P, 16, 512), jnp.bfloat16),
+        sds((L, P, 16, 512), jnp.bfloat16), sds((128, NB), jnp.int32),
+        sds((128,), jnp.int32))
+
+
 @pytest.mark.parametrize("kind,config", [
     ("kimi_linear", "KimiLinearConfig"), ("kimi_k2", "KimiK2Config"),
-    ("laguna", "LagunaConfig")])
+    ("laguna", "LagunaConfig"),
+    ("smallthinker", "SmallThinkerConfig")])
 def test_a_step_dispatched_ahead_runs_the_program_the_warm_up_compiled(
         kind, config):
     """The benchmark warms a decode bucket by a synchronous

@@ -137,23 +137,25 @@ def test_allocator_accounts_by_page_group():
     a = cache.allocate("a", 100)
     assert len(a) == 13 and len(cache.ring_table("a", 32)) == 5
     assert 0 not in cache.ring_table("a", 32)       # the null page
-    cache.allocate("b", 8)
+    cache.allocate("b", 40)
     assert not set(cache.ring_table("a", 32)) & set(cache.ring_table("b", 32))
     free = cache.free_blocks()
     assert not cache.can_allocate(8)
-    with pytest.raises(OutOfKVBlocksError, match="window-32 group"):
-        cache.allocate("c", 8)      # pages enough, no ring left
+    with pytest.raises(OutOfKVBlocksError, match="window-32 group") as e:
+        cache.allocate("c", 8)      # pages enough, none left of the group
+    assert e.value.group == 32
     assert cache.free_blocks() == free and cache.block_table("c") is None
     groups = cache.stats()["kv_window_groups"]
     assert groups == {32: {"ring_blocks": 5, "blocks_total": 10,
                            "blocks_used": 10, "occupancy": 1.0,
-                           "sequences": 2}}
+                           "sequences": 2, "blocks_whole_rings": 10}}
     cache.free("a")
     assert cache.ring_table("a", 32) is None
     assert cache.stats()["kv_window_groups"][32]["blocks_used"] == 5
     assert cache.can_allocate(8)
-    with pytest.raises(OutOfKVBlocksError, match="KV blocks"):
+    with pytest.raises(OutOfKVBlocksError, match="KV blocks") as e:
         cache.allocate("c", 64 * PAGE)      # a ring left, pages not enough
+    assert e.value.group == "full"
     assert cache.stats()["kv_window_groups"][32]["blocks_used"] == 5
     with pytest.raises(ValueError, match="cannot be shared"):
         cache.allocate_with_prefix("d", 16, [cache.block_table("b")[0]])
@@ -170,6 +172,64 @@ def test_allocator_accounts_by_page_group():
     k = _laguna()
     with pytest.raises(ValueError, match="page windows"):
         FlaxModelAdapter("laguna", k["cfg"], k["params"]).bind_cache(plain)
+
+
+@pytest.mark.parametrize("tokens, pages", [
+    (1, 1), (PAGE, 1), (PAGE + 1, 2), (4 * PAGE, 4), (4 * PAGE + 1, 5),
+    (5 * PAGE, 5), (100, 5), (1000, 5)])
+def test_a_sequence_takes_what_it_needs_of_a_ring(tokens, pages):
+    """Rings by need: ``min(ring, blocks_for(num_tokens))`` pages of the
+    window group, all real pages, given back whole at release; the full
+    group's table is what it was."""
+    cache = PagedKVCache(num_blocks=256, block_size=PAGE, windows=(32,),
+                         max_sequences=2)
+    assert cache.ring_blocks(32) == 5
+    assert cache.ring_need(32, tokens) == pages
+    table = cache.allocate("a", tokens)
+    ring = cache.ring_table("a", 32)
+    assert len(table) == cache.blocks_for(tokens)
+    assert len(ring) == pages and 0 not in ring and len(set(ring)) == pages
+    group = cache.stats()["kv_window_groups"][32]
+    assert group["blocks_used"] == pages and group["blocks_whole_rings"] == 5
+    cache.free("a")
+    assert cache.stats()["kv_window_groups"][32]["blocks_used"] == 0
+    assert cache.free_blocks() == 255
+
+
+def test_admission_is_exact_on_both_groups_with_rings_by_need():
+    """A window group with a stated size (``window_blocks``: 8 pages and
+    the null page, less than two whole rings of 5): short sequences are
+    admitted until the GROUP is short, by their need and not by a whole
+    ring; a refusal takes nothing of either group and names the group;
+    release admits. The default size is ``max_sequences`` whole rings, as
+    it was."""
+    cache = PagedKVCache(num_blocks=64, block_size=PAGE, windows=(32,),
+                         max_sequences=4, window_blocks=9)
+    assert cache.group_blocks(32) == 9
+    assert PagedKVCache(64, PAGE, windows=(32,), max_sequences=4
+                        ).group_blocks(32) == 4 * 5 + 1
+    assert PagedKVCache(64, PAGE, windows=(32,), window_blocks=9
+                        ).group_blocks(32) == 9     # (no max_sequences)
+    cache.allocate("a", 100)                        # a whole ring: 5
+    cache.allocate("b", 2 * PAGE)                   # 2
+    assert cache.can_allocate(PAGE) and not cache.can_allocate(2 * PAGE)
+    free = cache.free_blocks()
+    with pytest.raises(OutOfKVBlocksError, match="need 2 pages of the "
+                       "window-32 group, 1 free") as e:
+        cache.allocate("c", 2 * PAGE)
+    assert e.value.group == 32 and cache.free_blocks() == free
+    assert cache.block_table("c") is None and cache.ring_table("c", 32) is None
+    cache.allocate("c", PAGE)                       # 1: the group is full
+    assert cache.stats()["kv_window_groups"][32] == {
+        "ring_blocks": 5, "blocks_total": 8, "blocks_used": 8,
+        "occupancy": 1.0, "sequences": 3, "blocks_whole_rings": 15}
+    # the full group short, the window group not: nothing is taken
+    cache.free("a")
+    with pytest.raises(OutOfKVBlocksError, match="KV blocks") as e:
+        cache.allocate("d", 64 * PAGE)
+    assert e.value.group == "full"
+    assert cache.stats()["kv_window_groups"][32]["blocks_used"] == 3
+    assert len(cache.ring_table(cache.allocate("d", 100) and "d", 32)) == 5
 
 
 def test_engine_admits_by_both_groups_and_says_what_its_steps_read():
@@ -209,11 +269,12 @@ def test_engine_admits_by_both_groups_and_says_what_its_steps_read():
     assert decodes and all(
         {"attention", "live_tokens", "window_tokens", "kv_pages_live",
          "kv_pages_padded", "kv_window_pages_live", "kv_window_pages_held",
-         "kv_window_pages_padded"} <= set(a) for a in decodes)
+         "kv_window_pages_whole_rings", "kv_window_pages_padded"} <= set(a)
+        for a in decodes)
     assert all(a["attention"] == "gather" for a in decodes)     # the CPU
     assert all(a["window_tokens"] <= a["live_tokens"]
                and a["kv_window_pages_live"] <= a["kv_window_pages_held"]
-               for a in decodes)
+               <= a["kv_window_pages_whole_rings"] for a in decodes)
     assert any(a["window_tokens"] < a["live_tokens"] for a in decodes)
     prefills = [s["attrs"] for step in steps for d in walk(step)
                 if d["name"] == "llm.step.prefill" for s in walk(d)

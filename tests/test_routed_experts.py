@@ -371,3 +371,91 @@ def test_the_product_and_its_block_follow_the_shapes(T, top_k, experts, held,
             < held_rows * d * 6 <= moe.CHUNK_BYTES)
     counts = np.array([[0, 1, block, block + 1], [0, 0, 0, 5]])
     assert plan.rows_multiplied(counts) == (5 if grouped else 4) * block
+
+
+# ---- the gate's activation and the router's own input (SmallThinker) ----
+
+def _dense_glu(p, x, router_x, valid, top_k, act):
+    """All experts over all rows in plain jax.numpy: softmax scores of
+    ``router_x``, top-k, renormalised over the chosen; the gate through
+    ``act``; the pairs not chosen (and the rows not real) weighed zero."""
+    scores = jax.nn.softmax(router_x @ p["router"], axis=-1)
+    _, chosen = jax.lax.top_k(scores + p["router_bias"], top_k)
+    w = jnp.take_along_axis(scores, chosen, axis=1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    picked = jax.nn.one_hot(chosen, scores.shape[1]) * valid[:, None, None]
+    combine = jnp.sum(picked * w[..., None], axis=1)
+    gate = jnp.einsum("td,edf->etf", x, p["w_gate"])
+    gate = jnp.maximum(gate, 0.0) if act == "relu" else jax.nn.silu(gate)
+    h = gate * jnp.einsum("td,edf->etf", x, p["w_up"])
+    y = jnp.einsum("etf,efd->td", h * combine.T[..., None], p["w_down"])
+    return y, jnp.sum(picked, axis=(0, 1)).astype(jnp.int32)
+
+
+@pytest.mark.parametrize("act", ["silu", "relu"])
+@pytest.mark.parametrize("own_router_input", [False, True])
+@pytest.mark.parametrize("T", [40, 300])
+def test_the_gates_activation_and_the_routers_input_in_both_kernels(
+        T, own_router_input, act):
+    """40 rows go whole through the touched experts, 300 sorted through
+    the grouped kernel (both interpreted): ``act="relu"`` is ReGLU in
+    both, ``router_x`` is what the router scores while the experts
+    multiply ``x``; against the dense sum. The two activations and the
+    two router inputs give different results (so each case tests its
+    own), and ``router_x=x`` is the call without it, bit for bit."""
+    experts, top_k, d_ff, n_real = 16, 4, 24, T - 7
+    rng = np.random.default_rng(T)
+    x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+    other = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+    layer = RoutedExperts(experts, d_ff, top_k, renormalize=True,
+                          dtype=jnp.float32, score="softmax", act=act)
+    p = _params(layer, T, x)
+    valid = jnp.arange(T) < n_real
+    router_x = other if own_router_input else None
+    y, counts = jax.jit(layer.apply)({"params": p}, x, valid=valid,
+                                     router_x=router_x)
+    want, want_counts = _dense_glu(
+        p, x, other if own_router_input else x, valid, top_k, act)
+    np.testing.assert_array_equal(counts, want_counts)
+    assert int(counts.sum()) == n_real * top_k
+    np.testing.assert_allclose(y, want, atol=TOL)
+    assert float(jnp.max(jnp.abs(want))) > 1000 * TOL
+    assert not bool(jnp.any(y[n_real:]))
+    plan = moe.expert_product(T, top_k, experts, experts, D, 4)
+    assert plan.name == ("touched_kernel" if T <= moe.WHOLE_ROWS_BELOW
+                         else "grouped_kernel")
+    # the other activation and the other router input are other numbers
+    for a, rx in ((("silu" if act == "relu" else "relu"), router_x),
+                  (act, x if own_router_input else other)):
+        far, _ = RoutedExperts(
+            experts, d_ff, top_k, renormalize=True, dtype=jnp.float32,
+            score="softmax", act=a).apply({"params": p}, x, valid=valid,
+                                          router_x=rx)
+        assert float(jnp.max(jnp.abs(far - want))) > 100 * TOL
+    if not own_router_input:
+        same, _ = jax.jit(layer.apply)({"params": p}, x, valid=valid,
+                                       router_x=x)
+        np.testing.assert_array_equal(same, y)
+
+
+def test_an_expert_width_of_three_tiles():
+    """768 is the first served expert width that only the 256-wide entry
+    of ``_FF_TILES`` divides: three tiles an expert in both kernels (at
+    2560 x 768 bfloat16 as published; a toy 24-wide expert is one)."""
+    assert RE.ff_tile(2560, 768, 2) == 256
+    assert RE.ff_tile(2048, 512, 2) == 512 and RE.ff_tile(D, 24, 4) == 24
+    # three tiles walked at a toy width: d_ff 384 with the widest tile
+    # made 128
+    tiles, RE._FF_TILES = RE._FF_TILES, (128,)
+    try:
+        T, experts, top_k, d_ff = 20, 4, 2, 384
+        x = jnp.asarray(np.random.default_rng(1).normal(size=(T, D)),
+                        jnp.float32)
+        layer = RoutedExperts(experts, d_ff, top_k, dtype=jnp.float32,
+                              score="softmax", act="relu")
+        p = _params(layer, 3, x)
+        y, counts = layer.apply({"params": p}, x)
+        want, _ = _dense_glu(p, x, x, jnp.ones((T,), bool), top_k, "relu")
+        np.testing.assert_allclose(y, want, atol=TOL)
+    finally:
+        RE._FF_TILES = tiles
