@@ -1403,17 +1403,31 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables,
 
 
 # tokens the kernel attends to at a time: the pages that hold them are
-# copied into VMEM together while the chunk before is worked on. A chunk
-# costs ~0.5 us whatever it holds, so a pool whose rows (all kv heads of
-# a token) are narrower than 2 KiB takes four times the tokens: at 1 KiB
-# a row (4 kv heads of 128, bfloat16) 96 rows of the contexts a mixed
-# queue holds read 1.93 ms a layer in chunks of 128, 1.53 in 256, 1.32
-# in 512 (39 / 49 / 57% of the time the bytes take; a window of 4,096:
-# 1.59 / 1.29 / 1.16 ms; my chip runs, PR 43). At 2 KiB and over the
-# chunk is what it was.
-_PAGED_CHUNK_TOKENS = 128
+# copied into VMEM together while the chunk before is worked on. The
+# kernel's one instruction stream starts the copies and runs the products
+# in turn: a chunk costs what its K and V tiles take to pass through the
+# matrix units under a few query rows (one 128 x 128 tile a unit in ~128
+# cycles: 1.1 us for 512 tokens of 1 KiB rows, 0.55 us for 128 of 2 KiB)
+# plus what its copies take to start, while the bytes arrive underneath.
+# Read on the chip (PR 44, the kernel alone; bytes at 819 GB/s in
+# brackets): 96 rows of a mixed queue's contexts at 1 KiB a row (4 kv
+# heads of 128, bfloat16), tables of runs, chunks of 128 / 256 / 512 /
+# 1,024 tokens 1.47 / 1.05 / 0.87 / 0.85 ms a layer [0.67] (a copy a
+# page, PR 43: 1.93 / 1.53 / 1.32), a window of 4,096 1.23 / 0.90 / 0.73
+# / 0.73 [0.54]; 64 rows of ~6,500 tokens at 2 KiB a row (8 kv heads)
+# 2.91 / 2.35 / 2.34 ms in chunks of 128 / 256 / 512 [2.07]. So rows
+# under 2 KiB take 512 tokens and wider rows 256 (what two buffers a
+# pool hold in VMEM doubles with each step and gains nothing past these).
+_PAGED_CHUNK_TOKENS = 256
 _PAGED_NARROW_ROW_BYTES = 2048
 _PAGED_NARROW_CHUNK_TOKENS = 512
+
+# table entries a chunk's copies go by: a page's copy costs the core's
+# scalar unit as much to start and wait for as the memory needs to bring
+# 16 KB, so where a group of this many entries names consecutive pages of
+# the pool (``serve/llm/kv_cache.py`` hands a sequence its pages as
+# ascending runs), one copy a pool brings them all
+PAGED_RUN_PAGES = 8
 
 
 def paged_chunk_tokens(row_bytes: int) -> int:
@@ -1425,8 +1439,8 @@ def paged_chunk_tokens(row_bytes: int) -> int:
 
 def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
                          o_ref, k_buf, v_buf, sem, qbd_ref, m_ref, l_ref,
-                         acc_ref, *, block_size, pages, head_dim, sm_scale,
-                         window=None):
+                         acc_ref, *, block_size, pages, run, head_dim,
+                         sm_scale, window=None):
     """Every row's one query against its live pages, a chunk of
     ``pages`` pages at a time, all heads at once.
 
@@ -1437,16 +1451,26 @@ def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
     behind the window is copied.
 
     The pools stay in HBM; the block table names the pages of a chunk,
-    each copied by a DMA of its own into one of two [T, Hkv*D] buffers
-    while the other is attended to, the first chunk of the next row
-    under the last of this one. A chunk wholly past a row's length is
-    neither copied nor looked at. Heads stay side by side on the lanes,
-    as the pool holds them: the G query heads of every kv head form a
-    block-diagonal [H, Hkv*D] matrix (row h holds head h's query under
-    its kv head's columns, zeros elsewhere), so the scores of all heads
-    are one product against the chunk's keys, probabilities x values one
-    product [H, T] x [T, Hkv*D], and a head's output the columns of its
-    own kv head. No head is sliced out of a lane tile.
+    copied into one of two [pages, bs, Hkv*D] buffers while the other is
+    attended to, the first chunk of the next row under the last of this
+    one. A chunk is ``pages / run`` groups of ``run`` table entries.
+    Where a group's entries are ``p, p + 1, ..., p + run - 1`` (every
+    difference is checked: a table with a shared prefix or a copied page
+    is not sorted) ONE copy a pool brings the group from ``[p, p + run)``
+    of the layer; any other group (a run broken inside it, a ring that
+    wraps inside it, a table's end, the null page's padding) takes a copy
+    a page, and either way one wait a pool stands for the group's bytes.
+    A group wholly past a row's last live page is neither copied nor
+    waited for: the buffers are zeroed once, so what such a group leaves
+    in them is finite and its probabilities, exactly 0, keep it out of
+    the sums. A chunk wholly past a row's length is not looked at. Heads
+    stay side by side on the lanes, as the pool holds them: the G query
+    heads of every kv head form a block-diagonal [H, Hkv*D] matrix (row h
+    holds head h's query under its kv head's columns, zeros elsewhere),
+    so the scores of all heads are one product against the chunk's keys,
+    probabilities x values one product [H, T] x [T, Hkv*D], and a head's
+    output the columns of its own kv head. No head is sliced out of a
+    lane tile.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -1454,7 +1478,9 @@ def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
     B, G, C = q_ref.shape
     NB = bt_ref.shape[1]
     T = pages * block_size
+    E = run
     layer = layer_ref[0]
+    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
 
     def first_page(b):
         """The logical page a row's walk starts at."""
@@ -1462,23 +1488,69 @@ def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
             return 0
         return jnp.maximum(len_ref[b] - window, 0) // block_size
 
-    def copies(b, c, slot, start):
-        if start and window is not None:
-            at = first_page(b) + c * pages
-        for i in range(pages):
-            # (a wait needs the semaphore and the size, not the source)
-            if not start:
-                page = 0
-            elif window is None:
-                page = bt_ref[b, jnp.minimum(c * pages + i, NB - 1)]
-            else:
-                page = bt_ref[b, jax.lax.rem(at + i, NB)]
-            for s, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
-                copy = pltpu.make_async_copy(
-                    hbm.at[layer, page],
-                    buf.at[slot, pl.ds(i * block_size, block_size)],
-                    sem.at[slot, s])
-                copy.start() if start else copy.wait()
+    def live_groups(b, c, body):
+        """``body(g, x)`` for every group ``g`` of chunk ``c`` of row
+        ``b`` that holds a live page; ``x``: the place in the table of
+        the group's first entry (in a ring, one remainder a chunk)."""
+        base = first_page(b) + c * pages
+        live = (len_ref[b] + block_size - 1) // block_size
+        at = base if window is None else jax.lax.rem(base, NB)
+
+        def group(g, carry):
+            @pl.when(base + g * E < live)
+            def _():
+                x = at + g * E
+                if window is not None:
+                    x = jnp.where(x >= NB, x - NB, x)
+                body(g, x)
+            return carry
+        jax.lax.fori_loop(0, pages // E, group, 0)
+
+    def start(b, c, slot):
+        """Chunk ``c`` of row ``b`` on its way into buffer ``slot``."""
+        def group(g, x):
+            # the entries x .. x + E - 1, where the table holds them all
+            # before its end (or the ring's wrap)
+            whole = x <= NB - E
+            at = jnp.minimum(x, NB - E)
+            p = bt_ref[b, at]
+            is_run = whole
+            for i in range(1, E):
+                is_run &= bt_ref[b, at + i] == p + i
+
+            @pl.when(is_run)
+            def _():
+                for s, (hbm, buf) in enumerate(pools):
+                    pltpu.make_async_copy(
+                        hbm.at[layer, pl.ds(p, E)],
+                        buf.at[slot, pl.ds(g * E, E)],
+                        sem.at[slot, s]).start()
+
+            @pl.when(jnp.logical_not(is_run))
+            def _():
+                def page(i, carry):
+                    if window is None:
+                        y = jnp.minimum(x + i, NB - 1)
+                    else:
+                        y = jnp.where(x + i >= NB, x + i - NB, x + i)
+                    for s, (hbm, buf) in enumerate(pools):
+                        pltpu.make_async_copy(
+                            hbm.at[layer, bt_ref[b, y]],
+                            buf.at[slot, g * E + i],
+                            sem.at[slot, s]).start()
+                    return carry
+                jax.lax.fori_loop(0, E, page, 0)
+        live_groups(b, c, group)
+
+    def wait(b, c, slot):
+        # a semaphore counts what its copies brought: a group's fill its
+        # pages of the buffer however they were copied, so one wait for
+        # that many bytes sees them all in
+        def group(g, x):
+            for s, (_, buf) in enumerate(pools):
+                part = buf.at[slot, pl.ds(g * E, E)]
+                pltpu.make_async_copy(part, part, sem.at[slot, s]).wait()
+        live_groups(b, c, group)
 
     def row_after(b):
         """The next row that holds a token (B: none does)."""
@@ -1486,11 +1558,13 @@ def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
             lambda r: (r < B) & (len_ref[jnp.minimum(r, B - 1)] == 0),
             lambda r: r + 1, b + 1)
 
+    k_buf[...] = jnp.zeros_like(k_buf)
+    v_buf[...] = jnp.zeros_like(v_buf)
     first = row_after(-1)
 
     @pl.when(first < B)
     def _():
-        copies(first, 0, 0, True)
+        start(first, 0, 0)
 
     rows = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
     kv_head = jax.lax.div(
@@ -1520,10 +1594,11 @@ def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
 
             @pl.when(nb < B)
             def _():
-                copies(nb, jnp.where(more, c + 1, 0), 1 - slot, True)
-            copies(b, c, slot, False)
+                start(nb, jnp.where(more, c + 1, 0), 1 - slot)
+            wait(b, c, slot)
             s = jax.lax.dot_general(
-                qbd_ref[...], k_buf[slot], (((1,), (1,)), ((), ())),
+                qbd_ref[...], k_buf[slot].reshape(T, C),
+                (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale   # [H, T]
             pos = c * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             if window is None:
@@ -1540,7 +1615,7 @@ def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
             l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
                                                       keepdims=True)
             acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-                p.astype(v_buf.dtype), v_buf[slot],
+                p.astype(v_buf.dtype), v_buf[slot].reshape(T, C),
                 preferred_element_type=jnp.float32)
             return 1 - slot
 
@@ -1608,6 +1683,23 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
             return paged_attention_reference(
                 q, k_pages, v_pages, block_tables, lengths, layer=layer,
                 sm_scale=sm_scale, window=window)
+    NB, bs = block_tables.shape[1], k_pages.shape[2]
+    assert window is None or NB == window // bs + 1, (NB, window, bs)
+    return _paged_decode_call(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q,
+        k_pages, v_pages, window=window, interpret=interpret,
+        sm_scale=float(q.shape[2] ** -0.5 if sm_scale is None else sm_scale))
+
+
+# (a function of its own under ``jit``, as ``_latent_decode_call``: a
+# model's layers of one kind are the same call with another ``layer``,
+# and a step program traces the kernel and lowers it to Mosaic once a
+# kind, not once a layer)
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "window", "interpret"))
+def _paged_decode_call(layer, block_tables, lengths, q, k_pages, v_pages,
+                       *, sm_scale, window, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1616,17 +1708,17 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
     NB = block_tables.shape[1]
     Hkv = C // D
     G = H // Hkv
-    if sm_scale is None:
-        sm_scale = D ** -0.5
     pages = max(1, min(paged_chunk_tokens(
         C * jnp.dtype(k_pages.dtype).itemsize) // bs, NB))
+    # (a chunk of a table narrower than it is that table: its groups are
+    # then the largest that divide it)
+    run = max(e for e in range(1, PAGED_RUN_PAGES + 1) if pages % e == 0)
     # row h of the kernel's matrices is head h; whole sublane tiles of
     # the pool's dtype
     Hp = -(-H // 16) * 16
-    assert window is None or NB == window // bs + 1, (NB, window, bs)
     kernel = functools.partial(_paged_decode_kernel, block_size=bs,
-                               pages=pages, head_dim=D,
-                               sm_scale=float(sm_scale), window=window)
+                               pages=pages, run=run, head_dim=D,
+                               sm_scale=sm_scale, window=window)
 
     def whole(*_):
         return 0, 0, 0
@@ -1639,8 +1731,8 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((B, G, C), whole),
             scratch_shapes=[
-                pltpu.VMEM((2, pages * bs, C), k_pages.dtype),
-                pltpu.VMEM((2, pages * bs, C), v_pages.dtype),
+                pltpu.VMEM((2, pages, bs, C), k_pages.dtype),
+                pltpu.VMEM((2, pages, bs, C), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((Hp, C), k_pages.dtype),
                 pltpu.VMEM((Hp, 1), jnp.float32),
@@ -1656,9 +1748,7 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
         # it are whole sublanes; every value is one of q's)
         qf = q.astype(jnp.float32).reshape(B, Hkv, G, D).transpose(
             0, 2, 1, 3).reshape(B, G, C)
-        out = call(jnp.asarray(layer, jnp.int32).reshape(1),
-                   block_tables.astype(jnp.int32),
-                   lengths.astype(jnp.int32), qf, k_pages, v_pages)
+        out = call(layer, block_tables, lengths, qf, k_pages, v_pages)
         return out.reshape(B, G, Hkv, D).transpose(0, 2, 1, 3).reshape(
             B, H, D).astype(q.dtype)
 

@@ -5,7 +5,7 @@ and Serving Gemma 4 31B on Google Cloud TPU" serves through the same
 design). The cache is a fixed pool of fixed-size pages; each sequence
 owns a *block table* mapping its logical token positions to physical
 pages. Growing a sequence by one token allocates at most one page;
-finishing a sequence returns all its pages to the free list instantly.
+finishing a sequence returns all its pages to the free intervals at once.
 Admission control is therefore exact: a prompt of L tokens with a
 budget of G generated tokens needs ``ceil((L + G) / block_size)``
 pages, and the engine refuses to admit what it cannot finish —
@@ -14,6 +14,23 @@ sequences never deadlock mid-decode waiting for pages.
 Page 0 is reserved as the *null page*: batch-padding rows point every
 block-table entry at it, so padded jit steps scatter their garbage
 into scratch instead of a live sequence's memory.
+
+Pages are handed out as **ascending runs**: the free pages of a pool are
+a set of intervals (``_FreeRuns``), a need is taken from as few of them
+as hold it (the smallest interval that holds it all, the lowest among
+equals, so a fresh pool gives 1, 2, 3, ...; else the longest whole and
+then the smallest that holds the rest), a table lists each interval's
+pages ascending, and pages that come back join the free intervals beside
+them. The decode kernels are why (``ops.attention.paged_attention_decode``):
+a page's copy from HBM costs about as much to start as its 16 KB take to
+arrive, and where ``RUN_PAGES`` consecutive entries of a table name
+consecutive pages one copy brings them all. A free stack handed a
+released table back in reverse, cut where needs differ: descending
+pieces after one turn of the callers. Contiguity is best effort and
+changes nothing that is exact: admission is by the count of free pages
+in every group, and an allocation the counts allow never fails.
+``stats()`` reports ``kv_run_pages_share``: of the pages the sequences'
+tables hold, those in whole runs, counted when a table is handed out.
 
 Pages are *refcounted* so the radix prefix cache (prefix_cache.py) can
 share read-only prompt pages across sequences: ``allocate_with_prefix``
@@ -53,8 +70,14 @@ flax adapters.
 
 from __future__ import annotations
 
+import bisect
 import threading
 from typing import Dict, Iterable, List, Optional, Tuple
+
+# table entries the decode kernels fetch with one copy where they name
+# consecutive pages (``ops.attention.PAGED_RUN_PAGES``: the same number,
+# held equal by tests/test_llm_kernels_decode.py)
+RUN_PAGES = 8
 
 
 class OutOfKVBlocksError(Exception):
@@ -66,6 +89,127 @@ class OutOfKVBlocksError(Exception):
     def __init__(self, message: str, group="full"):
         super().__init__(message)
         self.group = group
+
+
+def run_pages(table: List[int]) -> int:
+    """Pages of ``table`` that lie in a group of ``RUN_PAGES`` consecutive
+    ones (table positions 0..7, 8..15, ...): what a decode kernel brings
+    with one copy a group."""
+    return RUN_PAGES * sum(
+        all(table[j + 1] - table[j] == 1 for j in range(i, i + RUN_PAGES - 1))
+        for i in range(0, len(table) - RUN_PAGES + 1, RUN_PAGES))
+
+
+class _FreeRuns:
+    """The free pages ``first..last`` of one pool as intervals: sorted
+    starts with their lengths beside them, neighbours joined when pages
+    come back, the count of free pages kept (``len``). A need is taken
+    from as few intervals as hold it, each piece ascending, so a table is
+    a few runs of consecutive pages (the allocator's lock guards it)."""
+
+    def __init__(self, first: int, last: int):
+        self._starts: List[int] = [first]
+        self._lens: List[int] = [last - first + 1]
+        self._count = last - first + 1
+
+    def __len__(self) -> int:
+        return self._count
+
+    def _fit(self, need: int) -> int:
+        """The smallest interval that holds ``need`` pages, the lowest
+        among equals (-1: none does)."""
+        best = -1
+        for i, n in enumerate(self._lens):
+            if n >= need and (best < 0 or n < self._lens[best]):
+                best = i
+        return best
+
+    def take(self, need: int) -> List[int]:
+        """``need`` pages (the caller has checked the count): from one
+        interval where one holds them (the smallest that does, so an
+        exact fit goes whole and the long intervals wait for the long
+        needs), else the longest intervals whole until one holds the
+        rest. Each interval's pages ascending, the intervals by
+        address."""
+        assert 0 <= need <= self._count, (need, self._count)
+        pieces: List[Tuple[int, int]] = []
+        while need:
+            i = self._fit(need)
+            if i >= 0:
+                start, n = self._starts[i], need
+                if n == self._lens[i]:
+                    del self._starts[i], self._lens[i]
+                else:
+                    self._starts[i] += n
+                    self._lens[i] -= n
+            else:
+                i = max(range(len(self._lens)),
+                        key=lambda j: (self._lens[j], -j))
+                start, n = self._starts.pop(i), self._lens.pop(i)
+            pieces.append((start, n))
+            self._count -= n
+            need -= n
+        pieces.sort()
+        return [p for start, n in pieces for p in range(start, start + n)]
+
+    def give(self, pages: Iterable[int]) -> None:
+        """Pages come back, in any order; each run joins the free
+        intervals beside it."""
+        pages = sorted(pages)
+        i = 0
+        while i < len(pages):
+            j = i
+            while j + 1 < len(pages) and pages[j + 1] == pages[j] + 1:
+                j += 1
+            self._give_run(pages[i], j - i + 1)
+            i = j + 1
+
+    def _give_run(self, start: int, n: int) -> None:
+        self._count += n
+        at = bisect.bisect_left(self._starts, start)
+        if at and self._starts[at - 1] + self._lens[at - 1] == start:
+            at -= 1
+            self._lens[at] += n
+        else:
+            self._starts.insert(at, start)
+            self._lens.insert(at, n)
+        if at + 1 < len(self._starts) \
+                and self._starts[at] + self._lens[at] == self._starts[at + 1]:
+            self._lens[at] += self._lens.pop(at + 1)
+            del self._starts[at + 1]
+
+    def __iter__(self):
+        for start, n in zip(self._starts, self._lens):
+            yield from range(start, start + n)
+
+
+class _Tables(dict):
+    """seq id -> the pages it holds of one page group, with the pages
+    that lie in whole runs (``run_pages``) counted once, when a table is
+    handed out, and summed beside the tables' pages."""
+
+    def __init__(self):
+        super().__init__()
+        self.runs: Dict[str, int] = {}
+        self.run_total = self.page_total = 0
+
+    def hand_out(self, seq_id: str, pages: List[int]) -> None:
+        """``pages`` become (or replace) the sequence's table."""
+        self.take_back(seq_id)
+        self[seq_id] = pages
+        self.runs[seq_id] = run_pages(pages)
+        self.run_total += self.runs[seq_id]
+        self.page_total += len(pages)
+
+    def take_back(self, seq_id: str) -> Optional[List[int]]:
+        pages = self.pop(seq_id, None)
+        if pages is not None:
+            self.run_total -= self.runs.pop(seq_id)
+            self.page_total -= len(pages)
+        return pages
+
+    def run_share(self) -> float:
+        return self.run_total / max(1, self.page_total)
 
 
 class PagedKVCache:
@@ -83,8 +227,8 @@ class PagedKVCache:
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         # page 0 reserved as the null/scratch page for padding rows
-        self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
-        self._tables: Dict[str, List[int]] = {}   # seq id -> pages
+        self._free = _FreeRuns(1, self.num_blocks - 1)
+        self._tables = _Tables()                  # seq id -> pages
         self._refs: Dict[int, int] = {}           # page -> reference count
         # window -> its page group: a ring (or what a sequence needs of
         # one) a sequence, page 0 null
@@ -144,8 +288,8 @@ class PagedKVCache:
 
     def _take_rings_locked(self, seq_id: str, num_tokens: int):
         for w, g in self._rings.items():
-            g.tables[seq_id] = [g.free.pop() for _ in range(
-                self.ring_need(w, num_tokens))]
+            g.tables.hand_out(
+                seq_id, g.free.take(self.ring_need(w, num_tokens)))
 
     def free_blocks(self) -> int:
         with self._lock:
@@ -168,10 +312,10 @@ class PagedKVCache:
             short = self._ring_short_locked(num_tokens)
             if short:
                 raise short
-            pages = [self._free.pop() for _ in range(need)]
+            pages = self._free.take(need)
             for p in pages:
                 self._refs[p] = 1
-            self._tables[seq_id] = pages
+            self._tables.hand_out(seq_id, pages)
             self._take_rings_locked(seq_id, num_tokens)
             return list(pages)
 
@@ -209,11 +353,11 @@ class PagedKVCache:
                 raise short
             for p in shared_pages:
                 self._refs[p] += 1
-            fresh = [self._free.pop() for _ in range(fresh_need)]
+            fresh = self._free.take(fresh_need)
             for p in fresh:
                 self._refs[p] = 1
             pages = list(shared_pages) + fresh
-            self._tables[seq_id] = pages
+            self._tables.hand_out(seq_id, pages)
             self._take_rings_locked(seq_id, num_tokens)
             return list(pages)
 
@@ -233,18 +377,28 @@ class PagedKVCache:
             return self._decref_locked(pages)
 
     def _decref_locked(self, pages: Iterable[int]) -> int:
-        freed = 0
+        freed = []
         for p in pages:
             n = self._refs.get(p, 0)
             if n <= 0:
                 continue
             if n == 1:
                 del self._refs[p]
-                self._free.append(p)
-                freed += 1
+                freed.append(p)
             else:
                 self._refs[p] = n - 1
-        return freed
+        self._free.give(freed)
+        return len(freed)
+
+    def table_run_pages(self, seq_id: str) -> Tuple[int, int]:
+        """(pages in whole runs of ``RUN_PAGES``, pages) of the tables the
+        sequence holds, all page groups together, as counted when they
+        were handed out."""
+        with self._lock:
+            tables = [self._tables] + [g.tables for g in
+                                       self._rings.values()]
+            return (sum(t.runs.get(seq_id, 0) for t in tables),
+                    sum(len(t.get(seq_id, ())) for t in tables))
 
     def copy_on_write(self, seq_id: str, index: int) -> Tuple[int, int]:
         """Give ``seq_id`` a private copy of block-table entry ``index``
@@ -261,10 +415,11 @@ class PagedKVCache:
             if not self._free:
                 raise OutOfKVBlocksError(
                     "copy-on-write needs 1 free KV block, 0 free")
-            new = self._free.pop()
+            new, = self._free.take(1)
             self._refs[new] = 1
             self._refs[old] -= 1
             table[index] = new
+            self._tables.hand_out(seq_id, table)
             return (old, new)
 
     def ref_count(self, page: int) -> int:
@@ -277,8 +432,8 @@ class PagedKVCache:
         rest are admittable on the very next engine step."""
         with self._lock:
             for g in self._rings.values():
-                g.free.extend(g.tables.pop(seq_id, ()))
-            pages = self._tables.pop(seq_id, None)
+                g.free.give(g.tables.take_back(seq_id) or ())
+            pages = self._tables.take_back(seq_id)
             if not pages:
                 return 0
             return self._decref_locked(pages)
@@ -304,7 +459,10 @@ class PagedKVCache:
                    "kv_blocks_used": used,
                    "kv_block_size": self.block_size,
                    "kv_occupancy": used / max(1, usable),
-                   "kv_sequences": len(self._tables)}
+                   "kv_sequences": len(self._tables),
+                   # of the pages the sequences' tables hold, those in
+                   # groups of RUN_PAGES consecutive ones
+                   "kv_run_pages_share": self._tables.run_share()}
             if self._rings:
                 out["kv_window_groups"] = {
                     w: g.stats() for w, g in self._rings.items()}
@@ -330,8 +488,8 @@ class _RingGroup:
             raise ValueError("need >= 2 blocks (page 0 is reserved)")
         self.ring = ring
         self.num_blocks = int(num_blocks)
-        self.free: List[int] = list(range(self.num_blocks - 1, 0, -1))
-        self.tables: Dict[str, List[int]] = {}
+        self.free = _FreeRuns(1, self.num_blocks - 1)
+        self.tables = _Tables()
 
     def stats(self) -> Dict[str, float]:
         usable = self.num_blocks - 1
@@ -339,5 +497,6 @@ class _RingGroup:
         return {"ring_blocks": self.ring, "blocks_total": usable,
                 "blocks_used": used, "occupancy": used / max(1, usable),
                 "sequences": len(self.tables),
+                "run_pages_share": self.tables.run_share(),
                 # what the running sequences' whole rings would be
                 "blocks_whole_rings": len(self.tables) * self.ring}

@@ -444,6 +444,7 @@ class FlaxModelAdapter:
         self._routed_tokens_total = 0           # real tokens through a router
         self._expert_products: Dict[int, Any] = {}
         self._kv_pages_live = self._kv_pages_padded = 0
+        self._kv_run_pages = self._kv_table_pages = 0
         self._decode_recurrence: Optional[str] = None   # ``bind_state``
         self._kda_kernel_steps = 0
         self._window_pages_live = self._window_pages_padded = 0
@@ -588,7 +589,12 @@ class FlaxModelAdapter:
         padded tables; for a model with state or routed experts, its
         slots and its experts' tokens (docs/TRACING.md)."""
         out = {"kv_pages_live_total": self._kv_pages_live,
-               "kv_pages_padded_total": self._kv_pages_padded}
+               "kv_pages_padded_total": self._kv_pages_padded,
+               # over the decode steps: the pages of their rows' tables
+               # (every page group) that lie in runs the kernel copies at
+               # once, and those tables' pages
+               "kv_run_pages_total": self._kv_run_pages,
+               "kv_table_pages_total": self._kv_table_pages}
         if self._spec is None:
             return out
         if self._rings:
@@ -910,17 +916,24 @@ class FlaxModelAdapter:
         and ``counters()``: which path it takes (the kernel over the
         rows' live pages, or the gather to every row's padded table; for
         a model with a recurrent state, which path its recurrence takes),
-        the pages that hold one of the rows' tokens, and the pages of
-        the ``B`` padded tables."""
+        the pages that hold one of the rows' tokens, the pages of the
+        ``B`` padded tables, and of the pages the rows' tables hold
+        (every page group) those in runs of consecutive pages
+        (``PagedKVCache.table_run_pages``, counted at admission)."""
         bs = self.cache.block_size
         lens = [r["len"] + 1 for r in rows]
         live = sum(-(-n // bs) for n in lens)
         padded = B * self.nb_max
+        run = sum(r["run_pages"][0] for r in rows)
+        held = sum(r["run_pages"][1] for r in rows)
         self._kv_pages_live += live
         self._kv_pages_padded += padded
+        self._kv_run_pages += run
+        self._kv_table_pages += held
         out = {"attention": self._decode_attention,
                "live_tokens": sum(lens),
-               "kv_pages_live": live, "kv_pages_padded": padded}
+               "kv_pages_live": live, "kv_pages_padded": padded,
+               "kv_run_pages": run, "kv_table_pages": held}
         if self._decode_recurrence is not None:
             out["recurrence"] = self._decode_recurrence
             self._kda_kernel_steps += self._decode_recurrence == "kda_kernel"
@@ -1005,7 +1018,8 @@ class FlaxModelAdapter:
                     self.copy_page(old, new)
             table = self.cache.block_table(s.seq_id)
             st = self._state[s.seq_id] = {
-                "table": table, "len": len(s.prompt), "slot": slot}
+                "table": table, "len": len(s.prompt), "slot": slot,
+                "run_pages": self.cache.table_run_pages(s.seq_id)}
             if self._rings:
                 st["rings"] = {w: self.cache.ring_table(s.seq_id, w)
                                for w in self._rings}
@@ -1123,7 +1137,9 @@ class FlaxModelAdapter:
                     a = self._arrays[name]
                     self._arrays[name] = a.at[:, idx].set(
                         jnp.asarray(blob["pages"][name], a.dtype))
-            self._state[seq_id] = {"table": table, "len": int(n_prompt)}
+            self._state[seq_id] = {
+                "table": table, "len": int(n_prompt),
+                "run_pages": self.cache.table_run_pages(seq_id)}
             return
         merged = (self.n_layers, nb, bs, -1)
         with self._lock:
@@ -1131,7 +1147,9 @@ class FlaxModelAdapter:
                 blob["k"], self.k_pages.dtype).reshape(merged))
             self.v_pages = self.v_pages.at[:, idx].set(jnp.asarray(
                 blob["v"], self.v_pages.dtype).reshape(merged))
-        self._state[seq_id] = {"table": table, "len": int(n_prompt)}
+        self._state[seq_id] = {
+            "table": table, "len": int(n_prompt),
+            "run_pages": self.cache.table_run_pages(seq_id)}
 
     def release(self, seq_id: str):
         st = self._state.pop(seq_id, None)

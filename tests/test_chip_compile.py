@@ -931,6 +931,37 @@ def test_paged_attention_decode_groups_of_seven_and_a_ring_of_257(
         sds((128,), jnp.int32))
 
 
+@pytest.mark.parametrize("B,H,Hkv,P,NB,window,parent", [
+    (16, 20, 20, 1025, 64, None, 32), (64, 48, 8, 36865, 576, None, 32),
+    (64, 64, 8, 2113, 33, 512, 32), (128, 28, 4, 24577, 576, None, 128),
+    (128, 28, 4, 18433, 257, 4096, 128)],
+    ids=["gpt2-large", "laguna-full", "laguna-ring", "smallthinker-full",
+         "smallthinker-ring"])
+def test_paged_attention_decode_holds_no_more_copy_sites_than_it_did(
+        B, H, Hkv, P, NB, window, parent):
+    """What a decode bucket's first call pays to trace and lower the
+    kernel grows with its copy sites (32 unrolled page copies a chunk
+    took ``setup_s`` from 173-187 to 228-237 s, PR 43). The groups of a
+    chunk and the pages of a group that is no run are loops inside the
+    kernel: every cell's kernel starts a copy at 8 sites (a run's and a
+    page's, K and V, at the first chunk and at the next one) and waits at
+    2, where the parent's (``parent``: 2 x 2 x the pages of its chunk)
+    had 32 at 2 KiB rows and 128 at 1 KiB; counted in the kernel's jaxpr,
+    each equation of which Mosaic lowers once."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    D = 64 if H == Hkv else 128
+    pool = sds((2, P, 16, Hkv * D), jnp.bfloat16)
+    text = str(jax.make_jaxpr(lambda q, k, v, bt, ln: (
+        A.paged_attention_decode(q, k, v, bt, ln, layer=1, window=window,
+                                 interpret=False)))(
+        sds((B, H, D), jnp.bfloat16), pool, pool, sds((B, NB), jnp.int32),
+        sds((B,), jnp.int32)))
+    assert "paged_attention_decode" in text
+    assert text.count("dma_start") == 8 <= parent
+    assert text.count("dma_wait") == 2
+
+
 @pytest.mark.parametrize("kind,config", [
     ("kimi_linear", "KimiLinearConfig"), ("kimi_k2", "KimiK2Config"),
     ("laguna", "LagunaConfig"),

@@ -160,18 +160,21 @@ def test_the_windows_edge():
         > 1e-4
 
 
-def _ring_pool(rng, B, ring, bs, C, lengths, window):
+def _ring_pool(rng, B, ring, bs, C, lengths, window, runs=False):
     """Pools and ring tables holding, for each row, every position of
     its last ``window`` (and a few before: what a ring still holds) at
     the place the rule puts it; and the same rows laid out flat by
-    position, for the masked reference."""
+    position, for the masked reference. ``runs``: a row's ring is
+    consecutive ascending pages (what the allocator hands out), else
+    shuffled ones."""
     T = int(max(lengths))
     k_flat = rng.normal(size=(B, T, C)).astype(np.float32)
     v_flat = rng.normal(size=(B, T, C)).astype(np.float32)
     P = B * ring + 1
     k_pages = rng.normal(size=(2, P, bs, C)).astype(np.float32)  # stale rows
     v_pages = rng.normal(size=(2, P, bs, C)).astype(np.float32)
-    tables = 1 + rng.permutation(B * ring).reshape(B, ring).astype(np.int32)
+    tables = 1 + (np.arange if runs else rng.permutation)(
+        B * ring).reshape(B, ring).astype(np.int32)
     for b, n in enumerate(lengths):
         for p in range(max(n - ring * bs + bs, 0), n):
             page = tables[b, (p // bs) % ring]
@@ -180,17 +183,23 @@ def _ring_pool(rng, B, ring, bs, C, lengths, window):
     return k_flat, v_flat, k_pages, v_pages, tables
 
 
+@pytest.mark.parametrize("tables", ["shuffled", "one-run"])
+@pytest.mark.parametrize("window", [64, 240])
 @pytest.mark.parametrize("G", [6, 8])
-def test_paged_decode_kernel_over_a_ring_equals_the_masked_gather(G):
+def test_paged_decode_kernel_over_a_ring_equals_the_masked_gather(
+        G, window, tables):
     """``paged_attention_decode(window=...)`` interpreted, groups of 6
     and 8 query heads a key/value head: rows shorter than the window,
-    exactly a window, several rings long, and an empty row."""
+    exactly a window, several rings long, and an empty row; over a ring
+    of 5 pages (one group of 5) and of 16 (two groups of 8: a ring of one
+    run is copied a group at once but where the walk wraps inside one),
+    shuffled pages and one run a row."""
     rng = np.random.default_rng(G)
-    Hkv, D, bs, window = 2, 128, 16, 64
+    Hkv, D, bs = 2, 128, 16
     ring, B = window // bs + 1, 5
-    lengths = np.array([200, 64, 17, 0, 333], np.int32)
+    lengths = np.array([200, window, 17, 0, 333], np.int32)
     k_flat, v_flat, k_pages, v_pages, tables = _ring_pool(
-        rng, B, ring, bs, Hkv * D, lengths, window)
+        rng, B, ring, bs, Hkv * D, lengths, window, runs=tables == "one-run")
     q = jnp.asarray(rng.normal(size=(B, Hkv * G, D)), jnp.float32)
     args = (q, jnp.asarray(k_pages), jnp.asarray(v_pages),
             jnp.asarray(tables), jnp.asarray(lengths))

@@ -180,7 +180,8 @@ def test_stateless_kinds_keep_their_step_program(kind):
     from ray_tpu.serve.llm.model_runner import bucket_name
     adapter, _ = _flax_adapter(kind)
     assert not adapter.has_state and adapter.counters() == {
-        "kv_pages_live_total": 0, "kv_pages_padded_total": 0}
+        "kv_pages_live_total": 0, "kv_pages_padded_total": 0,
+        "kv_run_pages_total": 0, "kv_table_pages_total": 0}
     assert adapter.k_pages.shape == (
         adapter.n_layers, 32, PAGE, adapter.n_kv_heads * adapter.head_dim)
     fn = adapter._step_fn(2, 1)
@@ -239,6 +240,13 @@ def test_decode_is_the_one_token_program(kind):
         assert dispatch["kv_pages_live"] == live
         assert dispatch["kv_pages_padded"] == 4 * adapter.nb_max
         assert adapter.counters()["kv_pages_live_total"] == live
+        # the pages the rows' tables hold, and those among them that a
+        # kernel's one copy would bring: a fresh pool gives whole runs
+        held = [adapter.cache.blocks_for(s.budget_tokens()) for s in seqs]
+        assert dispatch["kv_table_pages"] == sum(held)
+        assert dispatch["kv_run_pages"] == sum(n // 8 * 8 for n in held)
+        assert adapter.counters()["kv_run_pages_total"] \
+            == dispatch["kv_run_pages"]
     np.testing.assert_allclose(logits["decode"], logits["window"],
                                rtol=1e-5, atol=1e-5)
     for row, s in zip(logits["decode"], seqs):

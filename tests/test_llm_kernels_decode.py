@@ -13,42 +13,91 @@ from ray_tpu.serve.llm.kv_cache import OutOfKVBlocksError
 # ------------------------------------------------------ kernel numerics
 
 
-# lengths against pages of 16 tokens and chunks of 128: a padding row, one
-# token, a whole page, a page and one, an end mid-chunk, whole chunks, the
-# full table
+# lengths against pages of 16 tokens and groups of 8 pages (128 tokens): a
+# padding row, one token, a whole page, a page and one, an end mid-group,
+# whole groups, the full table
 _LENGTHS = [0, 1, 16, 17, 100, 128, 200, 256]
 
 
+def _tables(kind, held, NB, rng):
+    """[rows, NB] block tables of ``held[b]`` pages a row (the rest the
+    null page) from a pool of ``_pool_pages(held)`` pages, by ``kind``:
+    ``shuffled`` (no two neighbours consecutive but by chance),
+    ``one-run`` (a row's pages ascending and consecutive: what a fresh
+    interval gives), ``broken-runs`` (runs of 3 to 11 pages in any order:
+    a break inside most groups of 8), ``descending`` (consecutive, the
+    wrong way round), ``shared-prefix`` (every row's first three pages are
+    the SAME pages, from the pool's top, then a run of its own: not
+    sorted), ``swapped-inside`` (one run but for the second and third
+    entry of every group of 8, which change places: the group's two ends
+    still differ by 7)."""
+    tables = np.zeros((len(held), NB), np.int32)
+    at = 1
+    shared = list(range(1 + sum(held), _pool_pages(held)))
+    for row, n in zip(tables, held):
+        own = list(range(at, at + n))
+        at += n
+        if kind == "descending":
+            own = own[::-1]
+        elif kind == "broken-runs":
+            cuts, runs = 0, []
+            while cuts < n:
+                step = int(rng.integers(3, 12))
+                runs.append(own[cuts:cuts + step])
+                cuts += step
+            own = [p for i in rng.permutation(len(runs)) for p in runs[i]]
+        elif kind == "shared-prefix":
+            own = (shared + own)[:n]
+        elif kind == "swapped-inside":
+            for i in range(1, n - 1, 8):
+                own[i], own[i + 1] = own[i + 1], own[i]
+        row[:n] = own
+    if kind == "shuffled":
+        pages = iter(1 + rng.permutation(sum(held)))
+        for row, n in zip(tables, held):
+            row[:n] = [next(pages) for _ in range(n)]
+    return tables
+
+
+def _pool_pages(held):
+    """The null page, every row's own pages and three shared ones."""
+    return 1 + sum(held) + 3
+
+
+_TABLE_KINDS = ["shuffled", "one-run", "broken-runs", "descending",
+                "shared-prefix", "swapped-inside"]
+
+
+@pytest.mark.parametrize("tables", ["shuffled", "one-run", "shared-prefix"])
 @pytest.mark.parametrize("layer", [0, 2], ids=["first-layer", "last-layer"])
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
                                        ("bfloat16", 2e-2)])
 @pytest.mark.parametrize("H,Hkv,D", [(4, 4, 64), (8, 2, 128)],
                          ids=["mha-64", "gqa-128"])
 def test_paged_attention_matches_whole_kv_reference(H, Hkv, D, dtype, tol,
-                                                    layer):
+                                                    layer, tables):
     """The Pallas paged-decode kernel (interpret mode on CPU) over the
     serving pool [L, P, bs, Hkv*D], the paged gather reference, and the
     contiguous whole-kv decode path must agree on the same cache
     contents: GPT-2-like heads (two to a lane tile) and Llama-like
     (grouped, a tile each), block tables whose unused entries are the
-    null page, a row with no token (finite, and nothing else)."""
+    null page, a row with no token (finite, and nothing else); tables of
+    shuffled pages (a copy a page), of one ascending run a row (one copy
+    a group of 8) and with a shared prefix."""
     import jax.numpy as jnp
 
     from ray_tpu.ops import attention as A
-    rng = np.random.RandomState(0)
+    rng = np.random.default_rng(0)
     L, bs, NB = 3, 16, 16
     B, C = len(_LENGTHS), Hkv * D
-    P = 1 + sum(-(-n // bs) for n in _LENGTHS)
+    held = [-(-n // bs) for n in _LENGTHS]
+    P = _pool_pages(held)
     dt = jnp.dtype(dtype)
     lengths = jnp.asarray(_LENGTHS, jnp.int32)
-    k_pages = jnp.asarray(rng.randn(L, P, bs, C), dt)
-    v_pages = jnp.asarray(rng.randn(L, P, bs, C), dt)
-    tables = np.zeros((B, NB), np.int32)
-    pages = iter(rng.permutation(np.arange(1, P)))
-    for row, n in zip(tables, _LENGTHS):
-        row[:-(-n // bs)] = [next(pages) for _ in range(-(-n // bs))]
-    bt = jnp.asarray(tables)
-    q = jnp.asarray(rng.randn(B, H, D), dt)
+    k_pages = jnp.asarray(rng.normal(size=(L, P, bs, C)), dt)
+    v_pages = jnp.asarray(rng.normal(size=(L, P, bs, C)), dt)
+    bt = jnp.asarray(_tables(tables, held, NB, rng))
+    q = jnp.asarray(rng.normal(size=(B, H, D)), dt)
 
     ref = A.paged_attention_reference(q, k_pages, v_pages, bt, lengths,
                                       layer=layer)
@@ -69,6 +118,67 @@ def test_paged_attention_matches_whole_kv_reference(H, Hkv, D, dtype, tol,
     np.testing.assert_allclose(
         got[live], np.asarray(cont.astype(jnp.float32))[live],
         rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("G", [1, 6, 7, 8])
+@pytest.mark.parametrize("window", [None, 240], ids=["full", "ring"])
+@pytest.mark.parametrize("tables", _TABLE_KINDS)
+def test_paged_attention_copies_runs_as_the_tables_allow(tables, window, G):
+    """Any table is right, only slower: the kernel interpreted against the
+    gather, over pools of 1 KiB rows (chunks of 32 pages, four groups of
+    8; over the ring of 16 pages two), at 1, 6, 7 and 8 query heads a
+    key/value head. The rows: none, one token, whole chunks, a last chunk
+    that is mostly dead pages (its dead groups are not copied), the
+    table's end; over the ring also a short ring padded with the null
+    page, exactly a window, and rings that wrap, inside a group of a
+    table of one run too. A table of one run, runs broken inside a
+    group, a descending table, a shared prefix (not sorted), a group
+    whose two ends differ by 7 and which is still no run, shuffled
+    pages."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention as A
+    rng = np.random.default_rng(G)
+    Hkv, D, bs = 2, 128, 16
+    C = Hkv * D
+    assert A.paged_chunk_tokens(C * 4) == 512
+    if window is None:
+        NB, lengths = 40, [0, 1, 128, 129, 300, 513, 530, 640]
+        held = [min(NB, -(-n // bs) + 3) for n in lengths]
+    else:
+        NB, lengths = window // bs + 1, [0, 5, 100, 240, 256, 300, 513, 1000]
+        held = [min(NB, -(-n // bs)) for n in lengths]
+    held[0] = 0
+    B, P = len(lengths), _pool_pages(held)
+    k_pages = jnp.asarray(rng.normal(size=(2, P, bs, C)), jnp.float32)
+    v_pages = jnp.asarray(rng.normal(size=(2, P, bs, C)), jnp.float32)
+    bt = _tables(tables, held, NB, rng)
+    if tables == "swapped-inside":
+        assert bt[-1, 7] - bt[-1, 0] == 7 and bt[-1, 1] - bt[-1, 0] == 2
+    args = (jnp.asarray(rng.normal(size=(B, Hkv * G, D)), jnp.float32),
+            k_pages, v_pages, jnp.asarray(bt),
+            jnp.asarray(lengths, jnp.int32))
+    got = np.asarray(A.paged_attention_decode(
+        *args, layer=1, window=window, interpret=True))
+    want = np.asarray(A.paged_attention_reference(
+        *args, layer=1, window=window))
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=1e-5, atol=1e-5)
+    assert np.all(got[~live] == 0)
+
+
+def test_the_kernels_groups_are_the_allocators():
+    """The kernel copies a group of ``PAGED_RUN_PAGES`` table entries at
+    once where they are consecutive; the allocator counts the pages of
+    such groups by the same number."""
+    from ray_tpu.ops import attention as A
+    from ray_tpu.serve.llm import kv_cache
+    assert A.PAGED_RUN_PAGES == kv_cache.RUN_PAGES == 8
+    assert kv_cache.run_pages(list(range(5, 25))) == 16
+    assert kv_cache.run_pages(list(range(5, 12))) == 0
+    assert kv_cache.run_pages([1, 2, 3, 4, 6, 5, 7, 8]) == 0
+    assert kv_cache.run_pages([9, 2, 3, 4, 5, 6, 7, 16]) == 0
+    assert kv_cache.run_pages(list(range(20, 4, -1))) == 0
 
 
 def test_cached_attention_takes_the_kernel_by_what_it_sees(monkeypatch):
@@ -336,6 +446,227 @@ def test_paged_kv_allocator_exact_admission():
     assert c.free("a") == 3
     assert c.occupancy() == 0.0
     assert c.free("a") == 0             # double free is a no-op
+
+
+def test_the_allocator_hands_out_ascending_runs_and_joins_them_again():
+    """The order the free intervals pin: a fresh pool gives 1, 2, 3, ...;
+    a need goes to the smallest interval that holds it (an exact fit
+    whole, the long intervals kept for the long needs), else to the
+    longest intervals whole and then the smallest that holds the rest,
+    each interval's pages ascending, the intervals by address; a freed
+    table joins its neighbours, a page dropped alone too; copy-on-write
+    takes its page from the smallest interval."""
+    c = PagedKVCache(num_blocks=41, block_size=1)    # pages 1..40
+    assert c.allocate("a", 10) == list(range(1, 11))
+    assert c.allocate("b", 4) == list(range(11, 15))
+    assert c.allocate("c", 6) == list(range(15, 21))
+    assert c.allocate("d", 3) == list(range(21, 24))
+    assert c.stats()["kv_run_pages_share"] == 8 / 23    # a's first group
+    c.free("b")                       # free: 11..14, 24..40
+    c.free("d")                       # free: 11..14, 21..40 (joined)
+    assert c.allocate("e", 4) == [11, 12, 13, 14]       # the exact fit
+    c.free("e")
+    assert c.allocate("f", 3) == [11, 12, 13]   # the smallest that holds it
+    assert c.allocate("g", 2) == [21, 22]       # 14 alone does not
+    c.free("a")                       # free: 1..10, 14, 23..40
+    # 25 pages: no interval holds them; the longest whole (23..40), and
+    # the rest from the smallest that holds 7 (1..10), by address
+    assert c.allocate("h", 25) == list(range(1, 8)) + list(range(23, 41))
+    assert c.free_blocks() == 4                 # 8, 9, 10 and 14
+    c.allocate_with_prefix("i", 3, c.block_table("h")[:2])   # one fresh
+    assert c.block_table("i")[2] == 14          # from the smallest interval
+    assert c.copy_on_write("i", 0) == (1, 8)
+    for seq in "cfghi":
+        c.free(seq)
+    assert sorted(c._free) == list(range(1, 41))
+    assert c.allocate("z", 40) == list(range(1, 41))    # one interval again
+    assert c.decref([7]) == 1 and c.decref([5, 6]) == 2
+    assert c.allocate_with_prefix("y", 4, [1]) == [1, 5, 6, 7]
+
+
+def _check_allocator(cache, tables, extra):
+    """Every page but the null one is free or referenced, as often as
+    tables and other holders (``extra``: page -> count) name it; no ring
+    page is held twice; the counts are the intervals'."""
+    want = dict(extra)
+    for t in tables.values():
+        for p in t:
+            want[p] = want.get(p, 0) + 1
+    free = list(cache._free)
+    assert len(free) == len(set(free)) == cache.free_blocks()
+    assert not set(free) & set(want) and 0 not in want and 0 not in free
+    assert sorted(free + list(want)) == list(range(1, cache.num_blocks))
+    assert all(cache.ref_count(p) == n for p, n in want.items())
+    for w in cache.windows:
+        g = cache._rings[w]
+        held = [p for t in g.tables.values() for p in t]
+        assert len(held) == len(set(held)) and 0 not in held
+        assert sorted(held + list(g.free)) == list(range(1, g.num_blocks))
+        assert len(g.free) == g.num_blocks - 1 - len(held)
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["plain", "rings"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_allocator_keeps_its_books_under_random_traffic(seed, windowed):
+    """Random admissions, releases and (without a window group) shared
+    prefixes, copies on write and references taken and dropped by a
+    prefix cache: no page is ever held twice, ``can_allocate`` is true
+    exactly where every group's free count suffices and ``allocate``
+    then never raises (and raises, taking nothing, where it is false),
+    and when everything is released every page is back in one interval a
+    group."""
+    from ray_tpu.serve.llm.kv_cache import run_pages
+    rng = np.random.default_rng(seed)
+    bs = 4
+    cache = PagedKVCache(97, bs, windows=(48,) if windowed else (),
+                         max_sequences=6, window_blocks=41)
+    tables, extra, n = {}, {}, 0
+    for _ in range(600):
+        op = rng.choice(["admit", "admit", "release", "prefix", "cow",
+                         "incref", "decref"])
+        if op == "admit" or (windowed and op == "prefix"):
+            tokens = int(rng.integers(1, 160))
+            need = cache.blocks_for(tokens)
+            fits = cache.free_blocks() >= need and all(
+                cache.ring_need(w, tokens)
+                <= cache.stats()["kv_window_groups"][w]["blocks_total"]
+                - cache.stats()["kv_window_groups"][w]["blocks_used"]
+                for w in cache.windows)
+            assert cache.can_allocate(tokens) == fits
+            before = (cache.free_blocks(), cache.stats())
+            if fits:
+                tables[f"s{n}"] = cache.allocate(f"s{n}", tokens)
+                assert len(tables[f"s{n}"]) == need
+                for w in cache.windows:
+                    assert len(cache.ring_table(f"s{n}", w)) \
+                        == cache.ring_need(w, tokens)
+            else:
+                with pytest.raises(OutOfKVBlocksError):
+                    cache.allocate(f"s{n}", tokens)
+                assert (cache.free_blocks(), cache.stats()) == before
+            n += 1
+        elif op == "release" and tables:
+            seq = str(rng.choice(sorted(tables)))
+            held = tables.pop(seq)
+            freed = cache.free(seq)
+            assert freed == sum(
+                1 for p in set(held) if not extra.get(p) and not any(
+                    p in t for t in tables.values()))
+        elif op == "prefix" and tables:
+            donor = tables[str(rng.choice(sorted(tables)))]
+            shared = donor[:int(rng.integers(0, len(donor) + 1))]
+            tokens = bs * (len(shared) + int(rng.integers(1, 9)))
+            fresh = cache.blocks_for(tokens) - len(shared)
+            if cache.free_blocks() >= fresh:
+                t = cache.allocate_with_prefix(f"s{n}", tokens, shared)
+                assert t[:len(shared)] == shared
+                tables[f"s{n}"] = t
+            else:
+                with pytest.raises(OutOfKVBlocksError):
+                    cache.allocate_with_prefix(f"s{n}", tokens, shared)
+            n += 1
+        elif op == "cow" and tables and not windowed:
+            seq = str(rng.choice(sorted(tables)))
+            i = int(rng.integers(0, len(tables[seq])))
+            shared = cache.ref_count(tables[seq][i]) > 1
+            if shared and not cache.free_blocks():
+                with pytest.raises(OutOfKVBlocksError):
+                    cache.copy_on_write(seq, i)
+                continue
+            old, new = cache.copy_on_write(seq, i)
+            assert old == tables[seq][i] and (new != old) == shared
+            tables[seq][i] = new
+            assert cache.block_table(seq) == tables[seq]
+        elif op == "incref" and tables and not windowed:
+            t = tables[str(rng.choice(sorted(tables)))]
+            page = int(rng.choice(t))
+            cache.incref([page])
+            extra[page] = extra.get(page, 0) + 1
+        elif op == "decref" and extra:
+            page = int(rng.choice(sorted(extra)))
+            last = extra[page] == 1 and not any(
+                page in t for t in tables.values())
+            assert cache.decref([page]) == int(last)
+            extra[page] -= 1
+            if not extra[page]:
+                del extra[page]
+        _check_allocator(cache, tables, extra)
+        assert cache.stats()["kv_run_pages_share"] == sum(
+            run_pages(t) for t in tables.values()) / max(1, sum(
+                len(t) for t in tables.values()))
+    for seq in list(tables):
+        cache.free(seq)
+    cache.decref([p for p, k in extra.items() for _ in range(k)])
+    assert cache.free_blocks() == 96 and cache._free._starts == [1]
+    for w in cache.windows:
+        assert cache._rings[w].free._starts == [1]
+    assert cache.stats()["kv_sequences"] == 0 \
+        and cache.stats()["kv_run_pages_share"] == 0
+
+
+def _closed_loop_churn(cache, pool, running, admissions, seed):
+    """A closed loop's allocator: ``running`` sequences of the traffic
+    file's multiset (each cycle in an order the seed picks), each leaving
+    after about its output's steps, the next admitted at once."""
+    import heapq
+
+    from benchmark.harness import loadgen
+    order = loadgen.ordered(pool, seed, 3)
+    rng = np.random.default_rng(seed)
+    heap, now, n = [], 0.0, 0
+    while n < admissions:
+        while len(heap) < running and n < admissions:
+            prompt, out = next(order)
+            assert cache.can_allocate(prompt + out)
+            cache.allocate(f"s{n}", prompt + out)
+            heapq.heappush(heap, (now + out + prompt / 80.0
+                                  + rng.uniform(0, 3), f"s{n}"))
+            n += 1
+        now, seq = heapq.heappop(heap)
+        cache.free(seq)
+
+
+@pytest.mark.parametrize("cell,traffic,floor", [
+    ("smallthinker_21b_a3b", "serve_closed96_mix8k", 0.85),
+    ("laguna_xs_2", "serve_closed64_ctx8k", 0.85),
+    # (10 to 58 pages a sequence: the last group of a table is rarely
+    # whole, and the multiset itself allows 87.6%)
+    ("gpt2_large", "serve_closed32", 0.80)])
+def test_tables_stay_runs_under_a_cells_churn(cell, traffic, floor):
+    """After 5,000 admissions at a serving cell's pools, sequences and
+    multiset of lengths, the running tables' pages still lie in whole
+    runs of 8: 85% of them and more in every page group (98% at the long
+    contexts), and within three points of what the multiset's own page
+    counts allow."""
+    import json
+    import os
+
+    from benchmark.harness import loadgen
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "configs", cell + ".json")) as f:
+        config = json.load(f)
+    engine = config["serve"]["engine"]
+    with open(os.path.join(bench, "traffic", traffic + ".json")) as f:
+        pool = loadgen.length_pool(json.load(f))
+    windows = {"smallthinker_21b_a3b": (4096,), "laguna_xs_2": (512,)}.get(
+        cell, ())
+    bs = engine["block_size"]
+    cache = PagedKVCache(engine["num_blocks"], bs, windows=windows,
+                         max_sequences=engine["max_running"],
+                         window_blocks=engine.get("window_blocks"))
+    _closed_loop_churn(cache, pool, engine["max_running"], 5000, 44)
+    stats = cache.stats()
+    assert stats["kv_sequences"] >= engine["max_running"] - 1
+    needs = [cache.blocks_for(p + o) for p, o in pool]
+    assert stats["kv_run_pages_share"] >= max(
+        floor, sum(n // 8 * 8 for n in needs) / sum(needs) - 0.03)
+    for w in windows:
+        share = stats["kv_window_groups"][w]["run_pages_share"]
+        ring = cache.ring_blocks(w)
+        held = [min(ring, n) for n in needs]
+        assert share >= sum(n // 8 * 8 for n in held) / sum(held) - 0.03
+        assert share >= floor or ring < 64
 
 
 # --------------------------------------------------- incremental decode

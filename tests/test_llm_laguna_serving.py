@@ -148,7 +148,8 @@ def test_allocator_accounts_by_page_group():
     groups = cache.stats()["kv_window_groups"]
     assert groups == {32: {"ring_blocks": 5, "blocks_total": 10,
                            "blocks_used": 10, "occupancy": 1.0,
-                           "sequences": 2, "blocks_whole_rings": 10}}
+                           "sequences": 2, "blocks_whole_rings": 10,
+                           "run_pages_share": 0.0}}
     cache.free("a")
     assert cache.ring_table("a", 32) is None
     assert cache.stats()["kv_window_groups"][32]["blocks_used"] == 5
@@ -166,7 +167,9 @@ def test_allocator_accounts_by_page_group():
     assert plain.windows == () and plain.allocate("a", 100) == a
     assert set(plain.stats()) == {"kv_blocks_total", "kv_blocks_used",
                                   "kv_block_size", "kv_occupancy",
-                                  "kv_sequences"}
+                                  "kv_sequences", "kv_run_pages_share"}
+    # 13 pages of a fresh pool are one run: one whole group of 8
+    assert plain.stats()["kv_run_pages_share"] == 8 / 13
     # and a model with a window refuses a cache without its group
     from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
     k = _laguna()
@@ -222,7 +225,8 @@ def test_admission_is_exact_on_both_groups_with_rings_by_need():
     cache.allocate("c", PAGE)                       # 1: the group is full
     assert cache.stats()["kv_window_groups"][32] == {
         "ring_blocks": 5, "blocks_total": 8, "blocks_used": 8,
-        "occupancy": 1.0, "sequences": 3, "blocks_whole_rings": 15}
+        "occupancy": 1.0, "sequences": 3, "blocks_whole_rings": 15,
+        "run_pages_share": 0.0}
     # the full group short, the window group not: nothing is taken
     cache.free("a")
     with pytest.raises(OutOfKVBlocksError, match="KV blocks") as e:
@@ -269,8 +273,15 @@ def test_engine_admits_by_both_groups_and_says_what_its_steps_read():
     assert decodes and all(
         {"attention", "live_tokens", "window_tokens", "kv_pages_live",
          "kv_pages_padded", "kv_window_pages_live", "kv_window_pages_held",
-         "kv_window_pages_whole_rings", "kv_window_pages_padded"} <= set(a)
+         "kv_window_pages_whole_rings", "kv_window_pages_padded",
+         "kv_run_pages", "kv_table_pages"} <= set(a)
         for a in decodes)
+    # both groups' tables: the full group's pages and the rings'
+    assert all(a["kv_run_pages"] <= a["kv_table_pages"]
+               and a["kv_table_pages"] > a["kv_window_pages_held"]
+               for a in decodes)
+    assert metrics["kv_table_pages_total"] \
+        == sum(a["kv_table_pages"] for a in decodes)
     assert all(a["attention"] == "gather" for a in decodes)     # the CPU
     assert all(a["window_tokens"] <= a["live_tokens"]
                and a["kv_window_pages_live"] <= a["kv_window_pages_held"]

@@ -210,24 +210,33 @@ def test_paged_decode_kernel_groups_of_seven_over_whole_and_short_rings():
 
 
 def test_a_chunk_holds_more_tokens_only_where_the_pools_rows_are_narrow():
-    """The paged kernel's chunk by the width of a pool's rows: the widths
-    it was measured at keep their 128 tokens (Laguna's 8 key/value heads
-    of 128 in bfloat16, GPT-2 large's 20 of 64 in either type), this
-    model's 4 of 128 in bfloat16 take 512."""
-    assert A.paged_chunk_tokens(8 * 128 * 2) == 128
-    assert A.paged_chunk_tokens(20 * 64 * 2) == 128
-    assert A.paged_chunk_tokens(20 * 64 * 4) == 128
+    """The paged kernel's chunk by the width of a pool's rows: rows of
+    2 KiB and over take 256 tokens (Laguna's 8 key/value heads of 128 in
+    bfloat16, GPT-2 large's 20 of 64 in either type: 128 until a group of
+    8 pages came with one copy, PR 44), this model's 4 of 128 in bfloat16
+    take 512; either is whole groups of 8 pages of 16 tokens."""
+    assert A.paged_chunk_tokens(8 * 128 * 2) == 256
+    assert A.paged_chunk_tokens(20 * 64 * 2) == 256
+    assert A.paged_chunk_tokens(20 * 64 * 4) == 256
     assert A.paged_chunk_tokens(4 * 128 * 2) == 512
+    assert all(A.paged_chunk_tokens(b) % (16 * A.PAGED_RUN_PAGES) == 0
+               for b in (1024, 2048, 2560))
 
 
+@pytest.mark.parametrize("tables", ["shuffled", "one-run", "broken-runs",
+                                    "descending"])
 @pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
-def test_paged_decode_kernel_in_chunks_of_512_tokens(window):
+def test_paged_decode_kernel_in_chunks_of_512_tokens(window, tables):
     """``paged_attention_decode`` interpreted over a pool whose rows are
     1 KiB (2 key/value heads of 128 in float32), which takes chunks of 32
-    pages: contexts of several chunks and of less than one, a row whose
-    last chunk is mostly dead pages, a whole ring of 65 pages walked from
-    its middle round its end, a ring wrapped several times, a short ring
-    that never wraps, and an empty row. Against the gather."""
+    pages, four groups of 8: contexts of several chunks and of less than
+    one, a row whose last chunk is mostly dead pages (three of its four
+    groups are not copied), a whole ring of 65 pages walked from its
+    middle round its end (the group that holds the wrap takes a copy a
+    page), a ring wrapped several times, a short ring that never wraps
+    (its table's rest the null page), and an empty row; tables of
+    shuffled pages, of one ascending run a row, of runs of 3 to 12 pages
+    (breaks inside the groups), and descending. Against the gather."""
     rng = np.random.default_rng(11)
     Hkv, G, D, bs = 2, 7, 128, 16
     C = Hkv * D
@@ -239,11 +248,20 @@ def test_paged_decode_kernel_in_chunks_of_512_tokens(window):
     P = 1 + B * NB
     k_pages = jnp.asarray(rng.normal(size=(2, P, bs, C)), jnp.float32)
     v_pages = jnp.asarray(rng.normal(size=(2, P, bs, C)), jnp.float32)
-    tables = np.zeros((B, NB), np.int32)
+    kind, tables = tables, np.zeros((B, NB), np.int32)
     free = list(1 + rng.permutation(P - 1))
     for b, n in enumerate(lengths):
         held = min(NB, -(-int(n) // bs))
-        tables[b, :held] = [free.pop() for _ in range(held)]
+        own = list(range(1 + b * NB, 1 + b * NB + held))
+        if kind == "shuffled":
+            own = [free.pop() for _ in range(held)]
+        elif kind == "descending":
+            own = own[::-1]
+        elif kind == "broken-runs":
+            cuts = sorted(set(rng.integers(0, held + 1, held // 7)))
+            runs = [own[i:j] for i, j in zip([0] + cuts, cuts + [held])]
+            own = [p for i in rng.permutation(len(runs)) for p in runs[i]]
+        tables[b, :held] = own
     q = jnp.asarray(rng.normal(size=(B, Hkv * G, D)), jnp.float32)
     args = (q, k_pages, v_pages, jnp.asarray(tables), jnp.asarray(lengths))
     got = A.paged_attention_decode(*args, layer=1, window=window,
