@@ -65,10 +65,39 @@ def attention_reference(q, k, v, *, causal: bool = False,
 # Whole-kv kernels (short sequences)
 #
 # For self-attention at s <= _WHOLE_KV_MAX_S the entire kv fits VMEM, so
-# the fastest structure on v5e is NO inner loop at all: one [bq, d] x
-# [s, d]T dot, one masked exp, one [bq, s] x [s, dpad] dot — fully
-# static code Mosaic can pipeline. Measured (b16 h12 s1024 d64 bf16):
-# 0.84 ms vs 2.4 ms for the streaming flash loop, same numerics.
+# the fastest structure on v5e is fully static code Mosaic can pipeline:
+# no running maximum, no loop whose bounds the program computes. Without
+# ``causal`` that is one [bq, d] x [s, d]T dot, one exp, one [bq, s] x
+# [s, d] dot a program (``_whole_fwd_kernel`` / ``_whole_bwd_kernel``).
+#
+# Under ``causal`` one program a (batch, head) walks the query blocks in
+# a STATIC Python loop and multiplies block ``i`` against the keys and
+# values ``[0, (i + 1) * bq)`` only (``_causal_fwd_kernel`` /
+# ``_causal_bwd_kernel``): ``n`` blocks visit ``n (n + 1) / 2`` of the
+# ``n^2`` squares of the score matrix, and with no running maximum a row's
+# sum and its weighted values are plain sums over what was visited.
+# Read on a v5e (PR 45, device times of the kernels alone, b16 h12 s1024
+# d64 bf16, forward + backward a layer): the one-block form that masked
+# the finished 1,024 x 1,024 square 0.557 + 1.351 ms (93-97% of what the
+# matrix units give the FULL square at a 64-wide head, which fills half
+# of a 128 x 128 unit); this form at bq 128 / 256 / 512: 0.381 + 1.044 /
+# 0.390 + 0.945 / 0.428 + 1.129 ms; the streaming flash loop 1.5x the
+# one-block form. At 256 the backward stands at 87% of the matrix units'
+# bound for the blocks it visits. What did NOT pay: the compare on the
+# diagonal block alone (two products a query block where one does:
+# backward 1.073 ms), square tiles (1.00+), key blocks against the
+# queries after them (the forward's float32 read-add-write of its sums:
+# 0.558), bq 512 at s 2,048 (out of VMEM inside a 12-layer program).
+# The backward takes ``delta = sum(o * do)`` itself, from blocks it
+# holds: as an XLA reduction its [b h, s, 1] float32 result is laid out
+# 128 lanes a value (100 MB a layer written and read back: 0.267 ms a
+# layer in XLA against +0.010 ms in the kernel). ``lse`` leaves the
+# forward as a ROW [b h, 1, s] for the same reason (a column is 100 MB a
+# layer kept for the backward: 1.19 GB of the step's peak), turned by
+# one [bq, 128] transpose a block; alone the pair costs what it did
+# (0.381 + 0.971 ms), inside the train step the forward no longer waits
+# for its own output (0.432 -> 0.384 ms a layer). Indexing the column
+# out as a 1-D value in place of the transpose cost +0.18 ms a layer.
 #
 # Key trick — no running max: softmax is shift-invariant, so a static
 # shift with an overflow cap replaces the max/subtract/rescale passes
@@ -83,66 +112,45 @@ def attention_reference(q, k, v, *, causal: bool = False,
 _WHOLE_KV_MAX_S = 2048     # s*s*4B score block stays well inside VMEM
 _CAP_HI = 50.0             # logit cap: exp(50-25)=7e10 << f32 max
 _CAP_SHIFT = 25.0
+_CAUSAL_BLOCK_Q = 256      # see the sweep above
+
+_NT = (((1,), (1,)), ((), ()))     # a @ b.T
+_NN = (((1,), (0,)), ((), ()))     # a @ b
+_TN = (((0,), (0,)), ((), ()))     # a.T @ b
 
 
-def _whole_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal,
-                      block_q, head_dim):
-    from jax.experimental import pallas as pl
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
 
-    bq, d = block_q, head_dim
-    sk = k_ref.shape[0]
-    qi = pl.program_id(1)
-    s_ = jax.lax.dot_general(q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    e = jnp.exp(jnp.minimum(s_, _CAP_HI) - _CAP_SHIFT)
-    if causal:
-        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, sk), 0)
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, (bq, sk), 1)
-        e = jnp.where(k_pos <= q_pos, e, 0.0)
+
+def _whole_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref):
+    e = jnp.exp(jnp.minimum(_dot(q_ref[:], k_ref[:], _NT), _CAP_HI)
+                - _CAP_SHIFT)
     # row-sum on the VPU: cheaper than padding v with a ones column in
     # XLA (the concatenate cost ~1-5 ms/layer of HBM traffic per step)
     l = jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
-    acc = jax.lax.dot_general(e.astype(v_ref.dtype), v_ref[:],
-                              (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+    acc = _dot(e.astype(v_ref.dtype), v_ref[:], _NN)
     o_ref[:] = (acc / l).astype(o_ref.dtype)
     lse_ref[:] = jnp.log(l) + _CAP_SHIFT
 
 
 def _whole_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, *, causal, block_q):
+                      dq_ref, dk_ref, dv_ref):
     from jax.experimental import pallas as pl
 
-    bq = block_q
-    sk = k_ref.shape[0]
     qi = pl.program_id(1)
     qq = q_ref[:]
     kk = k_ref[:]
-    vv = v_ref[:]
     dd = do_ref[:]
-    s_ = jax.lax.dot_general(qq, kk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
     # same _CAP_HI clamp as the forward: without it, a logit above the
     # cap makes p here disagree with the clamped forward and the
     # gradient silently explodes instead of saturating
-    p = jnp.exp(jnp.minimum(s_, _CAP_HI) - lse_ref[:])
-    if causal:
-        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, sk), 0)
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, (bq, sk), 1)
-        p = jnp.where(k_pos <= q_pos, p, 0.0)
-    pc = p.astype(vv.dtype)
-    dp = jax.lax.dot_general(dd, vv, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = (p * (dp - delta_ref[:])).astype(qq.dtype)
-    dq_ref[:] = jax.lax.dot_general(
-        ds, kk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    dkc = jax.lax.dot_general(
-        ds, qq, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dk_ref.dtype)
-    dvc = jax.lax.dot_general(
-        pc, dd, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dv_ref.dtype)
+    p = jnp.exp(jnp.minimum(_dot(qq, kk, _NT), _CAP_HI) - lse_ref[:])
+    ds = (p * (_dot(dd, v_ref[:], _NT) - delta_ref[:])).astype(qq.dtype)
+    dq_ref[:] = _dot(ds, kk, _NN).astype(dq_ref.dtype)
+    dkc = _dot(ds, qq, _TN).astype(dk_ref.dtype)
+    dvc = _dot(p.astype(dd.dtype), dd, _TN).astype(dv_ref.dtype)
     # dk/dv accumulate across the q-block grid dimension: their output
     # block index is constant in qi, so Mosaic keeps them VMEM-resident
     @pl.when(qi == 0)
@@ -156,9 +164,69 @@ def _whole_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[:] = dv_ref[:] + dvc
 
 
+def _causal_strip(lo, hi):
+    # the live entries of query rows [lo, hi) against keys [0, hi)
+    q_pos = lo + jax.lax.broadcasted_iota(jnp.int32, (hi - lo, hi), 0)
+    return jax.lax.broadcasted_iota(jnp.int32, (hi - lo, hi), 1) <= q_pos
+
+
+def _column_to_row(col):
+    # [n, 1] -> [1, n] through one [n, 128] transpose
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], 128)))[0:1, :]
+
+
+def _row_to_column(row):
+    # [1, n] -> [n, 1]
+    return jnp.transpose(jnp.broadcast_to(row, (128, row.shape[1])))[:, 0:1]
+
+
+def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q):
+    # refs hold one (batch, head) whole: q/k/v/o [s, d]; lse [1, s], a
+    # row (see the header: a column lies 128 lanes a value in HBM)
+    for lo in range(0, q_ref.shape[0], block_q):
+        hi = lo + block_q
+        s_ = _dot(q_ref[lo:hi, :], k_ref[0:hi, :], _NT)
+        e = jnp.where(_causal_strip(lo, hi),
+                      jnp.exp(jnp.minimum(s_, _CAP_HI) - _CAP_SHIFT), 0.0)
+        l = jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+        acc = _dot(e.astype(v_ref.dtype), v_ref[0:hi, :], _NN)
+        o_ref[lo:hi, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[:, lo:hi] = _column_to_row(jnp.log(l) + _CAP_SHIFT)
+
+
+def _causal_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
+                       dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, block_q):
+    # dq of a query block from the keys at or before it; dk, dv of a key
+    # from the query blocks at or after it, summed in the float32
+    # scratch [s, d] and cast once at the end
+    dt = q_ref.dtype
+    for lo in range(0, q_ref.shape[0], block_q):
+        hi = lo + block_q
+        qq, dd = q_ref[lo:hi, :], do_ref[lo:hi, :]
+        kk, vv = k_ref[0:hi, :], v_ref[0:hi, :]
+        delta = jnp.sum(o_ref[lo:hi, :].astype(jnp.float32)
+                        * dd.astype(jnp.float32), axis=-1, keepdims=True)
+        # same _CAP_HI clamp as the forward (see _whole_bwd_kernel)
+        p = jnp.exp(jnp.minimum(_dot(qq, kk, _NT), _CAP_HI)
+                    - _row_to_column(lse_ref[:, lo:hi]))
+        p = jnp.where(_causal_strip(lo, hi), p, 0.0)
+        ds = (p * (_dot(dd, vv, _NT) - delta)).astype(dt)
+        dq_ref[lo:hi, :] = _dot(ds, kk, _NN).astype(dq_ref.dtype)
+        dk, dv = _dot(ds, qq, _TN), _dot(p.astype(dt), dd, _TN)
+        if lo:
+            dk_acc[0:lo, :] += dk[0:lo]
+            dv_acc[0:lo, :] += dv[0:lo]
+        # the diagonal block is the first to reach these keys
+        dk_acc[lo:hi, :] = dk[lo:hi]
+        dv_acc[lo:hi, :] = dv[lo:hi]
+    dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+    dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+
 def _whole_block_q(s: int) -> int:
-    # score block [bq, s] f32 capped at ~4 MiB so several pipeline
-    # buffers coexist in VMEM
+    # the one-block form (no ``causal``): score block [bq, s] f32 capped
+    # at ~4 MiB so several pipeline buffers coexist in VMEM: s itself up
+    # to 1,024, 512 at 2,048
     bq = max(128, min(s, (4 << 20) // (4 * s) // 128 * 128))
     while s % bq:
         bq //= 2
@@ -189,6 +257,37 @@ def _use_whole_kv(sq: int, sk: int, d: int,
         return False
     return (sq == sk and sk <= _WHOLE_KV_MAX_S and d <= 128
             and sk % 128 == 0 and sq % _whole_block_q(sq) == 0)
+
+
+def flash_plan(sq: int, sk: int, d: int, causal: bool,
+               exact: Optional[bool] = None,
+               block_q: int = DEFAULT_BLOCK_Q,
+               block_k: int = DEFAULT_BLOCK_K) -> dict:
+    """What ``flash_attention``'s kernels do at these shapes: ``path``
+    (``"whole_kv_causal"``: a static loop over query blocks, each against
+    the keys at or before it; ``"whole_kv"``: query blocks against all
+    keys; ``"streaming"``: the running-maximum kernels over key blocks of
+    ``block_k``), ``block_q``, and how many blocks of the score matrix
+    are multiplied, of how many (squares of ``block_q`` on the whole-kv
+    paths, ``block_q x block_k`` on the streaming one). A pure function
+    of the shapes; the whole-kv wrappers take their ``block_q`` from it
+    and nowhere else."""
+    if not _use_whole_kv(sq, sk, d, exact):
+        bq, bk = min(block_q, sq), min(block_k, sk)
+        nq, nk = -(-sq // bq), -(-sk // bk)
+        visited = sum(min(-(-(i + 1) * bq // bk), nk) for i in range(nq)) \
+            if causal else nq * nk
+        return {"path": "streaming", "block_q": bq,
+                "blocks_visited": visited, "blocks_total": nq * nk}
+    if not causal:
+        bq = _whole_block_q(sq)
+        return {"path": "whole_kv", "block_q": bq,
+                "blocks_visited": (sq // bq) ** 2,
+                "blocks_total": (sq // bq) ** 2}
+    bq = min(sq, _CAUSAL_BLOCK_Q if sq % _CAUSAL_BLOCK_Q == 0 else 128)
+    n = sq // bq
+    return {"path": "whole_kv_causal", "block_q": bq,
+            "blocks_visited": n * (n + 1) // 2, "blocks_total": n * n}
 
 
 def _debug_check_logits(q_scaled, k):
@@ -222,84 +321,96 @@ def _debug_check_logits(q_scaled, k):
         _raise(s_max)
 
 
+def _head_spec(rows, width, blocked=False):
+    """A (batch, head)'s [rows, width]: all of it or, ``blocked``, the
+    block of rows that the grid's second axis names."""
+    from jax.experimental import pallas as pl
+
+    if blocked:
+        return pl.BlockSpec((None, rows, width), lambda i, j: (i, j, 0))
+    return pl.BlockSpec((None, rows, width), lambda i, *_: (i, 0, 0))
+
+
+# (functions of their own under ``jit``, as ``_paged_decode_call``: a step
+# program lowers the unrolled kernels once a shape, not once a layer.
+# Read in the train cell, PR 45: the first step 15.8-16.2 s without,
+# 11.5-11.8 s with, the one-block form's 13.0)
+@functools.partial(jax.jit, static_argnums=(3, 4))
 def _whole_forward(q, k, v, causal, interpret=False):
     from jax.experimental import pallas as pl
 
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    bq = _whole_block_q(sq)
-    qf = q.reshape(b * h, sq, d)
-    kf = k.reshape(b * h, sk, d)
-    vf = v.reshape(b * h, sk, d)
-    kernel = functools.partial(_whole_fwd_kernel, causal=causal,
-                               block_q=bq, head_dim=d)
+    bq = flash_plan(sq, sk, d, causal, exact=False)["block_q"]
+    if causal:
+        # a program holds its head whole and walks the query blocks
+        kernel = functools.partial(_causal_fwd_kernel, block_q=bq)
+        grid, lse_shape = (b * h,), (1, sq)
+        q_spec, lse_spec = _head_spec(sq, d), _head_spec(1, sq)
+    else:
+        kernel, grid, lse_shape = _whole_fwd_kernel, (b * h, sq // bq), (sq, 1)
+        q_spec, lse_spec = _head_spec(bq, d, True), _head_spec(bq, 1, True)
     call = pl.pallas_call(
         kernel,
-        grid=(b * h, sq // bq),
-        in_specs=[
-            pl.BlockSpec((None, bq, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, bq, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, bq, 1), lambda i, j: (i, j, 0)),
-        ],
+        grid=grid,
+        in_specs=[q_spec, _head_spec(sk, d), _head_spec(sk, d)],
+        out_specs=[q_spec, lse_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, *lse_shape), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
     )
     with jax.named_scope("flash_fwd"):
-        out, lse = call(qf, kf, vf)
+        out, lse = call(q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
+                        v.reshape(b * h, sk, d))
     return out.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 
 
+@functools.partial(jax.jit, static_argnames=("causal", "interpret"))
 def _whole_backward(res, g, *, causal, interpret=False):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     q, k, v, out, lse = res
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    bq = _whole_block_q(sq)
-    delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32),
-                    axis=-1)  # [b,h,sq]
-    qf = q.reshape(b * h, sq, d)
-    kf = k.reshape(b * h, sk, d)
-    vf = v.reshape(b * h, sk, d)
-    dof = g.reshape(b * h, sq, d)
-    lsef = lse.reshape(b * h, sq, 1)
-    deltaf = delta.reshape(b * h, sq, 1)
-    kernel = functools.partial(_whole_bwd_kernel, causal=causal,
-                               block_q=bq)
+    bq = flash_plan(sq, sk, d, causal, exact=False)["block_q"]
+    if causal:
+        # the kernel takes delta = sum(o * do) itself, from o
+        kernel = functools.partial(_causal_bwd_kernel, block_q=bq)
+        grid = (b * h,)
+        lse, last = lse.reshape(b * h, 1, sq), out.reshape(b * h, sq, d)
+        q_spec = last_spec = _head_spec(sq, d)
+        lse_spec = _head_spec(1, sq)
+        scratch = [pltpu.VMEM((sk, d), jnp.float32)] * 2     # dk, dv
+    else:
+        kernel, grid, scratch = _whole_bwd_kernel, (b * h, sq // bq), []
+        lse = lse.reshape(b * h, sq, 1)
+        last = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32),
+                       axis=-1).reshape(b * h, sq, 1)        # delta
+        q_spec = _head_spec(bq, d, True)
+        lse_spec = last_spec = _head_spec(bq, 1, True)
     call = pl.pallas_call(
         kernel,
-        grid=(b * h, sq // bq),
-        in_specs=[
-            pl.BlockSpec((None, bq, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, bq, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, bq, 1), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, bq, 1), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, bq, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-        ],
+        grid=grid,
+        in_specs=[q_spec, _head_spec(sk, d), _head_spec(sk, d), q_spec,
+                  lse_spec, last_spec],
+        out_specs=[q_spec, _head_spec(sk, d), _head_spec(sk, d)],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
         ],
+        scratch_shapes=scratch,
         interpret=interpret,
         name="flash_bwd",
     )
     with jax.named_scope("flash_bwd"):
-        dq, dk, dv = call(qf, kf, vf, dof, lsef, deltaf)
+        dq, dk, dv = call(q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
+                          v.reshape(b * h, sk, d), g.reshape(b * h, sq, d),
+                          lse, last)
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
 
@@ -711,6 +822,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
             block_q, block_k = bq, bk
     if not use and not interpret:
         return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale)
+    # what the program being traced holds (static: once a trace, never a
+    # step), under the span open on this thread or in the module's ring
+    from ray_tpu._private import tracing
+    tracing.step_event("attention.flash_plan", 0.0, **flash_plan(
+        sq, sk, q.shape[3], causal, exact, block_q, block_k))
 
     def local(q, k, v):
         # Fold the softmax scale into q OUTSIDE the kernel (one [b,h,s,d]

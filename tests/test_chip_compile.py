@@ -78,6 +78,35 @@ def test_flash_attention_gpt2_small_shape(one_chip, exact, grad):
     _compile(_flash(exact, grad), *_qkv((16, 12, 1024, 64), one_chip))
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention_largest_whole_kv_shape(one_chip, grad):
+    """[2, 8, 2048, 128] bf16 causal, the longest and widest the
+    whole-kv path admits: eight query blocks unrolled in one program a
+    head, inside the chip's VMEM."""
+    assert A._use_whole_kv(2048, 2048, 128, False)
+    assert not A._use_whole_kv(2048 + 128, 2048 + 128, 128, False)
+    assert not A._use_whole_kv(2048, 2048, 256, False)
+    assert A.flash_plan(2048, 2048, 128, True, False) == {
+        "path": "whole_kv_causal", "block_q": 256,
+        "blocks_visited": 36, "blocks_total": 64}
+    _compile(_flash(False, grad), *_qkv((2, 8, 2048, 128), one_chip))
+
+
+def test_flash_attention_backward_keeps_no_score_square(one_chip):
+    """The GPT-2 small shape's forward + backward keeps no more outside
+    its kernels than the one-block form did (432.4 MiB of temporaries at
+    PR 44; 384.2 now that the kernel takes ``delta``, 128 lanes a value
+    in HBM, itself): no [1024, 1024] float32 square of scores, 768 MiB
+    over the heads, reaches HBM."""
+    with jax.default_matmul_precision("default"):
+        step = jax.jit(_flash(False, True)).lower(
+            *_qkv((16, 12, 1024, 64), one_chip)).compile()
+    text = step.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "1024,1024]" not in text
+    assert step.memory_analysis().temp_size_in_bytes <= 453_371_904
+
+
 def test_flash_attention_streaming_long_wide(one_chip):
     """[1, 32, 4096, 128]: past the whole-kv limit, head dim 128."""
     assert not A._use_whole_kv(4096, 4096, 128, None)

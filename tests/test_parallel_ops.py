@@ -286,6 +286,175 @@ def test_flash_attention_debug_asserts_on_capped_logits():
     assert np.isfinite(np.asarray(out)).all()
 
 
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+def _whole_kv_and_reference(q, k, v, g, causal, sm_scale=None):
+    """The whole-kv pair interpreted, and ``attention_reference`` in
+    float32 on the same (upcast) inputs: (out, dq, dk, dv) of each."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops import attention as A
+
+    def flash(q, k, v):
+        return A.flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+                                 force_pallas=True, interpret=True,
+                                 exact=False)
+
+    def ref(q, k, v):
+        return A.attention_reference(q, k, v, causal=causal,
+                                     sm_scale=sm_scale)
+    out, vjp = jax.vjp(flash, q, k, v)
+    up = [t.astype(jnp.float32) for t in (q, k, v)]
+    out_ref, vjp_ref = jax.vjp(ref, *up)
+    return ((out,) + vjp(g.astype(out.dtype)),
+            (out_ref,) + vjp_ref(g.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [128, 256, 512, 1024, 2048])
+def test_whole_kv_pair_matches_reference(s, d, causal, dtype):
+    """Forward and all three gradients of the whole-kv kernels against
+    the float32 reference, at every block count the plan gives (1 to 8
+    query blocks under ``causal``; the one-block form without)."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops import attention as A
+
+    assert A._use_whole_kv(s, s, d, False)
+    dtype = jnp.dtype(dtype)
+    keys = jax.random.split(jax.random.PRNGKey(s + d), 4)
+    q, k, v, g = (jax.random.normal(r, (1, 2, s, d), jnp.float32
+                                    ).astype(dtype) for r in keys)
+    got, want = _whole_kv_and_reference(q, k, v, g, causal)
+    limit = 2e-5 if dtype == jnp.float32 else 2e-2
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == dtype and a.shape == b.shape
+        assert _rel(a, b) < limit, (name, _rel(a, b))
+
+
+def test_whole_kv_causal_blocks_near_the_cap():
+    """Logits near ``_CAP_HI`` inside every diagonal block (above the
+    diagonal too) and far under it in every other block: a block skipped
+    wrongly, or a compare laid on the wrong block, moves the result by
+    its whole size."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops import attention as A
+
+    s, d = 1024, 64
+    plan = A.flash_plan(s, s, d, True, False)
+    bq, n = plan["block_q"], s // plan["block_q"]
+    assert n == 4
+    block = jnp.arange(s) // bq
+    own = jax.nn.one_hot(block, d, dtype=jnp.float32)         # [s, d]
+    others = (jnp.arange(d)[None, :] < n) - own
+    keys = jax.random.split(jax.random.PRNGKey(45), 4)
+    noise = [0.05 * jax.random.normal(r, (1, 1, s, d)) for r in keys[:2]]
+    q = (46.0 ** 0.5 * own - 20.0 / 46.0 ** 0.5 * others)[None, None] \
+        + noise[0]
+    k = (46.0 ** 0.5 * own)[None, None] + noise[1]
+    v, g = (jax.random.normal(r, (1, 1, s, d)) for r in keys[2:])
+    logits = np.asarray(jnp.einsum("bhqd,bhkd->bhqk", q, k))[0, 0]
+    same = np.asarray(block[:, None] == block[None, :])
+    assert 40.0 < logits[same].min() and logits.max() < A._CAP_HI
+    assert logits[~same].max() < -15.0
+    got, want = _whole_kv_and_reference(q, k, v, g, True, sm_scale=1.0)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert _rel(a, b) < 1e-4, (name, _rel(a, b))
+
+
+def test_whole_kv_causal_dk_dv_are_summed_in_float32():
+    """At s = 2,048 (8 query blocks) ``dk`` and ``dv`` in bfloat16 lie
+    closer to the float32 reference than the same blocks' exact
+    contributions summed in a bfloat16 running sum would."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops import attention as A
+
+    s, d = 2048, 64
+    bq = A.flash_plan(s, s, d, True, False)["block_q"]
+    keys = jax.random.split(jax.random.PRNGKey(2048), 4)
+    q, k, v, g = (jax.random.normal(r, (1, 1, s, d), jnp.float32
+                                    ).astype(jnp.bfloat16) for r in keys)
+    got, want = _whole_kv_and_reference(q, k, v, g, True)
+    up = [t.astype(jnp.float32) for t in (q, k, v)]
+    _, vjp = jax.vjp(lambda q, k, v: A.attention_reference(
+        q, k, v, causal=True), *up)
+    running = [jnp.zeros((1, 1, s, d), jnp.bfloat16)] * 2
+    for lo in range(0, s, bq):
+        rows = (jnp.arange(s) >= lo) & (jnp.arange(s) < lo + bq)
+        part = vjp(jnp.where(rows[None, None, :, None],
+                             g.astype(jnp.float32), 0.0))[1:]
+        running = [(r.astype(jnp.float32) + c).astype(jnp.bfloat16)
+                   for r, c in zip(running, part)]
+    for name, a, b, r in zip(("dk", "dv"), got[2:], want[2:], running):
+        assert _rel(a, b) < 0.8 * _rel(r, b), (name, _rel(a, b), _rel(r, b))
+
+
+@pytest.mark.parametrize("s", [128, 256, 384, 512, 1024, 2048])
+def test_flash_plan_counts_the_blocks_it_visits(s):
+    from ray_tpu.ops import attention as A
+
+    plan = A.flash_plan(s, s, 64, True, False)
+    n = s // plan["block_q"]
+    assert plan["path"] == "whole_kv_causal" and s % plan["block_q"] == 0
+    assert plan["blocks_visited"] == n * (n + 1) // 2
+    assert plan["blocks_total"] == n * n
+    full = A.flash_plan(s, s, 64, False, False)
+    assert full["path"] == "whole_kv"
+    assert full["block_q"] == A._whole_block_q(s)
+    assert full["blocks_visited"] == full["blocks_total"] > 0
+    exact = A.flash_plan(s, s, 64, True, True, block_q=128, block_k=128)
+    m = s // 128
+    assert exact == {"path": "streaming", "block_q": 128,
+                     "blocks_visited": m * (m + 1) // 2,
+                     "blocks_total": m * m}
+
+
+def test_flash_plan_is_where_the_wrappers_take_their_block(monkeypatch):
+    """The kernel's block and the plan's are one number: a plan that
+    says 128 at s = 512 makes both kernels walk four query blocks (two
+    products a block forward, five backward), and the results stand."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops import attention as A
+
+    s, d = 512, 64
+    assert A.flash_plan(s, s, d, True, False)["block_q"] == 256
+    real, asked = A.flash_plan, []
+
+    def plan128(*args, **kwargs):
+        plan = dict(real(*args, **kwargs), block_q=128)
+        asked.append(plan)
+        return plan
+    monkeypatch.setattr(A, "flash_plan", plan128)
+    # (the wrappers are jitted: a trace under one plan is not kept for
+    # the other, before or after)
+    def forget():
+        A._whole_forward.clear_cache()
+        A._whole_backward.clear_cache()
+    forget()
+    keys = jax.random.split(jax.random.PRNGKey(9), 4)
+    q, k, v, g = (jax.random.normal(r, (1, 1, s, d)) for r in keys)
+
+    def flash(q, k, v):
+        return A.flash_attention(q, k, v, causal=True, force_pallas=True,
+                                 interpret=True, exact=False)
+    text = str(jax.make_jaxpr(
+        lambda q, k, v, g: jax.vjp(flash, q, k, v)[1](g))(q, k, v, g))
+    assert text.count("dot_general") == (2 + 5) * (s // 128)
+    assert len(asked) == 3              # the event, forward, backward
+    got, want = _whole_kv_and_reference(q, k, v, g, True)
+    forget()
+    for a, b in zip(got, want):
+        assert _rel(a, b) < 2e-5
+
+
 def test_ring_attention_matches_full(cpu_mesh8):
     import jax
     import jax.numpy as jnp

@@ -602,19 +602,25 @@ def test_the_feed_records_its_batches():
 
 # ------------------------------------- names on the device trace itself
 
-def pallas_call_names(jaxpr):
+def pallas_call_names(jaxpr, outer=""):
     """``name`` of every pallas_call in a jaxpr with the scopes it was
-    traced under, through every nested jaxpr."""
+    traced under, through every nested jaxpr (the scopes inside a
+    ``jit`` of its own start anew: they follow the call's, as they do
+    once the program is lowered)."""
     out = []
     for eqn in jaxpr.eqns:
+        stack = "/".join(s for s in (outer, str(eqn.source_info.name_stack))
+                         if s)
         if eqn.primitive.name == "pallas_call":
-            out.append((eqn.params["name"], str(eqn.source_info.name_stack)))
+            out.append((eqn.params["name"], stack))
         for value in eqn.params.values():
             for v in (value if isinstance(value, (list, tuple)) else [value]):
                 inner = getattr(v, "jaxpr", v)
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    out.extend(pallas_call_names(inner))
+                    out.extend(pallas_call_names(
+                        inner, stack if eqn.primitive.name in ("jit", "pjit")
+                        else outer))
     return out
 
 
@@ -638,12 +644,15 @@ def tiny_train_step():
     # (a second trainer: the first's step is traced already)
     use = attention._use_pallas
     attention._use_pallas = lambda: True
+    ring = deque()
     try:
-        jaxpr = jax.make_jaxpr(make_causal_lm_trainer(
-            cfg, mesh=mesh, spec=spec).step)(state, batch)
+        with tracing.step_span("test.trace_step", ring):
+            jaxpr = jax.make_jaxpr(make_causal_lm_trainer(
+                cfg, mesh=mesh, spec=spec).step)(state, batch)
     finally:
         attention._use_pallas = use
-    return {"lowered": lowered, "kernels": pallas_call_names(jaxpr.jaxpr)}
+    return {"lowered": lowered, "kernels": pallas_call_names(jaxpr.jaxpr),
+            "cfg": cfg, "events": ring[0]["children"]}
 
 
 @pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd"])
@@ -652,6 +661,20 @@ def test_train_step_jaxpr_names_its_kernels(tiny_train_step, kernel):
              if name == kernel]
     assert len(found) == 2                              # one a layer
     assert all(f"/{kernel}" in stack and "attn" in stack for stack in found)
+
+
+def test_tracing_a_train_step_leaves_its_flash_plans(tiny_train_step):
+    """One ``attention.flash_plan`` event a ``flash_attention`` call (one
+    a layer), with the numbers of the plan the kernels were laid out by."""
+    from ray_tpu.ops.attention import flash_plan
+    cfg = tiny_train_step["cfg"]
+    plans = [e["attrs"] for e in tiny_train_step["events"]
+             if e["name"] == "attention.flash_plan"]
+    assert len(plans) == cfg.n_layer == 2
+    want = flash_plan(128, 128, cfg.n_embd // cfg.n_head, True)
+    assert want["path"] == "whole_kv_causal"
+    assert want["blocks_visited"] == want["blocks_total"] == 1
+    assert plans == [want, want]
 
 
 @pytest.mark.parametrize("scope,path", [
