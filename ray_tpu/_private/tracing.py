@@ -35,8 +35,8 @@ Sampling (bounds overhead end to end):
     ``RTPU_TRACE_SLOW_S`` (default 1.0 s) are always recorded, even
     when head-sampled out — the slow/broken tail is exactly what the
     critical-path analyzer exists for;
-  - ``RTPU_TRACING=0`` disables recording entirely (the overhead gate
-    in ``_BENCH_TRACE`` compares default sampling against this).
+  - ``RTPU_TRACING=0`` disables recording entirely: no span is made
+    and no trace context is propagated.
 
 The critical-path analyzer (``critical_path``) attributes a root span's
 wall time to named phases with a deepest-active-span sweep: at every

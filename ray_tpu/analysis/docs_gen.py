@@ -54,8 +54,7 @@ def render_configuration(repo_root: str) -> str:
                                                   system_config_vars)
     reads = scan_env_reads(
         [os.path.join(repo_root, "ray_tpu"),
-         os.path.join(repo_root, "tests"),
-         os.path.join(repo_root, "bench.py")], repo_root)
+         os.path.join(repo_root, "tests")], repo_root)
     dynamic = set(system_config_vars()) - set(
         k for k in CONFIG_VARS if CONFIG_VARS[k]["subsystem"]
         != "system-config")

@@ -14,8 +14,8 @@ copies of the entire replicated tree.
 
 Env knobs:
   RTPU_CKPT_ASYNC=0   write inline on the calling thread (the sync
-                      baseline; also what the _BENCH_CKPT=1 bench compares
-                      against)
+                      baseline tests/test_checkpoint_engine.py holds an
+                      asynchronous save's blocked time against)
 """
 
 from __future__ import annotations

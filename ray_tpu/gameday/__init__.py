@@ -32,9 +32,8 @@ Layers (docs/GAMEDAY.md):
                   game-day panel and the ``ray_tpu_slo_*`` gauges read
                   it).
 
-Entry points: ``ray-tpu gameday run <scenario>``,
-``_BENCH_GAMEDAY=1 python bench.py``, and the tier-1 flagship gate in
-``tests/test_gameday.py``.
+Entry points: ``ray-tpu gameday run <scenario>`` and the tier-1
+flagship gate in ``tests/test_gameday.py``.
 """
 
 from ray_tpu.gameday.loadgen import (Arrival, ArrivalSchedule,  # noqa: F401

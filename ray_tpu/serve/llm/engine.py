@@ -20,7 +20,7 @@ finished slots sit idle. This engine schedules at token granularity:
   generation budget (no mid-decode OOM, ``kv_cache.py``);
 * ``policy="static"`` keeps the same code path but only admits when
   the running set is empty — the flush-by-window baseline the
-  ``_BENCH_LLM`` gate compares against.
+  scheduler-equivalence tests compare against.
 
 Three fleet-efficiency features compose as engine flags
 (docs/LLM_SERVING.md):

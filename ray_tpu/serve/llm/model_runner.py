@@ -28,7 +28,7 @@ Two implementations:
   THROUGH THE BLOCK TABLES (a paging bug corrupts its output, which is
   exactly what the continuous-vs-static equivalence gate wants).
   Configurable per-step latency makes it the load-bearing workload for
-  the game day and ``_BENCH_LLM`` without flax in the loop.
+  the game days, without flax in the loop.
 
 * ``FlaxModelAdapter`` — wraps ``models/gpt2.py`` / ``models/llama.py``
   incremental-decode paths in their ``stacked`` form (the blocks'

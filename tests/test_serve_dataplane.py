@@ -5,8 +5,6 @@ queue → retriable shed → HTTP 503). Tier-1, CPU-only."""
 import json
 import logging
 import os
-import subprocess
-import sys
 import threading
 import time
 import urllib.error
@@ -18,8 +16,6 @@ import ray_tpu
 from ray_tpu import serve
 from ray_tpu.serve.exceptions import (BatchSubmitTimeoutError,
                                       ReplicaOverloadedError)
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # --------------------------------------------------- replica backpressure
@@ -413,28 +409,3 @@ def test_router_receives_load_reports_via_long_poll(serve_cluster):
     assert reports, "controller never published replica_load"
     sample = next(iter(reports.values()))
     assert "queue_len" in sample and "ts" in sample
-
-
-def test_bench_serve_smoke():
-    env = dict(os.environ, _BENCH_SERVE="1", JAX_PLATFORMS="cpu",
-               BENCH_SERVE_DURATION="0.3", BENCH_SERVE_CLIENTS="3",
-               BENCH_SERVE_SERVICE_MS="2", BENCH_SERVE_SKEW="5")
-    env.pop("LIBTPU_INIT_ARGS", None)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "bench.py")],
-        stdout=subprocess.PIPE, text=True, timeout=240, env=env,
-        cwd=REPO_ROOT)
-    row = None
-    for line in reversed(proc.stdout.strip().splitlines()):
-        line = line.strip()
-        if line.startswith("{"):
-            row = json.loads(line)
-            break
-    assert row is not None, proc.stdout
-    assert row.get("metric") == "serve_dataplane", row
-    for key in ("route_round_robin_rps", "route_p2c_rps",
-                "route_p2c_p50_ms", "route_p2c_p99_ms", "http_rps",
-                "http_p50_ms", "http_p99_ms", "batch_fixed_idle_p50_ms",
-                "batch_adaptive_idle_p50_ms", "batch_fixed_rps",
-                "batch_adaptive_rps"):
-        assert key in row, (key, row)

@@ -5,16 +5,13 @@ The scenarios mirror docs/SERVE_HA.md's failure matrix: a controller
 killed mid-load (journal recovery + replica re-adoption, traffic from
 cached route tables), health-gated start-before-stop rolling updates
 with zero failed requests, graceful drain on downscale/delete, and the
-chaos-seeded kills (`serve.controller.tick` / `serve.replica.request`)
-that the `_BENCH_SERVE_HA` bench measures.
+chaos-seeded kills (`serve.controller.tick` / `serve.replica.request`).
 """
 
 import json
 import os
 import pickle
 import signal
-import subprocess
-import sys
 import threading
 import time
 
@@ -23,8 +20,6 @@ import pytest
 import ray_tpu
 from ray_tpu import serve
 from ray_tpu._private import chaos
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -625,31 +620,3 @@ def test_node_preemption_replaces_replicas_before_drain(tmp_path):
         except Exception:
             pass
         cluster.shutdown()
-
-
-# ---------------------------------------------------------- bench smoke
-
-
-def test_bench_serve_ha_smoke():
-    env = dict(os.environ, _BENCH_SERVE_HA="1", JAX_PLATFORMS="cpu",
-               BENCH_SERVE_HA_DURATION="4", BENCH_SERVE_HA_CLIENTS="3")
-    env.pop("LIBTPU_INIT_ARGS", None)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "bench.py")],
-        stdout=subprocess.PIPE, text=True, timeout=300, env=env,
-        cwd=REPO_ROOT)
-    row = None
-    for line in reversed(proc.stdout.strip().splitlines()):
-        line = line.strip()
-        if line.startswith("{"):
-            row = json.loads(line)
-            break
-    assert row is not None, proc.stdout
-    assert row.get("metric") == "serve_ha", row
-    for key in ("rolling_total", "rolling_failed", "rolling_p99_ms",
-                "ctrl_kill_total", "ctrl_kill_failed", "ctrl_kill_p99_ms",
-                "ctrl_recovery_s"):
-        assert key in row, (key, row)
-    # the acceptance bar: zero dropped requests in both scenarios
-    assert row["rolling_failed"] == 0, row
-    assert row["ctrl_kill_failed"] == 0, row
