@@ -55,13 +55,14 @@ _HI = jax.lax.Precision.HIGHEST
 _KDA_TILE_BYTES = 1 << 20
 
 
-def short_conv(x, tail, weight, n_new=None):
+def short_conv(x, tail, weight, n_new=None, bias=None):
     """Causal depthwise convolution over time with a carried tail.
 
     x [B, S, C]: the new rows; tail [B, K-1, C]: the K-1 rows before
     them (zeros at a sequence's start); weight [K, C], ``weight[K-1]``
     multiplying the current row. ``n_new`` ([B] int, optional) is how
     many of the S rows are real (right-padded buckets; default all).
+    ``bias`` ([C], optional) is added to every output row.
     Returns (y [B, S, C], new_tail [B, K-1, C]): the tail that the next
     call of these sequences takes, i.e. the last K-1 real rows.
     """
@@ -69,6 +70,8 @@ def short_conv(x, tail, weight, n_new=None):
     K = weight.shape[0]
     xx = jnp.concatenate([tail.astype(x.dtype), x], axis=1)  # [B,S+K-1,C]
     y = sum(xx[:, j:j + S] * weight[j].astype(x.dtype) for j in range(K))
+    if bias is not None:
+        y = y + bias.astype(x.dtype)
     if n_new is None:
         return y, xx[:, S:]
     idx = n_new[:, None] + jnp.arange(K - 1)[None, :]          # [B, K-1]

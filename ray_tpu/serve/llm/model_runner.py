@@ -48,9 +48,16 @@ Two implementations:
   batched speculative verification is numerically the plain decode loop.
 
 A model may state what it caches instead (``kind="kimi_linear"``,
-``kind="kimi_k2"``: the models' ``cache_spec``): pages for some or all
-layers, of the row width it names, and per-sequence *state* for others
-(Kimi-K2 names one latent pool and no state). The
+``kind="kimi_k2"``, ``kind="jamba"``: the models' ``cache_spec``): pages
+for some or all layers, of the row width it names, and per-sequence
+*state* for others (Kimi-K2 names one latent pool and no state; Jamba K
+and V pages for one layer in fourteen and a Mamba state for the rest). A
+state array that a decode step's recurrence runs over names its kind
+(``"recurrence": "kda"`` | ``"mamba"``), and ``_recurrences`` holds each
+kind's chooser: ``bind_state`` asks it with the pool, the dispatch span
+says what it chose, and ``counters()`` counts the steps that took a kernel
+(``recurrence_kernel_steps_total``); a further kind is a further entry
+of that table and no further case anywhere. The
 adapter then owns one pool a named page kind and one array a named state
 kind with a **state slot** per running sequence (slot 0 is the null
 slot, where padding rows read and write): a slot is taken and zeroed
@@ -128,6 +135,15 @@ class RecurrentStateError(NotImplementedError):
     a model that keeps recurrent state per sequence. Pages can be cut at
     any token; a state can only be restored from a snapshot taken at
     that token, and nothing takes such snapshots yet (ROADMAP R1)."""
+
+
+def _recurrences() -> Dict[str, Any]:
+    """A state kind's ``recurrence`` -> the chooser of what a decode step
+    runs over its pool (``path(pool, S)``; a choice that ends in
+    ``_kernel`` updates the pool where it lies)."""
+    from ray_tpu.ops.linear_attention import kda_decode_path
+    from ray_tpu.ops.ssm import mamba_decode_path
+    return {"kda": kda_decode_path, "mamba": mamba_decode_path}
 
 
 class WindowedPagesError(NotImplementedError):
@@ -433,6 +449,13 @@ class FlaxModelAdapter:
             self._blocks = None            # two cached sublayers a layer
             self.vocab_size = self.cfg.vocab_size
             self._spec = longcat_flash.cache_spec(self.cfg)
+        elif kind == "jamba":
+            from ray_tpu.models import jamba
+            self.cfg = config or jamba.JambaConfig.tiny()
+            self.model = jamba.JambaModel(self.cfg)
+            self._blocks = None            # the model stacks its own runs
+            self.vocab_size = self.cfg.vocab_size
+            self._spec = jamba.cache_spec(self.cfg)
         else:
             raise ValueError(f"unknown model kind {kind!r}")
         if params is None:
@@ -446,7 +469,10 @@ class FlaxModelAdapter:
         self._kv_pages_live = self._kv_pages_padded = 0
         self._kv_run_pages = self._kv_table_pages = 0
         self._decode_recurrence: Optional[str] = None   # ``bind_state``
-        self._kda_kernel_steps = 0
+        self._recurrence_kind: Optional[str] = None     # "kda" | "mamba"
+        self._recurrence_kernel_steps = 0
+        self._state_admits = 0
+        self._state_admit_seconds = 0.0
         self._window_pages_live = self._window_pages_padded = 0
         self._window_pages_held = self._window_pages_whole = 0
         self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
@@ -574,12 +600,12 @@ class FlaxModelAdapter:
             layers, *rest = p["shape"]
             self._arrays[name] = jnp.zeros(
                 (layers, self.state_slots + 1, *rest), p["dtype"])
-            if p.get("recurrence") == "kda":
+            if p.get("recurrence"):
                 # what a decode step's recurrence runs (its dispatch
                 # span says it): the model's own chooser, asked with
                 # the same pool, as ``_decode_attention`` is
-                from ray_tpu.ops.linear_attention import kda_decode_path
-                self._decode_recurrence = kda_decode_path(
+                self._recurrence_kind = p["recurrence"]
+                self._decode_recurrence = _recurrences()[p["recurrence"]](
                     self._arrays[name], 1)
         self._free_slots = list(range(self.state_slots, 0, -1))
 
@@ -608,8 +634,18 @@ class FlaxModelAdapter:
         out.update(state_slots_total=self.state_slots,
                    state_slots_in_use=self.state_slots
                    - len(self._free_slots))
+        if self.has_state:
+            # the sequences given a zeroed slot, and the host seconds
+            # inside ``runner.state.admit``
+            out.update(state_admits_total=self._state_admits,
+                       state_admit_seconds_total=self._state_admit_seconds)
         if self._decode_recurrence is not None:
-            out["kda_kernel_steps_total"] = self._kda_kernel_steps
+            out["recurrence_kernel_steps_total"] = \
+                self._recurrence_kernel_steps
+            if self._recurrence_kind == "kda":
+                # (the name from before a second recurrence: a benchmark
+                # file reads it)
+                out["kda_kernel_steps_total"] = self._recurrence_kernel_steps
         if self._expert_tokens_total is not None:
             out["expert_tokens_total"] = self._expert_tokens_total.tolist()
             out["expert_tokens_last_step"] = self._expert_tokens_last.tolist()
@@ -628,6 +664,7 @@ class FlaxModelAdapter:
                 f"{len(self._free_slots)} state slots free: the engine's "
                 "max_running exceeds what bind_state was given")
         slots = [self._free_slots.pop() for _ in seq_ids]
+        t0 = time.perf_counter()
         with tracing.step_span("runner.state.admit", n=len(slots)):
             idx = np.zeros((_pad_pow2(len(slots)),), np.int32)
             idx[:len(slots)] = slots
@@ -636,6 +673,8 @@ class FlaxModelAdapter:
                 cleared = self._zero_fn()(
                     jnp.asarray(idx), *(self._arrays[n] for n in names))
                 self._arrays.update(zip(names, cleared))
+        self._state_admits += len(slots)
+        self._state_admit_seconds += time.perf_counter() - t0
         return slots
 
     def _zero_fn(self):
@@ -936,7 +975,8 @@ class FlaxModelAdapter:
                "kv_run_pages": run, "kv_table_pages": held}
         if self._decode_recurrence is not None:
             out["recurrence"] = self._decode_recurrence
-            self._kda_kernel_steps += self._decode_recurrence == "kda_kernel"
+            self._recurrence_kernel_steps += \
+                self._decode_recurrence.endswith("_kernel")
         for w, ring in self._rings.items():
             # a window layer reads a row's last ``w`` positions: the ring
             # pages that hold one of them, of the ``ring`` a row holds
@@ -1161,15 +1201,15 @@ def make_adapter(model: str = "toy",
                  model_config: Optional[Dict[str, Any]] = None):
     """Deployment-facing factory: ``model`` is ``toy`` |
     ``gpt2`` | ``llama`` | ``kimi_linear`` | ``kimi_k2`` | ``laguna`` |
-    ``longcat_flash`` | ``smallthinker``
+    ``longcat_flash`` | ``smallthinker`` | ``jamba``
     (tiny test configs unless ``model_config`` overrides)."""
     model_config = dict(model_config or {})
     if model == "toy":
         return ToyAdapter(**model_config)
     if model in ("gpt2", "llama", "kimi_linear", "kimi_k2", "laguna",
-                 "longcat_flash", "smallthinker"):
+                 "longcat_flash", "smallthinker", "jamba"):
         return FlaxModelAdapter(kind=model, **model_config)
     raise ValueError(
         f"unknown model {model!r} "
         "(toy | gpt2 | llama | kimi_linear | kimi_k2 | laguna | "
-        "longcat_flash | smallthinker)")
+        "longcat_flash | smallthinker | jamba)")

@@ -991,10 +991,161 @@ def test_paged_attention_decode_holds_no_more_copy_sites_than_it_did(
     assert text.count("dma_wait") == 2
 
 
+def _mamba_step(sharding, R=256, slots=257, layers=26, N=16, d_in=5120):
+    """The Mamba-1 decode kernel at the Jamba cell's shape: 256 rows over
+    the state pool [26, 257, 16, 5120] float32 where it lies, the layer a
+    traced scalar, each row's slot an array."""
+    from ray_tpu.ops import ssm
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    return (lambda u, dt, B, C, A_, D, pool, layer, at:
+            ssm.mamba_step_in_place(u, dt, B, C, A_, D, pool, layer, at)), (
+        sds((R, d_in)), sds((R, d_in)), sds((R, N)), sds((R, N)),
+        sds((N, d_in)), sds((d_in,)), sds((layers, slots, N, d_in)),
+        sds((), jnp.int32), sds((R,), jnp.int32))
+
+
+@pytest.mark.parametrize("R", [256, 8, 1])
+def test_mamba_recurrence_decode(one_chip, R):
+    """One Mosaic call; the pool is its own output (donated: no bytes
+    beside the arguments), and nothing of the rows' state is copied,
+    sliced or written back outside it. A full bucket, a bucket of one
+    block of rows and a single row."""
+    import math
+    fn, args = _mamba_step(one_chip, R=R)
+    with jax.default_matmul_precision("default"):
+        big = jax.jit(fn, donate_argnums=(6,)).lower(*args).compile()
+    text = big.as_text()
+    assert text.count("tpu_custom_call") == 1 and "mamba_recurrence" in text
+    memory = big.memory_analysis()
+    assert memory.alias_size_in_bytes == math.prod(args[6].shape) * 4
+    assert memory.temp_size_in_bytes < (1 << 20) + 3 * R * 5120 * 4
+    assert not _moved(text, R * 16 * 5120)
+
+
+def test_bare_mamba_kernel_is_refused_on_a_mesh(topo, monkeypatch):
+    """As the other kernels': a Mosaic call cannot be partitioned, so
+    ``mamba_decode_path`` says ``xla`` where a mesh of several devices is
+    being traced for, and the bare call is refused there."""
+    from ray_tpu.ops import ssm
+    mesh, _ = _mesh4(topo)
+    fn, args = _mamba_step(NamedSharding(mesh, P()), R=8, slots=9, layers=1)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _compile(fn, *args)
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    assert ssm.mamba_decode_path(args[6], 1) == "mamba_kernel"
+    with A.attention_mesh(mesh):
+        assert ssm.mamba_decode_path(args[6], 1) == "xla"
+
+
+def _jamba_cell():
+    """(engine, model kwargs) of the cell jamba2_3b.serve_closed256_chat,
+    from its configuration file."""
+    import json
+    import os
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "jamba2_3b.json")) as f:
+        cfg = json.load(f)
+    return cfg["serve"]["engine"], cfg["model"]["kwargs"]
+
+
+def _jamba_step(one_chip, topo, monkeypatch, B, S):
+    """``FlaxModelAdapter``'s step for Jamba as the cell jamba2_3b.
+    serve_closed256_chat runs it: the published widths, all 28 layers, the
+    whole vocabulary, K and V pools of ``num_blocks`` pages for the two
+    attention layers under tables of 96, 256 state slots and the null
+    one."""
+    from benchmark.reference import jamba_glue as glue
+    from ray_tpu.serve.llm.kv_cache import PagedKVCache
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    engine, kwargs = _jamba_cell()
+    cfg = glue.model_config({
+        "factory": "ray_tpu.models.jamba:JambaConfig", "kwargs": kwargs})
+    adapter = FlaxModelAdapter("jamba", cfg, params={})
+    params = jax.tree_util.tree_map(
+        lambda s: sds(s.shape, s.dtype),
+        jax.eval_shape(adapter.model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8), jnp.int32)))
+    adapter.bind_cache(PagedKVCache(2, 16))
+    adapter.bind_state(1)
+    adapter.state_slots = engine["max_running"]
+    assert adapter.nb_max == 96
+    arrays = [sds((a.shape[0], engine["num_blocks"]
+                   if name in adapter._spec["pages"]
+                   else engine["max_running"] + 1, *a.shape[2:]), a.dtype)
+              for name, a in adapter._arrays.items()]
+    with monkeypatch.context() as m:
+        m.setattr(jax, "devices", lambda *a, **k: topo.devices)
+        fn = adapter._step_fn(B, S)
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    with jax.default_matmul_precision("default"):
+        return params, arrays, fn.lower(
+            params, sds((B, S + 3 + 96), jnp.int32),
+            *_last_tokens(sds, engine["num_blocks"], S), *arrays).compile()
+
+
+@pytest.mark.parametrize("B,S,temp_gib", [
+    (256, 1, 0.003), (8, 1, 0.257), (4, 512, 0.515), (8, 256, 0.554)],
+    ids=["decode_b256_by_slot", "decode_b8", "prefill_4x512",
+         "prefill_8x256"])
+def test_jamba_programs_fit_the_chip_whole(one_chip, topo, monkeypatch, B, S,
+                                           temp_gib):
+    """The full decode bucket (rows in slot order), a narrow one (rows by
+    ``slots``) and the two largest prefill programs of the cell, compiled
+    for the described v5e: 5.65 GiB of weights (all 28 layers, the whole
+    vocabulary), 2.04 GiB of Mamba state, 0.19 of convolution tails and
+    0.375 of pages as arguments, all four arrays donated and written in
+    place, temporaries as read (and a tenth): 8.82 of 15.75 GiB at the
+    most. A decode step's recurrence is ONE Mosaic call a run of Mamba
+    layers' loop (three runs) over the state pool where it lies, and its
+    attention the paged kernel at groups of 20 in both attention layers;
+    nothing of the state pool's size is copied, laid out anew, padded or
+    stacked in any program, and the full decode bucket (rows in slot
+    order: what a steady window runs) holds 3 MiB beside its arguments.
+    (The convolution tails lie a tap's rows [slots, d_in] together, the
+    chip's own choice for [26, 257, 3, 5120]; the programs that take rows
+    by ``slots``, a narrow decode bucket and every prefill, lay them out
+    anew once on the way in and once out, 0.25 GiB: PERF.md, PR 48.)"""
+    import math
+    engine, _ = _jamba_cell()
+    params, arrays, step = _jamba_step(one_chip, topo, monkeypatch, B, S)
+    memory = step.memory_analysis()
+    gib = 2.0 ** 30
+    held = sum(math.prod(s.shape) * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    assert held == 6_063_769_088
+    pools = sum(math.prod(a.shape) * a.dtype.itemsize for a in arrays)
+    assert pools == 2_189_557_760 + 205_271_040 + 402_669_568
+    assert pools <= memory.alias_size_in_bytes <= 1.02 * pools
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    print(f"jamba b{B} s{S}: arguments "
+          f"{memory.argument_size_in_bytes / gib:.3f} GiB, temporaries "
+          f"{memory.temp_size_in_bytes / gib:.3f}, total {total / gib:.3f}")
+    assert memory.temp_size_in_bytes < 1.1 * temp_gib * gib + (1 << 20)
+    assert total < 8.9 * gib
+    assert total > 0.25 * 15.75 * gib       # the cell's floor, by far
+    text = step.as_text()
+    state = math.prod(arrays[2].shape)
+    assert not _moved(text, state)
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    if S == 1:
+        assert sum("mamba_recurrence" in c for c in calls) == 3
+        assert sum("paged_attention_decode" in c for c in calls) == 2
+        assert f"[{B},1536," not in text    # no table gathered whole
+    else:       # a prompt's scan is XLA's loop over its positions
+        assert not any("mamba_recurrence" in c for c in calls)
+
+
 @pytest.mark.parametrize("kind,config", [
     ("kimi_linear", "KimiLinearConfig"), ("kimi_k2", "KimiK2Config"),
     ("laguna", "LagunaConfig"),
-    ("smallthinker", "SmallThinkerConfig")])
+    ("smallthinker", "SmallThinkerConfig"), ("jamba", "JambaConfig")])
 def test_a_step_dispatched_ahead_runs_the_program_the_warm_up_compiled(
         kind, config):
     """The benchmark warms a decode bucket by a synchronous

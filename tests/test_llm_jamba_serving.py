@@ -1,0 +1,349 @@
+"""LLM serving, a second kind of per-sequence state (Jamba: a Mamba-1
+state slot and a convolution tail a sequence for 4 layers of 6, K and V
+pages for the other 2, five query heads over one) held to the plain
+reference's logits (docs/LLM_SERVING.md). Tier-1, CPU-only.
+
+Logits are compared, not tokens. Everything here is float32 at 'highest'
+on both sides (tests/conftest.py), so the served rows differ from the
+reference's full forward by the order of sums only: 5e-5 absolute on
+logits of spread ~0.16. A stale state, a wrong slot or a wrong page moves
+a row by 1e-2 or more (test_a_stale_slot_shows)."""
+
+import functools
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+from llm_test_helpers import PAGE, drain_stream, flax_seq, token_prompts
+
+from ray_tpu.serve.llm import (EngineConfig, LLMEngine, PagedKVCache,
+                               SamplingParams)
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TOL = 5e-5
+_J = {}
+
+
+def _jamba():
+    if not _J:
+        from benchmark.reference import jamba_glue, jamba_ref
+        from ray_tpu.models.jamba import JambaConfig
+        cfg = JambaConfig.tiny()
+        _J.update(cfg=cfg, params=jamba_glue.init_for(cfg, 7),
+                  sizes=jamba_ref.sizes_of(cfg), ref=jamba_ref)
+    return _J
+
+
+def _adapter(max_running=4):
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+    k = _jamba()
+    adapter = FlaxModelAdapter("jamba", k["cfg"], k["params"])
+    cache = PagedKVCache(num_blocks=64, block_size=PAGE)
+    adapter.bind_cache(cache)
+    adapter.bind_state(max_running)
+    return adapter, cache
+
+
+def _reference_rows(prompt, tokens, params=None):
+    """The reference's logits after the prompt and after each of
+    ``tokens`` but the last: what prefill and each decode returned."""
+    k = _jamba()
+    ids = np.asarray(list(prompt) + list(tokens[:-1]), np.int32)
+    rows = k["ref"].forward((params or k["params"])["params"], ids,
+                            k["sizes"])
+    return np.asarray(rows[len(prompt) - 1:])
+
+
+def _serve(adapter, seqs, n, rows=None):
+    """Prefill (unless ``rows`` has each sequence's logits so far) and n
+    greedy decode steps; every logits row that came back, a sequence."""
+    if rows is None:
+        rows = [[r] for r in adapter.prefill(seqs)]
+    for _ in range(n):
+        for s, got in zip(seqs, rows):
+            s.tokens.append(int(got[-1].argmax()))
+        for got, r in zip(rows, adapter.decode(seqs)):
+            got.append(r)
+    return rows
+
+
+def _check(seq, rows):
+    want = _reference_rows(seq.prompt, seq.tokens + [0])
+    np.testing.assert_allclose(np.stack(rows), want[:len(rows)], atol=TOL)
+
+
+def _walk(span):
+    yield span
+    for child in span.get("children", ()):
+        yield from _walk(child)
+
+
+def test_pages_and_slots_serve_the_references_logits():
+    """Rows of unequal length in ONE right-padded prefill step, decode in
+    the full bucket (rows in slot order) and in a narrower one (by
+    ``slots``); a sequence ends, a new one takes the slot it left and
+    joins the others."""
+    adapter, cache = _adapter()
+    prompts = token_prompts(41, adapter.vocab_size, (70, 5, 33, 19))
+    a, b, c = (flax_seq(cache, f"s{i}", p, budget=24)
+               for i, p in enumerate(prompts[:3]))
+    rows = _serve(adapter, [a, b, c], 4)
+    slot_b = adapter._state["s1"]["slot"]
+    adapter.release("s1")
+    cache.free("s1")
+    assert adapter.counters()["state_slots_in_use"] == 2
+    rows_ac = _serve(adapter, [a, c], 3, rows=[rows[0], rows[2]])
+    d = flax_seq(cache, "s3", prompts[3], budget=24)
+    rows_d = _serve(adapter, [d], 0)
+    assert adapter._state["s3"]["slot"] == slot_b      # the slot is reused
+    rows_acd = _serve(adapter, [a, c, d], 3, rows=rows_ac + rows_d)
+    for seq, got in zip((a, b, c, d), (rows_acd[0], rows[1], rows_acd[1],
+                                       rows_acd[2])):
+        _check(seq, got)
+    assert {k[:2] for k in adapter._fns if isinstance(k, tuple)} == {
+        (4, 128), (4, 1), (2, 1), (1, 32)}
+    assert adapter._by_slot(4, 1) and not adapter._by_slot(2, 1)
+    m = adapter.counters()
+    # two admitted groups (3 + 1 sequences), and their host seconds
+    assert m["state_admits_total"] == 4
+    assert m["state_admit_seconds_total"] > 0
+    assert m["state_slots_total"] == 4 and m["state_slots_in_use"] == 3
+    # off the chip the recurrence is the XLA step; no kernel step counted,
+    # and the counter from before a second recurrence is Kimi-Linear's
+    assert adapter._decode_recurrence == "xla"
+    assert m["recurrence_kernel_steps_total"] == 0
+    assert "kda_kernel_steps_total" not in m
+    assert "expert_tokens_total" not in m
+
+
+def test_a_slot_another_has_just_left_gives_a_fresh_slots_logits():
+    """A sequence admitted into the slot another has just left gives the
+    logits it gives in a fresh slot: state AND convolution tail are
+    zeroed at admission."""
+    adapter, cache = _adapter(max_running=1)
+    first, second = token_prompts(43, adapter.vocab_size, (40, 21))
+    a = flax_seq(cache, "a", first)
+    _serve(adapter, [a], 3)
+    adapter.release("a")
+    cache.free("a")
+    b = flax_seq(cache, "b", second)
+    reused = _serve(adapter, [b], 3)[0]
+    assert adapter._state["b"]["slot"] == 1
+    fresh_adapter, fresh_cache = _adapter(max_running=1)
+    c = flax_seq(fresh_cache, "c", second)
+    fresh = _serve(fresh_adapter, [c], 3)[0]
+    np.testing.assert_allclose(np.stack(reused), np.stack(fresh), atol=TOL)
+    _check(b, reused)
+
+
+def test_a_stale_slot_shows(monkeypatch):
+    """Without the zeroing at admission the second user of a slot starts
+    from the first one's state and tail, and its logits are off by far
+    more than the tolerance."""
+    adapter, cache = _adapter(max_running=1)
+    first, second = token_prompts(43, adapter.vocab_size, (40, 21))
+    a = flax_seq(cache, "a", first)
+    _serve(adapter, [a], 2)
+    adapter.release("a")
+    cache.free("a")
+    monkeypatch.setattr(adapter, "_zero_fn",
+                        lambda: lambda idx, *arrays: arrays)
+    b = flax_seq(cache, "b", second)
+    stale = _serve(adapter, [b], 1)[0]
+    want = _reference_rows(second, b.tokens + [0])
+    assert float(np.abs(np.stack(stale) - want[:2]).max()) > 100 * TOL
+
+
+def test_three_times_max_running_requests_through_four_slots():
+    """Through ``LLMEngine``: 12 short requests on 4 slots, so every slot
+    changes hands and several prompts share a prefill step; tokens are the
+    reference's greedy ones; the step log has ``runner.state.admit`` under
+    ``llm.step.prefill`` and the decode dispatch says which recurrence and
+    which attention ran."""
+    adapter, _ = _adapter()
+    lengths = (30, 9, 66, 12, 40, 5, 17, 23, 50, 8, 35, 14)
+    prompts = token_prompts(47, adapter.vocab_size, lengths)
+    eng = LLMEngine(adapter, EngineConfig(
+        max_running=4, num_blocks=64, block_size=PAGE, max_seq_len=128,
+        max_prefill_tokens=64))
+    try:
+        assert eng.metrics()["state_slots_total"] == 4
+        sids = [eng.add_request(p, SamplingParams(max_new_tokens=5))
+                for p in prompts]
+        served = [drain_stream(eng, sid, timeout=240.0)[0] for sid in sids]
+        deadline = time.time() + 10
+        while eng.metrics()["state_slots_in_use"] and time.time() < deadline:
+            time.sleep(0.05)
+        m = eng.metrics()
+        log = eng.step_log()
+    finally:
+        eng.stop()
+    for p, toks in zip(prompts, served):
+        want = _reference_rows(p, toks)
+        gap = want.max(-1) - want[np.arange(5), toks]
+        assert float(gap.max()) <= TOL
+    assert m["state_slots_in_use"] == 0
+    assert m["state_admits_total"] == 12
+    assert 0 < m["state_admit_seconds_total"] < 60
+    prefills = [s for step in log for s in _walk(step)
+                if s["name"] == "llm.step.prefill"]
+    assert sum(s["attrs"]["n"] for s in prefills) == 12
+    assert max(s["attrs"]["n"] for s in prefills) >= 2     # batched
+    admits = [c["name"] for s in prefills for c in _walk(s)]
+    assert admits.count("runner.state.admit") >= 3
+    said = {(s["attrs"].get("recurrence"), s["attrs"].get("attention"))
+            for step in log for d in _walk(step)
+            if d["name"] == "llm.step.decode" for s in _walk(d)
+            if s["name"] == "runner.dispatch"}
+    assert said == {("xla", "gather")}
+
+
+def test_decode_steps_through_the_recurrence_kernel(monkeypatch):
+    """Where the chooser says ``mamba_kernel`` (here: patched, the kernel
+    interpreted) the adapter's decode steps, rows in slot order (a bucket
+    as wide as the slots) and rows by ``slots`` (a narrower one), return
+    the logits of the steps that gather and scatter, leave the same state
+    in the slots, and are counted."""
+    from ray_tpu.ops import ssm
+
+    def serve():
+        adapter, cache = _adapter()
+        prompts = token_prompts(43, adapter.vocab_size, (21, 5, 33))
+        seqs = [flax_seq(cache, f"s{i}", p, budget=8)
+                for i, p in enumerate(prompts)]
+        rows = _serve(adapter, seqs, 2)             # bucket 4: in order
+        adapter.release("s1")
+        cache.free("s1")
+        rows = _serve(adapter, [seqs[0], seqs[2]], 2,
+                      rows=[rows[0], rows[2]])      # bucket 2: slots
+        return adapter, np.stack([np.stack(r) for r in rows])
+
+    plain, want = serve()
+    monkeypatch.setattr(ssm, "mamba_decode_path",
+                        lambda pool, S: "mamba_kernel" if S == 1 else "xla")
+    monkeypatch.setattr(ssm, "mamba_step_in_place", functools.partial(
+        ssm.mamba_step_in_place, interpret=True))
+    kernel, got = serve()
+    assert kernel._decode_recurrence == "mamba_kernel"
+    assert kernel.counters()["recurrence_kernel_steps_total"] == 4
+    np.testing.assert_allclose(got, want, atol=TOL)
+    np.testing.assert_allclose(kernel._arrays["mamba_state"],
+                               plain._arrays["mamba_state"], atol=TOL)
+    # the null slot is as it was made
+    assert float(np.abs(kernel._arrays["mamba_state"][:, 0]).max()) == 0.0
+
+
+def test_kimi_linears_counter_keeps_its_name():
+    """The recurrence's chooser comes from one table by the spec's name;
+    Kimi-Linear's adapter still reports ``kda_kernel_steps_total`` (a
+    benchmark file reads it) beside the shared name, and an unknown name
+    is an error where the state is bound."""
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter, _recurrences
+    assert set(_recurrences()) == {"kda", "mamba"}
+    kimi = FlaxModelAdapter("kimi_linear")
+    kimi.bind_cache(PagedKVCache(num_blocks=16, block_size=PAGE))
+    kimi.bind_state(2)
+    m = kimi.counters()
+    assert m["kda_kernel_steps_total"] == 0
+    assert m["recurrence_kernel_steps_total"] == 0
+    assert m["state_admits_total"] == 0
+    kimi._spec["state"]["kda_state"]["recurrence"] = "other"
+    with pytest.raises(KeyError):
+        kimi.bind_state(2)
+
+
+@pytest.mark.parametrize("what", [
+    "enable_prefix_cache", "spec_k", "decode_window", "rollback",
+    "export_kv", "import_kv"])
+def test_refuses_what_needs_a_snapshot_of_the_state(what):
+    """Dropping cached tokens, sharing them by page and shipping them as
+    pages each need the state as it was at that token."""
+    from ray_tpu.serve.llm.model_runner import RecurrentStateError
+    adapter, cache = _adapter()
+    base = dict(max_running=2, num_blocks=64, block_size=PAGE,
+                max_seq_len=128)
+    with pytest.raises(RecurrentStateError, match="state") as err:
+        if what == "enable_prefix_cache":
+            LLMEngine(adapter, EngineConfig(enable_prefix_cache=True,
+                                            **base))
+        elif what == "spec_k":
+            LLMEngine(adapter, EngineConfig(spec_k=2, **base))
+        else:
+            seq = flax_seq(cache, "a", [1, 2, 3])
+            adapter.prefill([seq])
+            {"decode_window": lambda: adapter.decode_window([seq], [[1, 2]]),
+             "rollback": lambda: adapter.rollback("a", 1),
+             "export_kv": lambda: adapter.export_kv("a", 3),
+             "import_kv": lambda: adapter.import_kv("a", 3, {}),
+             }[what]()
+    assert "snapshot" in str(err.value)
+
+
+def test_make_adapter_knows_the_kind():
+    from ray_tpu.serve.llm.model_runner import make_adapter
+    adapter = make_adapter("jamba")
+    assert adapter.kind == "jamba" and adapter.has_state
+    assert adapter.greedy_on_device and adapter.decode_ahead
+    assert adapter.page_windows == ()
+    with pytest.raises(ValueError, match="jamba"):
+        make_adapter("mamba")
+
+
+def test_jamba_streams_the_references_greedy_tokens_through_serve_run():
+    """``serve.run`` of an ``LLMServer("jamba", ...)`` replica (tiny
+    preset, weights from a seed), clients on ``handle.stream``: tokens
+    arrive in chunks and are, teacher-forced through the reference on the
+    same weights, each its row's largest logit."""
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.serve.llm import LLMServer
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+    params = FlaxModelAdapter("jamba", seed=5).params
+    prompts = token_prompts(59, 512, (40, 6))
+    ray_tpu.init(num_cpus=4, ignore_reinit_error=True,
+                 object_store_memory=128 * 1024 * 1024)
+    try:
+        dep = serve.deployment(name="jamba", num_replicas=1,
+                               max_concurrent_queries=8)(LLMServer)
+        h = serve.run(dep.bind("jamba", {"seed": 5}, {
+            "num_blocks": 64, "block_size": PAGE, "max_seq_len": 128,
+            "max_running": 2}), name="jamba", route_prefix="/jamba",
+            http_port=None)
+        for p, n in zip(prompts, (24, 12)):
+            chunks = list(h.stream({"tokens": p, "max_new_tokens": n,
+                                    "temperature": 0.0}))
+            toks = [t for c in chunks for t in c["tokens"]]
+            assert chunks[-1]["done"] and len(toks) == n
+            assert len(chunks) >= 2, "tokens must stream"
+            want = _reference_rows(p, toks, params)
+            gap = want.max(-1) - want[np.arange(n), toks]
+            assert float(gap.max()) <= 1e-4
+    finally:
+        try:
+            serve.shutdown()
+        finally:
+            ray_tpu.shutdown()
+
+
+def test_the_new_cell_rehearses_on_the_cpu():
+    """``benchmark/run.py --rehearse`` of jamba2_3b.serve_closed256_chat
+    at tiny widths: the replica is deployed, every reachable shape warmed,
+    the window served with no failed request, the checked requests' logits
+    and slot states held to the reference, the traced run's readers run;
+    exit code 3."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload",
+         "jamba2_3b.serve_closed256_chat", "--seed", "4800000019",
+         "--seconds", "6", "--trace", "1", "--rehearse"],
+        cwd=REPO_ROOT, env=env, capture_output=True, text=True,
+        timeout=280)
+    text = out.stdout + out.stderr
+    assert out.returncode == 3, text[-3000:]
+    assert "rehearsal passed" in text and " 0 failed {}" in text
+    assert text.count("state fed the right tokens: True") == 4
+    assert "[correct] verdict: True" in text
+    assert "state_admit_ms_per_request.serve = " in text
