@@ -54,8 +54,74 @@ class GPT2Config:
                    attention_backend="reference")
 
 
+class StackedDense(nn.Module):
+    """``nn.Dense`` of one layer of a stack: the served form's matrices
+    stay whole, ``kernel`` [layers, K, features] and ``bias`` [layers,
+    features] float32 as stored, and a block's product names its layer
+    (``ops.linear.stacked_linear``: on the chip a kernel that reads the
+    layer's tiles where they lie and rounds them in VMEM)."""
+    features: int
+    layers: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x, layer):
+        from ray_tpu.ops.linear import stacked_linear
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(batch_axis=(0,)),
+            (self.layers, x.shape[-1], self.features))
+        bias = self.param("bias", nn.initializers.zeros,
+                          (self.layers, self.features))
+        return stacked_linear(x.astype(self.dtype), kernel, bias, layer)
+
+
+def linear_path(cfg: "GPT2Config", params, rows: int) -> str:
+    """What the products of ``GPT2(cfg, stacked=True)`` run for a step of
+    ``rows`` rows over ``params``: ``ops.linear.stacked_linear_path``, the
+    question ``StackedDense`` asks while the step is traced, put to every
+    stack of the blocks with the rows it will be handed (for the adapter's
+    dispatch span). ``"kernel"`` or ``"xla"`` where a block's products
+    agree, else ``"mixed"``."""
+    from ray_tpu.ops import linear
+    said = {linear.stacked_linear_path(
+                jax.ShapeDtypeStruct((rows, w.shape[1]), cfg.dtype), w)
+            for path, w in jax.tree_util.tree_leaves_with_path(
+                params["params"]["h"]) if path[-1].key == "kernel"}
+    return said.pop() if len(said) == 1 else "mixed"
+
+
+class StackedLayerNorm(nn.Module):
+    """``nn.LayerNorm(dtype=float32)`` of one layer of a stack: ``scale``
+    and ``bias`` [layers, E], the layer's rows sliced."""
+    layers: int
+
+    @nn.compact
+    def __call__(self, x, layer):
+        shape = (self.layers, x.shape[-1])
+        scale = self.param("scale", nn.initializers.ones, shape)
+        bias = self.param("bias", nn.initializers.zeros, shape)
+        y = nn.LayerNorm(dtype=jnp.float32, use_scale=False,
+                         use_bias=False)(x)
+        return y * scale[layer] + bias[layer]
+
+
+def _dense(cfg, features: int, name: str, layers, x, layer):
+    """A block's product: ``nn.Dense``, or (``layers``: the served form)
+    layer ``layer`` of the stack."""
+    if layers is None:
+        return nn.Dense(features, dtype=cfg.dtype, name=name)(x)
+    return StackedDense(features, layers, cfg.dtype, name=name)(x, layer)
+
+
+def _layer_norm(name: str, layers, x, layer):
+    if layers is None:
+        return nn.LayerNorm(dtype=jnp.float32, name=name)(x)
+    return StackedLayerNorm(layers, name=name)(x, layer)
+
+
 class CausalSelfAttention(nn.Module):
     config: GPT2Config
+    layers: Optional[int] = None    # the served form: the stack's depth
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True, kv_cache=None,
@@ -63,7 +129,7 @@ class CausalSelfAttention(nn.Module):
         cfg = self.config
         B, S, E = x.shape
         head_dim = cfg.n_embd // cfg.n_head
-        qkv = nn.Dense(3 * cfg.n_embd, dtype=cfg.dtype, name="c_attn")(x)
+        qkv = _dense(cfg, 3 * cfg.n_embd, "c_attn", self.layers, x, layer)
         q, k, v = jnp.split(qkv, 3, axis=-1)
 
         def heads(t):  # [B,S,E] -> [B,H,S,D]
@@ -79,7 +145,7 @@ class CausalSelfAttention(nn.Module):
                 tok(q), tok(k), tok(v), kv_cache, seq_lengths,
                 valid=valid, layer=layer)
             y = y.reshape(B, S, E)
-            y = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="c_proj")(y)
+            y = _dense(cfg, cfg.n_embd, "c_proj", self.layers, y, layer)
             return (nn.Dropout(cfg.dropout)(y, deterministic),
                     new_cache)
         q, k, v = heads(q), heads(k), heads(v)
@@ -93,44 +159,47 @@ class CausalSelfAttention(nn.Module):
             from ray_tpu.ops.attention import attention_reference
             y = attention_reference(q, k, v, causal=True)
         y = y.transpose(0, 2, 1, 3).reshape(B, S, E)
-        y = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="c_proj")(y)
+        y = _dense(cfg, cfg.n_embd, "c_proj", self.layers, y, layer)
         y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
         return y
 
 
 class MLP(nn.Module):
     config: GPT2Config
+    layers: Optional[int] = None
 
     @nn.compact
-    def __call__(self, x, deterministic: bool = True):
+    def __call__(self, x, deterministic: bool = True, layer=None):
         cfg = self.config
-        h = nn.Dense(4 * cfg.n_embd, dtype=cfg.dtype, name="c_fc")(x)
+        h = _dense(cfg, 4 * cfg.n_embd, "c_fc", self.layers, x, layer)
         h = nn.gelu(h, approximate=True)
-        h = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="c_proj")(h)
+        h = _dense(cfg, cfg.n_embd, "c_proj", self.layers, h, layer)
         return nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
 
 
 class Block(nn.Module):
     config: GPT2Config
+    # the served form: this many blocks' parameters on a leading layer
+    # axis, and ``layer`` says which of them this call is
+    layers: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True, kv_cache=None,
                  seq_lengths=None, valid=None, layer=None):
-        cfg = self.config
+        cfg, n = self.config, self.layers
         if kv_cache is not None:
-            y, new_cache = CausalSelfAttention(cfg, name="attn")(
-                nn.LayerNorm(dtype=jnp.float32, name="ln_1")(x),
+            y, new_cache = CausalSelfAttention(cfg, n, name="attn")(
+                _layer_norm("ln_1", n, x, layer),
                 deterministic, kv_cache=kv_cache,
                 seq_lengths=seq_lengths, valid=valid, layer=layer)
             x = x + y
-            x = x + MLP(cfg, name="mlp")(
-                nn.LayerNorm(dtype=jnp.float32, name="ln_2")(x),
-                deterministic)
+            x = x + MLP(cfg, n, name="mlp")(
+                _layer_norm("ln_2", n, x, layer), deterministic, layer)
             return x, new_cache
-        x = x + CausalSelfAttention(cfg, name="attn")(
-            nn.LayerNorm(dtype=jnp.float32, name="ln_1")(x), deterministic)
-        x = x + MLP(cfg, name="mlp")(
-            nn.LayerNorm(dtype=jnp.float32, name="ln_2")(x), deterministic)
+        x = x + CausalSelfAttention(cfg, n, name="attn")(
+            _layer_norm("ln_1", n, x, layer), deterministic, layer=layer)
+        x = x + MLP(cfg, n, name="mlp")(
+            _layer_norm("ln_2", n, x, layer), deterministic, layer)
         return x
 
 
@@ -168,7 +237,13 @@ class GPT2(nn.Module):
                        dtype=cfg.dtype, name="wte")
         wpe = nn.Embed(cfg.n_positions, cfg.n_embd,
                        dtype=cfg.dtype, name="wpe")
-        x = wte(input_ids) + wpe(positions)
+        if self.stacked:
+            # the rows taken from the tables as stored, and only they cast
+            # (``nn.Embed`` casts the table: 50,257 rows to look up a few)
+            x = wte.embedding[input_ids].astype(cfg.dtype) \
+                + wpe.embedding[positions].astype(cfg.dtype)
+        else:
+            x = wte(input_ids) + wpe(positions)
         x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
 
         def block(h, carry, i):
@@ -176,13 +251,16 @@ class GPT2(nn.Module):
             if incremental:
                 return h(x, deterministic, kv_cache=cache,
                          seq_lengths=seq_lengths, valid=valid, layer=i), None
-            return (h(x, deterministic), None), None
+            return (h(x, deterministic, layer=i), None), None
 
         if self.stacked:
+            # the stacks go to the loop WHOLE and every product names its
+            # layer: over the loop's own slice of a stack the compiler
+            # would cast all of it ahead of the loop (``ops.linear``)
             (x, kv_cache), _ = nn.scan(
-                block, variable_axes={"params": 0},
-                split_rngs={"params": True})(
-                    Block(cfg, name="h"), (x, kv_cache),
+                block, variable_broadcast="params",
+                split_rngs={"params": False})(
+                    Block(cfg, cfg.n_layer, name="h"), (x, kv_cache),
                     jnp.arange(cfg.n_layer))
         else:
             for i in range(cfg.n_layer):

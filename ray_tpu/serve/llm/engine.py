@@ -658,6 +658,11 @@ class LLMEngine:
                 "itl_p50_s": round(q(itl, 0.50), 6),
                 "itl_p99_s": round(q(itl, 0.99), 6),
                 "steps_total": self._steps_total,
+                # of the programs those steps dispatched, the ones whose
+                # products read float32 weight stacks where they lie
+                # (``ops.linear``; 0 off the chip)
+                "stacked_linear_kernel_steps_total": int(getattr(
+                    self.adapter, "stacked_linear_kernel_steps", 0)),
                 "decode_steps_ahead_total": self._decode_steps_ahead_total,
                 "decode_tokens_discarded_total":
                     self._decode_tokens_discarded_total,

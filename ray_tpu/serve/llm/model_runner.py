@@ -40,7 +40,14 @@ Two implementations:
   chip its attention is the ``paged_attention_decode`` Pallas kernel,
   which reads each row's live pages where they lie in the pool; off the
   chip the same program gathers (``cached_attention`` chooses by what it
-  can observe, ``ops.attention.paged_decode_path``). Prefill and
+  can observe, ``ops.attention.paged_decode_path``). GPT-2's four
+  products a block take the float32 stacks whole and name their layer
+  (``ops.linear.stacked_linear``: on the chip a kernel that reads the
+  layer's tiles where they lie and rounds them in VMEM, so no program
+  casts a stack through HBM; the dispatch span's ``linear`` says which
+  ran and the engine's ``metrics()`` count
+  ``stacked_linear_kernel_steps_total``).
+  Prefill and
   ``decode_window`` go ``paged_gather`` + ``decode_attention`` (XLA) on
   every platform, padded to a bucket of at least 8 tokens — the
   multi-token incremental step is causal at the right offsets by
@@ -398,9 +405,14 @@ class FlaxModelAdapter:
         # what the model says it caches (None: K and V pages, every
         # layer alike)
         self._spec: Optional[Dict[str, Any]] = None
+        # what the model says its blocks' products run over the bound
+        # weights for a step of so many rows (None: not
+        # ``ops.linear.stacked_linear``'s)
+        self._linear_chooser = None
         if kind == "gpt2":
             from ray_tpu.models import gpt2
             self.cfg = config or gpt2.GPT2Config.tiny()
+            self._linear_chooser = gpt2.linear_path
             self.model, self._blocks = gpt2.GPT2(self.cfg, stacked=True), "h"
             self.n_heads = self.n_kv_heads = self.cfg.n_head
             self.head_dim = self.cfg.n_embd // self.cfg.n_head
@@ -478,6 +490,9 @@ class FlaxModelAdapter:
         self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
         self._flying: Optional[DecodeStep] = None   # dispatched, unfetched
         self.bucket_first_calls = 0        # _fns misses: steps that compiled
+        # programs dispatched whose blocks' products took the kernel that
+        # reads the float32 stacks where they lie (``_linear_path``)
+        self.stacked_linear_kernel_steps = 0
         self._lock = threading.Lock()
 
     @property
@@ -488,6 +503,8 @@ class FlaxModelAdapter:
     def params(self, tree):
         # accepted as the models' training form (one entry a block) too
         self._params = _stack_blocks(tree, self._blocks, self.n_layers)
+        # ``_linear_path`` by rows a step, asked anew of these weights
+        self._linear_paths: Dict[int, str] = {}
 
     @property
     def has_state(self) -> bool:
@@ -868,8 +885,11 @@ class FlaxModelAdapter:
         # which product the routed experts of this many rows will run:
         # the layer's own chooser, asked with the same shapes
         product = self._expert_product(B * S)
+        linear = self._linear_path(B * S)
+        self.stacked_linear_kernel_steps += linear == "kernel"
         with tracing.step_span("runner.dispatch", B=B, S=S,
                                first_call=(B, S, full) not in self._fns,
+                               **({"linear": linear} if linear else {}),
                                **({"expert_product": product.name}
                                   if product and op != "decode" else {}),
                                **(self._count_pages(rows, B)
@@ -992,6 +1012,18 @@ class FlaxModelAdapter:
                        kv_window_pages_whole_rings=len(rows) * ring,
                        kv_window_pages_padded=B * ring)
         return out
+
+    def _linear_path(self, T: int) -> Optional[str]:
+        """What a block's products run for a step of ``T`` rows: the
+        model's own answer (``_linear_chooser``: its chooser, asked of
+        every stack with the same shapes, on the thread that traces the
+        step). None: the model has no such products."""
+        if self._linear_chooser is None:
+            return None
+        if T not in self._linear_paths:         # a bucket's first call
+            self._linear_paths[T] = self._linear_chooser(
+                self.cfg, self.params, T)
+        return self._linear_paths[T]
 
     def _expert_product(self, T: int):
         """``moe.expert_product`` for a step of ``T`` rows (None: the

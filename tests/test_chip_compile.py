@@ -402,7 +402,91 @@ def test_decode_step_attends_to_its_live_pages_in_place(one_chip, topo,
         == 2 * count * pool.dtype.itemsize
     _, prefill = _served_step(one_chip, topo, monkeypatch, 1025, 16, 8,
                               False)
-    assert "tpu_custom_call" not in prefill.as_text()
+    # (the blocks' products are Mosaic calls in every program since PR 50:
+    # it is the attention's call that a prefill must not hold)
+    assert "paged_attention_decode" in text
+    assert "paged_attention_decode" not in prefill.as_text()
+
+
+def _materialized(text, *shapes):
+    """The instructions outside fused computations (the entry, a loop's
+    body: what is written to HBM) whose result is one of ``shapes``
+    (prefixes, as ``"bf16[36,1280,"``)."""
+    import re
+    out, fused = [], False
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) \(", line)
+        if head:
+            fused = head.group(2).startswith("fused_computation")
+        m = re.search(r" = (\w+\[[\d,]*\])", line)
+        if m and not fused and m.group(1).startswith(shapes):
+            out.append(line.strip()[:140])
+    return out
+
+
+@pytest.mark.parametrize("B,S", [(16, 1), (1, 512)],
+                         ids=["decode", "prefill"])
+def test_served_programs_cast_no_weight_stack_through_hbm(one_chip, topo,
+                                                          monkeypatch, B, S):
+    """GPT-2 large's programs as served (36 layers): the blocks' four
+    products are ``stacked_linear``'s kernel over the float32 stacks where
+    they lie, so no program writes a bfloat16 copy of a stack or of the
+    token table, and what a program needs beside its arguments is not
+    1.44 GiB (1,545,823,744 bytes for the decode program before PR 50:
+    the compiler cast all 36 layers ahead of the loop) but under 256 MiB.
+    The tied head's cast of the table is fused into its product."""
+    pool, step = _served_step(one_chip, topo, monkeypatch, 1025, B, S,
+                              False, n_layer=36)
+    text = step.as_text()
+    assert "stacked_linear" in text
+    assert not _materialized(text, "bf16[36,1280,", "bf16[36,5120,",
+                             "bf16[50257,1280]")
+    memory = step.memory_analysis()
+    assert memory.temp_size_in_bytes < (256 << 20)
+    assert memory.alias_size_in_bytes \
+        == 2 * pool.dtype.itemsize * pool.size
+
+
+def _stacked_linear(sharding, M, K, N, L=36):
+    from ray_tpu.ops.linear import stacked_linear_kernel
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    return stacked_linear_kernel, (
+        sds((M, K), jnp.bfloat16), sds((L, K, N), jnp.float32),
+        sds((L, N), jnp.float32), sds((), jnp.int32))
+
+
+@pytest.mark.parametrize("M", [16, 512])
+@pytest.mark.parametrize("K,N", [(1280, 3840), (1280, 1280), (1280, 5120),
+                                 (5120, 1280)],
+                         ids=["c_attn", "attn.c_proj", "c_fc", "mlp.c_proj"])
+def test_stacked_linear(one_chip, M, K, N):
+    """GPT-2 large's four products for a decode batch and a prompt: one
+    Mosaic call over the float32 stack, nothing of a layer's matrix
+    sliced, cast or copied outside it."""
+    fn, args = _stacked_linear(one_chip, M, K, N)
+    with jax.default_matmul_precision("default"):
+        big = jax.jit(fn).lower(*args).compile()
+    text = big.as_text()
+    assert text.count("tpu_custom_call") == 1 and "stacked_linear" in text
+    assert big.memory_analysis().temp_size_in_bytes < (1 << 20)
+    assert not _moved(text, K * N)
+
+
+def test_bare_stacked_linear_is_refused_on_a_mesh(topo, monkeypatch):
+    """As the other kernels': a Mosaic call cannot be partitioned, so
+    ``stacked_linear_path`` says ``xla`` where a mesh of several devices
+    is being traced for, and the bare call is refused there."""
+    from ray_tpu.ops import linear as LN
+    mesh, _ = _mesh4(topo)
+    fn, args = _stacked_linear(NamedSharding(mesh, P()), 16, 1280, 1280, L=2)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _compile(fn, *args)
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    assert LN.stacked_linear_path(*args[:2]) == "kernel"
+    with A.attention_mesh(mesh):
+        assert LN.stacked_linear_path(*args[:2]) == "xla"
 
 
 def _last_tokens(sds, pages, S):
