@@ -130,7 +130,6 @@ class CausalSelfAttention(nn.Module):
         B, S, E = x.shape
         head_dim = cfg.n_embd // cfg.n_head
         qkv = _dense(cfg, 3 * cfg.n_embd, "c_attn", self.layers, x, layer)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
 
         def heads(t):  # [B,S,E] -> [B,H,S,D]
             return t.reshape(B, S, cfg.n_head, head_dim).transpose(0, 2, 1, 3)
@@ -141,6 +140,7 @@ class CausalSelfAttention(nn.Module):
             # the S new queries against the whole cached prefix
             from ray_tpu.ops.attention import cached_attention
             tok = lambda t: t.reshape(B, S, cfg.n_head, head_dim)  # noqa: E731
+            q, k, v = jnp.split(qkv, 3, axis=-1)
             y, new_cache = cached_attention(
                 tok(q), tok(k), tok(v), kv_cache, seq_lengths,
                 valid=valid, layer=layer)
@@ -148,17 +148,22 @@ class CausalSelfAttention(nn.Module):
             y = _dense(cfg, cfg.n_embd, "c_proj", self.layers, y, layer)
             return (nn.Dropout(cfg.dropout)(y, deterministic),
                     new_cache)
-        q, k, v = heads(q), heads(k), heads(v)
-        if cfg.attention_backend == "ring":
-            from ray_tpu.ops.ring_attention import ring_attention
-            y = ring_attention(q, k, v, axis_name=cfg.ring_axis, causal=True)
-        elif cfg.attention_backend == "flash":
-            from ray_tpu.ops.attention import flash_attention
-            y = flash_attention(q, k, v, causal=True)
+        if cfg.attention_backend == "flash":
+            # the kernels cut their blocks from ``qkv`` where it lies and
+            # write [B, S, E] (or, where the shapes say so, lay the heads
+            # out as below: ops/attention.py:packed_heads)
+            from ray_tpu.ops.attention import flash_attention_packed
+            y = flash_attention_packed(qkv, cfg.n_head, causal=True)
         else:
-            from ray_tpu.ops.attention import attention_reference
-            y = attention_reference(q, k, v, causal=True)
-        y = y.transpose(0, 2, 1, 3).reshape(B, S, E)
+            q, k, v = (heads(t) for t in jnp.split(qkv, 3, axis=-1))
+            if cfg.attention_backend == "ring":
+                from ray_tpu.ops.ring_attention import ring_attention
+                y = ring_attention(q, k, v, axis_name=cfg.ring_axis,
+                                   causal=True)
+            else:
+                from ray_tpu.ops.attention import attention_reference
+                y = attention_reference(q, k, v, causal=True)
+            y = y.transpose(0, 2, 1, 3).reshape(B, S, E)
         y = _dense(cfg, cfg.n_embd, "c_proj", self.layers, y, layer)
         y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
         return y

@@ -24,6 +24,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 DEFAULT_BLOCK_Q = 512   # measured on v5e: 512 halves per-program overhead
 DEFAULT_BLOCK_K = 512   # vs 128 at s=1024 (2.1ms -> sub-ms fwd per op)
@@ -416,6 +417,258 @@ def _whole_backward(res, g, *, causal, interpret=False):
 
 
 # ---------------------------------------------------------------------------
+# Packed whole-kv causal kernels: the projection where it lies
+#
+# The same static loop over query blocks as ``_causal_fwd_kernel`` /
+# ``_causal_bwd_kernel``, on blocks cut from the token-major arrays by the
+# index maps: a program holds ``128 // head_dim`` heads (at GPT-2's 64, a
+# pair) as ONE [s, 128] column block of the projection's [B, S, 3E] output
+# (passed three times: q at column block ``j``, k at ``E / 128 + j``, v at
+# ``2 E / 128 + j``) and writes one [s, 128] column block of [B, S, E], so
+# no array is laid out anew for the kernels and every block is whole lanes.
+# Inside a program the heads are told apart by LANE, never by slicing: a
+# matrix unit is 128 deep and 128 wide, so ``q`` with the other heads'
+# lanes zeroed against the whole [hi, 128] key block is this head's scores
+# in the passes a 64-deep product takes, ``e @ v[.., 128]`` holds this
+# head's values in its own lanes (the rest go in a select), and the
+# products onto ``dk`` / ``dv`` (``ds.T @ q``, ``p.T @ do``, their right
+# operands zeroed outside the head) land in the head's lanes and add. The
+# softmax scale goes into q with the same multiply that zeroes the lanes.
+# ``dq``, ``dk`` and ``dv`` leave the backward as the three column blocks
+# of ONE [B, S, 3E] array, which ``c_attn``'s backward takes as it lies: a
+# BlockSpec gives an output one block a program, so the array stays in HBM
+# (``pl.ANY``) and a program copies its three [s, 128] blocks out of VMEM
+# itself, waiting for them a program later (Mosaic took it as written; the
+# fallback, three [B, S, E] outputs and a concatenate, was never needed).
+#
+# Which layout runs is read from the shapes (``packed_heads``): this one
+# where the plan is ``whole_kv_causal``, heads fill whole 128-lane blocks,
+# q, k and v are one projection's output with as many kv heads as q heads
+# and no mesh cuts the heads; the [b h, s, d] kernels above for every
+# other caller (grouped heads that a model lays out and rotates itself,
+# ``models/llama.py``; a ``tp`` mesh, whose ``shard_map`` cuts heads;
+# ``causal=False``). Read on a v5e (my chip run, PR 51: 12 layers of
+# forward + backward in one program, device times of the kernels from a
+# trace, ms a layer; [the [b h, s, d] kernels on the same values, split,
+# ``heads`` and the transpose back traced round them]): [16, 1024, 12 x
+# 64] bf16, GPT-2 small's, 0.372 + 0.906 [0.382 + 0.972, beside 0.729 ms
+# of copies and transposes that the packed path does not have: the whole
+# program 1.58 ms a layer against 2.67], results equal to the last bit;
+# [4, 2048, 12 x 64] 0.328 + 0.797 [0.310 + 0.796; copies 0.336]; one
+# 128-wide head a program, [16, 1024, 8 x 128]: 0.266 + 0.642 [0.252 +
+# 0.609; copies 0.585] and [2, 2048, 8 x 128] 0.114 + 0.280 [0.102 +
+# 0.254; 0.017]: a column block's DMA is rows of 256 bytes a tile where a
+# head's own array is one run, ~5% of a kernel that a pair of 64-wide
+# heads wins back by filling its lanes, and that the copies it saves
+# outweigh wherever there are copies to save.
+
+
+def _lanes_of_heads(width, head_dim):
+    """A [1, width] bool a head of a column block: the head's lanes
+    (``None`` where the block is one head)."""
+    if head_dim == width:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return [(lane >= h * head_dim) & (lane < (h + 1) * head_dim)
+            for h in range(width // head_dim)]
+
+
+def _only(lanes, x):
+    return x if lanes is None else jnp.where(lanes, x, 0.0)
+
+
+def _packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
+                       head_dim, scale):
+    # refs hold a batch row's [s, 128] column block: q/k/v of [B, S, 3E],
+    # o of [B, S, E]; lse [heads, s], a row a head (see the header)
+    dt = q_ref.dtype
+    heads = _lanes_of_heads(q_ref.shape[1], head_dim)
+    for lo in range(0, q_ref.shape[0], block_q):
+        hi = lo + block_q
+        q = q_ref[lo:hi, :].astype(jnp.float32) * scale
+        kk, vv = k_ref[0:hi, :], v_ref[0:hi, :]
+        live = _causal_strip(lo, hi)
+        acc = total = None
+        for h, lanes in enumerate(heads):
+            s_ = _dot(_only(lanes, q).astype(dt), kk, _NT)
+            e = jnp.where(live,
+                          jnp.exp(jnp.minimum(s_, _CAP_HI) - _CAP_SHIFT), 0.0)
+            l = jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+            mine = _dot(e.astype(dt), vv, _NN)
+            lse_ref[h:h + 1, lo:hi] = _column_to_row(jnp.log(l) + _CAP_SHIFT)
+            acc = mine if acc is None else jnp.where(lanes, mine, acc)
+            total = l if total is None else jnp.where(lanes, l, total)
+        o_ref[lo:hi, :] = (acc / total).astype(o_ref.dtype)
+
+
+def _packed_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dqkv_ref,
+                       buf, sem, dk_acc, dv_acc, *, block_q, head_dim, scale):
+    # as ``_causal_bwd_kernel``, the heads of the block by lane. dq, dk
+    # and dv go to their column blocks of ``dqkv_ref`` [B, S, 3E] in HBM
+    # from ``buf`` [2, 3, s, 128], a half of it a program (see the header)
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    dt = q_ref.dtype
+    i, j, cols = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
+    n = i * cols + j
+    slot = n % 2
+    heads = _lanes_of_heads(q_ref.shape[1], head_dim)
+    for lo in range(0, q_ref.shape[0], block_q):
+        hi = lo + block_q
+        q = q_ref[lo:hi, :].astype(jnp.float32) * scale
+        dd = do_ref[lo:hi, :].astype(jnp.float32)
+        kk, vv = k_ref[0:hi, :], v_ref[0:hi, :]
+        live = _causal_strip(lo, hi)
+        o_do = o_ref[lo:hi, :].astype(jnp.float32) * dd
+        dq = dk = dv = None
+        for h, lanes in enumerate(heads):
+            qq, mine = _only(lanes, q).astype(dt), _only(lanes, dd).astype(dt)
+            delta = jnp.sum(_only(lanes, o_do), axis=-1, keepdims=True)
+            # same _CAP_HI clamp as the forward (see _whole_bwd_kernel)
+            p = jnp.exp(jnp.minimum(_dot(qq, kk, _NT), _CAP_HI)
+                        - _row_to_column(lse_ref[h:h + 1, lo:hi]))
+            p = jnp.where(live, p, 0.0)
+            ds = (p * (_dot(mine, vv, _NT) - delta)).astype(dt)
+            dq_h = _dot(ds, kk, _NN)
+            dk_h, dv_h = _dot(ds, qq, _TN), _dot(p.astype(dt), mine, _TN)
+            dq = dq_h if dq is None else jnp.where(lanes, dq_h, dq)
+            dk = dk_h if dk is None else dk + dk_h
+            dv = dv_h if dv is None else dv + dv_h
+        buf[slot, 0, lo:hi, :] = (dq * scale).astype(dt)
+        if lo:
+            dk_acc[0:lo, :] += dk[0:lo]
+            dv_acc[0:lo, :] += dv[0:lo]
+        # the diagonal block is the first to reach these keys
+        dk_acc[lo:hi, :] = dk[lo:hi]
+        dv_acc[lo:hi, :] = dv[lo:hi]
+    buf[slot, 1] = dk_acc[:].astype(dt)
+    buf[slot, 2] = dv_acc[:].astype(dt)
+
+    def copies(slot, i, j):
+        return [pltpu.make_async_copy(
+            buf.at[slot, part],
+            dqkv_ref.at[i, :, pl.ds(pl.multiple_of(
+                (part * cols + j) * 128, 128), 128)],
+            sem.at[slot, part]) for part in range(3)]
+
+    for copy in copies(slot, i, j):
+        copy.start()
+
+    @pl.when(n > 0)
+    def _():
+        # the program before this one: its half of ``buf`` is written
+        # next (a wait takes its bytes from the shapes, not the place)
+        for copy in copies(1 - slot, i, j):
+            copy.wait()
+
+    @pl.when(n == pl.num_programs(0) * cols - 1)
+    def _():
+        for copy in copies(slot, i, j):
+            copy.wait()
+
+
+def _column_spec(rows, first):
+    """A batch row's [rows, 128] column block of a token-major [B, S,
+    width] array: block ``first + j`` along the last axis."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec((None, rows, 128), lambda i, j: (i, 0, first + j))
+
+
+def _lse_spec(heads, rows):
+    """A program's rows of ``lse`` [B, column blocks, heads, S]."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec((None, None, heads, rows), lambda i, j: (i, j, 0, 0))
+
+
+def _packed_layout(qkv, n_head):
+    b, s, width = qkv.shape
+    e = width // 3
+    d = e // n_head
+    # (rows, head_dim, heads a program, column blocks a part, block_q)
+    return s, d, 128 // d, e // 128, flash_plan(
+        s, s, d, True, exact=False)["block_q"]
+
+
+@functools.partial(jax.jit, static_argnames=("n_head", "scale", "interpret"))
+def _packed_forward(qkv, *, n_head, scale, interpret=False):
+    from jax.experimental import pallas as pl
+
+    s, d, per, cols, bq = _packed_layout(qkv, n_head)
+    b = qkv.shape[0]
+    call = pl.pallas_call(
+        functools.partial(_packed_fwd_kernel, block_q=bq, head_dim=d,
+                          scale=scale),
+        grid=(b, cols),
+        in_specs=[_column_spec(s, part * cols) for part in range(3)],
+        out_specs=[_column_spec(s, 0), _lse_spec(per, s)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, cols * 128), qkv.dtype),
+            jax.ShapeDtypeStruct((b, cols, per, s), jnp.float32),
+        ],
+        interpret=interpret,
+        name="flash_fwd",
+    )
+    with jax.named_scope("flash_fwd"):
+        return call(qkv, qkv, qkv)
+
+
+@functools.partial(jax.jit, static_argnames=("n_head", "scale", "interpret"))
+def _packed_backward(qkv, out, lse, g, *, n_head, scale, interpret=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, d, per, cols, bq = _packed_layout(qkv, n_head)
+    b, item = qkv.shape[0], qkv.dtype.itemsize
+    call = pl.pallas_call(
+        functools.partial(_packed_bwd_kernel, block_q=bq, head_dim=d,
+                          scale=scale),
+        grid=(b, cols),
+        in_specs=[*(_column_spec(s, part * cols) for part in range(3)),
+                  _column_spec(s, 0), _lse_spec(per, s), _column_spec(s, 0)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+        scratch_shapes=[pltpu.VMEM((2, 3, s, 128), qkv.dtype),
+                        pltpu.SemaphoreType.DMA((2, 3)),
+                        pltpu.VMEM((s, 128), jnp.float32),      # dk
+                        pltpu.VMEM((s, 128), jnp.float32)],     # dv
+        # (``buf``'s halves and their copies go from a program to the next)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # the five inputs twice, ``buf``, the two sums, and room for
+            # a head's [block_q, s] float32 strips
+            vmem_limit_bytes=16 * s * 128 * item + 2 * s * 128 * 4
+            + 12 * bq * s * 4 + (8 << 20)),
+        interpret=interpret,
+        name="flash_bwd",
+    )
+    with jax.named_scope("flash_bwd"):
+        return call(qkv, qkv, qkv, g, lse, out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _flash_packed(qkv, n_head, scale, interpret):
+    return _packed_forward(qkv, n_head=n_head, scale=scale,
+                           interpret=interpret)[0]
+
+
+def _flash_packed_fwd_rule(qkv, n_head, scale, interpret):
+    out, lse = _packed_forward(qkv, n_head=n_head, scale=scale,
+                               interpret=interpret)
+    return out, (qkv, out, lse)
+
+
+def _flash_packed_bwd_rule(n_head, scale, interpret, res, g):
+    return (_packed_backward(*res, g, n_head=n_head, scale=scale,
+                             interpret=interpret),)
+
+
+_flash_packed.defvjp(_flash_packed_fwd_rule, _flash_packed_bwd_rule)
+
+
+# ---------------------------------------------------------------------------
 # Pallas forward
 
 
@@ -751,6 +1004,11 @@ _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 _TRACE_MESH = threading.local()
+_BATCH_AXES = ("dp", "fsdp")    # the mesh axes that shard a batch's rows
+
+
+def _batch_axes(mesh):
+    return tuple(a for a in _BATCH_AXES if a in mesh.axis_names) or None
 
 
 @contextlib.contextmanager
@@ -779,6 +1037,14 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
     On TPU runs the Pallas kernel; elsewhere falls back to the XLA reference
     (still fused reasonably by XLA on CPU for tests).
+
+    The head-major layout: a caller whose q, k and v are one projection's
+    [B, S, 3E] output calls ``flash_attention_packed`` instead, which cuts
+    the kernels' blocks from that array where the shapes allow (GPT-2's
+    train step: no [B, H, S, D] copy, 9.5 ms of a 113 ms step; PR 51) and
+    comes here where they do not. What stays here by design: heads a model
+    lays out itself (``models/llama.py`` rotates and repeats them), every
+    mesh that cuts heads, ``causal=False`` and the streaming lengths.
 
     Traced under ``attention_mesh(mesh)`` for a mesh of more than one
     device, the kernel runs inside ``shard_map`` on each device's batch
@@ -826,7 +1092,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
     # step), under the span open on this thread or in the module's ring
     from ray_tpu._private import tracing
     tracing.step_event("attention.flash_plan", 0.0, **flash_plan(
-        sq, sk, q.shape[3], causal, exact, block_q, block_k))
+        sq, sk, q.shape[3], causal, exact, block_q, block_k),
+        packed=False, heads_per_program=1)
 
     def local(q, k, v):
         # Fold the softmax scale into q OUTSIDE the kernel (one [b,h,s,d]
@@ -847,12 +1114,100 @@ def flash_attention(q, k, v, *, causal: bool = False,
         from jax.sharding import PartitionSpec as P
 
         from ray_tpu.parallel.jax_compat import shard_map
-        batch = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names)
         head = "tp" if "tp" in mesh.axis_names else None
-        spec = P(batch or None, head, None, None)
+        spec = P(_batch_axes(mesh), head, None, None)
         local = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                           out_specs=spec, check_vma=False)
     return local(q, k, v)
+
+
+def packed_heads(s: int, n_head: int, n_kv_head: int, head_dim: int,
+                 causal: bool, exact: Optional[bool] = None,
+                 mesh=None) -> int:
+    """How many heads a program of the packed kernels holds at these
+    shapes, 0 where ``flash_attention_packed`` goes through
+    ``flash_attention``'s [b, h, s, d] layout instead. A pure function of
+    the shapes and the mesh: packed where the plan is ``whole_kv_causal``,
+    whole heads fill a 128-lane block (``128 % head_dim == 0``) and the
+    blocks the heads (``n_head % (128 // head_dim) == 0``), there are as
+    many kv heads as q heads, and the mesh is one device or shards the
+    batch alone (a ``tp`` axis cuts the heads of the projection)."""
+    if not causal or n_kv_head != n_head or head_dim < 1 or 128 % head_dim:
+        return 0
+    if n_head % (128 // head_dim) or flash_plan(
+            s, s, head_dim, True, exact)["path"] != "whole_kv_causal":
+        return 0
+    if mesh is not None and any(
+            size > 1 and axis not in _BATCH_AXES
+            for axis, size in mesh.shape.items()):
+        return 0
+    return 128 // head_dim
+
+
+def flash_attention_packed(qkv, n_head: int, *, n_kv_head: Optional[int] = None,
+                           causal: bool = False,
+                           sm_scale: Optional[float] = None,
+                           force_pallas: Optional[bool] = None,
+                           interpret: bool = False,
+                           exact: Optional[bool] = None,
+                           debug: Optional[bool] = None):
+    """Fused self-attention of a projection's output where it lies:
+    ``qkv`` [B, S, (n_head + 2 n_kv_head) head_dim], the queries', keys'
+    and values' heads side by side as ``c_attn`` leaves them, ->
+    [B, S, n_head head_dim], as ``c_proj`` takes it.
+
+    Where ``packed_heads`` says so (and a Pallas kernel runs at all: on a
+    TPU, or ``interpret``), the packed whole-kv causal kernels cut their
+    blocks from ``qkv`` itself, ``128 // head_dim`` heads a program, and
+    the gradient is one [B, S, 3E] array: no split, no [B, H, S, D] copy
+    and no transpose back is traced. Everywhere else (no ``causal``,
+    ``exact``, ``debug``, grouped kv heads, a head that does not fill
+    whole lanes, the streaming lengths, a mesh that cuts heads) this is
+    split + heads + ``flash_attention`` + the transpose back, with the
+    same arguments: one algorithm, two layouts."""
+    b, s, width = qkv.shape
+    n_kv = n_head if n_kv_head is None else n_kv_head
+    d = width // (n_head + 2 * n_kv)
+    if sm_scale is None:
+        sm_scale = d ** -0.5
+    use = _use_pallas() if force_pallas is None else force_pallas
+    mesh = getattr(_TRACE_MESH, "mesh", None)
+    per = 0
+    if (use or interpret) and not (debug if debug is not None
+                                   else _attn_debug()):
+        per = packed_heads(s, n_head, n_kv, d, causal, exact, mesh)
+    if not per:
+        q, k, v = jnp.split(qkv, [n_head * d, (n_head + n_kv) * d], axis=-1)
+
+        def heads(t, n):  # [B,S,n*D] -> [B,n,S,D]
+            return t.reshape(b, s, n, d).transpose(0, 2, 1, 3)
+
+        y = flash_attention(
+            heads(q, n_head), _repeat_kv(heads(k, n_kv), n_head // n_kv),
+            _repeat_kv(heads(v, n_kv), n_head // n_kv), causal=causal,
+            sm_scale=sm_scale, force_pallas=force_pallas,
+            interpret=interpret, exact=exact, debug=debug)
+        return y.transpose(0, 2, 1, 3).reshape(b, s, n_head * d)
+    from ray_tpu._private import tracing
+    tracing.step_event("attention.flash_plan", 0.0,
+                       **flash_plan(s, s, d, True, exact),
+                       packed=True, heads_per_program=per)
+    # the scale as q's dtype holds it: what ``(q * sm_scale).astype`` of
+    # the other layout multiplies by
+    scale = float(np.asarray(sm_scale, dtype=qkv.dtype))
+
+    def local(qkv):
+        return _flash_packed(qkv, n_head, scale, interpret)
+
+    if mesh is not None and mesh.size > 1:
+        # (as in ``flash_attention``: each device its batch shard)
+        from jax.sharding import PartitionSpec as P
+
+        from ray_tpu.parallel.jax_compat import shard_map
+        spec = P(_batch_axes(mesh), None, None)
+        local = shard_map(local, mesh=mesh, in_specs=(spec,),
+                          out_specs=spec, check_vma=False)
+    return local(qkv)
 
 
 # ---------------------------------------------------------------------------

@@ -68,14 +68,102 @@ def _flash(exact, grad):
                     argnums=(0, 1, 2))
 
 
+def _flash_packed(exact, grad, n_head, mesh=None):
+    def fwd(qkv):
+        with A.attention_mesh(mesh):
+            return A.flash_attention_packed(qkv, n_head, causal=True,
+                                            force_pallas=True, exact=exact)
+    if not grad:
+        return fwd
+    return jax.grad(lambda qkv: fwd(qkv).astype(jnp.float32).sum())
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
 @pytest.mark.parametrize("exact", [False, True],
                          ids=["whole-kv", "streaming"])
-def test_flash_attention_gpt2_small_shape(one_chip, exact, grad):
+@pytest.mark.parametrize("packed", [False, True],
+                         ids=["heads", "packed-entry"])
+def test_flash_attention_gpt2_small_shape(one_chip, packed, exact, grad):
     """[16, 12, 1024, 64] bf16 causal: the GPT-2 small train step's
-    attention, on both sides of ``_use_whole_kv``."""
+    attention, on both sides of ``_use_whole_kv``; through the packed
+    entry it is ``c_attn``'s [16, 1024, 2304] where it lies (``exact``:
+    the entry lays the heads out and takes the streaming kernels)."""
     assert A._use_whole_kv(1024, 1024, 64, exact) is (not exact)
-    _compile(_flash(exact, grad), *_qkv((16, 12, 1024, 64), one_chip))
+    if not packed:
+        _compile(_flash(exact, grad), *_qkv((16, 12, 1024, 64), one_chip))
+        return
+    assert A.packed_heads(1024, 12, 12, 64, True, exact) == (
+        0 if exact else 2)
+    text = _compile(_flash_packed(exact, grad, 12),
+                    *_qkv((16, 1024, 2304), one_chip)[:1])
+    assert bool(_materialized(text, "bf16[16,12,1024,64]",
+                              ops=("copy", "transpose"))) is exact
+
+
+@pytest.mark.parametrize("b,s,n_head,d", [
+    (2, 2048, 8, 64), (2, 2048, 8, 128), (2, 256, 4, 32)],
+    ids=["pairs-s2048", "one-head-s2048", "four-heads-s256"])
+def test_flash_attention_packed_other_shapes(one_chip, b, s, n_head, d):
+    """The packed pair at the longest rows the whole-kv path admits (a
+    pair of 64-wide heads: eight query blocks a head unrolled twice in
+    one program; a 128-wide head alone) and with four 32-wide heads a
+    block: forward + backward inside the chip's VMEM."""
+    assert A.packed_heads(s, n_head, n_head, d, True, False) == 128 // d
+    text = _compile(_flash_packed(False, True, n_head),
+                    *_qkv((b, s, 3 * n_head * d), one_chip)[:1])
+    assert text.count("tpu_custom_call") == 2
+
+
+def test_flash_attention_packed_on_a_batch_mesh(topo):
+    """A mesh that shards the batch alone keeps the packed kernels, each
+    chip its rows inside ``shard_map``: forward + backward compile for
+    four chips, and nothing is gathered or laid out anew round them."""
+    mesh = MeshSpec(dp=2, fsdp=2).build(list(topo.devices))
+    sharding = NamedSharding(mesh, P(("dp", "fsdp"), None, None))
+    assert A.packed_heads(1024, 12, 12, 64, True, False, mesh) == 2
+    text = _compile(_flash_packed(False, True, 12, mesh),
+                    *_qkv((32, 1024, 2304), sharding)[:1])
+    assert text.count("tpu_custom_call") == 2
+    assert "all-gather" not in text and "bf16[8,1024,2304]" in text
+    assert not _materialized(text, "bf16[8,12,1024,64]", "bf16[8,1024,",
+                             ops=("copy", "transpose"))
+
+
+def test_train_step_lays_nothing_out_anew_for_its_attention(topo,
+                                                            monkeypatch):
+    """The train step of the cell gpt2_small.train_fed at its real shape
+    (b16 x s1024, 12 heads of 64, bfloat16) lowers with the packed
+    kernels, one forward and one backward a layer, and its optimised
+    program holds no copy or transpose whose result is [16, 12, 1024, 64]
+    or [16, 1024, 768] (before PR 51: 144 of them, 9.5 ms of a 113 ms
+    step on the chip, ledger, PR 50), nor one of ``c_attn``'s
+    [16, 1024, 2304] or its gradient; and it keeps less beside its
+    arguments than it did (8,097,406,976 bytes of temporaries then)."""
+    from ray_tpu.models.gpt2 import GPT2Config
+    from ray_tpu.train.spmd import make_causal_lm_trainer
+
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    cfg = GPT2Config(vocab_size=50257, n_positions=1024, n_embd=768,
+                     n_layer=12, n_head=12, dtype=jnp.bfloat16,
+                     attention_backend="flash")
+    spec = MeshSpec()
+    trainer = make_causal_lm_trainer(
+        cfg, mesh=spec.build(list(topo.devices)[:1]), spec=spec)
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        jax.eval_shape(trainer.init, jax.random.PRNGKey(0)),
+        trainer.state_sharding_tree)
+    batch = {k: jax.ShapeDtypeStruct((16, 1024), jnp.int32, sharding=s)
+             for k, s in trainer.batch_shardings.items()}
+    with jax.default_matmul_precision("default"):
+        step = trainer.step.lower(state, batch).compile()
+    text = step.as_text()
+    assert text.count("tpu_custom_call") == 2 * cfg.n_layer
+    assert text.count("bf16[16,1024,2304]") > 0
+    assert not _materialized(text, "bf16[16,12,1024,64]",
+                             "bf16[16,1024,768]", "bf16[16,1024,2304]",
+                             ops=("copy", "transpose"))
+    assert step.memory_analysis().temp_size_in_bytes < 7 << 30
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
@@ -408,18 +496,24 @@ def test_decode_step_attends_to_its_live_pages_in_place(one_chip, topo,
     assert "paged_attention_decode" not in prefill.as_text()
 
 
-def _materialized(text, *shapes):
+def _materialized(text, *shapes, ops=None):
     """The instructions outside fused computations (the entry, a loop's
     body: what is written to HBM) whose result is one of ``shapes``
-    (prefixes, as ``"bf16[36,1280,"``)."""
+    (prefixes, as ``"bf16[36,1280,"``); with ``ops`` only those of these
+    opcodes or fusions the compiler named for one (``("copy",
+    "transpose")``: an array written again only to lie another way; a
+    ``copy-start`` / ``copy-done`` pair moves it between memories as it
+    lies and is not one)."""
     import re
     out, fused = [], False
     for line in text.splitlines():
         head = re.match(r"(ENTRY )?%(\S+) \(", line)
         if head:
             fused = head.group(2).startswith("fused_computation")
-        m = re.search(r" = (\w+\[[\d,]*\])", line)
-        if m and not fused and m.group(1).startswith(shapes):
+        m = re.search(r"%(\S+) = (\w+\[[\d,]*\])\S* ([\w-]+)\(", line)
+        if m and not fused and m.group(2).startswith(shapes) and (
+                ops is None or m.group(3) in ops
+                or m.group(3) == "fusion" and m.group(1).startswith(ops)):
             out.append(line.strip()[:140])
     return out
 

@@ -455,6 +455,308 @@ def test_flash_plan_is_where_the_wrappers_take_their_block(monkeypatch):
         assert _rel(a, b) < 2e-5
 
 
+def _heads(t, n):
+    """[B, S, n D] -> [B, n, S, D]"""
+    b, s, w = t.shape
+    return t.reshape(b, s, n, w // n).transpose(0, 2, 1, 3)
+
+
+def _packed_and_reference(qkv, g, n_head, **kwargs):
+    """The packed entry interpreted, and ``attention_reference`` in
+    float32 on the same (upcast) ``qkv`` through split + heads + the
+    transpose back: (out, dqkv) of each."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops import attention as A
+
+    def packed(qkv):
+        return A.flash_attention_packed(
+            qkv, n_head, **{"causal": True, "force_pallas": True,
+                            "interpret": True, "exact": False, **kwargs})
+
+    def ref(qkv):
+        q, k, v = (_heads(t, n_head) for t in jnp.split(qkv, 3, axis=-1))
+        y = A.attention_reference(q, k, v, causal=True)
+        return y.transpose(0, 2, 1, 3).reshape(g.shape)
+    out, vjp = jax.vjp(packed, qkv)
+    out_ref, vjp_ref = jax.vjp(ref, qkv.astype(jnp.float32))
+    return ((out,) + vjp(g.astype(out.dtype)),
+            (out_ref,) + vjp_ref(g.astype(jnp.float32)))
+
+
+def _qkv_and_g(seed, b, s, n_head, d, dtype="float32"):
+    import jax
+    import jax.numpy as jnp
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return (jax.random.normal(keys[0], (b, s, 3 * n_head * d),
+                              jnp.float32).astype(dtype),
+            jax.random.normal(keys[1], (b, s, n_head * d),
+                              jnp.float32).astype(dtype))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("s", [256, 1024])
+@pytest.mark.parametrize("d,n_head", [(64, 12), (64, 2), (128, 4)])
+def test_packed_pair_matches_reference(d, n_head, s, dtype):
+    """Forward and the gradient with respect to ``qkv`` of the packed
+    kernels (a pair of 64-wide heads, or one 128-wide head, a program;
+    blocks cut from [B, S, 3E] where it lies) against the float32
+    reference on the same ``qkv``, q's, k's and v's columns apart."""
+    import jax.numpy as jnp
+    from ray_tpu.ops import attention as A
+
+    assert A.packed_heads(s, n_head, n_head, d, True, False) == 128 // d
+    qkv, g = _qkv_and_g(s + d + n_head, 1, s, n_head, d, dtype)
+    got, want = _packed_and_reference(qkv, g, n_head)
+    limit = 2e-5 if dtype == "float32" else 2e-2
+    assert got[0].shape == g.shape and got[1].shape == qkv.shape
+    parts = [("out", got[0], want[0])] + [
+        (name, a, b) for name, a, b in zip(
+            ("dq", "dk", "dv"), jnp.split(got[1], 3, axis=-1),
+            jnp.split(want[1], 3, axis=-1))]
+    for name, a, b in parts:
+        assert a.dtype == jnp.dtype(dtype)
+        assert _rel(a, b) < limit, (name, _rel(a, b))
+
+
+@pytest.mark.parametrize("d", [64, 32])
+def test_packed_heads_of_a_program_do_not_leak(d):
+    """The heads of a 128-lane block are told apart by lane: one head's
+    keys and values made large (its queries and its share of ``do`` too)
+    leave every other head's output and gradients bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    n_head, s = 256 // d, 256
+    e = n_head * d
+    qkv, g = _qkv_and_g(7, 1, s, n_head, d)
+    loud = 1                                    # the block's second head
+    cols = (jnp.arange(3 * e) % e) // d == loud
+    qkv_loud = jnp.where(cols, 3.0 * qkv + 1.0, qkv)
+    g_loud = jnp.where(cols[:e], 5.0 * g, g)
+    (out, dqkv), _ = _packed_and_reference(qkv, g, n_head)
+    (out_loud, dqkv_loud), _ = _packed_and_reference(qkv_loud, g_loud,
+                                                     n_head)
+    quiet = ~np.asarray(cols)
+    np.testing.assert_array_equal(np.asarray(out)[..., quiet[:e]],
+                                  np.asarray(out_loud)[..., quiet[:e]])
+    np.testing.assert_array_equal(np.asarray(dqkv)[..., quiet],
+                                  np.asarray(dqkv_loud)[..., quiet])
+    assert not np.array_equal(np.asarray(out)[..., ~quiet[:e]],
+                              np.asarray(out_loud)[..., ~quiet[:e]])
+
+
+def test_packed_gives_what_the_head_major_kernels_give():
+    """One algorithm, two layouts: the same products in the same
+    precision, so in bfloat16 the packed pair's output and gradient are
+    the [b h, s, d] kernels' (on the chip to the last bit, my chip run,
+    PR 51; here a 128-deep float32 sum with 64 zeros in it may round
+    another way than the 64-deep one: a few values one bfloat16 step
+    apart, 1e-4 of the whole where the float32 reference is 2e-3 away)."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops import attention as A
+
+    n_head, d, s = 4, 64, 512
+    qkv, g = _qkv_and_g(51, 2, s, n_head, d, "bfloat16")
+
+    def head_major(qkv):
+        q, k, v = (_heads(t, n_head) for t in jnp.split(qkv, 3, axis=-1))
+        y = A.flash_attention(q, k, v, causal=True, force_pallas=True,
+                              interpret=True, exact=False)
+        return y.transpose(0, 2, 1, 3).reshape(g.shape)
+    out, vjp = jax.vjp(head_major, qkv)
+    got, _ = _packed_and_reference(qkv, g, n_head)
+    assert _rel(got[0], out) < 1e-4, _rel(got[0], out)
+    assert _rel(got[1], vjp(g)[0]) < 1e-4, _rel(got[1], vjp(g)[0])
+
+
+def test_packed_dk_dv_are_summed_in_float32():
+    """As ``test_whole_kv_causal_dk_dv_are_summed_in_float32`` holds of
+    the [b h, s, d] form: at s = 2,048 (8 query blocks) the packed
+    kernel's ``dk`` and ``dv`` in bfloat16 lie closer to the float32
+    reference than the blocks' exact parts in a bfloat16 running sum."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops import attention as A
+
+    s, d, n_head = 2048, 64, 2
+    e = n_head * d
+    bq = A.flash_plan(s, s, d, True, False)["block_q"]
+    qkv, g = _qkv_and_g(2048, 1, s, n_head, d, "bfloat16")
+    got, want = _packed_and_reference(qkv, g, n_head)
+
+    def ref(qkv):
+        q, k, v = (_heads(t, n_head) for t in jnp.split(qkv, 3, axis=-1))
+        y = A.attention_reference(q, k, v, causal=True)
+        return y.transpose(0, 2, 1, 3).reshape(g.shape)
+    _, vjp = jax.vjp(ref, qkv.astype(jnp.float32))
+    running = jnp.zeros((1, s, 2 * e), jnp.bfloat16)
+    for lo in range(0, s, bq):
+        rows = (jnp.arange(s) >= lo) & (jnp.arange(s) < lo + bq)
+        part = vjp(jnp.where(rows[None, :, None],
+                             g.astype(jnp.float32), 0.0))[0][..., e:]
+        running = (running.astype(jnp.float32) + part).astype(jnp.bfloat16)
+    for name, cols in (("dk", slice(e, 2 * e)), ("dv", slice(2 * e, None))):
+        a, b = got[1][..., cols], want[1][..., cols]
+        r = running[..., cols.start - e:(cols.stop or 3 * e) - e]
+        assert _rel(a, b) < 0.8 * _rel(r, b), (name, _rel(a, b), _rel(r, b))
+
+
+def _tp_mesh():
+    import jax
+    from ray_tpu.parallel.mesh import MeshSpec
+    return MeshSpec(dp=2, tp=2).build(jax.devices("cpu")[:4])
+
+
+@pytest.mark.parametrize("case,n_head,n_kv,d,kwargs,mesh", [
+    ("an odd head count", 3, 3, 64, {}, None),
+    ("a head of 96", 2, 2, 96, {}, None),
+    ("no causal", 2, 2, 64, {"causal": False}, None),
+    ("exact", 2, 2, 64, {"exact": True}, None),
+    ("grouped kv heads", 4, 2, 64, {}, None),
+    ("a tp mesh", 4, 4, 64, {}, _tp_mesh)], ids=lambda v: v if isinstance(
+        v, str) else "")
+def test_what_the_packed_kernels_cannot_serve_takes_the_heads(
+        cpu_mesh8, case, n_head, n_kv, d, kwargs, mesh):
+    """The shape rule: each of these is split + heads +
+    ``flash_attention`` + the transpose back, traced so (the event says
+    ``packed`` false, a transpose is in the jaxpr) and with that answer,
+    forward and gradient."""
+    import contextlib
+    from collections import deque
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu._private import tracing
+    from ray_tpu.ops import attention as A
+
+    s = 256
+    mesh = mesh and mesh()
+    kwargs = {"causal": True, "exact": False, "force_pallas": True,
+              "interpret": True, **kwargs}
+    assert A.packed_heads(s, n_head, n_kv, d, kwargs["causal"],
+                          kwargs["exact"], mesh) == 0
+    keys = jax.random.split(jax.random.PRNGKey(n_head + d), 2)
+    qkv = jax.random.normal(keys[0], (2, s, (n_head + 2 * n_kv) * d))
+    g = jax.random.normal(keys[1], (2, s, n_head * d))
+
+    def packed(qkv):
+        return A.flash_attention_packed(qkv, n_head, n_kv_head=n_kv,
+                                        **kwargs)
+
+    def old(qkv):
+        q, k, v = jnp.split(qkv, [n_head * d, (n_head + n_kv) * d], axis=-1)
+        k, v = (jnp.repeat(_heads(t, n_kv), n_head // n_kv, axis=1)
+                for t in (k, v))
+        y = A.flash_attention(_heads(q, n_head), k, v, **kwargs)
+        return y.transpose(0, 2, 1, 3).reshape(g.shape)
+    ring = deque()
+    with A.attention_mesh(mesh) if mesh else contextlib.nullcontext():
+        with tracing.step_span("test.rule", ring):
+            text = str(jax.make_jaxpr(packed)(qkv))
+        got, vjp = jax.vjp(packed, qkv)
+        want, vjp_old = jax.vjp(old, qkv)
+        dgot, dwant = vjp(g)[0], vjp_old(g)[0]
+    plans = [e["attrs"] for e in ring[0]["children"]
+             if e["name"] == "attention.flash_plan"]
+    assert [(p["packed"], p["heads_per_program"]) for p in plans] \
+        == [(False, 1)]
+    assert "transpose" in text
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(dgot), np.asarray(dwant))
+
+
+def test_packed_path_traces_no_split_and_no_transpose():
+    """Where the packed kernels run nothing is laid out anew for them:
+    forward + backward are the two kernels (the backward's [1, bq] rows
+    of ``lse`` are turned inside it) and the event says so."""
+    from collections import deque
+    import jax
+    from ray_tpu._private import tracing
+    from ray_tpu.ops import attention as A
+
+    qkv, g = _qkv_and_g(3, 2, 256, 4, 64)
+    ring = deque()
+
+    def packed(qkv):
+        return A.flash_attention_packed(qkv, 4, causal=True,
+                                        force_pallas=True, interpret=True)
+    with tracing.step_span("test.packed", ring):
+        jaxpr = jax.make_jaxpr(
+            lambda qkv, g: jax.vjp(packed, qkv)[1](g))(qkv, g)
+    outside = {str(eqn.primitive) for eqn in jaxpr.jaxpr.eqns}
+    assert not outside & {"transpose", "split", "concatenate", "slice",
+                          "reshape", "mul"}, outside
+    plans = [e["attrs"] for e in ring[0]["children"]
+             if e["name"] == "attention.flash_plan"]
+    assert plans == [{**A.flash_plan(256, 256, 64, True),
+                      "packed": True, "heads_per_program": 2}]
+
+
+def test_packed_batch_mesh_runs_each_device_its_rows(cpu_mesh8):
+    """A mesh that shards the batch alone keeps the packed kernels, each
+    device its rows inside ``shard_map``; the answer is the one
+    device's."""
+    import jax
+    from ray_tpu.ops import attention as A
+    from ray_tpu.parallel.mesh import MeshSpec
+
+    mesh = MeshSpec(dp=2, fsdp=2).build(jax.devices("cpu")[:4])
+    assert A.packed_heads(256, 2, 2, 64, True, False, mesh) == 2
+    qkv, g = _qkv_and_g(4, 4, 256, 2, 64)
+    alone, _ = _packed_and_reference(qkv, g, 2)
+    with A.attention_mesh(mesh):
+        text = str(jax.make_jaxpr(lambda x: A.flash_attention_packed(
+            x, 2, causal=True, force_pallas=True, interpret=True))(qkv))
+        meshed, _ = _packed_and_reference(qkv, g, 2)
+    assert "shard_map" in text
+    for a, b in zip(meshed, alone):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+
+
+def test_gpt2_with_the_packed_kernels_matches_the_reference_backend(
+        monkeypatch):
+    """The model's loss and parameter gradients through the packed path
+    (two 64-wide heads: one pair a program) against
+    ``attention_backend="reference"`` with the same parameters."""
+    import dataclasses
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models.gpt2 import GPT2, GPT2Config
+    from ray_tpu.ops import attention as A
+
+    flash = dataclasses.replace(
+        GPT2Config.tiny(), n_embd=128, n_head=2, attention_backend="flash")
+    ref = dataclasses.replace(flash, attention_backend="reference")
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0,
+                             flash.vocab_size)
+    params = GPT2(ref).init(jax.random.PRNGKey(0), ids)
+    calls = []
+
+    def interpreted(qkv, n_head, **kwargs):
+        calls.append(A.packed_heads(qkv.shape[1], n_head, n_head,
+                                    qkv.shape[2] // 3 // n_head, True))
+        return real(qkv, n_head, force_pallas=True, interpret=True,
+                    **kwargs)
+    real = A.flash_attention_packed
+    monkeypatch.setattr(A, "flash_attention_packed", interpreted)
+
+    def loss(cfg):
+        def f(params):
+            logits = GPT2(cfg).apply(params, ids)
+            logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
+            return -jnp.take_along_axis(
+                logp, ids[:, 1:, None], axis=-1).mean()
+        return jax.value_and_grad(f)(params)
+    got, dgot = loss(flash)
+    want, dwant = loss(ref)
+    assert calls == [2] * flash.n_layer
+    assert abs(float(got) - float(want)) < 1e-5 * abs(float(want))
+    for a, b in zip(jax.tree.leaves(dgot), jax.tree.leaves(dwant)):
+        assert _rel(a, b) < 1e-4, _rel(a, b)
+
+
 def test_ring_attention_matches_full(cpu_mesh8):
     import jax
     import jax.numpy as jnp

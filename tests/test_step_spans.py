@@ -664,16 +664,21 @@ def test_train_step_jaxpr_names_its_kernels(tiny_train_step, kernel):
 
 
 def test_tracing_a_train_step_leaves_its_flash_plans(tiny_train_step):
-    """One ``attention.flash_plan`` event a ``flash_attention`` call (one
-    a layer), with the numbers of the plan the kernels were laid out by."""
-    from ray_tpu.ops.attention import flash_plan
+    """One ``attention.flash_plan`` event a ``flash_attention_packed``
+    call (one a layer), with the numbers of the plan the kernels were laid
+    out by and the layout: the tiny model's four 32-wide heads are one
+    128-lane block of ``c_attn``'s output, so the packed kernels run."""
+    from ray_tpu.ops.attention import flash_plan, packed_heads
     cfg = tiny_train_step["cfg"]
     plans = [e["attrs"] for e in tiny_train_step["events"]
              if e["name"] == "attention.flash_plan"]
     assert len(plans) == cfg.n_layer == 2
-    want = flash_plan(128, 128, cfg.n_embd // cfg.n_head, True)
+    head_dim = cfg.n_embd // cfg.n_head
+    want = flash_plan(128, 128, head_dim, True)
     assert want["path"] == "whole_kv_causal"
     assert want["blocks_visited"] == want["blocks_total"] == 1
+    assert packed_heads(128, cfg.n_head, cfg.n_head, head_dim, True) == 4
+    want = {**want, "packed": True, "heads_per_program": 4}
     assert plans == [want, want]
 
 
