@@ -46,7 +46,8 @@ attention runs follows from what the call can observe
 Weights are stored and multiplied in ``dtype`` (bfloat16 as served);
 norms, the router, the gate, rotary angles and the softmax are float32.
 
-Device-trace scopes: ``attn_full/{qkv,rope,write,attend,gate,out}`` and
+Device-trace scopes: ``attn_full/{qkv,norm,rope,write,attend,gate,out}``
+(``norm``: a family with a query/key norm) and
 ``attn_window/{...}``, ``moe/router``, ``moe/experts``, ``moe/shared``,
 ``mlp``, ``lm_head``.
 """
@@ -237,7 +238,9 @@ class LagunaAttention(nn.Module):
     full layer) or the positions a sliding layer reads. ``config`` is a
     ``LagunaConfig``, or another family's with the same names
     (``models/smallthinker.py``: its ``rope_of`` gives None for a layer
-    type without rotary, and it has no gate)."""
+    type without rotary, and it has no gate; ``models/lfm2.py``: its
+    ``qk_norm`` puts q and k under an RMSNorm over a head's values, one
+    learned gain of ``head_dim`` each, before the rotary)."""
     config: Any
     heads: int
     window: Optional[int]
@@ -269,6 +272,10 @@ class LagunaAttention(nn.Module):
         served = k_pages is not None
         at = jnp.arange(S)[None, :] + (
             seq_lengths[:, None] if served else jnp.zeros((B, 1), jnp.int32))
+        if getattr(cfg, "qk_norm", False):
+            with jax.named_scope(f"{scope}/norm"):
+                q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(q).astype(dt)
+                k = RMSNorm(cfg.rms_norm_eps, name="k_norm")(k).astype(dt)
         if rope is not None:        # (None: no position encoding, NoPE)
             with jax.named_scope(f"{scope}/rope"):
                 turn = rope.cos_sin(at)

@@ -133,10 +133,28 @@ class SwiGLU(nn.Module):
 
 # RoutedExperts: the most tokens that go as whole rows through the touched
 # experts. Up to WHOLE_ROWS_BELOW an expert's weights (read once) outweigh
-# the rows it did not get; above, rows are worth sorting. What is served
-# lies far to either side (a decode step has at most 64 tokens, a prefill
-# at least 512), so it was set by that and not by a sweep: nothing between
-# was measured.
+# the rows it did not get; above, rows are worth sorting. A touched expert
+# multiplies ALL the rows, 2 FLOPs a row for each 2-byte weight it reads,
+# so its product stands on the chip's ridge where the rows are the peak
+# over the bandwidth (a v5e: 197 TFLOP/s over 819 GB/s = 240 rows) and is
+# bound by the weights below it: 256 is that ridge in whole row tiles.
+# Measured AT it (my chip run, PR 53: LFM2-8B-A1B, 32 experts of 2048 x
+# 1792, 4 a token, all held, 256 rows a step, so an expert expects 32
+# tokens and the touched form multiplies 8 times the routed pairs, the
+# sorted form at blocks of 128 rows 4 times; at least 28 experts touched):
+# a layer alone 0.967 ms touched (its 705 MB of weights take 0.86 at the
+# bandwidth) against 1.054 sorted into blocks of 128 rows, 1.111 of 64,
+# 1.505 of 32, 1.248 of 256; with 160 or 129 of the 256 rows real the same
+# order (0.969 / 1.054 and 0.942 / 1.022 / 0.999 of 64); inside the 14
+# routed layers of the b256 decode program 19.41 ms a step touched against
+# 20.46 (blocks of 128) and 21.73 (of 64), and 18.45 against 19.45 with 160
+# rows real: the touched form wins by 5% of a step, because both read the
+# same weights and the sorted one pays its sort, its gathers and 32 more
+# grid steps. Whatever an expert expects (``T * top_k`` over the router's
+# width: 32 there, the most of any model served here; fewer pairs an expert
+# leave the sorted form less to save), a bucket of 256 rows goes whole.
+# Above 256 rows and below 512 no program exists (a bucket is a power of
+# two) and nothing was measured.
 WHOLE_ROWS_BELOW = 256
 # The grouped product's sorted rows (bfloat16 in, float32 out) for the
 # worst case (every assignment landing on the experts held here) are held
@@ -213,8 +231,9 @@ class RoutedExperts(nn.Module):
     The router scores every token against its ``num_experts +
     zero_experts`` outputs (``score``: ``sigmoid``, or ``softmax`` over
     the whole width; float32), chooses the ``top_k`` largest of ``score +
-    bias``, and weighs the chosen by ``score / sum(chosen scores)`` (if
-    ``renormalize``; else the score as it is) times ``scaling``. Of the
+    bias``, and weighs the chosen by ``score / (sum(chosen scores) +
+    renormalize_eps)`` (if ``renormalize``; else the score as it is)
+    times ``scaling``. Of the
     chosen, this layer computes those in ``held = (first, count)``: the
     part of the result that its own gated experts give (``act``: the
     gate's activation, ``"silu"`` or ``"relu"``), plus
@@ -258,6 +277,8 @@ class RoutedExperts(nn.Module):
     score: str = "sigmoid"                       # | "softmax"
     zero_experts: int = 0           # router outputs beyond the real experts
     act: str = "silu"                            # | "relu"
+    # added to the chosen scores' sum before the division (LFM2's 1e-6)
+    renormalize_eps: float = 0.0
 
     @nn.compact
     def __call__(self, x, valid=None, router_x=None):
@@ -282,7 +303,9 @@ class RoutedExperts(nn.Module):
             _, chosen = jax.lax.top_k(scores + bias, self.top_k)
             w = jnp.take_along_axis(scores, chosen, axis=1)    # [T, k]
             if self.renormalize:
-                w = w / jnp.sum(w, axis=-1, keepdims=True)
+                total = jnp.sum(w, axis=-1, keepdims=True)
+                w = w / (total + self.renormalize_eps
+                         if self.renormalize_eps else total)
             w = w * self.scaling
             here = (chosen >= first) & (chosen < first + count) \
                 & real[:, None]

@@ -55,7 +55,8 @@ Two implementations:
   batched speculative verification is numerically the plain decode loop.
 
 A model may state what it caches instead (``kind="kimi_linear"``,
-``kind="kimi_k2"``, ``kind="jamba"``: the models' ``cache_spec``): pages
+``kind="kimi_k2"``, ``kind="jamba"``, ``kind="lfm2"``: the models'
+``cache_spec``): pages
 for some or all layers, of the row width it names, and per-sequence
 *state* for others (Kimi-K2 names one latent pool and no state; Jamba K
 and V pages for one layer in fourteen and a Mamba state for the rest). A
@@ -64,7 +65,11 @@ state array that a decode step's recurrence runs over names its kind
 kind's chooser: ``bind_state`` asks it with the pool, the dispatch span
 says what it chose, and ``counters()`` counts the steps that took a kernel
 (``recurrence_kernel_steps_total``); a further kind is a further entry
-of that table and no further case anywhere. The
+of that table and no further case anywhere. A state may name no
+recurrence at all (LFM2: the two-row tail of its gated convolutions and
+nothing else): it has its slots, its admission and its refusals like any
+state, its dispatch spans name no ``recurrence`` and ``counters()`` has
+none of a recurrence's keys. The
 adapter then owns one pool a named page kind and one array a named state
 kind with a **state slot** per running sequence (slot 0 is the null
 slot, where padding rows read and write): a slot is taken and zeroed
@@ -468,6 +473,13 @@ class FlaxModelAdapter:
             self._blocks = None            # the model stacks its own runs
             self.vocab_size = self.cfg.vocab_size
             self._spec = jamba.cache_spec(self.cfg)
+        elif kind == "lfm2":
+            from ray_tpu.models import lfm2
+            self.cfg = config or lfm2.Lfm2Config.tiny()
+            self.model = lfm2.Lfm2Model(self.cfg)
+            self._blocks = None            # operator and feed-forward vary
+            self.vocab_size = self.cfg.vocab_size
+            self._spec = lfm2.cache_spec(self.cfg)
         else:
             raise ValueError(f"unknown model kind {kind!r}")
         if params is None:
@@ -891,7 +903,7 @@ class FlaxModelAdapter:
                                first_call=(B, S, full) not in self._fns,
                                **({"linear": linear} if linear else {}),
                                **({"expert_product": product.name}
-                                  if product and op != "decode" else {}),
+                                  if product else {}),
                                **(self._count_pages(rows, B)
                                   if op == "decode" else
                                   {"prompt_tokens": sum(len(r["tokens"])
@@ -1229,19 +1241,21 @@ class FlaxModelAdapter:
             self._free_slots.append(st["slot"])
 
 
+# the kinds ``FlaxModelAdapter`` builds (tests/test_llm_lfm2_serving.py
+# holds that each of them does)
+FLAX_KINDS = ("gpt2", "llama", "kimi_linear", "kimi_k2", "laguna",
+              "longcat_flash", "smallthinker", "jamba", "lfm2")
+
+
 def make_adapter(model: str = "toy",
                  model_config: Optional[Dict[str, Any]] = None):
-    """Deployment-facing factory: ``model`` is ``toy`` |
-    ``gpt2`` | ``llama`` | ``kimi_linear`` | ``kimi_k2`` | ``laguna`` |
-    ``longcat_flash`` | ``smallthinker`` | ``jamba``
-    (tiny test configs unless ``model_config`` overrides)."""
+    """Deployment-facing factory: ``model`` is ``toy`` or one of
+    ``FLAX_KINDS`` (tiny test configs unless ``model_config``
+    overrides)."""
     model_config = dict(model_config or {})
     if model == "toy":
         return ToyAdapter(**model_config)
-    if model in ("gpt2", "llama", "kimi_linear", "kimi_k2", "laguna",
-                 "longcat_flash", "smallthinker", "jamba"):
+    if model in FLAX_KINDS:
         return FlaxModelAdapter(kind=model, **model_config)
     raise ValueError(
-        f"unknown model {model!r} "
-        "(toy | gpt2 | llama | kimi_linear | kimi_k2 | laguna | "
-        "longcat_flash | smallthinker | jamba)")
+        f"unknown model {model!r} ({' | '.join(('toy',) + FLAX_KINDS)})")

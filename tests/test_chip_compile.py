@@ -229,8 +229,9 @@ def test_bare_pallas_call_is_refused_on_a_mesh(topo):
         _compile(_flash(None, False), *_qkv((32, 12, 1024, 64), sharding))
 
 
-@pytest.mark.parametrize("H,Hkv,D", [(12, 12, 64), (32, 8, 128)],
-                         ids=["gpt2-small", "llama-gqa"])
+@pytest.mark.parametrize("H,Hkv,D", [(12, 12, 64), (32, 8, 128),
+                                     (32, 8, 64)],
+                         ids=["gpt2-small", "llama-gqa", "lfm2-gqa-64"])
 def test_paged_attention_decode(one_chip, H, Hkv, D):
     """B=8 sequences against a pool of 4 layers of 16-token pages, 64
     pages each, the layer a traced scalar."""
@@ -1318,6 +1319,108 @@ def test_jamba_programs_fit_the_chip_whole(one_chip, topo, monkeypatch, B, S,
         assert f"[{B},1536," not in text    # no table gathered whole
     else:       # a prompt's scan is XLA's loop over its positions
         assert not any("mamba_recurrence" in c for c in calls)
+
+
+def _lfm2_cell():
+    """(engine, model kwargs) of the cell lfm2_8b_a1b.serve_closed256_1k,
+    from its configuration file."""
+    import json
+    import os
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "lfm2_8b_a1b.json")) as f:
+        cfg = json.load(f)
+    return cfg["serve"]["engine"], cfg["model"]["kwargs"]
+
+
+def _lfm2_step(one_chip, topo, monkeypatch, B, S):
+    """``FlaxModelAdapter``'s step for LFM2 as the cell lfm2_8b_a1b.
+    serve_closed256_1k runs it: the published widths, layers 0-15 with
+    all 32 experts, the whole vocabulary, K and V pools of ``num_blocks``
+    pages for the four attention layers under tables of 128, 256 tail
+    slots and the null one."""
+    from benchmark.reference import lfm2_glue as glue
+    from ray_tpu.serve.llm.kv_cache import PagedKVCache
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    engine, kwargs = _lfm2_cell()
+    cfg = glue.model_config({
+        "factory": "ray_tpu.models.lfm2:Lfm2Config", "kwargs": kwargs})
+    adapter = FlaxModelAdapter("lfm2", cfg, params={})
+    params = jax.tree_util.tree_map(
+        lambda s: sds(s.shape, s.dtype),
+        jax.eval_shape(adapter.model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8), jnp.int32)))
+    adapter.bind_cache(PagedKVCache(2, 16))
+    adapter.bind_state(1)
+    adapter.state_slots = engine["max_running"]
+    assert adapter.nb_max == 128
+    arrays = [sds((a.shape[0], engine["num_blocks"]
+                   if name in adapter._spec["pages"]
+                   else engine["max_running"] + 1, *a.shape[2:]), a.dtype)
+              for name, a in adapter._arrays.items()]
+    with monkeypatch.context() as m:
+        m.setattr(jax, "devices", lambda *a, **k: topo.devices)
+        fn = adapter._step_fn(B, S)
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    with jax.default_matmul_precision("default"):
+        return params, arrays, fn.lower(
+            params, sds((B, S + 3 + 128), jnp.int32),
+            *_last_tokens(sds, engine["num_blocks"], S), *arrays).compile()
+
+
+@pytest.mark.parametrize("B,S,temp_gib", [
+    (256, 1, 0.067), (8, 1, 0.03), (4, 1024, 0.603), (8, 512, 0.411)],
+    ids=["decode_b256_by_slot", "decode_b8", "prefill_4x1024",
+         "prefill_8x512"])
+def test_lfm2_programs_fit_the_chip_with_every_expert(one_chip, topo,
+                                                      monkeypatch, B, S,
+                                                      temp_gib):
+    """The full decode bucket (rows in slot order), a narrow one (rows by
+    ``slots``) and the two largest prefill programs of the cell, compiled
+    for the described v5e: 10.06 GiB of weights (16 layers, all 32
+    experts, the whole vocabulary), 2.25 GiB of pages and 24 MiB of tails
+    as arguments, the three arrays donated and written in place,
+    temporaries as read (and a tenth): 12.94 of 15.75 GiB at the most. A
+    decode step's routed product is ONE Mosaic call a routed layer (14)
+    over that layer's experts where they lie: nothing of an expert
+    stack's size is copied, sliced or laid out anew in any program (a
+    loop over stacked layers did copy it: models/lfm2.py). Its attention
+    is the paged kernel at groups of 4 heads of 64 in all four attention
+    layers, and no table is gathered whole."""
+    import math
+    engine, _ = _lfm2_cell()
+    params, arrays, step = _lfm2_step(one_chip, topo, monkeypatch, B, S)
+    memory = step.memory_analysis()
+    gib = 2.0 ** 30
+    held = sum(math.prod(s.shape) * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    assert held == 10_800_230_144
+    pools = sum(math.prod(a.shape) * a.dtype.itemsize for a in arrays)
+    assert pools == 2_416_050_176 + 25_264_128
+    assert pools <= memory.alias_size_in_bytes <= 1.02 * pools
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    print(f"lfm2 b{B} s{S}: arguments "
+          f"{memory.argument_size_in_bytes / gib:.3f} GiB, temporaries "
+          f"{memory.temp_size_in_bytes / gib:.3f}, total {total / gib:.3f}")
+    assert memory.temp_size_in_bytes < 1.1 * temp_gib * gib + (1 << 20)
+    assert total < 13.0 * gib
+    assert total > 0.25 * 15.75 * gib       # the cell's floor, by far
+    text = step.as_text()
+    # an expert stack [32, 2048, 1792] is an argument and nothing else
+    assert all(" parameter(" in line for line in _materialized(
+        text, "bf16[32,2048,1792]", "bf16[32,1792,2048]"))
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    if S == 1:
+        assert sum("routed_experts_" in c for c in calls) == 14
+        assert sum("paged_attention_decode" in c for c in calls) == 4
+        assert f"[{B},2048,512]" not in text    # no table gathered whole
+    else:       # 4,096 padded tokens: sorted row blocks
+        assert sum("routed_experts_grouped" in c for c in calls) == 14
+        assert not any("paged_attention_decode" in c for c in calls)
 
 
 @pytest.mark.parametrize("kind,config", [

@@ -459,3 +459,103 @@ def test_an_expert_width_of_three_tiles():
         np.testing.assert_allclose(y, want, atol=TOL)
     finally:
         RE._FF_TILES = tiles
+
+
+# ---- the rows between 128 and 512 (PR 53: LFM2's 256-row decode step) ----
+
+# what ``cache_spec`` gives ``expert_product`` beside a step's tokens for
+# each routed model the benchmark serves (top_k, router's experts, held,
+# hidden, itemsize, zero-compute outputs)
+SERVED = {
+    "kimi_linear": (8, 256, 64, 2304, 2, 0),
+    "kimi_k2": (8, 384, 12, 7168, 2, 0),
+    "laguna": (8, 256, 256, 2048, 2, 0),
+    "longcat_flash": (12, 512, 16, 6144, 2, 256),
+    "smallthinker": (6, 64, 64, 2560, 2, 0),
+    "lfm2": (4, 32, 32, 2048, 2, 0),
+}
+# T -> what the tree before PR 53 chose (name, block rows, rows held) for
+# each of them, in SERVED's order
+AT_THE_PARENT = {
+    1: [("touched_kernel", 16, 16)] * 6,
+    64: [("touched_kernel", 64, 64)] * 6,
+    128: [("touched_kernel", 128, 128)] * 6,
+    512: [("grouped_kernel", 128, 12288), ("grouped_kernel", 128, 5632),
+          ("grouped_kernel", 128, 36864), ("grouped_kernel", 128, 8192),
+          ("grouped_kernel", 128, 11264), ("grouped_kernel", 128, 6144)],
+    2048: [("grouped_kernel", 128, 24576), ("grouped_kernel", 128, 768),
+           ("grouped_kernel", 128, 49152), ("grouped_kernel", 128, 896),
+           ("grouped_kernel", 256, 28672), ("grouped_kernel", 256, 16384)],
+    8192: [("grouped_kernel", 256, 81920), ("grouped_kernel", 256, 768),
+           ("grouped_kernel", 256, 131072), ("grouped_kernel", 128, 896),
+           ("grouped_kernel", 256, 65536), ("grouped_kernel", 256, 40960)],
+}
+
+
+@pytest.mark.parametrize("T", list(AT_THE_PARENT))
+def test_at_most_128_and_at_least_512_rows_choose_as_they_did(T):
+    """The choice between 128 and 512 rows was measured at PR 53; at
+    most 128 and at least 512 rows choose what they chose before it, for
+    every routed model served."""
+    for shapes, want in zip(SERVED.values(), AT_THE_PARENT[T]):
+        assert tuple(moe.expert_product(T, *shapes)) == want
+
+
+@pytest.mark.parametrize("model", list(SERVED))
+def test_a_bucket_of_256_rows_goes_whole_whatever_an_expert_expects(model):
+    """256 rows are the one bucket between 128 and 512 (a power of two).
+    The touched form won there at LFM2's shape, where an expert expects
+    the most tokens of any model served (32): moe.py has the readings."""
+    shapes = SERVED[model]
+    top_k, experts = shapes[0], shapes[1] + shapes[5]
+    assert 256 * top_k / experts <= 32
+    assert tuple(moe.expert_product(256, *shapes)) == (
+        "touched_kernel", 256, 256)
+    # 129-255 real rows run the 256-row program: its plan reads the
+    # bucket, never the rows that are real
+    assert tuple(moe.expert_product(160, *shapes)) == (
+        "touched_kernel", 160, 160)
+
+
+@pytest.mark.parametrize("real", [256, 129])
+def test_both_forms_give_one_result_at_lfm2s_shape(real, monkeypatch):
+    """32 sigmoid-scored experts, 4 a token under a selection bias, the
+    1e-6 in the renormalisation, 256 rows of which ``real`` are real: the
+    touched form (what ``expert_product`` chooses) and the sorted form at
+    blocks of 128 and of 64 rows (forced) give the same rows and the same
+    counts, and both are the dense sum's."""
+    T, experts, top_k, d_ff = 256, 32, 4, 24
+    rng = np.random.default_rng(real)
+    x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+    layer = RoutedExperts(experts, d_ff, top_k, renormalize=True,
+                          renormalize_eps=1e-6, dtype=jnp.float32)
+    p = _params(layer, T, x, jnp.asarray(0.1 * rng.normal(size=experts),
+                                         jnp.float32))
+    valid = jnp.arange(T) < real
+    assert moe.expert_product(T, top_k, experts, experts, D, 4).name \
+        == "touched_kernel"
+    y, counts = jax.jit(layer.apply)({"params": p}, x, valid=valid)
+    assert int(counts.sum()) == real * top_k
+    # the dense sum, with the bias in the choice alone and the epsilon
+    scores = jax.nn.sigmoid(x @ p["router"])
+    _, chosen = jax.lax.top_k(scores + p["router_bias"], top_k)
+    w = jnp.take_along_axis(scores, chosen, axis=1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    combine = jnp.sum(jax.nn.one_hot(chosen, experts) * w[..., None]
+                      * valid[:, None, None], axis=1)
+    h = jax.nn.silu(jnp.einsum("td,edf->etf", x, p["w_gate"])) \
+        * jnp.einsum("td,edf->etf", x, p["w_up"])
+    want = jnp.einsum("etf,efd->td", h * combine.T[..., None], p["w_down"])
+    np.testing.assert_allclose(y, want, atol=TOL)
+    assert float(jnp.max(jnp.abs(want))) > 1000 * TOL
+    real_product = moe.expert_product
+    for bm in (128, 64):
+        monkeypatch.setattr(
+            moe, "expert_product", lambda T, k, E, held, d, *a, bm=bm:
+            moe.ExpertProduct("grouped_kernel", bm,
+                              moe._worst_rows(T * k, held, bm)))
+        sorted_y, sorted_counts = jax.jit(layer.apply)(
+            {"params": p}, x, valid=valid)
+        np.testing.assert_array_equal(sorted_counts, counts)
+        np.testing.assert_allclose(sorted_y, y, atol=TOL)
+        monkeypatch.setattr(moe, "expert_product", real_product)
