@@ -60,6 +60,7 @@ interpreter and jax report theirs.
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import os
 import sys
@@ -435,6 +436,29 @@ class step_span:
                 (_step_roots if self.ring is None else self.ring).append(rec)
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
+
+
+@contextlib.contextmanager
+def hung_under(rec: Optional[Dict[str, Any]]):
+    """Step spans that finish in the body hang under ``rec``, a span's
+    finished record (``step_span.rec``), and not under the span open on
+    the thread: the end of work that the span gave away belongs to its
+    tree though it comes later (a prompt's ``runner.fetch`` under the
+    ``llm.step.prefill`` that dispatched its program). Such a child lies
+    outside its parent's ``t0`` .. ``t1``. The profiler's annotations are
+    where the body ran. ``None`` (the span was opened with
+    ``RTPU_TRACING=0``): the body runs as it is."""
+    if rec is None:
+        yield
+        return
+    stack = getattr(_step_tls, "stack", None)
+    if stack is None:
+        stack = _step_tls.stack = []
+    stack.append(rec)
+    try:
+        yield
+    finally:
+        stack.pop()
 
 
 def step_roots(*names: str) -> List[Dict[str, Any]]:

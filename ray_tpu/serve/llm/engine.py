@@ -45,7 +45,14 @@ the adapter can feed a row's greedy token on the device
 (``decode_ahead``) and every row of the step is greedy, the engine
 dispatches decode step n + 1 before it fetches step n, so the device
 holds its next program when one ends; the tokens, pools and state are
-those of the synchronous order (``_decode``).
+those of the synchronous order (``_decode``). A step that carries
+prompts does not wait for them either: their program is dispatched and
+left in flight (``_prefill``), the next step's decode program is given
+to the device with their rows in it, each fed its first token on the
+device, and only then are the prompts' tokens fetched and committed
+(``_land_prompt``). Where that cannot be (a sampled row, an adapter that
+returns logits, a draft model, a prefill-role sequence) the prompts are
+fetched at once.
 
 Tokens stream out through per-sequence cursors (``poll``), which the
 replica exposes as ``__llm_next__`` and the router/proxy turn into
@@ -66,7 +73,9 @@ inter-token latency per request.
 
 Step spans (``tracing.step_span``, docs/TRACING.md): every engine step
 is an ``llm.step`` tree (``llm.step.decode`` / ``.admit`` / ``.prefill``
-/ ``.commit``, the adapter's ``runner.*`` spans below them) kept in a
+/ ``.commit``, the adapter's ``runner.*`` spans below them; the fetch of
+prompts left in flight under the ``llm.step.prefill`` that dispatched
+them, a step back) kept in a
 ring of 4096 (``step_log``), and every finished request leaves one
 record in ``request_log``. Neither is sampled or shipped; both are empty
 under ``RTPU_TRACING=0``. The cumulative ``*_total`` step counters in
@@ -184,10 +193,13 @@ class Sequence:
 
 @dataclass
 class _Flying:
-    """The decode step the device has and the host has not fetched."""
+    """A program the device has and the host has not fetched: a decode
+    step, or the prompts of a prefill step."""
     seqs: List[Sequence]
-    step: Any               # the adapter's DecodeStep
+    step: Any               # the adapter's DecodeStep / PromptStep
     t0: float               # when its program could start
+    # a prompt's: the ``llm.step.prefill`` record that dispatched it
+    rec: Optional[Dict[str, Any]] = None
 
 
 class _Locked:
@@ -304,7 +316,10 @@ class LLMEngine:
         self._decode_steps_ahead_total = 0
         self._decode_tokens_discarded_total = 0
         self._flying: Optional[_Flying] = None      # the engine thread's
+        # the prompts in flight, dispatched after ``_flying``'s step
+        self._prompt: Optional[_Flying] = None      # the engine thread's
         self._prefill_steps_total = 0
+        self._prefill_steps_ahead_total = 0
         self._decode_rows_total = 0
         self._prefill_seqs_total = 0
         self._prefill_tokens_total = 0
@@ -667,6 +682,8 @@ class LLMEngine:
                 "decode_tokens_discarded_total":
                     self._decode_tokens_discarded_total,
                 "prefill_steps_total": self._prefill_steps_total,
+                "prefill_steps_ahead_total":
+                    self._prefill_steps_ahead_total,
                 "decode_rows_total": self._decode_rows_total,
                 "prefill_seqs_total": self._prefill_seqs_total,
                 "prefill_tokens_total": self._prefill_tokens_total,
@@ -727,7 +744,7 @@ class LLMEngine:
                 if self._stopped:
                     return
                 if not self._running and not self._waiting \
-                        and self._flying is None:
+                        and self._flying is None and self._prompt is None:
                     self._work_cv.wait(timeout=0.5)
                     continue
             try:
@@ -831,7 +848,8 @@ class LLMEngine:
                 if self._draft is not None:
                     if decode_seqs:
                         self._decode_spec(decode_seqs)
-                elif decode_seqs or self._flying is not None:
+                elif decode_seqs or self._flying is not None \
+                        or self._prompt is not None:
                     self._decode(decode_seqs)
                 with tracing.step_span("llm.step.admit") as span:
                     with _Locked(self, span):
@@ -862,55 +880,69 @@ class LLMEngine:
 
         Where the adapter feeds a row's greedy token on the device
         (``decode_ahead``) and no row of the step samples, step n + 1 is
-        dispatched BEFORE step n is fetched and committed: its rows are
-        step n's less those whose budget step n fills (the host can count
-        that), each fed step n's token on the device, and the sequences
-        prefilled since, fed from the host. Pages never move (a
+        dispatched BEFORE what is in flight is fetched and committed:
+        step n and, dispatched after it by the step's ``_prefill``, the
+        prompts admitted since. Its rows are step n's less those whose
+        budget step n fills (the host can count that), each fed step n's
+        token on the device; the prompts' rows less those whose budget
+        is one token, each fed its first token on the device; and the
+        sequences whose tokens the host has (adopted ones, a prompt
+        fetched at once), fed from the host. Pages never move (a
         sequence's whole budget is allocated at admission) and lengths
-        grow by one, so nothing else of step n is needed. Any other step
-        (an adapter that returns logits, a row with temperature > 0) first
-        fetches and commits the step in flight and then runs as ever.
+        grow by one, so nothing else of what is in flight is needed. Any
+        other step (an adapter that returns logits, a row with
+        temperature > 0) first fetches and commits what is in flight and
+        then runs as ever.
 
-        A row that step n ends by its ``stop_token``, or that was
-        cancelled meanwhile, is in step n + 1 all the same. Its token of
-        that step is discarded in ``_commit``, and its write is harmless:
-        it lands at a position inside the sequence's own budget, in pages
-        it owned when the step was dispatched, and every program takes
-        the pools and the state from the program before it, so the device
-        runs them in dispatch order. A prompt admitted into the freed
-        *pages* is therefore written after it; so is a sequence that takes
-        the freed *ring* (it reads no ring row it has not written
-        itself), and one that takes the freed *state slot* (zeroed by
-        ``llm_state_admit`` after it). (``_retire`` waits for the step in
-        flight before it releases a sequence, but for the device's
-        memory, not for this.)"""
+        A row that step n (or its prompt's first token) ends by its
+        ``stop_token``, or that was cancelled meanwhile, is in step n + 1
+        all the same. Its token of that step is discarded in ``_commit``,
+        and its write is harmless: it lands at a position inside the
+        sequence's own budget, in pages it owned when the step was
+        dispatched, and every program takes the pools and the state from
+        the program before it, so the device runs them in dispatch order.
+        A prompt admitted into the freed *pages* is therefore written
+        after it; so is a sequence that takes the freed *ring* (it reads
+        no ring row it has not written itself), and one that takes the
+        freed *state slot* (zeroed by ``llm_state_admit`` after it).
+        (``_retire`` waits for the newest program in flight before it
+        releases a sequence, but for the device's memory, not for
+        this.)"""
         flying, self._flying = self._flying, None
-        if flying is not None:
-            over = {s.seq_id for s in flying.seqs
+        prompt, self._prompt = self._prompt, None
+        landing = [f for f in (flying, prompt) if f is not None]
+        if landing:
+            over = {s.seq_id for f in landing for s in f.seqs
                     if len(s.tokens) + 1 >= s.sampling.max_new_tokens}
             seqs = [s for s in seqs if s.seq_id not in over]
         t0 = time.time()
         if seqs and self._looks_ahead(seqs):
             with tracing.step_span("llm.step.decode", n=len(seqs),
-                                   ahead=flying is not None):
+                                   ahead=bool(landing)):
                 step = self.adapter.decode(seqs, tokens_only=True,
                                            fetch=False)
                 self._flying = _Flying(seqs, step, t0)
                 self._decode_rows_total += len(seqs)
-                if flying is not None:
+                if landing:
                     self._decode_steps_ahead_total += 1
+                if flying is not None:
                     tokens = flying.step.fetch()
                     # its program starts when the one before it ends
                     self._flying.t0 = time.time()
             self._runner_seconds_total += time.time() - t0
             if flying is not None:
                 self._commit(flying.seqs, tokens, step_t0=flying.t0)
+            if prompt is not None:
+                self._land_prompt(prompt)
             return
-        if flying is not None:      # nothing to dispatch ahead: it lands
-            with tracing.step_span("llm.step.decode", n=0):
-                tokens = flying.step.fetch()
-            self._runner_seconds_total += time.time() - t0
-            self._commit(flying.seqs, tokens, step_t0=flying.t0)
+        if landing:                 # nothing to dispatch ahead: it lands
+            if flying is not None:
+                with tracing.step_span("llm.step.decode", n=0):
+                    tokens = flying.step.fetch()
+                self._runner_seconds_total += time.time() - t0
+                self._commit(flying.seqs, tokens, step_t0=flying.t0)
+            if prompt is not None:
+                self._land_prompt(prompt)
             with _Locked(self, self._step_span):
                 seqs = [self._seqs[sid] for sid in self._running
                         if sid in self._seqs]
@@ -925,9 +957,43 @@ class LLMEngine:
         self._runner_seconds_total += time.time() - t0
         self._commit(seqs, logits, step_t0=t0)
 
+    def _land_prompt(self, prompt: _Flying):
+        """Fetch the prompts a ``_prefill`` left in flight and commit
+        their first tokens. The fetch's record (``runner.fetch``, with
+        what the program counted) hangs under the ``llm.step.prefill``
+        that dispatched the program, where a reader of a prefill step
+        looks for it, and nowhere else."""
+        t0 = time.time()
+        with tracing.hung_under(prompt.rec):
+            tokens = prompt.step.fetch()
+        t1 = time.time()
+        self._runner_seconds_total += t1 - t0
+        if self._flying is not None:
+            # its program starts when the one before it ends
+            self._flying.t0 = t1
+        with _Locked(self, self._step_span):
+            for s in prompt.seqs:
+                s.t_prefill_end = t1
+        self._commit(prompt.seqs, tokens, step_t0=prompt.t0)
+
     def _looks_ahead(self, seqs: List[Sequence]) -> bool:
         return bool(getattr(self.adapter, "decode_ahead", False)) \
             and all(self._greedy(s) for s in seqs)
+
+    def _leaves_in_flight(self, seqs: List[Sequence]) -> bool:
+        """A prefill step's program is dispatched and not fetched where
+        the decode step behind it will look ahead with its rows: every
+        admitted sequence and every running row greedy on an adapter
+        with ``decode_ahead``; and nothing of the step needs the host to
+        have seen the prompt's end at once (a prefill-role sequence,
+        whose pages are exported as its one token ends it)."""
+        if self._draft is not None or not self._looks_ahead(seqs) \
+                or any(s.export_kv for s in seqs):
+            return False        # (before the lock: most adapters end here)
+        with _Locked(self, self._step_span):
+            running = [self._seqs[sid] for sid in self._running
+                       if sid in self._seqs]
+        return self._looks_ahead(running)
 
     def _decode_spec(self, seqs: List[Sequence]):
         """Speculative step: draft proposes per greedy sequence, the
@@ -971,27 +1037,50 @@ class LLMEngine:
         return windows
 
     def _prefill(self, seqs: List[Sequence], tokens: int):
+        """The admitted prompts' program. Where the next decode step can
+        feed their first tokens on the device (``_leaves_in_flight``) it
+        is dispatched and left in flight (``ahead``): the sequences run,
+        and the next ``_decode`` fetches and commits their tokens once it
+        has given the device the step behind them. Otherwise it is
+        fetched and committed here."""
         t0 = time.time()
+        ahead = self._leaves_in_flight(seqs)
         with tracing.step_span("llm.step.prefill", n=len(seqs),
-                               tokens=tokens) as span:
+                               tokens=tokens, ahead=ahead) as span:
             for s in seqs:
                 s.t_prefill_start = t0
-            logits = self.adapter.prefill(
-                seqs, **self._tokens_only(seqs))    # [B, V]
+            try:
+                if ahead:
+                    step = self.adapter.prefill(seqs, tokens_only=True,
+                                                fetch=False)
+                else:
+                    logits = self.adapter.prefill(
+                        seqs, **self._tokens_only(seqs))    # [B, V]
+            except BaseException:
+                # admitted, so in no queue: they fail with the running
+                with _Locked(self, span):
+                    self._running.extend(s.seq_id for s in seqs)
+                raise
             t1 = time.time()
             self._runner_seconds_total += t1 - t0
             if self.prefix_cache is not None:
-                # publish the finished prompts' full pages to the radix
-                # tree (before _commit can free a finished seq's pages)
+                # publish the prompts' full pages to the radix tree
+                # (before _commit can free a finished seq's pages; a
+                # prompt that shares them is dispatched after this one)
                 for s in seqs:
                     table = self.cache.block_table(s.seq_id)
                     if table:
                         self.prefix_cache.insert(s.prompt, table)
             with _Locked(self, span):
                 for s in seqs:
-                    s.t_prefill_end = t1
+                    if not ahead:
+                        s.t_prefill_end = t1
                     s.status = RUNNING
                     self._running.append(s.seq_id)
+        if ahead:
+            self._prefill_steps_ahead_total += 1
+            self._prompt = _Flying(seqs, step, t0, span.rec)
+            return
         self._commit(seqs, logits, step_t0=t0)
 
     @staticmethod
@@ -1134,7 +1223,7 @@ class LLMEngine:
 
     def _retire(self, finished: List[Sequence]):
         """What a finished request costs, under ``llm.step.retire``: the
-        wait for the step in flight (``runner.wait``), then for each
+        wait for what is in flight (``runner.wait``), then for each
         sequence ``runner.release`` (the snapshot to export, the
         adapter's release and whatever a deployment hooked onto it) and
         ``llm.step.finalize`` (its pages back to the pool, its ledger
@@ -1145,7 +1234,10 @@ class LLMEngine:
             if self._flying is not None:
                 # what a release runs on the device (a snapshot to export,
                 # a deployment's probe of the rows a sequence leaves) may
-                # need the memory the step in flight holds until it ends
+                # need the memory a program in flight holds until it ends.
+                # This step is the newest program (``_decode`` commits
+                # only once it has dispatched), and the device runs them
+                # in order: prompts in flight before it have ended too
                 self._flying.step.wait()
             for seq in finished:
                 with tracing.step_span("runner.release"):
@@ -1202,7 +1294,8 @@ class LLMEngine:
     def _fail_all(self, err: Exception):
         """A model-step failure fails the sequences it was computing —
         pollers see an explicit error, never a silent truncation."""
-        self._flying = None     # its sequences fail with the others
+        # what is in flight: its sequences fail with the others
+        self._flying = self._prompt = None
         with self._lock:
             ids = list(self._running) + list(self._waiting)
             self._running.clear()

@@ -226,6 +226,8 @@ class PagedKVCache:
             raise ValueError("need >= 2 blocks (page 0 is reserved)")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
+        # the sequences that may hold pages at once (0: not said)
+        self.max_sequences = int(max_sequences)
         # page 0 reserved as the null/scratch page for padding rows
         self._free = _FreeRuns(1, self.num_blocks - 1)
         self._tables = _Tables()                  # seq id -> pages

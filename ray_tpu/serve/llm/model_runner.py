@@ -95,7 +95,15 @@ the device from ``_last_tokens`` (every decode program writes its rows'
 greedy tokens there and reads the rows' inputs from there where the host
 says so), so the engine can dispatch step n + 1 before it fetches step n.
 It is the same program a bucket either way: a synchronous ``decode`` is
-that dispatch, then the fetch. What cannot work without snapshots of
+that dispatch, then the fetch. A prompt's program runs ahead the same
+way: ``prefill(seqs, tokens_only=True, fetch=False)`` dispatches it and
+returns it unfetched (``PromptStep``). Behind every prompt program a
+copy of a few integers (``llm_prefill_feed``, one program a prefill
+batch width) puts its rows' greedy tokens into ``_last_tokens`` above
+the rows a decode program writes (from ``_feed_base`` on), and a decode
+row whose prompt is still in flight reads its input there, so no prompt
+or decode program differs for it; a synchronous ``prefill`` is the same
+dispatch and copy, then the fetch. What cannot work without snapshots of
 the state raises ``RecurrentStateError``: ``decode_window`` /
 ``rollback``, ``export_kv`` / ``import_kv`` (and the engine refuses
 ``enable_prefix_cache`` and ``spec_k`` at construction). A model that
@@ -353,14 +361,18 @@ def bucket_name(B: int, S: int, full: bool = False) -> str:
 class DecodeStep:
     """A decode step the device has been given and the host has not
     fetched (``FlaxModelAdapter.decode(..., fetch=False)``). ``at`` says
-    where each sequence's row lies in the padded batch: the step
-    dispatched after it reads a row's input token there, on the device.
+    where each sequence's greedy token will lie in ``_last_tokens`` (its
+    row of the padded batch): the step dispatched after it reads a row's
+    input token there, on the device.
     ``fetch()`` waits for the program and returns what a synchronous
     ``decode`` would have; only then do the step's tokens count into the
     sequences' cached lengths (``_state[seq]["len"]``), so a sequence
     released with a step still in flight shows the length the host
-    knows of. ``wait()`` only waits: the program has ended, and the
+    knows of. ``wait()`` only waits: the program has ended (and every
+    program dispatched before it: the device runs them in order), and the
     device holds nothing of it but the integers ``fetch()`` will read."""
+
+    _held_as = "_flying"    # the adapter's name for the step while it flies
 
     def __init__(self, adapter, at: Dict[str, int], states, take, pending):
         self.at = at
@@ -371,13 +383,24 @@ class DecodeStep:
         out = self._take()
         for st in self._states:
             st["len"] += 1
-        if self._adapter._flying is self:
-            self._adapter._flying = None
+        if getattr(self._adapter, self._held_as) is self:
+            setattr(self._adapter, self._held_as, None)
         return out
 
     def wait(self):
         with tracing.step_span("runner.wait"):
             self._pending.block_until_ready()
+
+
+class PromptStep(DecodeStep):
+    """A prompt's program the device has been given and the host has not
+    fetched (``FlaxModelAdapter.prefill(..., fetch=False)``). ``at`` says
+    where each sequence's first token will lie in ``_last_tokens``, above
+    the rows a decode program writes: the decode step dispatched behind it
+    feeds those rows from there. The prompt's tokens are in the
+    sequences' cached lengths since the dispatch (``states`` is empty)."""
+
+    _held_as = "_flying_prompt"
 
 
 class FlaxModelAdapter:
@@ -501,6 +524,7 @@ class FlaxModelAdapter:
         self._window_pages_held = self._window_pages_whole = 0
         self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
         self._flying: Optional[DecodeStep] = None   # dispatched, unfetched
+        self._flying_prompt: Optional[PromptStep] = None    # the same
         self.bucket_first_calls = 0        # _fns misses: steps that compiled
         # programs dispatched whose blocks' products took the kernel that
         # reads the float32 stacks where they lie (``_linear_path``)
@@ -565,7 +589,7 @@ class FlaxModelAdapter:
     def bind_cache(self, cache):
         jnp = self._jnp
         self.cache = cache
-        self._flying = None
+        self._flying = self._flying_prompt = None
         dtype = self.cfg.dtype
         if set(self.page_windows) != set(getattr(cache, "windows", ())):
             raise ValueError(
@@ -609,9 +633,15 @@ class FlaxModelAdapter:
             self.state_slots = 0
             # the last decode program's greedy tokens by row of its padded
             # batch (no batch has more rows than the pool has pages, and
-            # one shape serves every bucket's program)
+            # one shape serves every bucket's program); above the widest
+            # batch, from ``_feed_base``, the last prompt program's
+            # (``_feed_fn``)
+            rows = min(cache.max_sequences or cache.num_blocks - 1,
+                       cache.num_blocks - 1)
+            self._feed_base = _pad_pow2(rows)
             self._last_tokens = jnp.zeros(
-                (_pad_pow2(cache.num_blocks),), jnp.int32)
+                (max(_pad_pow2(cache.num_blocks), 2 * self._feed_base),),
+                jnp.int32)
         # NB: every block table is padded to the worst-case blocks per
         # sequence so decode jits once per batch bucket
         self.nb_max = cache.blocks_for(
@@ -717,6 +747,26 @@ class FlaxModelAdapter:
                 if jax.devices()[0].platform == "tpu" else ()
             fn = self._fns["zero_slots"] = jax.jit(
                 llm_state_admit, donate_argnums=donate)
+        return fn
+
+    def _feed_fn(self):
+        """The copy behind every prompt program: its rows' greedy tokens
+        (``small[:B]``) into ``_last_tokens`` from ``_feed_base`` on,
+        where the decode program dispatched next reads the input of a
+        row whose token comes as ``-1 - (_feed_base + i)``. A program of
+        its own, one a prefill batch width, so that no prompt or decode
+        program differs for it; a synchronous ``prefill`` runs it too
+        (whoever warms a bucket compiles it)."""
+        fn = self._fns.get("feed_prompts")
+        if fn is None:
+            import jax
+            base = self._feed_base
+
+            def llm_prefill_feed(last, small, B):
+                return jax.lax.dynamic_update_slice(last, small[:B], (base,))
+            donate = (0,) if jax.devices()[0].platform == "tpu" else ()
+            fn = self._fns["feed_prompts"] = jax.jit(
+                llm_prefill_feed, static_argnums=(2,), donate_argnums=donate)
         return fn
 
     def state_of(self, seq_id: str) -> Dict[str, Any]:
@@ -924,6 +974,9 @@ class FlaxModelAdapter:
                     if fed:
                         self._last_tokens, *arrays = arrays
                     self._arrays = dict(zip(self._arrays, arrays))
+                    if op == "prefill":
+                        self._last_tokens = self._feed_fn()(
+                            self._last_tokens, small, B)
                     if tokens_only:
                         # nobody will read them: the device frees them
                         # when the program ends (64 x 100,352 float32 a
@@ -1082,7 +1135,12 @@ class FlaxModelAdapter:
                        * self._spec["routed_experts"][0])
         return out
 
-    def prefill(self, seqs, tokens_only: bool = False) -> np.ndarray:
+    def prefill(self, seqs, tokens_only: bool = False, fetch: bool = True):
+        """The prompts' tokens into their pages (and state). ``fetch=False``
+        (``decode_ahead``) returns the dispatched ``PromptStep`` in place
+        of its result: the decode step dispatched behind it feeds these
+        sequences' first tokens on the device. At most one prompt program
+        may be in flight when the next is dispatched."""
         rows = []
         slots = self._take_slots([s.seq_id for s in seqs]) \
             if self.has_state else [0] * len(seqs)
@@ -1108,25 +1166,36 @@ class FlaxModelAdapter:
                 st["rings"] = {w: self.cache.ring_table(s.seq_id, w)
                                for w in self._rings}
             rows.append(dict(st, tokens=s.prompt[cached:], len=cached))
-        return self._run(rows, "prefill", tokens_only)
+        if self._spec is None:
+            return self._run(rows, "prefill", tokens_only)
+        at, take, pending = self._dispatch(rows, "prefill", tokens_only)
+        step = self._flying_prompt = PromptStep(
+            self, {s.seq_id: self._feed_base + i for s, i in zip(seqs, at)},
+            (), take, pending)
+        return step.fetch() if fetch else step
 
     def decode(self, seqs, tokens_only: bool = False, fetch: bool = True):
         """One token a sequence. ``fetch=False`` (``decode_ahead``)
         returns the dispatched ``DecodeStep`` in place of its result. A
         sequence that is in the step still in flight has no
         ``s.tokens[-1]`` for this step yet: its row feeds that step's
-        greedy token on the device and lies one token further on. At most
-        one step may be in flight when the next is dispatched, and it is
-        fetched before any other."""
-        flying = self._flying
+        greedy token on the device and lies one token further on; one
+        whose prompt's program is in flight feeds its first token the same
+        way, at the length the prompt left. At most one decode step (and
+        one prompt program, dispatched after it) may be in flight when
+        the next is dispatched, and they are fetched before any other."""
+        flying, prompt = self._flying, self._flying_prompt
         states = [self._state[s.seq_id] for s in seqs]
         rows = []
         for s, st in zip(seqs, states):
             src = flying.at.get(s.seq_id) if flying else None
+            further = src is not None
+            if src is None and prompt:
+                src = prompt.at.get(s.seq_id)
             rows.append(dict(
                 st, slot=st.get("slot", 0),
                 tokens=[s.tokens[-1] if src is None else -1 - src],
-                len=st["len"] + (src is not None)))
+                len=st["len"] + further))
         at, take, pending = self._dispatch(rows, "decode", tokens_only)
         step = self._flying = DecodeStep(
             self, dict(zip((s.seq_id for s in seqs), at)), states, take,

@@ -1,7 +1,8 @@
 """What the ``test_llm_*`` files share: token prompts from a seed, a
-sequence with its pages allocated, and a poll loop over an engine's
-stream. Pages hold 8 tokens throughout, so 12-token prompts end mid-page
-and every sequence crosses a page boundary while it decodes."""
+sequence with its pages allocated, a poll loop over an engine's stream,
+and the wrapper that holds an engine synchronous. Pages hold 8 tokens
+throughout, so 12-token prompts end mid-page and every sequence crosses a
+page boundary while it decodes."""
 
 import time
 
@@ -10,6 +11,18 @@ import numpy as np
 from ray_tpu.serve.llm import SamplingParams
 
 PAGE = 8
+
+
+class Synchronous:
+    """An adapter with its look-ahead withheld: everything else is the
+    adapter's own."""
+    decode_ahead = False
+
+    def __init__(self, adapter):
+        self._adapter = adapter
+
+    def __getattr__(self, name):
+        return getattr(self._adapter, name)
 
 
 def flax_seq(cache, sid, prompt, budget=8, shared_pages=()):

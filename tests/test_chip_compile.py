@@ -1482,3 +1482,75 @@ def test_a_step_dispatched_ahead_runs_the_program_the_warm_up_compiled(
             == [len(s.prompt) + 3 for s in seqs]
         return np.stack(out)
     np.testing.assert_array_equal(served(True), served(False))
+
+
+@pytest.mark.parametrize("kind,config", [
+    ("kimi_linear", "KimiLinearConfig"), ("kimi_k2", "KimiK2Config"),
+    ("laguna", "LagunaConfig"), ("longcat_flash", "LongcatFlashConfig"),
+    ("smallthinker", "SmallThinkerConfig"), ("jamba", "JambaConfig"),
+    ("lfm2", "Lfm2Config")])
+def test_a_prompt_left_in_flight_runs_the_programs_the_warm_up_compiled(
+        kind, config):
+    """The benchmark warms a prefill bucket by a synchronous
+    ``adapter.prefill(seqs)`` that returns logits. A prompt dispatched
+    and left in flight (``tokens_only=True, fetch=False``), and the decode
+    step behind it that feeds its rows' first tokens on the device, are
+    the very programs that call and the warm-up's ``decode`` compiled,
+    the copy behind a prompt's program (``llm_prefill_feed``) among them:
+    no entry more in ``_fns``, ``bucket_first_calls`` as it was, each
+    jitted function traced once; and the tokens are the synchronous
+    order's. (The tiny presets, on the CPU: nothing here depends on the
+    chip.)"""
+    import importlib
+
+    import numpy as np
+
+    from ray_tpu.serve.llm import PagedKVCache, SamplingParams
+    from ray_tpu.serve.llm.engine import Sequence
+    from ray_tpu.serve.llm.model_runner import FlaxModelAdapter
+    glue = importlib.import_module(f"benchmark.reference.{kind}_glue")
+    cfg = getattr(importlib.import_module(f"ray_tpu.models.{kind}"),
+                  config).tiny()
+    adapter = FlaxModelAdapter(kind, cfg, glue.init_for(cfg, 7))
+
+    def bound(tag):
+        cache = PagedKVCache(64, 8, windows=adapter.page_windows,
+                             max_sequences=4)
+        adapter.bind_cache(cache)
+        if adapter.has_state:
+            adapter.bind_state(4)
+        seqs = []
+        for i, n in enumerate((11, 5, 20)):
+            cache.allocate(f"{tag}{i}", n + 8)
+            seqs.append(Sequence(f"{tag}{i}", None, list(range(1, n + 1)),
+                                 SamplingParams(max_new_tokens=8)))
+        return seqs
+
+    def commit(seqs, tokens):
+        for s, t in zip(seqs, tokens):
+            s.tokens.append(int(t))
+
+    # the warm-up's calls: logits from the prompts, then a decode step
+    seqs = bound("w")
+    commit(seqs, adapter.prefill(seqs).argmax(-1))
+    want = [[s.tokens[0] for s in seqs]]
+    for _ in range(2):
+        want.append(adapter.decode(seqs, tokens_only=True))
+        commit(seqs, want[-1])
+    before = (adapter.bucket_first_calls, set(adapter._fns))
+    sizes = {k: fn._cache_size() for k, fn in adapter._fns.items()}
+
+    seqs = bound("a")
+    prompt = adapter.prefill(seqs, tokens_only=True, fetch=False)
+    assert prompt.at == {s.seq_id: adapter._feed_base + i
+                         for i, s in enumerate(seqs)}
+    first = adapter.decode(seqs, tokens_only=True, fetch=False)
+    got = [prompt.fetch()]
+    assert adapter._flying_prompt is None and adapter._flying is first
+    second = adapter.decode(seqs, tokens_only=True, fetch=False)
+    got += [first.fetch(), second.fetch()]
+    assert (adapter.bucket_first_calls, set(adapter._fns)) == before
+    assert {k: fn._cache_size() for k, fn in adapter._fns.items()} == sizes
+    assert [adapter._state[s.seq_id]["len"] for s in seqs] \
+        == [len(s.prompt) + 2 for s in seqs]
+    np.testing.assert_array_equal(np.stack(got), np.stack(want))

@@ -9,7 +9,7 @@ same requests, through the tiny Kimi-Linear (state slots), Kimi-K2 (one
 latent pool) and Laguna (a ring a sequence) adapters, and are compared
 token for token and, at every ``release``, row for row of what the
 sequence left in the pools, the ring and the state. The synchronous
-engine is the same engine round ``Synchronous``, a wrapper of this file
+engine is the same engine round ``Synchronous``, a wrapper of the helpers
 that withholds the adapter's ``decode_ahead``; the program has no option
 for it.
 
@@ -19,6 +19,11 @@ of its steps with other neighbours, in a bucket of another width, and its
 sums run in another order: 7e-7 on rows of size 1 (float32 at 'highest'
 on both sides, tests/conftest.py). A row another token was fed to, or one
 written at another position, differs by 1e-1 and more.
+
+A prefill step's program is left in flight the same way ("what feeds a
+prompt's rows"): the decode step behind it is dispatched before the
+prompts' first tokens are fetched, its new rows fed on the device. The
+cases from ``test_prompts_in_flight_...`` on hold that half.
 
 Every engine of a kind binds the same adapter (a fresh cache, pools and
 state each time), so its steps compile once for the file; a prompt is
@@ -30,7 +35,8 @@ import time
 
 import numpy as np
 import pytest
-from llm_test_helpers import PAGE, drain_stream, token_prompts
+from llm_test_helpers import (PAGE, Synchronous, drain_stream,
+                              token_prompts)
 
 from ray_tpu.serve.llm import (EngineConfig, LLMEngine, SamplingParams,
                                ToyAdapter)
@@ -44,18 +50,6 @@ SHAPES = ((30, 9), (9, 14), (60, 5), (12, 1), (41, 20), (17, 3), (25, 12),
           (50, 7), (10, 16), (33, 2), (21, 10), (45, 6))
 TOL = 1e-5
 _ADAPTERS, _BASE = {}, {}
-
-
-class Synchronous:
-    """An adapter with its look-ahead withheld: everything else is the
-    adapter's own."""
-    decode_ahead = False
-
-    def __init__(self, adapter):
-        self._adapter = adapter
-
-    def __getattr__(self, name):
-        return getattr(self._adapter, name)
 
 
 def _adapter(kind):
@@ -125,13 +119,15 @@ def _serve(kind, requests, synchronous=False, before=None, skip=(),
         _quiet(eng, adapter)
         return {"tokens": [t for t, _ in out], "then": more,
                 "reasons": [c["finish_reason"] for _, c in out],
+                "chunks": [c for _, c in out],
                 "left": [left.get(sid) for sid in sids],
                 "metrics": eng.metrics(), "steps": eng.step_log(),
                 "ledger": eng.token_ledger(), "itl": len(eng._itl),
-                "free": sorted(eng.cache._free), "flying": eng._flying}
+                "free": sorted(eng.cache._free),
+                "flying": eng._flying or eng._prompt}
     finally:
         eng.stop()
-        for name in ("release", "decode"):
+        for name in ("release", "decode", "prefill"):
             adapter.__dict__.pop(name, None)
 
 
@@ -162,6 +158,11 @@ def _decode_spans(steps):
     ``ahead``)."""
     return [s for step in steps for s in _walk(step)
             if s["name"] == "llm.step.decode" and "ahead" in s["attrs"]]
+
+
+def _prefill_spans(steps):
+    return [s for step in steps for s in _walk(step)
+            if s["name"] == "llm.step.prefill"]
 
 
 def _same_left(a, b, state=True):
@@ -229,6 +230,12 @@ def test_mixed_requests_are_served_as_the_synchronous_engine_serves_them(
         == m["decode_steps_ahead_total"] >= 0.8 * len(spans)
     assert not any(s["attrs"]["ahead"]
                    for s in _decode_spans(sync["steps"]))
+    # every prompt's program was left in flight, none of the other's
+    assert m["prefill_steps_ahead_total"] == m["prefill_steps_total"] == 12
+    assert sync["metrics"]["prefill_steps_ahead_total"] == 0
+    assert all(s["attrs"]["ahead"] for s in _prefill_spans(ahead["steps"]))
+    assert not any(s["attrs"]["ahead"]
+                   for s in _prefill_spans(sync["steps"]))
     # one program a bucket, whoever fed the token
     fns = {k: fn for k, fn in _adapter(kind)._fns.items()
            if isinstance(k, tuple)}
@@ -353,6 +360,214 @@ def test_a_sampled_row_makes_the_steps_synchronous_while_it_runs(kind):
     assert 4 <= flags[first:last].count(False)
     # (the first step after the sampled row left has nothing in flight)
     assert flags[last - 1] is False and all(flags[last:])
+    # its own prompt is fetched at once; those before it were left in
+    # flight
+    left = [s["attrs"]["ahead"] for s in _prefill_spans(ahead["steps"])]
+    assert left[:4] == [True] * 4 and left[4] is False
+    assert ahead["metrics"]["prefill_steps_ahead_total"] == sum(left) < 6
+
+
+
+# ------------------------------------------------------ prompts in flight
+
+# 16 requests on 4 slots, a prompt a step: answers of one to four tokens,
+# so a slot is taken again as soon as it is left
+BUSY = ((30, 2), (9, 3), (20, 1), (12, 4), (41, 2), (17, 1), (25, 3),
+        (50, 2), (10, 4), (33, 1), (21, 2), (45, 3), (14, 1), (28, 2),
+        (11, 3), (37, 2))
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_prompts_in_flight_are_served_as_the_synchronous_engine_serves_them(
+        kind):
+    """A prompt arrives every step and slots are taken again at once:
+    every prompt's program is left in flight, the decode step behind it
+    feeds its rows' first tokens on the device, and tokens and what each
+    release leaves are the synchronous engine's. A prompt whose budget is
+    one token is in no decode step (the rows dispatched are those of the
+    tokens served after the first, none discarded)."""
+    requests = _requests(kind, BUSY)
+    ahead = _serve(kind, requests)
+    sync = _serve(kind, requests, synchronous=True)
+    _same_served(ahead, sync, requests)
+    assert [len(t) for t in ahead["tokens"]] == [m for _, m in BUSY]
+    _clean(ahead, kind)
+    m = ahead["metrics"]
+    assert m["prefill_steps_ahead_total"] == m["prefill_steps_total"] \
+        == len(BUSY)
+    assert sync["metrics"]["prefill_steps_ahead_total"] == 0
+    assert m["decode_tokens_discarded_total"] == 0
+    assert m["decode_rows_total"] == sync["metrics"]["decode_rows_total"] \
+        == sum(m - 1 for _, m in BUSY)
+    assert sorted(ahead["ledger"]) == sorted(sync["ledger"])
+    # slots were reused while prompts flew: more prompts than steps
+    # without one
+    steps = [[c["name"] for c in st["children"]] for st in ahead["steps"]]
+    with_prompt = [names for names in steps if "llm.step.prefill" in names]
+    assert len(with_prompt) == len(BUSY) > len(steps) - len(with_prompt)
+    # a first token reached its client when its prompt was fetched
+    assert all(r["ttft_s"] > 0 for r in ahead["chunks"])
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_first_token_that_stops_is_in_the_next_step_and_discarded(kind):
+    """Two requests whose FIRST token is their ``stop_token``: the host
+    learns it when the prompt is fetched, after the decode step behind it
+    was dispatched with the row in it. That step's token is discarded,
+    the sequence leaves the prompt and nothing else, and whoever takes
+    its pages, ring and slot is served as ever."""
+    base = _baseline(kind)["tokens"]
+    requests = _requests(kind)
+    for i in (2, 5):
+        requests[i] = (requests[i][0], SamplingParams(
+            max_new_tokens=SHAPES[i][1], stop_token=base[i][0]))
+    ahead = _serve(kind, requests)
+    sync = _serve(kind, requests, synchronous=True)
+    _same_served(ahead, sync, requests)
+    for i, toks in enumerate(base):
+        assert ahead["tokens"][i] == (toks[:1] if i in (2, 5) else toks)
+        assert ahead["reasons"][i] == ("stop" if i in (2, 5) else "length")
+    _clean(ahead, kind)
+    assert ahead["metrics"]["decode_tokens_discarded_total"] == 2
+    assert sync["metrics"]["decode_tokens_discarded_total"] == 0
+    assert ahead["metrics"]["prefill_steps_ahead_total"] == 12
+    assert sorted(ahead["ledger"]) == sorted(sync["ledger"])
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_prompt_cancelled_in_flight_frees_its_pages_once(kind):
+    """``cancel`` of the third request inside the decode call that
+    follows its prefill step: its prompt's program is still in flight,
+    and the decode step just dispatched holds its row. Both tokens are
+    discarded, its pages, ring and slot go back once, and every other
+    request is served as ever."""
+    requests = _requests(kind)
+    seen = []
+
+    def cancel(eng):
+        prompt = _adapter(kind)._flying_prompt
+        seen.append(prompt is not None and list(prompt.at))
+        eng.cancel("seq-3")
+    runs = [_serve(kind, requests, synchronous=sync, skip=(2,),
+                   before=_at_decode(kind, 3, cancel))
+            for sync in (False, True)]
+    assert seen == [["seq-3"], False]
+    _same_served(*runs, requests, but=(2,))
+    for run in runs:
+        _clean(run, kind)
+        assert all(r[0] != "r2" for r in run["ledger"])
+    assert [r["metrics"]["decode_tokens_discarded_total"]
+            for r in runs] == [2, 1]
+    spans = _prefill_spans(runs[0]["steps"])
+    assert spans[2]["attrs"]["ahead"] is True
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_prefill_call_that_raises_fails_the_step_in_flight_too(kind):
+    """The fourth prefill call raises with the decode step behind the
+    third prompt in flight: every admitted, running and waiting sequence
+    fails with the error, nothing stays in flight or allocated, and the
+    requests that follow are served as ever."""
+    picked = (0, 1, 2, 4, 5, 6)
+    requests = _requests(kind, [SHAPES[i] for i in picked])
+
+    def before(eng):
+        adapter, calls = _adapter(kind), [0]
+
+        def prefill(seqs, **kwargs):
+            calls[0] += 1
+            if calls[0] == 4:
+                assert eng._flying is not None
+                raise RuntimeError("the chip fell over")
+            return type(adapter).prefill(adapter, seqs, **kwargs)
+        adapter.prefill = prefill
+
+    def then(eng):
+        assert eng._flying is None and eng._prompt is None \
+            and eng.metrics()["kv_blocks_used"] == 0
+        sids = [eng.add_request(p, sp, request_id=f"again{i}")
+                for i, (p, sp) in enumerate(requests)]
+        return [drain_stream(eng, sid, timeout=240.0) for sid in sids]
+    run = _serve(kind, requests, before=before, then=then)
+    assert set(run["reasons"]) == {"error"}
+    assert run["metrics"]["failed_total"] == 6
+    _clean(run, kind)
+    again = _serve(kind, requests, synchronous=True)["tokens"]
+    for want, (toks, chunk) in zip(again, run["then"]):
+        assert toks == want and chunk["finish_reason"] == "length"
+
+
+def test_a_prefill_role_prompt_is_fetched_at_once():
+    """``prefill_export`` (Kimi-K2: pages and no state) beside greedy
+    requests: the step that admits it fetches its prompt synchronously,
+    its snapshot is the synchronous engine's, the others' prompts are
+    left in flight."""
+    kind = "kimi_k2"
+    requests = _requests(kind, SHAPES[:3])
+    prompt = _requests(kind, ((19, 4),))[0][0]
+
+    def then(eng):
+        sid = eng.prefill_export(prompt, SamplingParams(max_new_tokens=4))
+        toks, _ = drain_stream(eng, sid, timeout=240.0)
+        blob = eng.take_export(sid)
+        return toks, blob
+    ahead, sync = (_serve(kind, requests, synchronous=s, then=then)
+                   for s in (False, True))
+    _same_served(ahead, sync, requests)
+    (toks, blob), (want, want_blob) = ahead["then"], sync["then"]
+    assert toks == want and len(toks) == 1
+    assert blob["first_token"] == want_blob["first_token"] == toks[0]
+    for name, pages in blob["kv"]["pages"].items():
+        np.testing.assert_allclose(
+            np.asarray(pages, np.float32),
+            np.asarray(want_blob["kv"]["pages"][name], np.float32),
+            atol=TOL, rtol=0, err_msg=name)
+    left = [s["attrs"]["ahead"] for s in _prefill_spans(ahead["steps"])]
+    assert left == [True, True, True, False]
+    assert ahead["metrics"]["prefill_steps_ahead_total"] == 3
+    _clean(ahead, kind)
+
+
+
+@pytest.mark.parametrize("case", ("logits", "sampled", "spec_k",
+                                  "prefill_role"))
+def test_no_prompt_is_left_in_flight_where_the_host_needs_it_at_once(case):
+    """``prefill_steps_ahead_total`` stays 0, every ``llm.step.prefill``
+    says ``ahead`` false and the requests are served: an adapter that
+    returns logits; rows that sample; an engine that drafts (``spec_k``);
+    prefill-role requests (the last three on Kimi-K2, which has
+    ``decode_ahead``). What the engine observes decides, not an
+    option."""
+    config = dict(max_running=4, num_blocks=64, block_size=PAGE,
+                  max_seq_len=64)
+    adapter = ToyAdapter() if case == "logits" else _adapter("kimi_k2")
+    if case == "spec_k":
+        config.update(spec_k=2, draft_model="toy", draft_model_config={
+            "vocab_size": adapter.vocab_size})
+    sampling = SamplingParams(max_new_tokens=4, **(
+        {"temperature": 0.7, "seed": 3} if case == "sampled" else {}))
+    eng = LLMEngine(adapter, EngineConfig(**config))
+    try:
+        add = eng.prefill_export if case == "prefill_role" \
+            else eng.add_request
+        sids = [add(p, sampling) for p in token_prompts(
+            61, adapter.vocab_size, (9, 14, 6))]
+        served = [drain_stream(eng, sid, timeout=240.0)[0] for sid in sids]
+        deadline = time.time() + 30
+        while eng.in_flight() and time.time() < deadline:
+            time.sleep(0.02)
+    finally:
+        eng.stop()
+    # (a step's tree is logged when the step returns: read once the
+    # engine's thread has ended)
+    metrics, steps = eng.metrics(), eng.step_log()
+    assert [len(t) for t in served] \
+        == [1 if case == "prefill_role" else 4] * 3
+    assert metrics["prefill_steps_total"] >= 1
+    assert metrics["prefill_steps_ahead_total"] == 0
+    spans = _prefill_spans(steps)
+    assert spans and not any(s["attrs"]["ahead"] for s in spans)
+    assert eng._prompt is None and eng._flying is None
 
 
 # ------------------------------------------------------- the step's order
@@ -368,11 +583,16 @@ class RecordingAdapter:
     def bind_cache(self, cache):
         self.cache = cache
 
-    def prefill(self, seqs, tokens_only=False):
-        assert tokens_only
-        self.calls.append(("prefill", [s.seq_id for s in seqs]))
+    def prefill(self, seqs, tokens_only=False, fetch=True):
+        assert tokens_only and not fetch
+        k = 1 + sum(1 for c in self.calls if c[0] == "prompt")
+        self.calls.append(("prompt", k, [s.seq_id for s in seqs]))
         self._len.update({s.seq_id: len(s.prompt) for s in seqs})
-        return np.asarray([len(s.prompt) for s in seqs])
+        out = np.asarray([len(s.prompt) for s in seqs])
+        step = type("Step", (), {})()
+        step.fetch = lambda: self.calls.append(("prompt_fetch", k)) or out
+        step.wait = lambda: self.calls.append(("prompt_wait", k))
+        return step
 
     def decode(self, seqs, tokens_only=False, fetch=True):
         assert tokens_only and not fetch
@@ -391,10 +611,11 @@ class RecordingAdapter:
 
 
 def test_step_n_plus_1_is_dispatched_before_step_n_is_fetched():
-    """A steady batch of three: dispatch(1), then dispatch(n + 1) before
-    fetch(n) every step, ``ahead`` on each of those; the rows whose budget
-    the step in flight fills are not in the next; the last fetch has
-    nothing dispatched before it."""
+    """A steady batch of three: their prompts' program, then dispatch(1)
+    before the prompts are fetched, then dispatch(n + 1) before fetch(n)
+    every step, ``ahead`` on each of those; the rows whose budget the
+    step in flight fills are not in the next; the last fetch has nothing
+    dispatched before it."""
     adapter = RecordingAdapter()
     eng = LLMEngine(adapter, EngineConfig(
         max_running=4, num_blocks=64, block_size=PAGE, max_seq_len=64,
@@ -417,13 +638,21 @@ def test_step_n_plus_1_is_dispatched_before_step_n_is_fetched():
     assert order == [("dispatch", 1)] + [
         c for n in range(1, n_steps)
         for c in (("dispatch", n + 1), ("fetch", n))] + [("fetch", n_steps)]
+    # the decode program after the prompts is dispatched before they are
+    # fetched, and they are fetched before the one after it
+    kinds = [c[:2] for c in adapter.calls
+             if c[0] in ("prompt", "dispatch", "prompt_fetch")]
+    assert kinds[:4] == [("prompt", 1), ("dispatch", 1),
+                         ("prompt_fetch", 1), ("dispatch", 2)]
     # no row was dispatched past its budget (6, 6 and 4 tokens, the first
     # of each from its prefill)
     assert sum(len(c[2]) for c in adapter.calls
                if c[0] == "dispatch") == 5 + 5 + 3
+    # (the first had the prompts in flight before it, no step)
     assert [s["attrs"]["ahead"] for s in _decode_spans(steps)] \
-        == [False] + [True] * (n_steps - 1)
-    assert metrics["decode_steps_ahead_total"] == n_steps - 1
+        == [True] * n_steps
+    assert metrics["decode_steps_ahead_total"] == n_steps
+    assert metrics["prefill_steps_ahead_total"] == 1
     assert metrics["decode_tokens_discarded_total"] == 0
     # a sequence is released with no program in flight: the step
     # dispatched last has been waited for, or fetched
@@ -433,6 +662,52 @@ def test_step_n_plus_1_is_dispatched_before_step_n_is_fetched():
         before = adapter.calls[:i]
         last = max(c[1] for c in before if c[0] == "dispatch")
         assert ("wait", last) in before or ("fetch", last) in before
+
+
+def test_a_release_waits_for_the_newest_program_in_flight():
+    """A prompt a step (each admitted alone) while the others decode:
+    every prompt's program has the decode step behind it dispatched
+    before it is fetched, and whenever a sequence is released the program
+    dispatched LAST, a prompt's or a decode step's, has been waited for
+    or fetched: the device runs them in order, so nothing is in flight
+    beside the release."""
+    adapter = RecordingAdapter()
+    eng = LLMEngine(adapter, EngineConfig(
+        max_running=4, num_blocks=64, block_size=PAGE, max_seq_len=64,
+        max_prefill_tokens=8))
+    try:
+        sids = [eng.add_request([1] * 8, SamplingParams(max_new_tokens=m))
+                for m in (3, 3, 5, 1, 2, 4)]
+        served = [drain_stream(eng, sid)[0] for sid in sids]
+        deadline = time.time() + 10
+        while eng.in_flight() and time.time() < deadline:
+            time.sleep(0.02)
+        metrics = eng.metrics()
+    finally:
+        eng.stop()
+    assert [len(t) for t in served] == [3, 3, 5, 1, 2, 4]
+    assert metrics["prefill_steps_ahead_total"] == 6
+    assert metrics["decode_tokens_discarded_total"] == 0
+    calls = adapter.calls
+    for k in range(1, 7):
+        sent = calls.index(("prompt", k, [f"seq-{k}"]))
+        behind = next(i for i in range(sent, len(calls))
+                      if calls[i][0] == "dispatch")
+        fetched = calls.index(("prompt_fetch", k))
+        assert sent < behind < fetched
+        # (the fourth, of one token, is in no decode step)
+        assert (k != 4) == (calls[behind][2][-1] == 0)
+        nxt = [i for i, c in enumerate(calls) if c[:2] == ("prompt", k + 1)]
+        assert not nxt or fetched < nxt[0]
+    done = {("dispatch", "wait"): "fetch", ("prompt", "prompt_wait"):
+            "prompt_fetch"}
+    releases = [i for i, c in enumerate(calls) if c[0] == "release"]
+    assert len(releases) == 6
+    for i in releases:
+        before = [c[:2] for c in calls[:i]]
+        what, n = [c for c in before if c[0] in ("dispatch", "prompt")][-1]
+        wait = "wait" if what == "dispatch" else "prompt_wait"
+        assert (wait, n) in before or (done[(what, wait)], n) in before
 
 
 class RecordingToy(ToyAdapter):

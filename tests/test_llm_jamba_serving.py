@@ -292,11 +292,18 @@ def test_make_adapter_knows_the_kind():
         make_adapter("mamba")
 
 
-def test_jamba_streams_the_references_greedy_tokens_through_serve_run():
+@pytest.mark.parametrize("together", (False, True))
+def test_jamba_streams_the_references_greedy_tokens_through_serve_run(
+        together):
     """``serve.run`` of an ``LLMServer("jamba", ...)`` replica (tiny
     preset, weights from a seed), clients on ``handle.stream``: tokens
     arrive in chunks and are, teacher-forced through the reference on the
-    same weights, each its row's largest logit."""
+    same weights, each its row's largest logit. ``together``: the second
+    client asks while the first is answered, so its prompt's program is
+    left in flight behind a decode step and the step after feeds its
+    first token on the device; one after the other, a prompt is in flight
+    alone. Either way every prompt was."""
+    import threading
     import ray_tpu
     from ray_tpu import serve
     from ray_tpu.serve.llm import LLMServer
@@ -309,18 +316,47 @@ def test_jamba_streams_the_references_greedy_tokens_through_serve_run():
         dep = serve.deployment(name="jamba", num_replicas=1,
                                max_concurrent_queries=8)(LLMServer)
         h = serve.run(dep.bind("jamba", {"seed": 5}, {
-            "num_blocks": 64, "block_size": PAGE, "max_seq_len": 128,
+            "num_blocks": 64, "block_size": PAGE, "max_seq_len": 256,
             "max_running": 2}), name="jamba", route_prefix="/jamba",
             http_port=None)
-        for p, n in zip(prompts, (24, 12)):
-            chunks = list(h.stream({"tokens": p, "max_new_tokens": n,
-                                    "temperature": 0.0}))
+        # (together: an answer long enough to be still going when the
+        # second question has been admitted)
+        budgets = (200 if together else 24, 12)
+        got = {0: [], 1: []}
+
+        def ask(i, p, n):
+            for chunk in h.stream({"tokens": p, "max_new_tokens": n,
+                                   "temperature": 0.0}):
+                got[i].append(chunk)
+        clients = [threading.Thread(target=ask, args=(i, p, n))
+                   for i, (p, n) in enumerate(zip(prompts, budgets))]
+        for c in clients:
+            c.start()
+            while together and not got[0] and c.is_alive():
+                time.sleep(0.005)   # the first is being answered
+            if not together:
+                c.join(timeout=240)
+        for c in clients:
+            c.join(timeout=240)
+        for i, (p, n) in enumerate(zip(prompts, budgets)):
+            chunks = got[i]
             toks = [t for c in chunks for t in c["tokens"]]
             assert chunks[-1]["done"] and len(toks) == n
             assert len(chunks) >= 2, "tokens must stream"
             want = _reference_rows(p, toks, params)
             gap = want.max(-1) - want[np.arange(n), toks]
             assert float(gap.max()) <= 1e-4
+        m = ray_tpu.get(h.options("__llm_metrics__").remote(), timeout=60.0)
+        assert m["prefill_steps_ahead_total"] == m["prefill_steps_total"] == 2
+        assert m["decode_steps_ahead_total"] >= 20
+        prefills = [s for step in m["step_log"] for s in step["children"]
+                    if s["name"] == "llm.step.prefill"]
+        assert [s["attrs"]["ahead"] for s in prefills] == [True, True]
+        if together:    # the second prompt flew behind a decode step
+            step = next(st for st in m["step_log"]
+                        if prefills[1] in st["children"])
+            assert step["children"][0]["name"] == "llm.step.decode"
+            assert m["decode_tokens_discarded_total"] == 0
     finally:
         try:
             serve.shutdown()

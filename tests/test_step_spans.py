@@ -106,6 +106,11 @@ def test_step_trees_nest(run):
             assert span["t0"] <= span["t1"]
             children, events = spans_of(span)
             end = span["t0"]
+            if span["name"] == "llm.step.prefill" and span["attrs"]["ahead"]:
+                # its program was left in flight: the fetch, made by the
+                # step after, is hung under the span that dispatched it
+                assert children.pop()["t0"] >= step["t1"]
+                assert run["kind"] == "ahead"
             for child in children:              # in order, inside, disjoint
                 assert end <= child["t0"] <= child["t1"] <= span["t1"]
                 end = child["t1"]
