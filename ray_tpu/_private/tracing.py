@@ -55,12 +55,15 @@ the span open on the same thread; finished root spans land in a bounded
 ring their owner reads (docs/TRACING.md, "Step spans"). ``step_event``
 hangs a span whose length is known only when it is over (a collector's
 pause, a compile) into the same trees; ``watch_process`` makes the
-interpreter and jax report theirs.
+interpreter and jax report theirs, and keeps a table of the process's
+programs (``programs``). ``setup_report`` is what a process did before it
+was ready (docs/TRACING.md, "Before a process is ready").
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import gc
 import os
 import sys
@@ -494,10 +497,45 @@ gc_seconds_total = 0.0
 gc_collections_total = 0
 compiles_total = 0
 compile_seconds_total = 0.0
+# the Python side of a program's first call, which no compile cache
+# removes; a trace inside another's trace or lowering is in its parent's
+trace_seconds_total = 0.0
+lower_seconds_total = 0.0
+compile_cache_hits_total = 0
+compile_cache_misses_total = 0
+compile_cache_retrieval_seconds_total = 0.0
 
+# the events ``watch_process`` makes the process report: the collector's
+# pauses and the three stages of a program's first call
+JAX_EVENTS = ("jax.trace", "jax.lower", "jax.compile")
+PROCESS_EVENTS = ("py.gc",) + JAX_EVENTS
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_JAX_DURATIONS = {TRACE_EVENT: "trace", LOWER_EVENT: "lower",
+                  COMPILE_EVENT: "compile", _RETRIEVAL_EVENT: "retrieval"}
+# jax fires one of these inside a compile request that the persistent
+# cache answered or that it compiled and WROTE to the cache, on the
+# request's thread, before the request's duration
+_JAX_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                     "/jax/compilation_cache/cache_misses": "miss"}
+
 _gc_t0: Optional[float] = None      # the collection under way (one at a time)
 _watching_jax = False
+# depth (open traces and lowerings), cache, retrieval_s
+_jax_tls = threading.local()
+
+# one row a jitted function's name, over every trace, lowering and
+# compile request of the process: what survives the rings
+PROGRAMS_CAP = 512
+OTHER_PROGRAMS = "(other programs)"     # the row of names over the cap
+# jax.* events kept under one span (a constructor that makes its weights
+# op by op dispatches thousands of programs)
+EVENTS_UNDER_A_SPAN = 256
+_programs: Dict[str, Dict[str, Any]] = {}
+_programs_lock = threading.Lock()   # taken where jax traces or compiles
 
 
 def _on_gc(phase: str, info: Dict[str, int]) -> None:
@@ -514,20 +552,111 @@ def _on_gc(phase: str, info: Dict[str, int]) -> None:
                    collected=info.get("collected"))
 
 
-def _on_jax_duration(event: str, duration: float, **_) -> None:
-    global compiles_total, compile_seconds_total
-    if event == COMPILE_EVENT:
-        compiles_total += 1
-        compile_seconds_total += duration
-        step_event("jax.compile", float(duration))
+def _program_row(fun: str, now: float) -> Dict[str, Any]:
+    row = _programs.get(fun)
+    if row is None:
+        if len(_programs) >= PROGRAMS_CAP:
+            fun = OTHER_PROGRAMS
+            row = _programs.get(fun)
+        if row is None:
+            row = _programs[fun] = {
+                "fun": fun, "n": 0, "trace_s": 0.0, "nested_trace_s": 0.0,
+                "lower_s": 0.0, "compiles": 0, "compile_s": 0.0,
+                "cache_hits": 0, "cache_misses": 0, "t_first": now}
+    row["t_last"] = now
+    return row
+
+
+def _on_jax_scalar(event: str, value: float, **_) -> None:
+    # jax reports a stage's start so; its end is the duration below. A
+    # lowering traces too (a rule written as a Python function)
+    if event == TRACE_EVENT or event == LOWER_EVENT:
+        _jax_tls.depth = getattr(_jax_tls, "depth", 0) + 1
+
+
+def _on_jax_event(event: str, **_) -> None:
+    said = _JAX_CACHE_EVENTS.get(event)
+    if said is not None:
+        _jax_tls.cache = said
+
+
+def _on_jax_duration(event: str, duration: float, fun_name: str = "",
+                     **_) -> None:
+    global compiles_total, compile_seconds_total, trace_seconds_total, \
+        lower_seconds_total, compile_cache_hits_total, \
+        compile_cache_misses_total, compile_cache_retrieval_seconds_total
+    kind = _JAX_DURATIONS.get(event)
+    if kind is None:
+        return
+    tls, duration = _jax_tls, float(duration)
+    if kind == "retrieval":         # inside a compile request that hit
+        tls.retrieval_s = duration
+        return
+    now = time.time()
+    # a trace names the function, the later stages ``jit(<function>)``:
+    # all three go by the name the program's XLA module carries
+    attrs = {"fun": "jit_" + fun_name if kind == "trace"
+             else fun_name.replace("(", "_", 1).rstrip(")")}
+    nested, retrieval_s = False, 0.0
+    if kind != "compile":
+        tls.depth = max(getattr(tls, "depth", 1) - 1, 0)
+        nested = kind == "trace" and tls.depth > 0
+        tls.cache = None    # (what a request that raised left behind)
+    else:
+        attrs["cache"] = getattr(tls, "cache", None) or "off"
+        if attrs["cache"] == "hit":
+            retrieval_s = getattr(tls, "retrieval_s", 0.0)
+            attrs["retrieval_ms"] = retrieval_s * 1e3
+        tls.cache = None
+    with _programs_lock:
+        row = _program_row(attrs["fun"], now)
+        if kind == "trace":
+            row["n"] += 1
+            row["trace_s"] += duration
+            if nested:
+                row["nested_trace_s"] += duration
+            else:
+                trace_seconds_total += duration
+        elif kind == "lower":
+            row["lower_s"] += duration
+            lower_seconds_total += duration
+        else:
+            row["compiles"] += 1
+            row["compile_s"] += duration
+            compiles_total += 1
+            compile_seconds_total += duration
+            if attrs["cache"] == "hit":
+                row["cache_hits"] += 1
+                compile_cache_hits_total += 1
+                compile_cache_retrieval_seconds_total += retrieval_s
+            elif attrs["cache"] == "miss":
+                row["cache_misses"] += 1
+                compile_cache_misses_total += 1
+    if nested:      # in its parent's event, and in its own row
+        return
+    stack = getattr(_step_tls, "stack", None)
+    if stack and len(stack[-1]["children"]) >= EVENTS_UNDER_A_SPAN:
+        held = stack[-1]["attrs"]
+        held["jax_events_dropped"] = held.get("jax_events_dropped", 0) + 1
+        return
+    step_event("jax." + kind, duration, **attrs)
 
 
 def watch_process() -> None:
-    """Make this process report its collector's pauses and its compiles
-    as step events (``py.gc`` with ``generation`` and ``collected``;
-    ``jax.compile``, one a backend compile request, cache hit or not) and
-    count them (``process_counters``). Idempotent. jax is never imported
-    from here: a process that has it by then gets the listener."""
+    """Make this process report its collector's pauses and its programs'
+    first calls as step events and count them (``process_counters``,
+    ``programs``): ``py.gc`` with ``generation`` and ``collected``;
+    ``jax.trace``, ``jax.lower`` and ``jax.compile`` with ``fun`` (the
+    name of the program's XLA module), one a stage jax went through: the
+    function traced (one traced inside another's trace or lowering is
+    counted in ``programs`` alone), its jaxpr lowered, and a backend
+    compile request with ``cache``: ``"hit"`` (the persistent cache had
+    it; ``retrieval_ms``), ``"miss"`` (compiled, and written to it) or
+    ``"off"`` (neither: no cache directory, or a program under the
+    cache's thresholds of size and compile time, which it never keeps).
+    A call of a program that is compiled reaches none of the
+    listeners. Idempotent. jax is never imported from here: a process
+    that has it by then gets the listeners."""
     global _watching_jax
     if _on_gc not in gc.callbacks:
         gc.callbacks.append(_on_gc)
@@ -536,13 +665,132 @@ def watch_process() -> None:
         _watching_jax = True
         jax.monitoring.register_event_duration_secs_listener(
             _on_jax_duration)
+        jax.monitoring.register_event_listener(_on_jax_event)
+        jax.monitoring.register_scalar_listener(_on_jax_scalar)
 
 
 def process_counters() -> Dict[str, Any]:
     return {"gc_seconds_total": round(gc_seconds_total, 6),
             "gc_collections_total": gc_collections_total,
             "compiles_total": compiles_total,
-            "compile_seconds_total": round(compile_seconds_total, 6)}
+            "compile_seconds_total": round(compile_seconds_total, 6),
+            "trace_seconds_total": round(trace_seconds_total, 6),
+            "lower_seconds_total": round(lower_seconds_total, 6),
+            "compile_cache_hits_total": compile_cache_hits_total,
+            "compile_cache_misses_total": compile_cache_misses_total,
+            "compile_cache_retrieval_seconds_total": round(
+                compile_cache_retrieval_seconds_total, 6)}
+
+
+def programs() -> List[Dict[str, Any]]:
+    """One row a program this process traced, lowered or compiled, by
+    its first event: ``{fun, n, trace_s, nested_trace_s, lower_s,
+    compiles, compile_s, cache_hits, cache_misses, t_first, t_last}``.
+    ``n`` counts its traces and ``compiles`` its backend compile
+    requests; ``nested_trace_s`` is the part of ``trace_s`` that lay
+    inside another function's trace or lowering and is in that row's
+    seconds too
+    (``trace_s - nested_trace_s`` sums to the time the process spent
+    tracing). At most ``PROGRAMS_CAP`` names have a row; what further
+    names cost is summed in the row ``OTHER_PROGRAMS``. Kept whatever
+    ``RTPU_TRACING`` says."""
+    with _programs_lock:
+        return [dict(row) for row in _programs.values()]
+
+
+# ------------------------------------------- before a process is ready
+
+_T_IMPORT = time.time()
+_process_t0: Optional[float] = None
+# root spans of set-up work whose owner has no ring of its own
+# (``setup_span``)
+_setup_roots: Deque[Dict[str, Any]] = deque(maxlen=64)
+
+
+def process_t0() -> float:
+    """When the kernel started this process, on ``time.time()``'s clock
+    (``/proc/self/stat``'s start time against ``/proc/uptime``, to 10
+    ms); where that cannot be read, when this module was imported."""
+    global _process_t0
+    if _process_t0 is None:
+        _process_t0 = _T_IMPORT
+        try:
+            with open("/proc/self/stat") as f:
+                started = float(f.read().rsplit(")", 1)[1].split()[19])
+            with open("/proc/uptime") as f:
+                age = float(f.read().split()[0]) \
+                    - started / os.sysconf("SC_CLK_TCK")
+            t0 = time.time() - age
+            if age >= 0.0 and t0 <= _T_IMPORT + 0.05:
+                _process_t0 = t0
+        except (OSError, ValueError, IndexError):
+            pass
+    return _process_t0
+
+
+def setup_span(name: str):
+    """Decorator: every call of the function runs under a root span
+    ``name`` kept in the set-up ring (``setup_report()`` hands it out: a
+    feed's spans do not push it out), in a process that is watched
+    (``watch_process``). For set-up work whose owner keeps no ring of its
+    own: the train worker's ``train.setup.*``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            watch_process()
+            with step_span(name, _setup_roots):
+                return fn(*args, **kwargs)
+        return run
+    return wrap
+
+
+def setup_report(spans: Optional[List[Dict[str, Any]]] = None,
+                 first_calls=()) -> Dict[str, Any]:
+    """What this process did before it was ready, as plain data:
+    ``process_t0``, ``spans`` (its set-up trees: the owner's, or those of
+    ``setup_span``), ``first_calls`` (the owner's record of each
+    program's first call), ``programs()`` and ``process_counters()``."""
+    return {"process_t0": process_t0(),
+            "spans": list(_setup_roots) if spans is None else list(spans),
+            "first_calls": list(first_calls), "programs": programs(),
+            "counters": process_counters()}
+
+
+def describe_setup(top: int = 5) -> str:
+    """``setup_report()`` of a process whose set-up spans are
+    ``setup_span``'s, in one line for a log: process start to the first
+    span, each span, the ``top`` programs by trace + lower + compile
+    seconds with what the cache said, and the feed's first batch."""
+    report = setup_report()
+    t0 = report["process_t0"]
+    parts = []
+    spans = sorted(report["spans"], key=lambda s: s["t0"])
+    if spans:
+        parts.append(f"process start to {spans[0]['name']} "
+                     f"{spans[0]['t0'] - t0:.2f} s")
+    parts += [f"{s['name']} {s['t1'] - s['t0']:.2f} s" for s in spans]
+    rows = sorted(report["programs"], reverse=True, key=lambda r: (
+        r["trace_s"] - r["nested_trace_s"] + r["lower_s"] + r["compile_s"]))
+    c = report["counters"]
+    parts.append(
+        f"{len(report['programs'])} programs: trace "
+        f"{c['trace_seconds_total']:.2f} s, lower "
+        f"{c['lower_seconds_total']:.2f} s, compile "
+        f"{c['compile_seconds_total']:.2f} s in {c['compiles_total']} "
+        f"requests ({c['compile_cache_hits_total']} cache hits, "
+        f"{c['compile_cache_misses_total']} misses); the largest: "
+        + ", ".join(
+            f"{r['fun']} {r['trace_s'] - r['nested_trace_s']:.2f} + "
+            f"{r['lower_s']:.2f} + {r['compile_s']:.2f} s "
+            f"({r['cache_hits']} hit, {r['cache_misses']} miss)"
+            for r in rows[:top]))
+    if len(_step_roots) < STEP_RING:    # else the first has left the ring
+        first = next(iter(step_roots("data.feed.host_batch")), None)
+        if first is not None:
+            parts.append(
+                f"first data.feed.host_batch {first['t1'] - first['t0']:.2f}"
+                f" s, done {first['t1'] - t0:.2f} s after process start")
+    return "set-up: " + "; ".join(parts)
 
 
 # ------------------------------------------------- task-span synthesis

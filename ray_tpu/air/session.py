@@ -8,10 +8,15 @@ installed thread-locally in each train worker (and in function trainables);
 
 from __future__ import annotations
 
+import logging
 import queue
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
+
+from ray_tpu._private import tracing
+
+logger = logging.getLogger(__name__)
 
 
 @dataclass
@@ -42,6 +47,7 @@ class _Session:
     checkpoint_manager: Any = None
     ckpt_next_step: int = 0
     async_checkpointer: Any = None
+    reported: bool = False  # the first report logs the worker's set-up
 
 
 _tls = threading.local()
@@ -103,6 +109,11 @@ def report(metrics: Dict[str, Any], *, checkpoint=None):
     s = _get_session()
     if s is None:
         raise RuntimeError("session.report() called outside a train session")
+    if not s.reported:
+        # what this worker did before it had a result to report
+        # (docs/TRACING.md, "Before a process is ready")
+        s.reported = True
+        logger.info("%s", tracing.describe_setup())
     if checkpoint is not None and s.checkpoint_manager is not None:
         checkpoint = _route_through_manager(s, checkpoint)
     s.result_queue.put(TrainingResult(dict(metrics), checkpoint))

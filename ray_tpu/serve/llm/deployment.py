@@ -11,7 +11,7 @@ shedding, ledgers, tracing, HA and drain all apply unchanged:
   ``__llm_cancel__(sid)``          abandon a stream
   ``__llm_metrics__()``            engine metrics + token ledger +
                                    step_log + request_log + slow_steps
-                                   + process_events
+                                   + process_events + setup
   ``__llm_profile__(dir, s)``      jax.profiler capture of a live replica
   ``__llm_prefill__(payload)``     disagg hop 1: prompt + first token,
                                    returns a KV handoff descriptor
@@ -46,6 +46,7 @@ from __future__ import annotations
 import logging
 import os
 import time
+from collections import deque
 from typing import Any, Dict, List, Optional, Union
 
 from ray_tpu._private import tracing
@@ -86,9 +87,18 @@ class LLMServer:
     def __init__(self, model: str = "toy",
                  model_config: Optional[Dict[str, Any]] = None,
                  engine_config: Optional[Dict[str, Any]] = None):
-        self.adapter = make_adapter(model, model_config)
-        cfg = EngineConfig(**(engine_config or {}))
-        self.engine = LLMEngine(self.adapter, cfg)
+        # what the replica did before it was ready: one ``llm.setup``
+        # tree, the adapter's and the engine's spans under it, and every
+        # program the constructor traces counted from its first
+        # (docs/TRACING.md, "Before a process is ready")
+        tracing.watch_process()
+        self._setup_log: deque = deque(maxlen=1)
+        with tracing.step_span("llm.setup", self._setup_log,
+                               model=model) as setup:
+            self.adapter = make_adapter(model, model_config)
+            setup.set(kind=type(self.adapter).__name__)
+            cfg = EngineConfig(**(engine_config or {}))
+            self.engine = LLMEngine(self.adapter, cfg)
         self.tokenizer = ByteTokenizer(self.adapter.vocab_size)
         self.model = model
         self._shipper: Optional[KVShipper] = None
@@ -276,7 +286,9 @@ class LLMServer:
         m["slow_steps"] = self.engine.slow_steps()
         # the collections and compiles of threads that had no step span
         # open (those of the engine thread lie in the step trees)
-        m["process_events"] = tracing.step_roots("py.gc", "jax.compile")
+        m["process_events"] = tracing.step_roots(*tracing.PROCESS_EVENTS)
+        m["setup"] = tracing.setup_report(
+            self._setup_log, getattr(self.adapter, "first_calls", ()))
         m["device"] = self.adapter.device_info()
         return m
 

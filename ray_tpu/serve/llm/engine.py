@@ -84,7 +84,10 @@ its waits: ``cpu_ms`` and ``lock_wait_ms`` on its spans, ``runner.wait``
 and ``runner.release`` under ``llm.step.retire``, the process's ``py.gc``
 and ``jax.compile`` events where they fell, and a step of
 ``RTPU_TRACE_SLOW_S`` or more leaves a record in ``slow_steps`` that says
-what it waited for (``step_watch.py``).
+what it waited for (``step_watch.py``). ``llm.step`` says ``runner_ms``:
+the model runner's spans that ran between its ends, by the clock and not
+by the tree (a prompt's late fetch counts in the step that waited for
+it).
 """
 
 from __future__ import annotations
@@ -202,6 +205,13 @@ class _Flying:
     rec: Optional[Dict[str, Any]] = None
 
 
+def _runner_ms(children: List[Dict[str, Any]]) -> float:
+    """Milliseconds of the ``runner.*`` spans among these finished spans
+    and below them (none lies under another)."""
+    return sum((c["t1"] - c["t0"]) * 1e3 if c["name"].startswith("runner.")
+               else _runner_ms(c["children"]) for c in children)
+
+
 class _Locked:
     """``with _Locked(engine, span):`` the engine lock on the engine
     thread. Where it is not free at once, the wait to acquire it is
@@ -241,44 +251,6 @@ class LLMEngine:
         # a model's windowed page kinds are page groups of their own: what
         # a running sequence needs of a ring (kv_cache.py)
         self._windows = tuple(getattr(adapter, "page_windows", ()))
-        self.cache = PagedKVCache(
-            self.config.num_blocks, self.config.block_size,
-            windows=self._windows, max_sequences=self.config.max_running,
-            window_blocks=self.config.window_blocks)
-        # sequences whose admission waited for pages, by the page group
-        # that was short ("full", or a window); each counted once
-        self._admissions_waited: Dict[Any, int] = {}
-        adapter.bind_cache(self.cache)
-        self._refuse_with_window(
-            self.config.enable_prefix_cache, "enable_prefix_cache",
-            "a ring page is overwritten as its sequence grows, so a "
-            "prefix's pages cannot be shared")
-        self._refuse_with_window(
-            self.config.spec_k > 0, "spec_k (speculative decoding)",
-            "a rejected draft token has overwritten the ring row a ring "
-            "before it")
-        self._stateful = bool(getattr(adapter, "has_state", False))
-        if self._stateful:
-            # a recurrent state cannot be cut back, shared by page or
-            # shipped as pages without a snapshot taken at that token
-            self._refuse_with_state(
-                self.config.enable_prefix_cache, "enable_prefix_cache",
-                "a shared prefix page stands for the tokens before it, "
-                "and the state after those tokens was not kept")
-            self._refuse_with_state(
-                self.config.spec_k > 0, "spec_k (speculative decoding)",
-                "a rejected draft token cannot be taken out of the state")
-            adapter.bind_state(self.config.max_running)
-        self.prefix_cache = None
-        if self.config.enable_prefix_cache:
-            from ray_tpu.serve.llm.prefix_cache import RadixPrefixCache
-            self.prefix_cache = RadixPrefixCache(self.cache)
-        self._draft = None
-        if self.config.spec_k > 0:
-            from ray_tpu.serve.llm.spec_decode import make_draft
-            self._draft = make_draft(
-                self.config.draft_model or "toy",
-                self.config.draft_model_config)
         self._seqs: Dict[str, Sequence] = {}
         self._waiting: deque = deque()          # seq ids, FIFO
         self._running: List[str] = []           # decode batch membership
@@ -294,11 +266,9 @@ class LLMEngine:
         self._rate_win: deque = deque()          # (ts, tokens committed)
         self._hit_win: deque = deque()           # (ts, cache-hit tokens)
         self._total_generated = 0
-        self._total_prompt = 0
         self._total_requests = 0
         self._total_finished = 0
         self._total_shed = 0
-        self._total_failed = 0
         self._total_cache_hit = 0       # finalized (ledger-consistent)
         self._total_draft = 0
         self._total_accepted = 0
@@ -327,13 +297,58 @@ class LLMEngine:
         self._runner_seconds_total = 0.0
         self._lock_wait_seconds_total = 0.0
         self._step_span = None          # the open llm.step
-        tracing.watch_process()
-        self._watch = StepWatch(
-            sys.modules[type(adapter).__module__].__file__)
-        self._thread = threading.Thread(target=self._loop, daemon=True,
-                                        name="rtpu-llm-engine")
-        self._thread.start()
-        self._watch.start()
+        # the allocator, the adapter's pools and state arrays (docs/
+        # TRACING.md, "Before a process is ready")
+        with tracing.step_span("llm.setup.cache") as span:
+            self.cache = PagedKVCache(
+                self.config.num_blocks, self.config.block_size,
+                windows=self._windows, max_sequences=self.config.max_running,
+                window_blocks=self.config.window_blocks)
+            # sequences whose admission waited for pages, by the page group
+            # that was short ("full", or a window); each counted once
+            self._admissions_waited: Dict[Any, int] = {}
+            adapter.bind_cache(self.cache)
+            self._refuse_with_window(
+                self.config.enable_prefix_cache, "enable_prefix_cache",
+                "a ring page is overwritten as its sequence grows, so a "
+                "prefix's pages cannot be shared")
+            self._refuse_with_window(
+                self.config.spec_k > 0, "spec_k (speculative decoding)",
+                "a rejected draft token has overwritten the ring row a ring "
+                "before it")
+            self._stateful = bool(getattr(adapter, "has_state", False))
+            if self._stateful:
+                # a recurrent state cannot be cut back, shared by page or
+                # shipped as pages without a snapshot taken at that token
+                self._refuse_with_state(
+                    self.config.enable_prefix_cache, "enable_prefix_cache",
+                    "a shared prefix page stands for the tokens before it, "
+                    "and the state after those tokens was not kept")
+                self._refuse_with_state(
+                    self.config.spec_k > 0, "spec_k (speculative decoding)",
+                    "a rejected draft token cannot be taken out of the state")
+                adapter.bind_state(self.config.max_running)
+            nbytes = getattr(adapter, "cache_bytes", None)
+            if nbytes is not None:
+                span.set(**nbytes())
+        with tracing.step_span("llm.setup.engine"):
+            self.prefix_cache = None
+            if self.config.enable_prefix_cache:
+                from ray_tpu.serve.llm.prefix_cache import RadixPrefixCache
+                self.prefix_cache = RadixPrefixCache(self.cache)
+            self._draft = None
+            if self.config.spec_k > 0:
+                from ray_tpu.serve.llm.spec_decode import make_draft
+                self._draft = make_draft(
+                    self.config.draft_model or "toy",
+                    self.config.draft_model_config)
+            tracing.watch_process()
+            self._watch = StepWatch(
+                sys.modules[type(adapter).__module__].__file__)
+            self._thread = threading.Thread(target=self._loop, daemon=True,
+                                            name="rtpu-llm-engine")
+            self._thread.start()
+            self._watch.start()
 
     def _refuse_with_state(self, asked: bool, what: str, why: str):
         if asked and self._stateful:
@@ -398,7 +413,6 @@ class LLMEngine:
             self._seqs[seq_id] = seq
             self._waiting.append(seq_id)
             self._total_requests += 1
-            self._total_prompt += n_prompt
             self._work_cv.notify_all()
             return seq_id
 
@@ -487,7 +501,6 @@ class LLMEngine:
                     ^ sampling.seed)
             self._seqs[seq_id] = seq
             self._total_requests += 1
-            self._total_prompt += n_prompt
         if terminal is not None:
             # the prefill replica's single token already ended the
             # stream — no pages, no import, just a finished cursor
@@ -658,11 +671,9 @@ class LLMEngine:
                 "tokens_per_s": round(
                     window_tokens / window_s, 3) if window_s > 0 else 0.0,
                 "generated_tokens_total": self._total_generated,
-                "prompt_tokens_total": self._total_prompt,
                 "requests_total": self._total_requests,
                 "finished_total": self._total_finished,
                 "shed_total": self._total_shed,
-                "failed_total": self._total_failed,
                 "cache_hit_tokens_total": self._total_cache_hit,
                 "cache_hit_tokens_per_s": round(
                     hit_tokens / window_s, 3) if window_s > 0 else 0.0,
@@ -839,7 +850,7 @@ class LLMEngine:
         step = self._step_span = tracing.step_span(
             "llm.step", self._step_log, i=self._steps_total,
             running=len(self._running), waiting=len(self._waiting),
-            lock_wait_ms=0.0)
+            lock_wait_ms=0.0, runner_ms=0.0)
         try:
             with step:
                 with _Locked(self, step):
@@ -868,6 +879,11 @@ class LLMEngine:
                 # with seconds of CPU computed
                 cpu_ms = (time.thread_time() - cpu0) * 1e3
                 step.set(cpu_ms=cpu_ms)
+                if step.rec is not None:
+                    # the model runner's spans that ran in this step (a
+                    # prompt's late fetch is in it already)
+                    step.rec["attrs"]["runner_ms"] += _runner_ms(
+                        step.rec["children"])
         finally:
             t1 = time.time()
             if cpu_ms is None:          # the step raised
@@ -962,11 +978,16 @@ class LLMEngine:
         their first tokens. The fetch's record (``runner.fetch``, with
         what the program counted) hangs under the ``llm.step.prefill``
         that dispatched the program, where a reader of a prefill step
-        looks for it, and nowhere else."""
+        looks for it, and nowhere else; its length counts into the
+        ``runner_ms`` of the step open now, which waited for it."""
         t0 = time.time()
+        held = prompt.rec["children"] if prompt.rec is not None else []
+        n = len(held)
         with tracing.hung_under(prompt.rec):
             tokens = prompt.step.fetch()
         t1 = time.time()
+        if self._step_span.rec is not None:
+            self._step_span.rec["attrs"]["runner_ms"] += _runner_ms(held[n:])
         self._runner_seconds_total += t1 - t0
         if self._flying is not None:
             # its program starts when the one before it ends
@@ -1308,7 +1329,6 @@ class LLMEngine:
                 seq.error = f"{type(err).__name__}: {err}"
                 seq.finish_reason = "error"
                 seq.t_finish = time.time()
-                self._total_failed += 1
                 try:
                     self.adapter.release(sid)
                 except Exception as e:  # noqa: BLE001 — what failed the

@@ -220,6 +220,9 @@ class ToyAdapter:
     def device_info(self) -> Dict[str, Any]:
         return {"platform": "host", "device_kind": "numpy"}
 
+    def cache_bytes(self) -> Dict[str, int]:
+        return {"bytes": self.pages.nbytes}
+
     def copy_page(self, src: int, dst: int):
         self.pages[dst] = self.pages[src]
 
@@ -351,6 +354,20 @@ def _stack_blocks(tree, name: str, n: int):
     return {**tree, "params": rest}
 
 
+def _device_memory(tree) -> Dict[str, int]:
+    """What the device that holds ``tree`` says of its memory, for a
+    set-up span's attributes: ``memory_in_use_bytes`` and
+    ``memory_peak_bytes`` (a TPU says; the CPU does not: {})."""
+    import jax
+    leaf = next(iter(jax.tree_util.tree_leaves(tree)), None)
+    if not hasattr(leaf, "devices"):
+        return {}
+    stats = next(iter(leaf.devices())).memory_stats() or {}
+    return {f"memory_{name}_bytes": int(stats[key])
+            for name, key in (("in_use", "bytes_in_use"),
+                              ("peak", "peak_bytes_in_use")) if key in stats}
+
+
 def bucket_name(B: int, S: int, full: bool = False) -> str:
     """The jitted step's name for one (batch, length) bucket."""
     if full:
@@ -428,6 +445,10 @@ class FlaxModelAdapter:
                  params=None, seed: int = 0):
         import jax
         import jax.numpy as jnp
+        # the constructor's own programs are traced, lowered and compiled
+        # before any step: counted from here on (docs/TRACING.md, "Before a
+        # process is ready")
+        tracing.watch_process()
         self._jnp = jnp
         self.kind = kind
         # what the model says it caches (None: K and V pages, every
@@ -437,6 +458,45 @@ class FlaxModelAdapter:
         # weights for a step of so many rows (None: not
         # ``ops.linear.stacked_linear``'s)
         self._linear_chooser = None
+        with tracing.step_span("llm.setup.adapter", kind=kind):
+            self._bind_model(kind, config)
+        with tracing.step_span("llm.setup.params") as span:
+            if params is None:
+                dummy = jnp.zeros((1, 8), jnp.int32)
+                params = self.model.init(jax.random.PRNGKey(seed), dummy)
+            self.params = params
+            span.set(bytes=sum(
+                x.size * x.dtype.itemsize
+                for x in jax.tree_util.tree_leaves(self.params)),
+                **_device_memory(self.params))
+        self._expert_tokens_total = self._expert_tokens_last = None
+        self._zero_expert_tokens_total = None   # [routed layers]
+        self._routed_tokens_total = 0           # real tokens through a router
+        self._expert_products: Dict[int, Any] = {}
+        self._kv_pages_live = self._kv_pages_padded = 0
+        self._kv_run_pages = self._kv_table_pages = 0
+        self._decode_recurrence: Optional[str] = None   # ``bind_state``
+        self._recurrence_kind: Optional[str] = None     # "kda" | "mamba"
+        self._recurrence_kernel_steps = 0
+        self._state_admits = 0
+        self._state_admit_seconds = 0.0
+        self._window_pages_live = self._window_pages_padded = 0
+        self._window_pages_held = self._window_pages_whole = 0
+        self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
+        self._flying: Optional[DecodeStep] = None   # dispatched, unfetched
+        self._flying_prompt: Optional[PromptStep] = None    # the same
+        # one record a bucket: the finished ``runner.dispatch`` span of
+        # its first call with the ``jax.*`` events under it, wherever the
+        # call came from (a step of the engine, a warm-up outside one)
+        self.first_calls: List[Dict[str, Any]] = []
+        # programs dispatched whose blocks' products took the kernel that
+        # reads the float32 stacks where they lie (``_linear_path``)
+        self.stacked_linear_kernel_steps = 0
+        self._lock = threading.Lock()
+
+    def _bind_model(self, kind: str, config):
+        """The model's module, its configuration and its object, and what
+        the model says of its cache (``_spec``)."""
         if kind == "gpt2":
             from ray_tpu.models import gpt2
             self.cfg = config or gpt2.GPT2Config.tiny()
@@ -505,31 +565,6 @@ class FlaxModelAdapter:
             self._spec = lfm2.cache_spec(self.cfg)
         else:
             raise ValueError(f"unknown model kind {kind!r}")
-        if params is None:
-            dummy = jnp.zeros((1, 8), jnp.int32)
-            params = self.model.init(jax.random.PRNGKey(seed), dummy)
-        self.params = params
-        self._expert_tokens_total = self._expert_tokens_last = None
-        self._zero_expert_tokens_total = None   # [routed layers]
-        self._routed_tokens_total = 0           # real tokens through a router
-        self._expert_products: Dict[int, Any] = {}
-        self._kv_pages_live = self._kv_pages_padded = 0
-        self._kv_run_pages = self._kv_table_pages = 0
-        self._decode_recurrence: Optional[str] = None   # ``bind_state``
-        self._recurrence_kind: Optional[str] = None     # "kda" | "mamba"
-        self._recurrence_kernel_steps = 0
-        self._state_admits = 0
-        self._state_admit_seconds = 0.0
-        self._window_pages_live = self._window_pages_padded = 0
-        self._window_pages_held = self._window_pages_whole = 0
-        self._fns: Dict[Any, Any] = {}     # (B, S, full?) -> jitted step
-        self._flying: Optional[DecodeStep] = None   # dispatched, unfetched
-        self._flying_prompt: Optional[PromptStep] = None    # the same
-        self.bucket_first_calls = 0        # _fns misses: steps that compiled
-        # programs dispatched whose blocks' products took the kernel that
-        # reads the float32 stacks where they lie (``_linear_path``)
-        self.stacked_linear_kernel_steps = 0
-        self._lock = threading.Lock()
 
     @property
     def params(self):
@@ -541,6 +576,12 @@ class FlaxModelAdapter:
         self._params = _stack_blocks(tree, self._blocks, self.n_layers)
         # ``_linear_path`` by rows a step, asked anew of these weights
         self._linear_paths: Dict[int, str] = {}
+
+    @property
+    def bucket_first_calls(self) -> int:
+        """``_fns`` misses: the (batch, length) buckets met so far, each
+        a step that traced, lowered and compiled its program."""
+        return sum(1 for key in self._fns if isinstance(key, tuple))
 
     @property
     def has_state(self) -> bool:
@@ -667,6 +708,15 @@ class FlaxModelAdapter:
                 self._decode_recurrence = _recurrences()[p["recurrence"]](
                     self._arrays[name], 1)
         self._free_slots = list(range(self.state_slots, 0, -1))
+
+    def cache_bytes(self) -> Dict[str, int]:
+        """What ``bind_cache`` and ``bind_state`` made, for the engine's
+        ``llm.setup.cache`` span: the pools' and state arrays' ``bytes``
+        and, where the device says, its memory with them in it."""
+        arrays = [self.k_pages, self.v_pages] if self._spec is None \
+            else [*self._arrays.values(), self._last_tokens]
+        return {"bytes": sum(a.size * a.dtype.itemsize for a in arrays),
+                **_device_memory(arrays)}
 
     def counters(self) -> Dict[str, Any]:
         """What ``engine.metrics()`` adds of the model step: the pages
@@ -806,7 +856,6 @@ class FlaxModelAdapter:
         jnp = self._jnp
         if self._spec is not None:
             fn = self._fns[key] = self._spec_step_fn(B, S, full)
-            self.bucket_first_calls += 1
             return fn
 
         def step(params, tokens, k_pages, v_pages, block_tables,
@@ -831,7 +880,6 @@ class FlaxModelAdapter:
         donate = (2, 3) if jax.devices()[0].platform == "tpu" else ()
         fn = jax.jit(step, donate_argnums=donate)
         self._fns[key] = fn
-        self.bucket_first_calls += 1
         return fn
 
     def _by_slot(self, B: int, S: int) -> bool:
@@ -949,8 +997,8 @@ class FlaxModelAdapter:
         product = self._expert_product(B * S)
         linear = self._linear_path(B * S)
         self.stacked_linear_kernel_steps += linear == "kernel"
-        with tracing.step_span("runner.dispatch", B=B, S=S,
-                               first_call=(B, S, full) not in self._fns,
+        first = (B, S, full) not in self._fns
+        with tracing.step_span("runner.dispatch", B=B, S=S, first_call=first,
                                **({"linear": linear} if linear else {}),
                                **({"expert_product": product.name}
                                   if product else {}),
@@ -958,7 +1006,7 @@ class FlaxModelAdapter:
                                   if op == "decode" else
                                   {"prompt_tokens": sum(len(r["tokens"])
                                                         for r in rows),
-                                   "padded_tokens": B * S})):
+                                   "padded_tokens": B * S})) as dispatch:
             fn = self._step_fn(B, S, full)
             with self._lock:
                 if self._spec is None:
@@ -982,6 +1030,10 @@ class FlaxModelAdapter:
                         # when the program ends (64 x 100,352 float32 a
                         # step in flight are 25.7 MB)
                         logits = None
+        if first and dispatch.rec is not None:
+            self.first_calls.append(dict(dispatch.rec, children=[
+                c for c in dispatch.rec["children"]
+                if c["name"].startswith("jax.")]))
 
         def fetch() -> np.ndarray:
             with tracing.step_span("runner.fetch") as span:

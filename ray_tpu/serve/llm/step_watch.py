@@ -8,8 +8,8 @@ itself while it waits: a thread that needs nothing but the interpreter
 notes how late it wakes (the time in which NO Python thread of the
 process could run) and, once the open step is a slow one, takes every
 thread's stack. ``verdict`` then names the cause from the tree, the
-step's CPU time, the lateness, the stacks and the process's ``py.gc`` /
-``jax.compile`` events.
+step's CPU time, the lateness, the stacks and the process's ``py.gc`` and
+``jax.trace`` / ``jax.lower`` / ``jax.compile`` events.
 """
 
 from __future__ import annotations
@@ -31,7 +31,7 @@ RING = 64
 FRAMES = 12
 VERDICTS = ("compile", "gc", "interpreter held", "host starved", "lock",
             "device or runtime", "engine")
-EVENTS = ("py.gc", "jax.compile")
+JAX_EVENTS, EVENTS = tracing.JAX_EVENTS, tracing.PROCESS_EVENTS
 
 # a thread whose innermost Python frame stands at such a call waits for
 # something else than the interpreter (the last two: this repo's RPC
@@ -217,12 +217,17 @@ def verdict(rec: Dict[str, Any],
     inside = {name: 0.0 for name in EVENTS}
     for e in [s for s in _walk(tree) if s["name"] in EVENTS] + rec["events"]:
         inside[e["name"]] += (min(e["t1"], t1) - max(e["t0"], t0)) * 1e3
-    if inside["jax.compile"] > half:
+    # a program's first call: traced, lowered, then compiled or read
+    # from the cache
+    compiles = sum(inside[name] for name in JAX_EVENTS)
+    if compiles > half:
         where = next((s["name"] for s in _walk(tree) if any(
-            c["name"] == "jax.compile" for c in s["children"])), None)
-        return "compile", (f"{inside['jax.compile']:.0f} ms of compiles"
-                           + (f" under {where}" if where else
-                              " on another thread"))
+            c["name"] in JAX_EVENTS for c in s["children"])), None)
+        return "compile", (
+            f"{compiles:.0f} ms of compiles (trace "
+            f"{inside['jax.trace']:.0f}, lower {inside['jax.lower']:.0f}, "
+            f"backend or cache {inside['jax.compile']:.0f})"
+            + (f" under {where}" if where else " on another thread"))
     if inside["py.gc"] > half:
         return "gc", f"{inside['py.gc']:.0f} ms of collections"
     engine = rec["engine_thread"]
@@ -262,5 +267,5 @@ def verdict(rec: Dict[str, Any],
             f"{rec['watch_late_ms']:.0f} ms late")
     return "engine", (
         f"{leaf} took {leaf_ms:.0f} ms of its own, {rec['cpu_ms']:.1f} ms "
-        f"of CPU in the step ({inside['jax.compile']:.0f} ms of compiles "
+        f"of CPU in the step ({compiles:.0f} ms of compiles "
         f"and {inside['py.gc']:.0f} ms of collections inside it)")

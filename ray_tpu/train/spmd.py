@@ -31,6 +31,7 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu._private import tracing
 from ray_tpu.ops.attention import attention_mesh
 from ray_tpu.parallel.mesh import MeshSpec, param_sharding
 
@@ -81,6 +82,7 @@ class SpmdTrainer:
     eval_loss: Optional[Callable] = None
 
 
+@tracing.setup_span("train.setup.build")
 def make_causal_lm_trainer(
     model_config=None,
     *,
@@ -96,6 +98,13 @@ def make_causal_lm_trainer(
     Reference analogue (capability, not design): the HF GPT-2 fine-tune
     config (train/huggingface/huggingface_trainer.py:157) — there, torch
     Trainer + DDP inside Ray workers; here, one pjit'd program over the mesh.
+
+    What a worker does before its first step is recorded (docs/TRACING.md,
+    "Before a process is ready"): this builder runs under
+    ``train.setup.build`` and ``trainer.init`` under ``train.setup.init``
+    (the call: the state's program traced, compiled and dispatched);
+    ``trainer.step`` is the jitted function itself, and its first call's
+    cost is its row of ``tracing.programs()``.
     """
     from ray_tpu.models.gpt2 import GPT2, GPT2Config, causal_lm_loss
 
@@ -123,7 +132,8 @@ def make_causal_lm_trainer(
     abstract = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
     st_sh = state_shardings(abstract, mesh, spec)
 
-    init = jax.jit(init_fn, out_shardings=st_sh)
+    init = tracing.setup_span("train.setup.init")(
+        jax.jit(init_fn, out_shardings=st_sh))
 
     batch_sh = {
         "input_ids": NamedSharding(mesh, P(("dp", "fsdp"), "sp")),

@@ -332,8 +332,7 @@ def test_a_decode_call_that_raises_fails_the_step_in_flight_too(kind):
                 for i, (p, sp) in enumerate(requests)]
         return [drain_stream(eng, sid, timeout=240.0) for sid in sids]
     run = _serve(kind, requests, before=_at_decode(kind, 4, boom), then=then)
-    assert set(run["reasons"]) == {"error"}
-    assert run["metrics"]["failed_total"] == 6
+    assert run["reasons"] == ["error"] * 6
     _clean(run, kind)
     again = _serve(kind, requests, synchronous=True)["tokens"]
     for want, (toks, chunk) in zip(again, run["then"]):
@@ -489,8 +488,7 @@ def test_a_prefill_call_that_raises_fails_the_step_in_flight_too(kind):
                 for i, (p, sp) in enumerate(requests)]
         return [drain_stream(eng, sid, timeout=240.0) for sid in sids]
     run = _serve(kind, requests, before=before, then=then)
-    assert set(run["reasons"]) == {"error"}
-    assert run["metrics"]["failed_total"] == 6
+    assert run["reasons"] == ["error"] * 6
     _clean(run, kind)
     again = _serve(kind, requests, synchronous=True)["tokens"]
     for want, (toks, chunk) in zip(again, run["then"]):

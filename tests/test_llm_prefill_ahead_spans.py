@@ -118,3 +118,36 @@ def test_a_prompts_fetch_is_recorded_and_counted_once():
                    for d in _named(step, "llm.step.decode"))
         assert sum(f["attrs"]["expert_tokens"] for f in fetches) \
             == counted["expert_tokens"]
+
+
+@pytest.mark.parametrize("synchronous", (False, True))
+def test_runner_ms_counts_a_late_fetch_in_the_step_that_waited(synchronous):
+    """``llm.step`` says ``runner_ms`` by the clock: the ``runner.*``
+    spans that ran between its ends, whichever tree holds their records.
+    A prompt left in flight is dispatched in one step and fetched in the
+    next: by the tree the first step holds both records, by the clock
+    each step holds what it ran, and over the log both sums are every
+    runner span's time, once."""
+    log = _runs()[synchronous][0]
+
+    def ms(span):
+        return (span["t1"] - span["t0"]) * 1e3
+
+    def runner(step):
+        return [s for s in _walk(step) if s["name"].startswith("runner.")]
+
+    spans = [s for step in log for s in runner(step)]
+    assert sum(step["attrs"]["runner_ms"] for step in log) \
+        == pytest.approx(sum(map(ms, spans)))
+    for step in log:
+        ran = [s for s in spans
+               if step["t0"] <= s["t0"] and s["t1"] <= step["t1"]]
+        assert step["attrs"]["runner_ms"] == pytest.approx(
+            sum(map(ms, ran)), abs=1e-6)
+        assert step["attrs"]["runner_ms"] <= ms(step)
+    # every prompt's fetch is late: in the tree of the step that
+    # dispatched it, in the ``runner_ms`` of a later one
+    late = [s for step in log for s in runner(step)
+            if s["t0"] >= step["t1"]]
+    assert len(late) == (0 if synchronous else len(SHAPES))
+    assert all(s["name"] == "runner.fetch" for s in late)

@@ -88,7 +88,7 @@ def test_engine_serves_mixed_lengths_and_a_short_group_makes_a_request_wait():
     for p, n, t in zip(prompts, budgets, toks):
         assert len(t) == n
         assert float(_greedy_gap(p, t).max()) <= TOL
-    assert metrics["failed_total"] == 0 and metrics["finished_total"] == 5
+    assert metrics["finished_total"] == 5
     waited = metrics["admissions_waited_total"]
     assert waited.get("window_32", 0) >= 1 and "full" not in waited
     group = metrics["kv_window_groups"][32]

@@ -30,7 +30,7 @@ ENGINE = dict(max_running=4, num_blocks=64, block_size=16, max_seq_len=128,
 
 
 # spans that are over when they are known (tracing.step_event)
-EVENTS = ("py.gc", "jax.compile")
+EVENTS = tracing.PROCESS_EVENTS
 
 
 def make_adapter(kind):
@@ -185,7 +185,8 @@ def test_span_counts_are_the_engines_counters(run):
     assert (cached > 0) == ("prefix" in run["kind"])
     assert total("llm.step.admit", "prefill_tokens") \
         == total("llm.step.prefill", "tokens") \
-        == m["prefill_tokens_total"] == m["prompt_tokens_total"] - cached
+        == m["prefill_tokens_total"] \
+        == sum(r["n_prompt"] for r in run["requests"]) - cached
     assert m["prefill_steps_total"] == sum(
         1 for s in spans if s["name"] == "llm.step.prefill")
     assert total("llm.step.commit", "finished") == m["finished_total"]
@@ -342,6 +343,126 @@ def test_a_profiler_capture_holds_the_step_spans(tmp_path):
         == 4 * (1 + len(rounds))
 
 
+# ------------------------------------------- before a replica is ready
+
+SETUP = ("llm.setup.adapter", "llm.setup.params", "llm.setup.cache",
+         "llm.setup.engine")
+
+
+@pytest.fixture(scope="module", params=["toy", "gpt2"])
+def served_setup(request):
+    """A replica's ``__llm_metrics__()["setup"]`` after two requests
+    through the engine and one bucket met OUTSIDE any step (what a
+    warm-up does), and what the adapter counted."""
+    from ray_tpu.serve.llm import LLMServer
+    from ray_tpu.serve.llm.engine import Sequence
+    t_before = time.time()
+    server = LLMServer(request.param, engine_config=ENGINE)
+    t_after = time.time()
+    try:
+        serve(server.engine, PROMPTS[:2])
+        outside = None
+        if request.param != "toy":
+            seqs = []
+            for i in range(3):      # a prefill bucket no request reached
+                server.engine.cache.allocate(f"warm-{i}", 12)
+                seqs.append(Sequence(f"warm-{i}", None, [1] * 11,
+                                     SamplingParams(max_new_tokens=1)))
+            server.adapter.prefill(seqs)
+            for seq in seqs:
+                server.adapter.release(seq.seq_id)
+                server.engine.cache.free(seq.seq_id)
+            outside = (4, 16)
+        metrics = server.__llm_metrics__()
+        return {"kind": request.param, "setup": metrics["setup"],
+                "metrics": metrics, "between": (t_before, t_after),
+                "outside": outside, "buckets": [
+                    k for k in getattr(server.adapter, "_fns", ())
+                    if isinstance(k, tuple)]}
+    finally:
+        server.engine.stop()
+
+
+def test_the_setup_tree_lies_in_order_inside_its_parent(served_setup):
+    import json
+    setup = served_setup["setup"]
+    assert set(setup) == {"process_t0", "spans", "first_calls", "programs",
+                          "counters"}
+    json.dumps(setup)                           # plain data, as it is
+    (root,) = setup["spans"]
+    t_before, t_after = served_setup["between"]
+    assert root["name"] == "llm.setup"
+    assert root["attrs"]["model"] == served_setup["kind"]
+    assert root["attrs"]["kind"] == (
+        "ToyAdapter" if served_setup["kind"] == "toy"
+        else "FlaxModelAdapter")
+    assert setup["process_t0"] < t_before <= root["t0"] <= root["t1"] \
+        <= t_after
+    children = spans_of(root)[0]
+    assert [c["name"] for c in children] == list(
+        SETUP[2:] if served_setup["kind"] == "toy" else SETUP)
+    end = root["t0"]
+    for child in children:                      # in order, inside, disjoint
+        assert end <= child["t0"] <= child["t1"] <= root["t1"]
+        end = child["t1"]
+    by_name = {c["name"]: c for c in children}
+    assert by_name["llm.setup.cache"]["attrs"]["bytes"] > 0
+    if served_setup["kind"] != "toy":
+        assert by_name["llm.setup.adapter"]["attrs"] == {"kind": "gpt2"}
+        assert by_name["llm.setup.params"]["attrs"]["bytes"] > 0
+    assert setup["counters"] == {
+        k: served_setup["metrics"][k] for k in setup["counters"]}
+
+
+def test_every_bucket_leaves_one_first_call_record(served_setup):
+    setup = served_setup["setup"]
+    if served_setup["kind"] == "toy":
+        assert setup["first_calls"] == []   # (programs: the process's)
+        return
+    calls = setup["first_calls"]
+    assert all(c["name"] == "runner.dispatch" and c["attrs"]["first_call"]
+               for c in calls)
+    met = [(c["attrs"]["B"], c["attrs"]["S"]) for c in calls]
+    assert sorted(met) == sorted((B, S) for B, S, _ in
+                                 served_setup["buckets"])
+    assert len(met) == len(set(met)) \
+        == served_setup["metrics"]["bucket_first_calls_total"]
+    # the one met outside a step is among them, and in no step's tree
+    assert served_setup["outside"] in met
+    in_steps = {(s["attrs"]["B"], s["attrs"]["S"])
+                for st in served_setup["metrics"]["step_log"]
+                for s in walk(st) if s["name"] == "runner.dispatch"}
+    assert served_setup["outside"] not in in_steps
+    funs = {r["fun"] for r in setup["programs"]}
+    for call in calls:
+        # the bucket's own three stages are among its children, and
+        # nothing but jax's events
+        assert {c["name"] for c in call["children"]} == {
+            "jax.trace", "jax.lower", "jax.compile"}
+        name = "jit_" + bucket_name(call["attrs"]["B"], call["attrs"]["S"])
+        assert [c["name"] for c in call["children"]
+                if c["attrs"]["fun"] == name] == [
+                    "jax.trace", "jax.lower", "jax.compile"]
+        assert name in funs
+        assert all(call["t0"] <= c["t0"] <= c["t1"] <= call["t1"]
+                   for c in call["children"])
+
+
+def test_runner_ms_is_the_runner_spans_of_the_step(run):
+    """``llm.step`` says ``runner_ms``: the ``runner.*`` spans that ran
+    between its ends. Over a run their sum is every runner span's, a
+    prompt's late fetch counted once, in the step that waited for it."""
+    steps = run["steps"]
+    assert all(s["attrs"]["runner_ms"] >= 0.0 for s in steps)
+    assert all(s["attrs"]["runner_ms"] <= ms(s) + 1e-6 for s in steps)
+    spans = [s for st in steps for s in walk(st)
+             if s["name"].startswith("runner.")]
+    assert sum(s["attrs"]["runner_ms"] for s in steps) == pytest.approx(
+        sum(map(ms, spans)))
+    if not run["kind"].startswith("toy"):
+        assert spans and sum(s["attrs"]["runner_ms"] for s in steps) > 0
+
+
 # ------------------------------------------------------------ step_span
 
 def test_step_span_nests_by_thread_and_fills_the_ring():
@@ -447,10 +568,12 @@ def test_a_first_call_compiles_under_the_span_that_made_it():
     first, second = (
         [e for e in step["children"][0]["children"] if e["name"] != "py.gc"]
         for step in ring)
-    assert [e["name"] for e in first] == ["jax.compile"] and second == []
+    assert [e["name"] for e in first] == [
+        "jax.trace", "jax.lower", "jax.compile"] and second == []
+    assert len({e["attrs"]["fun"] for e in first}) == 1
     dispatch = ring[0]["children"][0]
-    assert dispatch["t0"] <= first[0]["t0"] < first[0]["t1"] \
-        <= dispatch["t1"]
+    assert all(dispatch["t0"] <= e["t0"] < e["t1"] <= dispatch["t1"]
+               for e in first)
     after = tracing.process_counters()
     assert after["compiles_total"] == before["compiles_total"] + 1
     assert after["compile_seconds_total"] > before["compile_seconds_total"]
@@ -606,6 +729,58 @@ def test_the_feed_records_its_batches():
 
 
 # ------------------------------------- names on the device trace itself
+
+def test_a_train_worker_records_its_setup_and_logs_it_once(caplog):
+    """``make_causal_lm_trainer`` runs under ``train.setup.build`` and
+    ``trainer.init`` under ``train.setup.init`` (``trainer.step`` stays
+    the jitted function: its first call is its row of ``programs()``);
+    the worker's first ``session.report`` logs the set-up in one line."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.air import session
+    from ray_tpu.models.gpt2 import GPT2Config
+    from ray_tpu.parallel.mesh import MeshSpec
+    from ray_tpu.train.spmd import make_causal_lm_trainer
+    spec = MeshSpec()
+    t0 = time.time()
+    trainer = make_causal_lm_trainer(
+        GPT2Config.tiny(), mesh=spec.build(jax.devices()[:1]), spec=spec)
+    state = trainer.init(jax.random.PRNGKey(0))
+    assert hasattr(trainer.step, "lower")       # jitted, not wrapped
+    batch = {k: jnp.zeros((2, 16), jnp.int32)
+             for k in ("input_ids", "labels")}
+    state, metrics = trainer.step(state, batch)
+    jax.block_until_ready(metrics["loss"])
+    report = tracing.setup_report()
+    build, init = [s for s in report["spans"] if s["t0"] >= t0
+                   and s["name"].startswith("train.setup.")]
+    assert (build["name"], init["name"]) == (
+        "train.setup.build", "train.setup.init")
+    assert report["process_t0"] < build["t0"] <= build["t1"] <= init["t0"]
+    stages = [(e["name"], e["attrs"]["fun"]) for e in init["children"]
+              if e["name"].startswith("jax.")]
+    assert stages[-3:] == [("jax.trace", "jit_init_fn"),
+                           ("jax.lower", "jit_init_fn"),
+                           ("jax.compile", "jit_init_fn")]
+    rows = {r["fun"]: r for r in report["programs"]}
+    assert rows["jit_train_step"]["compiles"] >= 1
+    assert rows["jit_train_step"]["t_last"] >= init["t1"]
+    held = session._Session()
+    session._set_session(held)
+    try:
+        with caplog.at_level(logging.INFO, logger="ray_tpu.air.session"):
+            session.report({"loss": 1.0})
+            session.report({"loss": 0.5})
+    finally:
+        session._set_session(None)
+    lines = [r.getMessage() for r in caplog.records
+             if r.name == "ray_tpu.air.session"]
+    assert len(lines) == 1 and "\n" not in lines[0]
+    assert lines[0].startswith("set-up: process start to ")
+    assert "train.setup.build" in lines[0] and "train.setup.init" in lines[0]
+    assert "programs: trace " in lines[0]
+    assert held.result_queue.qsize() == 2
+
 
 def pallas_call_names(jaxpr, outer=""):
     """``name`` of every pallas_call in a jaxpr with the scopes it was

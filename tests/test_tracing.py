@@ -541,3 +541,250 @@ def test_timeline_drop_counter_and_flusher_stop():
     timeline.record_task("again", time.time(), time.time() + 1e-4)
     assert flusher_threads()
     timeline.stop_flusher()
+
+
+# ------------------------------------- before a process is ready
+# (docs/TRACING.md, "Before a process is ready": the compile pipeline as
+# step events and a table by program)
+
+def _jax_events(span):
+    return [c for c in span["children"] if c["name"].startswith("jax.")]
+
+
+def test_a_first_call_is_traced_lowered_and_compiled_under_the_open_span():
+    from collections import deque
+
+    import jax
+    import jax.numpy as jnp
+    tracing.watch_process()
+
+    @jax.jit
+    def inside_it(x):
+        return jnp.tanh(x) * 5.0
+
+    @jax.jit
+    def test_tracing_first_call(x):
+        return inside_it(x) + inside_it(x + 1.0)
+
+    x = jnp.ones((3, 7))
+    x.block_until_ready()
+    before = tracing.process_counters()
+    ring = deque()
+    with tracing.step_span("runner.dispatch", ring, first_call=True) as span:
+        test_tracing_first_call(x).block_until_ready()
+    events = _jax_events(ring[0])
+    assert [e["name"] for e in events] == [
+        "jax.trace", "jax.lower", "jax.compile"]
+    # the function traced inside the other's trace is no event of its own
+    assert {e["attrs"]["fun"] for e in events} == {
+        "jit_test_tracing_first_call"}
+    assert events[2]["attrs"]["cache"] in ("hit", "miss", "off")
+    assert all(span.rec["t0"] <= e["t0"] <= e["t1"] <= span.rec["t1"]
+               for e in events)
+    assert [e["t1"] for e in events] == sorted(e["t1"] for e in events)
+    after = tracing.process_counters()
+    assert after["compiles_total"] == before["compiles_total"] + 1
+    for key, e in (("trace_seconds_total", events[0]),
+                   ("lower_seconds_total", events[1]),
+                   ("compile_seconds_total", events[2])):
+        assert after[key] - before[key] == pytest.approx(
+            e["t1"] - e["t0"], abs=1e-5)
+    rows = {r["fun"]: r for r in tracing.programs()}
+    outer, inner = rows["jit_test_tracing_first_call"], rows["jit_inside_it"]
+    assert (outer["n"], outer["compiles"], outer["nested_trace_s"]) \
+        == (1, 1, 0.0)
+    assert outer["trace_s"] > 0 and outer["lower_s"] > 0 \
+        and outer["compile_s"] > 0
+    assert outer["t_first"] <= outer["t_last"] <= span.rec["t1"]
+    # traced twice inside the outer trace, never lowered or compiled alone
+    assert inner["n"] == 2 and inner["compiles"] == 0
+    assert inner["nested_trace_s"] == inner["trace_s"] <= outer["trace_s"]
+
+
+def test_programs_sums_by_fun_and_its_cap_overflows_visibly(monkeypatch):
+    monkeypatch.setattr(tracing, "_programs", {})
+    monkeypatch.setattr(tracing, "PROGRAMS_CAP", 2)
+    for name, seconds in (("a", 1.0), ("b", 2.0), ("a", 4.0), ("c", 8.0),
+                          ("d", 16.0)):
+        tracing._on_jax_duration(tracing.TRACE_EVENT, seconds, fun_name=name)
+        tracing._on_jax_duration(tracing.LOWER_EVENT, seconds / 2,
+                                 fun_name=f"jit({name})")
+        tracing._on_jax_event("/jax/compilation_cache/cache_hits"
+                              if name == "a" else
+                              "/jax/compilation_cache/cache_misses")
+        tracing._on_jax_duration(tracing.COMPILE_EVENT, seconds / 4,
+                                 fun_name=f"jit({name})")
+    rows = {r["fun"]: r for r in tracing.programs()}
+    assert list(rows) == ["jit_a", "jit_b", tracing.OTHER_PROGRAMS]
+    assert (rows["jit_a"]["n"], rows["jit_a"]["trace_s"],
+            rows["jit_a"]["lower_s"], rows["jit_a"]["compile_s"],
+            rows["jit_a"]["cache_hits"], rows["jit_a"]["cache_misses"]) \
+        == (2, 5.0, 2.5, 1.25, 2, 0)
+    other = rows[tracing.OTHER_PROGRAMS]       # what c and d cost
+    assert (other["n"], other["trace_s"], other["compiles"],
+            other["cache_misses"]) == (2, 24.0, 2, 2)
+    assert rows["jit_b"]["t_first"] <= rows["jit_b"]["t_last"] \
+        <= other["t_last"]
+
+
+def test_a_warm_call_reaches_no_listener():
+    import jax
+    import jax.numpy as jnp
+    tracing.watch_process()
+    fn = jax.jit(lambda x: jnp.cos(x) * 7.0 + 2.0)
+    x = jnp.ones((5,))
+    fn(x).block_until_ready()
+    fired = []
+
+    def count(*args, **kwargs):
+        fired.append(args[0])
+
+    registered = (
+        (jax.monitoring.register_event_listener,
+         jax.monitoring.unregister_event_listener),
+        (jax.monitoring.register_event_duration_secs_listener,
+         jax.monitoring.unregister_event_duration_listener),
+        (jax.monitoring.register_scalar_listener,
+         jax.monitoring.unregister_scalar_listener),
+        (jax.monitoring.register_event_time_span_listener,
+         jax.monitoring.unregister_event_time_span_listener))
+    for register, _ in registered:
+        register(count)
+    try:
+        before = tracing.process_counters()
+        for _ in range(100):
+            fn(x)
+        fn(x).block_until_ready()
+    finally:
+        for _, unregister in registered:
+            unregister(count)
+    assert fired == []
+    after = tracing.process_counters()
+    assert {k: after[k] for k in after if not k.startswith("gc_")} \
+        == {k: before[k] for k in before if not k.startswith("gc_")}
+
+
+def test_tracing_off_keeps_the_counters_and_drops_the_records(monkeypatch):
+    from collections import deque
+
+    import jax
+    import jax.numpy as jnp
+    tracing.watch_process()
+
+    @jax.jit
+    def test_tracing_switched_off(x):
+        return jnp.sin(x) - 11.0
+
+    x = jnp.ones((2, 9))
+    x.block_until_ready()
+    monkeypatch.setenv("RTPU_TRACING", "0")
+    tracing.refresh()
+    try:
+        before = tracing.process_counters()
+        roots = len(tracing.step_roots())
+        ring = deque()
+        with tracing.step_span("runner.dispatch", ring):
+            test_tracing_switched_off(x).block_until_ready()
+        assert not ring and len(tracing.step_roots()) == roots
+    finally:
+        monkeypatch.undo()
+        tracing.refresh()
+    after = tracing.process_counters()
+    assert after["compiles_total"] == before["compiles_total"] + 1
+    assert after["trace_seconds_total"] > before["trace_seconds_total"]
+    assert after["lower_seconds_total"] > before["lower_seconds_total"]
+    (row,) = [r for r in tracing.programs()
+              if r["fun"] == "jit_test_tracing_switched_off"]
+    assert row["n"] == row["compiles"] == 1
+
+
+_CACHE_PROBE = """
+import json, sys
+from collections import deque
+import jax, jax.numpy as jnp
+from ray_tpu._private import tracing
+tracing.watch_process()
+
+@jax.jit
+def cache_probe(x):
+    return jnp.tanh(x @ x.T) * 3.0
+
+ring = deque()
+with tracing.step_span("root", ring):
+    cache_probe(jnp.ones((8, 8))).block_until_ready()
+(event,) = [c for c in ring[0]["children"] if c["name"] == "jax.compile"
+            and c["attrs"]["fun"] == "jit_cache_probe"]
+(row,) = [r for r in tracing.programs() if r["fun"] == "jit_cache_probe"]
+print(json.dumps({"attrs": event["attrs"], "row": row,
+                  "counters": tracing.process_counters()}))
+"""
+
+
+@pytest.fixture(scope="module")
+def cache_probes(tmp_path_factory):
+    """The same program compiled in three fresh processes: twice with one
+    temporary compile cache directory, then with the cache switched off."""
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root,
+               JAX_COMPILATION_CACHE_DIR=str(
+                   tmp_path_factory.mktemp("compile_cache")),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    out = []
+    for extra in ({}, {}, {"JAX_ENABLE_COMPILATION_CACHE": "false"}):
+        done = subprocess.run(
+            [sys.executable, "-c", _CACHE_PROBE], env=dict(env, **extra),
+            capture_output=True, text=True, timeout=240)
+        assert done.returncode == 0, done.stderr[-2000:]
+        out.append(json.loads(done.stdout.strip().splitlines()[-1]))
+    return out
+
+
+@pytest.mark.parametrize("process,cache", [(0, "miss"), (1, "hit"),
+                                           (2, "off")])
+def test_a_compile_says_what_the_persistent_cache_said(
+        cache_probes, process, cache):
+    got = cache_probes[process]
+    assert got["attrs"]["cache"] == cache
+    assert ("retrieval_ms" in got["attrs"]) == (cache == "hit")
+    assert (got["row"]["cache_hits"], got["row"]["cache_misses"]) \
+        == (int(cache == "hit"), int(cache == "miss"))
+    counters = got["counters"]
+    assert counters["compile_cache_hits_total"] >= got["row"]["cache_hits"]
+    assert (counters["compile_cache_hits_total"] > 0) == (cache == "hit")
+    assert (counters["compile_cache_misses_total"] > 0) == (cache == "miss")
+    assert (counters["compile_cache_retrieval_seconds_total"] > 0) \
+        == (cache == "hit")
+
+
+def test_process_t0_is_before_this_module_and_after_the_machine_started():
+    t0 = tracing.process_t0()
+    assert t0 == tracing.process_t0()              # read once
+    assert t0 <= tracing._T_IMPORT + 0.05
+    assert time.time() - t0 < 6 * 3600             # this test process
+
+
+def test_setup_span_keeps_its_roots_and_describe_setup_is_one_line():
+    import json
+
+    @tracing.setup_span("test.setup.build")
+    def build(n):
+        with tracing.step_span("test.setup.inner", n=n):
+            return n + 1
+
+    assert build(2) == 3 and build.__name__ == "build"
+    report = tracing.setup_report()
+    (root,) = [s for s in report["spans"] if s["name"] == "test.setup.build"]
+    assert [c["name"] for c in root["children"]
+            if c["name"] != "py.gc"] == ["test.setup.inner"]
+    assert report["process_t0"] <= root["t0"]
+    assert set(report) == {"process_t0", "spans", "first_calls", "programs",
+                           "counters"}
+    json.dumps(report)
+    line = tracing.describe_setup()
+    assert "\n" not in line and line.startswith("set-up: process start to ")
+    assert "test.setup.build" in line and "programs: trace" in line
