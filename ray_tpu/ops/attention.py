@@ -1637,82 +1637,197 @@ def latent_attention(q_nope, q_rope, latent, w_kvb, q_positions, *,
     return jnp.moveaxis(out, 0, 1).reshape(B, S, H, v_dim)
 
 
+# table entries a chunk's copies go by, in both decode kernels over pages
+# (``paged_attention_decode``, ``latent_attention_decode``): a page's copy
+# costs the core's scalar unit as much to start and wait for as the memory
+# needs to bring 16 KB, so where a group of this many entries names
+# consecutive pages of the pool (``serve/llm/kv_cache.py`` hands a sequence
+# its pages as ascending runs), one copy a pool brings them all
+PAGED_RUN_PAGES = 8
+
+
+def _row_after(len_ref, B, b):
+    """The next row after ``b`` that holds a token (B: none does)."""
+    return jax.lax.while_loop(
+        lambda r: (r < B) & (len_ref[jnp.minimum(r, B - 1)] == 0),
+        lambda r: r + 1, b + 1)
+
+
+def _live_groups(base, live, pages, E, body, ring=None):
+    """``body(g, x)`` for every group ``g`` of ``E`` table entries, of the
+    chunk of ``pages`` that starts at logical page ``base``, that holds
+    one of the row's ``live`` pages; ``x``: the place in the table of the
+    group's first entry (in a ring of ``ring`` pages, one remainder a
+    chunk)."""
+    from jax.experimental import pallas as pl
+    at = base if ring is None else jax.lax.rem(base, ring)
+
+    def group(g, carry):
+        @pl.when(base + g * E < live)
+        def _():
+            x = at + g * E
+            if ring is not None:
+                x = jnp.where(x >= ring, x - ring, x)
+            body(g, x)
+        return carry
+    jax.lax.fori_loop(0, pages // E, group, 0)
+
+
+def _start_group(pools, sem, layer, bt_ref, b, slot, g, x, E, end,
+                 ring=False):
+    """Group ``g`` of a chunk of row ``b``, the ``E`` table entries from
+    place ``x``, on its way into ``slot`` of every pool's buffer
+    (``pools``: one ``(pool in HBM, buffer [2, pages, bs, C])`` or two;
+    ``sem`` [2, pools]). Where the table holds the ``E`` entries before
+    its end (or the ring's wrap) and they are ``p, p + 1, ..., p + E - 1``
+    (every difference is checked: a table with a shared prefix or a
+    copied page is not sorted) ONE copy a pool brings ``[p, p + E)`` of
+    the layer; any other group takes a copy a page, from the entry at
+    ``min(x + i, end)`` (in a ring: at ``x + i`` wrapped). Either way a
+    pool's semaphore has the group's ``E`` pages to count."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    NB = bt_ref.shape[1]
+    whole = x <= NB - E
+    at = jnp.minimum(x, NB - E)
+    p = bt_ref[b, at]
+    is_run = whole
+    for i in range(1, E):
+        is_run &= bt_ref[b, at + i] == p + i
+
+    @pl.when(is_run)
+    def _():
+        for s, (hbm, buf) in enumerate(pools):
+            pltpu.make_async_copy(
+                hbm.at[layer, pl.ds(p, E)],
+                buf.at[slot, pl.ds(g * E, E)],
+                sem.at[slot, s]).start()
+
+    @pl.when(jnp.logical_not(is_run))
+    def _():
+        def page(i, carry):
+            if not ring:
+                y = jnp.minimum(x + i, end)
+            else:
+                y = jnp.where(x + i >= NB, x + i - NB, x + i)
+            for s, (hbm, buf) in enumerate(pools):
+                pltpu.make_async_copy(
+                    hbm.at[layer, bt_ref[b, y]],
+                    buf.at[slot, g * E + i],
+                    sem.at[slot, s]).start()
+            return carry
+        jax.lax.fori_loop(0, E, page, 0)
+
+
+def _wait_group(pools, sem, slot, g, E):
+    """Group ``g``'s pages are in ``slot`` of every pool's buffer: a
+    semaphore counts what its copies brought, a group's fill its pages of
+    the buffer however they were copied, so one wait a pool for that many
+    bytes sees them all in."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    for s, (_, buf) in enumerate(pools):
+        part = buf.at[slot, pl.ds(g * E, E)]
+        pltpu.make_async_copy(part, part, sem.at[slot, s]).wait()
+
+
+def _run_pages(pages: int) -> int:
+    """Entries a group of a chunk of ``pages`` holds: ``PAGED_RUN_PAGES``,
+    or (a chunk of a table narrower than it is that table) the largest
+    count under it that divides the chunk."""
+    return max(e for e in range(1, PAGED_RUN_PAGES + 1) if pages % e == 0)
+
+
 # latent rows the decode kernel attends to at a time, in bytes. A page of
 # this pool is small (16 rows of 640 bfloat16 values: 20 KB, 25 ns of the
 # chip's memory bandwidth), so a chunk is sized by what it holds, not by
-# a count of tokens: a chunk costs ~0.4 us beside its rows (the loop, the
-# rescaling of the sums; my chip runs, PR 36), which 64 such pages (1,024
-# tokens, 1.6 us of copies) carry and 16 would not
+# a count of tokens: a chunk costs ~0.2 us beside its rows and a row ~1 us
+# (the loop, the rescaling of the sums, the products of a last chunk's
+# empty places), which 64 such pages carry and 16 do not. Read on the
+# chip (PR 56, the kernel alone on the device's clock, tables as the
+# allocator leaves them, 99% of their groups runs; a layer's call in ms
+# at chunks of 256 / 512 / 1,024 / 2,048 tokens, the bytes at 819 GB/s
+# in brackets, the copy-a-page kernel before it in parentheses): 32 rows
+# of ~6,100 tokens under 64 heads 0.641 / 0.457 / 0.386 / 0.384 [0.307]
+# (0.721 / 0.555 / 0.503); 64 rows of ~1,950 under 64 heads 0.441 /
+# 0.330 / 0.293 / 0.295 [0.196] (0.491 / 0.397 / 0.382); 64 rows of
+# ~1,560 under 32 heads 0.344 / 0.248 / 0.223 / 0.222 [0.157] (0.386 /
+# 0.301 / 0.296). One size serves the three; past it two buffers double
+# for nothing. Without its copies the kernel takes 0.250 / 0.194 / 0.139
+# ms at this size and without its two products 0.353 / 0.251 / 0.195:
+# the copies' side is the longer, and the whole within 9-17% of it. One
+# wait a buffer for a chunk whose groups are all live, in place of one a
+# group, read 0.381 / 0.290 / 0.222: under 1.5%, not worth a second
+# path. Tables with NO run (shuffled pages) read 0.789 / 0.550 / 0.426
+# where a straight line of 64 copies read 0.503 / 0.382 / 0.296: the
+# allocator is held to runs
+# (tests/test_llm_kernels_decode.py:test_tables_stay_runs_under_a_cells_churn)
 _LATENT_CHUNK_BYTES = 1280 << 10
 
 
-def _latent_decode_kernel(layer_ref, bt_ref, len_ref, last_ref, chunks_ref,
+def _latent_decode_kernel(layer_ref, bt_ref, len_ref, live_ref, chunks_ref,
                           q_ref, pool, o_ref, buf, sem, slot_ref, m_ref,
-                          l_ref, acc_ref, *, block_size, pages, rank,
+                          l_ref, acc_ref, *, block_size, pages, run, rank,
                           sm_scale):
     """Grid step ``b``: row b's absorbed query [H, W] against its live
     latent pages, a chunk of ``pages`` pages at a time.
 
-    The pool stays in HBM. The block table names a chunk's pages, each
-    copied by a DMA of its own into one of two [T, W] buffers while the
-    other is attended to; the first chunk of the next row that holds a
-    token goes under the last chunk of this one (buffers, semaphores and
-    the slot in use outlive a grid step). A chunk wholly past a row's
-    length is neither copied nor looked at; the last chunk's places past
-    the row's last live page (``last_ref``: its place in the table;
-    like ``chunks_ref``, the row's chunks, reckoned outside: a division
-    costs the core's scalar unit a third of a microsecond) take that page
-    again, masked: a chunk is then always ``pages`` copies, issued as
-    straight-line code (a third less time on the chip than a loop over
-    the live ones; my chip runs, PR 36) and waited for at once, and no
-    page is read that does not hold one of the row's tokens. The values
-    are the first ``rank`` columns of the same buffer. A row of length 0
-    visits no chunk: zeros.
+    The pool stays in HBM. The block table names a chunk's pages, copied
+    into one of two [pages, bs, W] buffers while the other is attended
+    to; the first chunk of the next row that holds a token goes under the
+    last chunk of this one (buffers, semaphores and the slot in use
+    outlive a grid step). A chunk is ``pages / run`` groups of ``run``
+    table entries, copied as ``paged_attention_decode`` copies its own
+    (``_start_group``): where a group's entries name ``run`` consecutive
+    pages ONE copy brings the group, any other group takes a copy a page,
+    and either way one wait stands for the group's bytes. A group wholly
+    past a row's last live page (``live_ref``: the pages that hold one of
+    its tokens; like ``chunks_ref``, the row's chunks, reckoned outside:
+    a division costs the core's scalar unit a third of a microsecond) is
+    neither copied nor waited for: the buffers are zeroed once, before
+    the first row, so what such a group leaves in them is finite (zeros,
+    or latent rows an earlier chunk brought), and its probabilities,
+    exactly 0 under the mask by ``length``, keep it out of the sums. A
+    chunk wholly past a row's length is not looked at. In a live group
+    that is no run, the places past the row's last live page take that
+    page again, masked (a table's unused entries are the null page, which
+    is never read); a live group that is a run brings the pages the row
+    holds and has yet to write, masked the same. The values are the first
+    ``rank`` columns of the same buffer. A row of length 0 visits no
+    chunk: zeros.
     """
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     b, B = pl.program_id(0), pl.num_programs(0)
     T = pages * block_size
+    W = buf.shape[-1]
     layer = layer_ref[0]
+    pools = ((pool, buf),)
 
     def start(r, c, slot):
         """Chunk c of row r on its way into buffer ``slot``."""
-        first, last = c * pages, last_ref[r]
+        live = live_ref[r]
+        _live_groups(c * pages, live, pages, run,
+                     lambda g, x: _start_group(pools, sem, layer, bt_ref, r,
+                                               slot, g, x, run, live - 1))
 
-        def page(i, _):
-            pltpu.make_async_copy(
-                pool.at[layer, bt_ref[r, jnp.minimum(first + i, last)]],
-                buf.at[slot, pl.ds(pl.multiple_of(i * block_size,
-                                                  block_size), block_size)],
-                sem.at[slot]).start()
-            return _
-        # (traced once, lowered to straight-line code)
-        jax.lax.fori_loop(0, pages, page, 0, unroll=True)
-
-    def wait(slot):
-        # a semaphore counts what its copies brought: a chunk's fill the
-        # buffer, so one wait for a buffer's worth sees them all in
-        pltpu.make_async_copy(buf.at[slot], buf.at[slot],
-                              sem.at[slot]).wait()
-
-    def row_after(r):
-        """The next row that holds a token (B: none does)."""
-        return jax.lax.while_loop(
-            lambda n: (n < B) & (len_ref[jnp.minimum(n, B - 1)] == 0),
-            lambda n: n + 1, r + 1)
+    def wait(r, c, slot):
+        _live_groups(c * pages, live_ref[r], pages, run,
+                     lambda g, x: _wait_group(pools, sem, slot, g, run))
 
     @pl.when(b == 0)
     def _():
+        buf[...] = jnp.zeros_like(buf)
         slot_ref[0] = 0
-        first = row_after(-1)
+        first = _row_after(len_ref, B, -1)
 
         @pl.when(first < B)
         def _():
             start(first, 0, 0)
 
     length, n = len_ref[b], chunks_ref[b]
-    after = row_after(b)
+    after = _row_after(len_ref, B, b)
     q = q_ref[...]
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -1725,10 +1840,9 @@ def _latent_decode_kernel(layer_ref, bt_ref, len_ref, last_ref, chunks_ref,
         @pl.when(nr < B)
         def _():
             start(nr, jnp.where(more, c + 1, 0), 1 - slot)
-        wait(slot)
-        rows = buf.at[slot]
+        wait(b, c, slot)
         s = jax.lax.dot_general(
-            q, rows[...], (((1,), (1,)), ((), ())),
+            q, buf[slot].reshape(T, W), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale       # [H, T]
         pos = c * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(pos < length, s, _NEG_INF)
@@ -1739,7 +1853,7 @@ def _latent_decode_kernel(layer_ref, bt_ref, len_ref, last_ref, chunks_ref,
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p.astype(buf.dtype), rows[:, :rank],
+            p.astype(buf.dtype), buf[slot, :, :, :rank].reshape(T, rank),
             preferred_element_type=jnp.float32)
         return 1 - slot
 
@@ -1786,7 +1900,8 @@ def _latent_decode_call(layer, block_tables, lengths, q, pages, *, rank,
     n_pages = max(1, min(
         _LATENT_CHUNK_BYTES // (bs * W * pages.dtype.itemsize), NB))
     kernel = functools.partial(_latent_decode_kernel, block_size=bs,
-                               pages=n_pages, rank=rank, sm_scale=sm_scale)
+                               pages=n_pages, run=_run_pages(n_pages),
+                               rank=rank, sm_scale=sm_scale)
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1796,8 +1911,8 @@ def _latent_decode_call(layer, block_tables, lengths, q, pages, *, rank,
             out_specs=pl.BlockSpec((None, H, rank),
                                    lambda b, *_: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, n_pages * bs, W), pages.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((2, n_pages, bs, W), pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 1)),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, 1), jnp.float32),
@@ -1809,8 +1924,7 @@ def _latent_decode_call(layer, block_tables, lengths, q, pages, *, rank,
         interpret=interpret,
         name="latent_attention_decode",
     )
-    return call(layer, block_tables, lengths,
-                jnp.maximum(lengths - 1, 0) // bs,
+    return call(layer, block_tables, lengths, -(-lengths // bs),
                 -(-lengths // (n_pages * bs)), q, pages)
 
 
@@ -1893,13 +2007,6 @@ _PAGED_CHUNK_TOKENS = 256
 _PAGED_NARROW_ROW_BYTES = 2048
 _PAGED_NARROW_CHUNK_TOKENS = 512
 
-# table entries a chunk's copies go by: a page's copy costs the core's
-# scalar unit as much to start and wait for as the memory needs to bring
-# 16 KB, so where a group of this many entries names consecutive pages of
-# the pool (``serve/llm/kv_cache.py`` hands a sequence its pages as
-# ascending runs), one copy a pool brings them all
-PAGED_RUN_PAGES = 8
-
 
 def paged_chunk_tokens(row_bytes: int) -> int:
     """Tokens a chunk of ``paged_attention_decode`` for a pool whose rows
@@ -1944,7 +2051,6 @@ def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
     lane tile.
     """
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, G, C = q_ref.shape
     NB = bt_ref.shape[1]
@@ -1961,77 +2067,25 @@ def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
 
     def live_groups(b, c, body):
         """``body(g, x)`` for every group ``g`` of chunk ``c`` of row
-        ``b`` that holds a live page; ``x``: the place in the table of
-        the group's first entry (in a ring, one remainder a chunk)."""
+        ``b`` that holds a live page; ``x``: the group's place in the
+        table."""
         base = first_page(b) + c * pages
         live = (len_ref[b] + block_size - 1) // block_size
-        at = base if window is None else jax.lax.rem(base, NB)
-
-        def group(g, carry):
-            @pl.when(base + g * E < live)
-            def _():
-                x = at + g * E
-                if window is not None:
-                    x = jnp.where(x >= NB, x - NB, x)
-                body(g, x)
-            return carry
-        jax.lax.fori_loop(0, pages // E, group, 0)
+        _live_groups(base, live, pages, E, body,
+                     None if window is None else NB)
 
     def start(b, c, slot):
         """Chunk ``c`` of row ``b`` on its way into buffer ``slot``."""
-        def group(g, x):
-            # the entries x .. x + E - 1, where the table holds them all
-            # before its end (or the ring's wrap)
-            whole = x <= NB - E
-            at = jnp.minimum(x, NB - E)
-            p = bt_ref[b, at]
-            is_run = whole
-            for i in range(1, E):
-                is_run &= bt_ref[b, at + i] == p + i
-
-            @pl.when(is_run)
-            def _():
-                for s, (hbm, buf) in enumerate(pools):
-                    pltpu.make_async_copy(
-                        hbm.at[layer, pl.ds(p, E)],
-                        buf.at[slot, pl.ds(g * E, E)],
-                        sem.at[slot, s]).start()
-
-            @pl.when(jnp.logical_not(is_run))
-            def _():
-                def page(i, carry):
-                    if window is None:
-                        y = jnp.minimum(x + i, NB - 1)
-                    else:
-                        y = jnp.where(x + i >= NB, x + i - NB, x + i)
-                    for s, (hbm, buf) in enumerate(pools):
-                        pltpu.make_async_copy(
-                            hbm.at[layer, bt_ref[b, y]],
-                            buf.at[slot, g * E + i],
-                            sem.at[slot, s]).start()
-                    return carry
-                jax.lax.fori_loop(0, E, page, 0)
-        live_groups(b, c, group)
+        live_groups(b, c, lambda g, x: _start_group(
+            pools, sem, layer, bt_ref, b, slot, g, x, E, NB - 1,
+            ring=window is not None))
 
     def wait(b, c, slot):
-        # a semaphore counts what its copies brought: a group's fill its
-        # pages of the buffer however they were copied, so one wait for
-        # that many bytes sees them all in
-        def group(g, x):
-            for s, (_, buf) in enumerate(pools):
-                part = buf.at[slot, pl.ds(g * E, E)]
-                pltpu.make_async_copy(part, part, sem.at[slot, s]).wait()
-        live_groups(b, c, group)
-
-    def row_after(b):
-        """The next row that holds a token (B: none does)."""
-        return jax.lax.while_loop(
-            lambda r: (r < B) & (len_ref[jnp.minimum(r, B - 1)] == 0),
-            lambda r: r + 1, b + 1)
+        live_groups(b, c, lambda g, x: _wait_group(pools, sem, slot, g, E))
 
     k_buf[...] = jnp.zeros_like(k_buf)
     v_buf[...] = jnp.zeros_like(v_buf)
-    first = row_after(-1)
+    first = _row_after(len_ref, B, -1)
 
     @pl.when(first < B)
     def _():
@@ -2050,7 +2104,7 @@ def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm,
         else:
             n = ((length + block_size - 1) // block_size - first_page(b)
                  + pages - 1) // pages
-        after = row_after(b)
+        after = _row_after(len_ref, B, b)
         qbd = jnp.zeros(acc_ref.shape, jnp.float32)
         for j in range(G):
             qbd = jnp.where(own[j], q_ref[b, pl.ds(j, 1), :], qbd)
@@ -2181,9 +2235,7 @@ def _paged_decode_call(layer, block_tables, lengths, q, k_pages, v_pages,
     G = H // Hkv
     pages = max(1, min(paged_chunk_tokens(
         C * jnp.dtype(k_pages.dtype).itemsize) // bs, NB))
-    # (a chunk of a table narrower than it is that table: its groups are
-    # then the largest that divide it)
-    run = max(e for e in range(1, PAGED_RUN_PAGES + 1) if pages % e == 0)
+    run = _run_pages(pages)
     # row h of the kernel's matrices is head h; whole sublane tiles of
     # the pool's dtype
     Hp = -(-H // 16) * 16

@@ -248,20 +248,34 @@ def test_paged_attention_decode(one_chip, H, Hkv, D):
 
 
 @pytest.mark.parametrize("L,P,B,H,NB", [(7, 18433, 32, 64, 576),
-                                        (2, 12289, 64, 32, 192)],
-                         ids=["kimi-k2-cell", "kimi-linear-cell"])
+                                        (2, 12289, 64, 32, 192),
+                                        (8, 12289, 64, 64, 192)],
+                         ids=["kimi-k2-cell", "kimi-linear-cell",
+                              "longcat-flash-cell"])
 def test_latent_attention_decode(one_chip, L, P, B, H, NB):
-    """The latent-decode kernel at both Kimi cells' real shapes: the pool
-    [L, P, 16, 640] bfloat16, the whole block table as scalar prefetch
-    ([32, 576] int32 is 74 KB of scalar memory), the layer a traced
-    scalar."""
+    """The latent-decode kernel at the three latent cells' real shapes:
+    the pool [L, P, 16, 640] bfloat16, the whole block table as scalar
+    prefetch ([32, 576] int32 is 74 KB of scalar memory), the layer a
+    traced scalar. The groups of a chunk and the pages of a group that is
+    no run are loops inside the kernel, as the paged kernel's: it starts
+    a copy at 4 sites (a run's and a page's, at the first chunk and at
+    the next one), under 1 + ``PAGED_RUN_PAGES``, and waits at 1, where
+    the parent's had 128 (2 x the 64 pages of its chunk) and 1; counted
+    in the kernel's jaxpr, each equation of which Mosaic lowers once."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    _compile(lambda q, pool, bt, ln, layer: A.latent_attention_decode(
-        q, pool, bt, ln, rank=512, sm_scale=0.1, layer=layer),
-             sds((B, H, 640), jnp.bfloat16),
-             sds((L, P, 16, 640), jnp.bfloat16), sds((B, NB), jnp.int32),
-             sds((B,), jnp.int32), sds((), jnp.int32))
+
+    def call(q, pool, bt, ln, layer):
+        return A.latent_attention_decode(
+            q, pool, bt, ln, rank=512, sm_scale=0.1, layer=layer)
+    args = (sds((B, H, 640), jnp.bfloat16),
+            sds((L, P, 16, 640), jnp.bfloat16), sds((B, NB), jnp.int32),
+            sds((B,), jnp.int32), sds((), jnp.int32))
+    _compile(call, *args)
+    text = str(jax.make_jaxpr(call)(*args))
+    assert "latent_attention_decode" in text
+    assert text.count("dma_start") == 4 <= 1 + A.PAGED_RUN_PAGES
+    assert text.count("dma_wait") == 1
 
 
 def _kda_step(sharding, B=64, slots=65, layers=6, H=32, d=128):

@@ -181,6 +181,44 @@ def test_the_kernels_groups_are_the_allocators():
     assert kv_cache.run_pages(list(range(20, 4, -1))) == 0
 
 
+def test_latent_run_pages_share_reads_the_latent_kernels_steps():
+    """The benchmark's ``latent_run_pages_share.serve``: the allocator's
+    two counts on the window's decode steps that ran the latent kernel,
+    and on no other step (a gather's, a step outside the window); nothing
+    where no such step says them."""
+    import importlib.util
+    import os
+    import types
+
+    from benchmark.harness import cells
+    spec = importlib.util.spec_from_file_location(
+        "latent_run_share", os.path.join(
+            cells.BENCH_DIR, "layer_metrics",
+            "latent_run_pages_share.serve.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+
+    def step(t0, attention, run, held, name="llm.step.decode"):
+        attrs = {"attention": attention, "kv_run_pages": run,
+                 "kv_table_pages": held}
+        return {"name": "llm.step", "t0": t0, "t1": t0 + 0.5, "children": [
+            {"name": name, "t0": t0, "t1": t0 + 0.4, "children": [
+                {"name": "runner.dispatch", "t0": t0, "t1": t0 + 0.1,
+                 "attrs": attrs, "children": []}]}]}
+    log = [step(0, "latent_kernel", 1000, 1000),        # before the window
+           step(10, "latent_kernel", 560, 576),
+           step(11, "latent_kernel", 376, 384),
+           step(12, "gather", 0, 64),
+           step(13, "latent_kernel", 8, 8, name="llm.step.prefill")]
+    obs = types.SimpleNamespace(engine_metrics={"step_log": log},
+                                t0=9.0, t1=20.0)
+    assert reader.read(obs) == 100.0 * (560 + 376) / (576 + 384)
+    obs.engine_metrics = {"step_log": [log[3]]}
+    assert reader.read(obs) is None
+    obs.engine_metrics = {}
+    assert reader.read(obs) is None
+
+
 def test_cached_attention_takes_the_kernel_by_what_it_sees(monkeypatch):
     """One token a row over the serving pool on a chip: the kernel, and
     its result is the gather's; a window of tokens, a pool the kernel
@@ -237,15 +275,17 @@ def test_cached_attention_takes_the_kernel_by_what_it_sees(monkeypatch):
 _R, _DR, _W, _DN, _DV = 512, 64, 640, 16, 16      # a row as both Kimis'
 
 
-def _latent_case(lengths, H, dtype, seed=0, tables=None, L=2):
+def _latent_case(lengths, H, dtype, seed=0, tables=None, L=2, held=None):
     """A pool [L, P, 16, W] with the rows' pages scattered in it (page 0
     the null page, pages no table reaches beyond the last), queries and
-    ``kv_b``; tables of 80 pages whose unused entries are the null page."""
+    ``kv_b``; tables of 80 pages whose unused entries are the null page
+    (``held``: the pages a row of ``tables`` holds, its unwritten ones
+    among them)."""
     import jax.numpy as jnp
     rng = np.random.RandomState(seed)
     B = len(lengths)
     need = [-(-n // 16) for n in lengths]
-    P = 1 + sum(need) + 3
+    P = _pool_pages(need if held is None else held)
     dt = jnp.dtype(dtype)
     pool = rng.randn(L, P, 16, _W).astype(np.float32)
     pool[..., _R + _DR:] = 0
@@ -307,21 +347,49 @@ def _boundaries(dtype):
     return [0, 1, 16, 17, T - 1, T, T + 1, 1280]
 
 
+def _poisoned(case, held):
+    """The case's pool with NaN in the null page and in every page no
+    table reaches (``held``: the entries a row's table holds): a copy
+    from outside a row's own pages would end in its sums."""
+    import jax.numpy as jnp
+    reached = np.zeros(case["pool"].shape[1], bool)
+    for row, n in zip(np.asarray(case["tables"]), held):
+        reached[row[:n]] = True
+    assert not reached[0] and (~reached).sum() >= 1
+    return jnp.where(reached[None, :, None, None], case["pool"], jnp.nan)
+
+
 @pytest.mark.parametrize("case", [
     "h32-bfloat16", "h64-bfloat16", "h32-float32", "h64-float32",
     "traced-layer", "descending-table", "empty-first-and-last",
-    "poisoned-pages"])
+    "poisoned-pages"] + [f"{kind}-h{H}" for kind in _TABLE_KINDS
+                         for H in (32, 64)])
 def test_latent_attention_decode_matches_the_gather(case):
     """The Pallas latent-decode kernel (interpret mode on the CPU) over
     the latent pool as stored against ``paged_gather`` +
-    ``latent_attention(absorbed=True)`` on the same pool."""
+    ``latent_attention(absorbed=True)`` on the same pool. By table kind
+    (``_tables``: any table is right, only slower), at 32 and 64 heads:
+    rows of no token, of one page, of exactly a group of 8 pages, of
+    exactly a chunk, of a chunk and one token, each holding three pages
+    it has yet to write (a run goes on past a row's last live page), the
+    null page and every page outside the tables NaN."""
     import jax
     import jax.numpy as jnp
-    H = 64 if case.startswith("h64") else 32
-    dtype = "float32" if case.endswith("float32") else "bfloat16"
+    H = 64 if case.startswith("h64") or case.endswith("-h64") else 32
+    kind = case.rsplit("-h", 1)[0]
+    dtype = "float32" if case.endswith("float32") or kind in _TABLE_KINDS \
+        else "bfloat16"
     tol = 1e-5 if dtype == "float32" else 2e-2
-    lengths, tables, layers = _boundaries(dtype), None, [1]
-    if case == "descending-table":
+    lengths, tables, layers, held = _boundaries(dtype), None, [1], None
+    if kind in _TABLE_KINDS:
+        T = _chunk_tokens(dtype)
+        lengths = [0, 16, 128, T, T + 1]
+        held = [0] + [-(-n // 16) + 3 for n in lengths[1:]]
+        tables = _tables(kind, held, 80, np.random.default_rng(H))
+        if kind == "swapped-inside":
+            assert tables[-1, 7] - tables[-1, 0] == 7 \
+                and tables[-1, 1] - tables[-1, 0] == 2
+    elif case == "descending-table":
         lengths = [1280, 100]
         tables = np.zeros((2, 80), np.int32)
         tables[0] = np.arange(87, 7, -1)
@@ -331,7 +399,7 @@ def test_latent_attention_decode_matches_the_gather(case):
     elif case == "traced-layer":
         lengths, layers = [0, 17, 1030], [0, 1, 2]
     c = _latent_case(lengths, H, dtype, seed=len(case), tables=tables,
-                     L=max(layers) + 1)
+                     L=max(layers) + 1, held=held)
     live = np.asarray(lengths) > 0
     if case == "traced-layer":
         # the layer a loop's counter, as a model that loops over stacked
@@ -341,15 +409,10 @@ def test_latent_attention_decode_matches_the_gather(case):
                 _latent_kernel(c, i)[0]),
             jnp.zeros((len(layers), len(lengths), H, _DV),
                       c["pool"].dtype))
-    elif case == "poisoned-pages":
-        # NaN in the null page and in every page no table reaches: a
-        # copy past a row's live pages would end in its sums
-        reached = np.zeros(c["pool"].shape[1], bool)
-        for row, n in zip(np.asarray(c["tables"]), lengths):
-            reached[row[:-(-n // 16)]] = True
-        assert not reached[0] and (~reached).sum() >= 4
-        poisoned = jnp.where(reached[None, :, None, None], c["pool"],
-                             jnp.nan)
+    elif case == "poisoned-pages" or held is not None:
+        # (without ``held``: a row's table holds its live pages alone, so
+        # a copy past a row's live pages would end in its sums)
+        poisoned = _poisoned(c, held or [-(-n // 16) for n in lengths])
         got, raw = _latent_kernel(c, layers[0], pool=poisoned)
         assert np.all(np.isfinite(np.asarray(raw)))
         got = got[None]
@@ -361,6 +424,43 @@ def test_latent_attention_decode_matches_the_gather(case):
         np.testing.assert_allclose(have[live], want[live], rtol=tol,
                                    atol=tol)
         assert np.all(have[~live] == 0)
+
+
+def test_latent_decode_keeps_a_skipped_groups_buffer_rows_out_of_the_sums():
+    """A group wholly past a row's last live page is never copied, so its
+    rows of the buffer hold what was there before: NaN when the kernel
+    starts (the interpreter fills a scratch buffer so, as a chip's VMEM
+    may hold anything), and after a row of whole chunks that row's
+    latents, here 1e30 a value. A first row of three tokens (seven of its
+    chunk's eight groups are skipped) and a row of one page behind a row
+    of a chunk and a page must come out finite and the gather's: the
+    buffers are zeroed before the first row, and a masked position's
+    probability is exactly 0."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def peek(o_ref, scratch):
+        o_ref[...] = scratch[...]
+    raw = pl.pallas_call(
+        peek, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
+        interpret=True)()
+    assert np.all(np.isnan(np.asarray(raw)))
+
+    T = _chunk_tokens("float32")
+    lengths = [3, T + 16, 16]
+    c = _latent_case(lengths, 32, "float32", seed=11)
+    # the long row's pages from its second group on: huge, finite
+    pool = np.asarray(c["pool"]).copy()
+    pool[:, np.asarray(c["tables"])[1, 8:T // 16 + 1]] *= 1e30
+    c["pool"] = jnp.asarray(pool)
+    got, raw = _latent_kernel(c, 1)
+    assert np.all(np.isfinite(np.asarray(raw)))
+    want = np.asarray(_latent_reference(c, 1))
+    np.testing.assert_allclose(np.asarray(got)[[0, 2]], want[[0, 2]],
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_mla_mixer_takes_the_latent_kernel_by_what_it_sees(monkeypatch):
@@ -631,7 +731,13 @@ def _closed_loop_churn(cache, pool, running, admissions, seed):
     ("laguna_xs_2", "serve_closed64_ctx8k", 0.85),
     # (10 to 58 pages a sequence: the last group of a table is rarely
     # whole, and the multiset itself allows 87.6%)
-    ("gpt2_large", "serve_closed32", 0.80)])
+    ("gpt2_large", "serve_closed32", 0.80),
+    # the latent pools (``latent_attention_decode`` takes the same groups
+    # since PR 56): tables of 304 to 576 pages, whose multiset allows
+    # 99.1%, and of 112 to 192, which allow 97.6% and 97.5%
+    ("kimi_k2_7_code", "serve_closed32_ctx8k", 0.97),
+    ("longcat_flash_omni", "serve_closed64_ctx2k", 0.95),
+    ("kimi_linear_48b_a3b", "serve_closed64", 0.95)])
 def test_tables_stay_runs_under_a_cells_churn(cell, traffic, floor):
     """After 5,000 admissions at a serving cell's pools, sequences and
     multiset of lengths, the running tables' pages still lie in whole
